@@ -7,7 +7,7 @@ import numpy as np
 
 from _common import setup
 
-setup(n_virtual=1)
+setup()
 
 import paddle_tpu as paddle                                # noqa: E402
 import paddle_tpu.nn as nn                                 # noqa: E402

@@ -13,7 +13,7 @@ import numpy as np
 
 from _common import setup
 
-jax = setup(n_virtual=1)
+jax = setup()
 
 import jax.numpy as jnp                                    # noqa: E402
 from paddle_tpu.inference.generation import (              # noqa: E402

@@ -6,7 +6,7 @@ import numpy as np
 
 from _common import setup
 
-jax = setup(n_virtual=8)
+jax = setup()
 
 import jax.numpy as jnp                                    # noqa: E402
 from jax.sharding import Mesh                              # noqa: E402

@@ -12,7 +12,7 @@ import numpy as np
 
 from _common import setup
 
-jax = setup(n_virtual=8)
+jax = setup()
 
 import jax.numpy as jnp                                   # noqa: E402
 from paddle_tpu.distributed.trainer import (MeshConfig,   # noqa: E402

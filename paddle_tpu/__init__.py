@@ -23,6 +23,12 @@ import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
 
+# the persistent compilation cache: JAX_COMPILATION_CACHE_DIR when set,
+# else a fixed .jax_cache under the checkout (core/backend.py)
+from .core.backend import configure_compile_cache as _configure_cache
+
+_configure_cache()
+
 # -- core ---------------------------------------------------------------------
 from .core.dtypes import (  # noqa: F401
     bool_ as bool, uint8, int8, int16, int32, int64, float16, bfloat16,

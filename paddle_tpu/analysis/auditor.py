@@ -110,15 +110,11 @@ def trace_artifacts(spec: ProgramSpec, x64_probe: bool = True
     art = ProgramArtifacts(spec=spec, closed=closed, in_avals=in_avals,
                            out_avals=out_avals, donated=donated)
     if x64_probe and not jax.config.jax_enable_x64:
-        try:
-            from jax.experimental import enable_x64
-            with enable_x64():
-                closed_x64 = mk(*spec.args, **spec.kwargs)
-            (art.in_avals_x64, art.out_avals_x64, _) = \
-                _flat_io(closed_x64, spec)
-            art.closed_x64 = closed_x64
-        except Exception:  # noqa: BLE001 — probe is best-effort
-            art.closed_x64 = None
+        with jax.enable_x64(True):
+            closed_x64 = mk(*spec.args, **spec.kwargs)
+        (art.in_avals_x64, art.out_avals_x64, _) = \
+            _flat_io(closed_x64, spec)
+        art.closed_x64 = closed_x64
     # note: no lower()/compile() here — every current rule reads the
     # jaxpr level (donation via pjit donated_invars), and lowering
     # would re-trace the whole program for text nothing consumes

@@ -309,7 +309,7 @@ def _collectives_spec(register: bool):
 
     fn = jax.jit(shard_map(body, mesh=mesh,
                            in_specs=P("dp", None), out_specs=P(),
-                           check_rep=False))
+                           check_vma=False))
     spec = ProgramSpec(
         name="collectives", fn=fn,
         args=(jax.ShapeDtypeStruct((2 * len(devs), 8), jnp.float32),),

@@ -542,8 +542,15 @@ def _flops_paged_decode(spec):
     return 4.0 * B * H * hd * MB * BS
 
 
+def _row_dims(spec):
+    """(B, D) of a decode kernel's residual rows, which ride as a
+    (B, 1, D) view (one (1, 1, D) block per sequence)."""
+    shape = spec.inputs[0].shape
+    return int(shape[0]), int(shape[-1])
+
+
 def _flops_decode_attn_block(spec):
-    B, D = (int(s) for s in spec.inputs[0].shape)
+    B, D = _row_dims(spec)
     Hhd = int(spec.inputs[2].shape[1])
     KVhd = int(spec.inputs[3].shape[1])
     MB = int(spec.prefetch[0][0][1])
@@ -562,7 +569,7 @@ def _flops_decode_mlp_block(spec):
 
 
 def _flops_decode_block_fused(spec):
-    B, D = (int(s) for s in spec.inputs[0].shape)
+    B, D = _row_dims(spec)
     Hhd = int(spec.inputs[2].shape[1])
     KVhd = int(spec.inputs[3].shape[1])
     F = int(spec.inputs[7].shape[1])

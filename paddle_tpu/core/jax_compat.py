@@ -1,103 +1,34 @@
-"""Version shims for renamed jax APIs — the single home.
-
-ops/ and distributed/ both need these; keeping one copy means the next
-jax rename is patched in one place instead of silently diverging the
-ring/ulysses paths from the pipeline/collective paths.
+"""The few JAX names ops/, inference/, distributed/ and analysis/ share
+that are spelled awkwardly in JAX 0.9.0 (the installed release) — bound
+once here, so the ring/ulysses paths cannot diverge from the
+pipeline/collective paths.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax keeps it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax._src.core import extend_axis_env_nd
 
 __all__ = ["shard_map", "shard_map_norep", "axis_size",
            "extend_axis_env"]
 
+#: static mesh-axis size inside shard_map/collective tracing: an
+#: axis-env lookup, so the sharded decode jaxpr carries exactly its
+#: declared collectives (regression-tested against the audit catalog's
+#: ``serving_decode_tp`` jaxpr)
+axis_size = jax.lax.axis_size
+
 
 def shard_map_norep(fn, mesh, in_specs, out_specs):
-    """shard_map without the replication check, across the jax rename
-    (check_rep -> check_vma)."""
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return shard_map(fn, check_rep=False, **kwargs)
-    except TypeError:  # jax >= 0.8 renamed the replication check
-        return shard_map(fn, check_vma=False, **kwargs)
+    """shard_map without the varying-manual-axes (replication) check."""
+    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
-# which resolver answered for a given axis name — probing the renamed
-# APIs raises/except once per CALL SITE otherwise, and the last-resort
-# psum(1, axis) fallback is worse than slow: on jax versions where the
-# literal does not constant-fold it EMITS a collective into the traced
-# body, so a decode program with several axis_size sites would carry
-# collectives its audit spec never declared
-_AXIS_SIZE_RESOLVER: dict = {}
-
-
-def _resolve_axis_size(axis_name):
-    """Try the static lookups newest-first; return (size, resolver)."""
-    try:
-        return jax.lax.axis_size(axis_name), "lax.axis_size"
-    except AttributeError:
-        pass
-    try:
-        # jax 0.4.x: the trace context's axis env answers statically
-        # (no collective in the jaxpr). Depending on the minor version
-        # axis_frame returns the size itself or a frame with .size.
-        fr = jax.core.axis_frame(axis_name)
-        return int(getattr(fr, "size", fr)), "core.axis_frame"
-    except Exception:  # noqa: BLE001 — fall through to psum
-        pass
-    # last resort only: psum of a python literal folds to the static
-    # axis size on every known version; if it ever returns a tracer we
-    # must NOT memoize it (a cached tracer outlives its trace)
-    return jax.lax.psum(1, axis_name), "lax.psum"
-
-
-def axis_size(axis_name):
-    """Static mesh-axis size inside shard_map/collective tracing.
-
-    Resolved via a STATIC axis-env lookup (``jax.lax.axis_size`` on new
-    jax, ``jax.core.axis_frame`` on 0.4.x), with the winning resolver
-    memoized per axis name so repeated call sites inside a traced body
-    neither re-probe the renamed APIs nor fall through to the
-    ``psum(1, axis)`` fallback — the sharded decode jaxpr must carry
-    exactly its declared collectives (regression-tested against the
-    audit catalog's ``serving_decode_tp`` jaxpr)."""
-    resolver = _AXIS_SIZE_RESOLVER.get(axis_name)
-    if resolver == "lax.axis_size":
-        return jax.lax.axis_size(axis_name)
-    if resolver == "core.axis_frame":
-        fr = jax.core.axis_frame(axis_name)
-        return int(getattr(fr, "size", fr))
-    size, resolver = _resolve_axis_size(axis_name)
-    if resolver != "lax.psum":   # never memoize the collective path:
-        # its result can be a tracer, and caching one leaks it
-        _AXIS_SIZE_RESOLVER[axis_name] = resolver
-    return size
-
-
-@contextlib.contextmanager
 def extend_axis_env(pairs):
-    """Bind (axis_name, size) pairs in the ambient axis env so a bare
-    collective (``psum(x, "tp")`` outside any shard_map) can TRACE —
-    the auditor uses this to trace per-shard program bodies abstractly
-    (``ProgramSpec.axis_env``) without a mesh or devices."""
-    pairs = [(str(n), int(s)) for n, s in pairs]
-    try:
-        ctx = jax.core.extend_axis_env_nd(pairs)
-    except AttributeError:
-        # older spelling: one (name, size, tag) frame at a time
-        ctx = None
-    if ctx is not None:
-        with ctx:
-            yield
-        return
-    with contextlib.ExitStack() as stack:
-        for name, size in pairs:
-            stack.enter_context(jax.core.extend_axis_env(name, size, None))
-        yield
+    """Context manager binding (axis_name, size) pairs in the ambient
+    axis env so a bare collective (``psum(x, "tp")`` outside any
+    shard_map) can TRACE — the auditor uses this to trace per-shard
+    program bodies abstractly (``ProgramSpec.axis_env``) without a mesh
+    or devices. JAX exports no public spelling of this."""
+    return extend_axis_env_nd([(str(n), int(s)) for n, s in pairs])

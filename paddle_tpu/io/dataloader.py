@@ -61,7 +61,7 @@ class _DevicePrefetchIter:
     queued ahead of the consumer, so the transfer for batch N+1 runs
     while the step consuming batch N computes. One thread serializes
     transfers — deliberate: concurrent h2d streams contend for the
-    same PCIe/tunnel bandwidth without helping latency."""
+    same host-link bandwidth without helping latency."""
 
     _END = ("end", None)
 

@@ -31,7 +31,8 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional
 
-from .compile import device_peak_flops, device_peak_hbm_bw
+from .compile import (UnknownDevicePeak, device_peak_flops,
+                      device_peak_hbm_bw)
 
 __all__ = ["kernel_cost", "roofline_point", "capture_kernel_costs",
            "decode_step_bytes", "decode_roofline",
@@ -39,9 +40,17 @@ __all__ = ["kernel_cost", "roofline_point", "capture_kernel_costs",
 
 
 def peak_snapshot() -> Dict:
-    """The labelled peak pair every roofline row prices against."""
-    flops, flops_src = device_peak_flops()
-    bw, bw_src = device_peak_hbm_bw()
+    """The labelled peak pair every roofline row prices against. On a
+    device with no peak on record (the CPU under test) both peaks are
+    ``None`` and the source says so: the modeled bytes and FLOPs still
+    report, every peak-derived field stays ``None``."""
+    def one(fn):
+        try:
+            return fn()
+        except UnknownDevicePeak as e:
+            return None, f"unknown: {e}"
+    flops, flops_src = one(device_peak_flops)
+    bw, bw_src = one(device_peak_hbm_bw)
     return {"peak_flops": flops, "peak_hbm_bw": bw,
             "peak_source": {"flops": flops_src, "hbm_bw": bw_src}}
 
@@ -81,8 +90,13 @@ def roofline_point(bytes_modeled: Optional[float],
     if has_bytes and has_flops:
         intensity = flops_modeled / bytes_modeled
         out["intensity"] = round(intensity, 3)
-        ridge = peak_flops / peak_bw
-        out["bound"] = "memory" if intensity < ridge else "compute"
+        if peak_flops and peak_bw:
+            ridge = peak_flops / peak_bw
+            out["bound"] = "memory" if intensity < ridge else "compute"
+    # a missing peak is a missing input: its side of the roofline and
+    # its achieved fraction stay None
+    has_bytes = has_bytes and bool(peak_bw)
+    has_flops = has_flops and bool(peak_flops)
     t_bw = bytes_modeled / peak_bw if has_bytes else None
     t_fl = flops_modeled / peak_flops if has_flops else None
     t_roof = max(t for t in (t_bw, t_fl) if t is not None) \
@@ -201,13 +215,14 @@ def decode_roofline(step_bytes: Dict[str, int],
     measured_us = measured_us or {}
     variants = {}
     for name, nbytes in step_bytes.items():
-        t_bw_us = nbytes / peak_bw * 1e6
         row = {"bytes_per_step": int(nbytes),
-               "step_us_at_peak_bw": round(t_bw_us, 3),
-               "achieved_bw_frac": None}
-        t = measured_us.get(name)
-        if t:
-            row["achieved_bw_frac"] = _sig4(t_bw_us / t)
+               "step_us_at_peak_bw": None, "achieved_bw_frac": None}
+        if peak_bw:
+            t_bw_us = nbytes / peak_bw * 1e6
+            row["step_us_at_peak_bw"] = round(t_bw_us, 3)
+            t = measured_us.get(name)
+            if t:
+                row["achieved_bw_frac"] = _sig4(t_bw_us / t)
         variants[name] = row
     return {"variants": variants, "peak_hbm_bw": peak_bw,
             "peak_source": peaks["peak_source"]}

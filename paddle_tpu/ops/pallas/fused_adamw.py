@@ -21,6 +21,15 @@ from ._util import (audited_pallas_call, interpret_mode as _interpret,
 from .registry import KERNELS
 
 
+#: elements per grid step. The kernel's fp32 interior (p, g, m, v and
+#: the updated values, all block-sized) lives on the scoped-VMEM stack
+#: beside the eight double-buffered block windows: compiled for v5e, a
+#: 131072-element block is refused ("RESOURCE_EXHAUSTED ... memory space
+#: vmem while allocating on stack"), 65536 compiles with bf16 moments,
+#: and 32768 compiles at every dtype mix with margin.
+BLOCK = 32768
+
+
 def _adamw_kernel(p_ref, g_ref, m_ref, v_ref, lr_ref, bc_ref,
                   *outs, b1, b2, eps, wd, shadow):
     p_out, m_out, v_out = outs[0], outs[1], outs[2]
@@ -59,7 +68,7 @@ def fused_adamw(param, grad, moment1, moment2, lr, step,
     weight training writes the bf16 model shadow for free).
     """
     n = param.shape[0]
-    block = min(131072, n)
+    block = min(BLOCK, n)
     # pad to a block multiple rather than shrinking the block: the
     # largest-divisor fallback degrades to block=1 (a grid of n
     # sequential invocations) for awkward/prime n from direct callers

@@ -164,6 +164,33 @@ def _kernel_weight(ref, bits, dt, axis=0):
     return w.astype(dt)
 
 
+def _silu_mul(g, u):
+    """``jax.nn.silu(g) * u`` at the model dtype, with the sigmoid taken
+    in f32: Mosaic's ``logistic`` lowering broadcasts an f32 ``1.0`` into
+    the operand's vector type, which fails MLIR verification for bf16
+    operands. The f32 sigmoid rounded back to ``g.dtype`` is the value
+    XLA's bf16 ``logistic`` produces, so the composition's op order
+    (sigmoid -> * g -> * u, each rounded to the model dtype) holds."""
+    sg = jax.nn.sigmoid(g.astype(jnp.float32)).astype(g.dtype)
+    return g * sg * u
+
+
+def _split_heads(t, n, hd):
+    """(1, n*hd) -> (n, hd) by lane slices stacked along the sublane
+    axis. Mosaic has no layout for the ``reshape`` that splits a lane
+    dim into heads narrower than a 128-lane tile ("infer-vector-layout:
+    unsupported shape cast"); slices + concatenate compile at any head
+    dim. f32 operands only: one-row pieces of a packed dtype do not."""
+    return jnp.concatenate([t[:, h * hd:(h + 1) * hd] for h in range(n)],
+                           axis=0)
+
+
+def _merge_heads(t):
+    """(n, hd) -> (1, n*hd): the inverse of :func:`_split_heads`."""
+    return jnp.concatenate([t[h:h + 1, :] for h in range(t.shape[0])],
+                           axis=1)
+
+
 def _weight_itemsize(meta) -> float:
     """Bytes per weight element under the meta's weight-dtype class —
     what the supports() VMEM math charges for weight tiles."""
@@ -213,7 +240,7 @@ def _attn_block_kernel(bt_ref, len_ref, x_ref, nw_ref, wq_ref, wk_ref,
     def _prologue():
         # RMSNorm — same staging as ops.rms_norm_ref: fp32 moment, cast
         # back to the model dtype BEFORE the weight multiply
-        xf = x_ref[:].astype(jnp.float32)                     # (1, D)
+        xf = x_ref[0].astype(jnp.float32)                     # (1, D)
         ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
         h = (xf * jax.lax.rsqrt(ms + epsf)).astype(dt) * nw_ref[:]
 
@@ -228,19 +255,19 @@ def _attn_block_kernel(bt_ref, len_ref, x_ref, nw_ref, wq_ref, wk_ref,
         q = proj(wq_ref, sqw_ref if wq_bits else None)
         k = proj(wk_ref, skw_ref if wq_bits else None)
         v = proj(wv_ref, svw_ref if wq_bits else None)
-        sinr, cosr = sin_ref[:], cos_ref[:]                   # (1, hd2)
+        sinr, cosr = sin_ref[0], cos_ref[0]                   # (1, hd2)
 
         def rope(t, n):
             # mimic the unfused op order exactly: the projection lands
             # at model dtype, apply_rope recasts to f32 and rotates
-            t = t.astype(dt).astype(jnp.float32).reshape(n, hd)
+            t = _split_heads(t.astype(dt).astype(jnp.float32), n, hd)
             t1, t2 = t[:, :hd2], t[:, hd2:]
             return jnp.concatenate([t1 * cosr - t2 * sinr,
                                     t2 * cosr + t1 * sinr], axis=-1)
 
         qr = rope(q, kv * groups).astype(dt)                  # (H, hd)
         kr = rope(k, kv).astype(dt)                           # (KV, hd)
-        vm = v.astype(dt).reshape(kv, hd)
+        vm = _split_heads(v, kv, hd).astype(dt)
         kn_ref[0] = kr          # raw new-token K/V: the caller owns the
         vn_ref[0] = vm          # pool write (quantizing if int8)
         q_scr[:] = qr.astype(jnp.float32)
@@ -305,8 +332,8 @@ def _attn_block_kernel(bt_ref, len_ref, x_ref, nw_ref, wq_ref, wk_ref,
             pg = p[kvh * groups:(kvh + 1) * groups, :]
             pv_rows.append(pg * va[kvh:kvh + 1, :])           # (g, hd)
         acc_fin = acc_scr[:] * alpha + jnp.concatenate(pv_rows, axis=0)
-        attn = (acc_fin / l_fin).astype(dt)                   # (H, hd)
-        o = jnp.dot(attn.reshape(1, -1),
+        attn = _merge_heads(acc_fin / l_fin).astype(dt)       # (1, H*hd)
+        o = jnp.dot(attn,
                     _kernel_weight(wo_ref, wq_bits, dt),
                     preferred_element_type=jnp.float32)
         if wq_bits:
@@ -314,7 +341,7 @@ def _attn_block_kernel(bt_ref, len_ref, x_ref, nw_ref, wq_ref, wk_ref,
         # residual=False returns the bare o-projection: the tensor-
         # parallel caller psums the per-shard partials across the head
         # axis FIRST and adds the (replicated) residual after
-        xo_ref[:] = (x_ref[:] + o.astype(dt)) if residual \
+        xo_ref[0] = (x_ref[0] + o.astype(dt)) if residual \
             else o.astype(dt)
 
 
@@ -399,14 +426,18 @@ def fused_attn_block_pallas(x, nw, wq, wk, wv, wo, sin, cos,
     sin_b = jnp.take(jnp.asarray(sin), seq_lens, axis=0)     # (B, hd2)
     cos_b = jnp.take(jnp.asarray(cos), seq_lens, axis=0)
 
-    row = lambda b, mi, bt, ln: (b, 0)                   # noqa: E731
+    # per-sequence rows ride as (1, 1, W) blocks of a (B, 1, W) view:
+    # Mosaic tiles the LAST TWO block dims (8 sublanes x 128 lanes)
+    # unless they span the array's own, which a one-row block of a
+    # (B, W) array cannot
+    row = lambda b, mi, bt, ln: (b, 0, 0)                # noqa: E731
     const = lambda b, mi, bt, ln: (0, 0)                 # noqa: E731
 
     def page_index(j):
         return clamped_page_index(BS, pp, j)
 
     in_specs = [
-        pl.BlockSpec((1, D), row),                        # x
+        pl.BlockSpec((1, 1, D), row),                     # x
         pl.BlockSpec((1, D), const),                      # norm weight
         # weight tiles ride at their STORED shapes (int4 halves the
         # pack axis), resident per kernel invocation like the fp tiles
@@ -414,10 +445,11 @@ def fused_attn_block_pallas(x, nw, wq, wk, wv, wo, sin, cos,
         pl.BlockSpec(tuple(wk.shape), const),             # wk
         pl.BlockSpec(tuple(wv.shape), const),             # wv
         pl.BlockSpec(tuple(wo.shape), const),             # wo
-        pl.BlockSpec((1, hd // 2), row),                  # sin row
-        pl.BlockSpec((1, hd // 2), row),                  # cos row
+        pl.BlockSpec((1, 1, hd // 2), row),               # sin row
+        pl.BlockSpec((1, 1, hd // 2), row),               # cos row
     ]
-    inputs = [x, nw.reshape(1, D), wq, wk, wv, wo, sin_b, cos_b]
+    inputs = [x.reshape(B, 1, D), nw.reshape(1, D), wq, wk, wv, wo,
+              sin_b.reshape(B, 1, hd // 2), cos_b.reshape(B, 1, hd // 2)]
     if bits:
         # per-output-channel f32 scales, one const row per projection
         for s in (sqw, skw, svw, sow):
@@ -442,9 +474,9 @@ def fused_attn_block_pallas(x, nw, wq, wk, wv, wo, sin, cos,
         grid=(B, pl.cdiv(MB, pp)),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, KV, hd), lambda b, mi, bt, ln: (b, 0, 0)),
-            pl.BlockSpec((1, KV, hd), lambda b, mi, bt, ln: (b, 0, 0)),
+            pl.BlockSpec((1, 1, D), row),
+            pl.BlockSpec((1, KV, hd), row),
+            pl.BlockSpec((1, KV, hd), row),
         ],
         scratch_shapes=[
             pltpu.VMEM((H, hd), jnp.float32),     # q
@@ -457,13 +489,13 @@ def fused_attn_block_pallas(x, nw, wq, wk, wv, wo, sin, cos,
         # all three outputs are per-sequence blocks revisited across the
         # page steps (prologue/epilogue writes under pl.when)
         accum_outputs=(0, 1, 2),
-        out_shape=[jax.ShapeDtypeStruct((B, D), x.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, 1, D), x.dtype),
                    jax.ShapeDtypeStruct((B, KV, hd), x.dtype),
                    jax.ShapeDtypeStruct((B, KV, hd), x.dtype)],
         interpret=_interpret(),
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(seq_lens, jnp.int32), *inputs)
-    return xo, kn, vn
+    return xo.reshape(B, D), kn, vn
 
 
 # ---------------------------------------------------------------------------
@@ -499,8 +531,7 @@ def _mlp_block_kernel(x_ref, nw_ref, wg_ref, wu_ref, wd_ref, *rest,
     if wq_bits:
         g = g * sg_ref[:]
         u = u * su_ref[:]
-    g, u = g.astype(dt), u.astype(dt)
-    ff = jax.nn.silu(g) * u                       # swiglu, model dtype
+    ff = _silu_mul(g.astype(dt), u.astype(dt))    # swiglu, model dtype
     dn = jnp.dot(ff, _kernel_weight(wd_ref, wq_bits, dt, axis=1),
                  preferred_element_type=jnp.float32)
     if wq_bits:
@@ -514,7 +545,9 @@ def _mlp_block_kernel(x_ref, nw_ref, wg_ref, wu_ref, wd_ref, *rest,
             else acc_scr[:].astype(dt)
 
 
-_MLP_BLOCK_CANDIDATES = (512, 256, 1024, 2048)
+# 128 last: it only ever becomes the default pick where nothing wider
+# fits (D=4096 bf16), so narrower models keep their tile
+_MLP_BLOCK_CANDIDATES = (512, 256, 1024, 2048, 128)
 
 
 def mlp_autotune_key(B, D, F, dtype, budget=None,
@@ -544,16 +577,20 @@ def _mlp_candidates(F: int):
 
 def _mlp_vmem_need(B: int, D: int, itemsize: int, bf: int,
                    w_itemsize: float = None) -> int:
-    """Per-grid-step VMEM bytes at tile ``bf``: 3 weight tiles + the
-    x/h/acc activation rows + the g/u/ff intermediates.
+    """Per-grid-step VMEM bytes at tile ``bf``: the 3 weight tiles and
+    the x / out rows as the pipeline holds them — TWO buffers each (the
+    F-tiles move every step; compiled for v5e, D=4096 bf=384 and D=6144
+    bf=256, 18 MiB of tile buffers, are refused where a single-buffer
+    count admitted them) — plus the h/acc scratch rows and the body's
+    f32 rows (xf, the down-projection) and g/u/ff tiles.
     ``w_itemsize``: bytes per weight ELEMENT (1 for int8, 0.5 for
     packed int4 — which also adds the f32 scale rows); defaults to the
     activation itemsize (plain fp weights)."""
     if w_itemsize is None:
         w_itemsize = itemsize
     scales = (2 * bf + D) * 4 if w_itemsize != itemsize else 0
-    return int(3 * D * bf * w_itemsize) + scales \
-        + B * D * (4 + 2 * itemsize) + 3 * B * bf * 4
+    return 2 * (int(3 * D * bf * w_itemsize) + scales) \
+        + B * D * (4 * itemsize + itemsize + 4 + 2 * 4) + 3 * B * bf * 4
 
 
 def _mlp_fitting_candidates(B: int, D: int, F: int, itemsize: int,
@@ -709,7 +746,7 @@ def _block_fused_kernel(bt_ref, len_ref, x_ref, nw_ref, wq_ref, wk_ref,
         # identical staging to _attn_block_kernel's prologue: RMSNorm,
         # QKV projections (epilogue-scaled when weight-quantized), RoPE,
         # new-token K/V out + attention-view scratch, m/l/acc init
-        xf = x_ref[:].astype(jnp.float32)                     # (1, D)
+        xf = x_ref[0].astype(jnp.float32)                     # (1, D)
         ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
         h = (xf * jax.lax.rsqrt(ms + epsf)).astype(dt) * nw_ref[:]
 
@@ -721,17 +758,17 @@ def _block_fused_kernel(bt_ref, len_ref, x_ref, nw_ref, wq_ref, wk_ref,
         q = proj(wq_ref, sqw_ref if wq_bits else None)
         k = proj(wk_ref, skw_ref if wq_bits else None)
         v = proj(wv_ref, svw_ref if wq_bits else None)
-        sinr, cosr = sin_ref[:], cos_ref[:]                   # (1, hd2)
+        sinr, cosr = sin_ref[0], cos_ref[0]                   # (1, hd2)
 
         def rope(t, n):
-            t = t.astype(dt).astype(jnp.float32).reshape(n, hd)
+            t = _split_heads(t.astype(dt).astype(jnp.float32), n, hd)
             t1, t2 = t[:, :hd2], t[:, hd2:]
             return jnp.concatenate([t1 * cosr - t2 * sinr,
                                     t2 * cosr + t1 * sinr], axis=-1)
 
         qr = rope(q, kv * groups).astype(dt)                  # (H, hd)
         kr = rope(k, kv).astype(dt)                           # (KV, hd)
-        vm = v.astype(dt).reshape(kv, hd)
+        vm = _split_heads(v, kv, hd).astype(dt)
         kn_ref[0] = kr
         vn_ref[0] = vm
         q_scr[:] = qr.astype(jnp.float32)
@@ -796,15 +833,15 @@ def _block_fused_kernel(bt_ref, len_ref, x_ref, nw_ref, wq_ref, wk_ref,
             pg = p[kvh * groups:(kvh + 1) * groups, :]
             pv_rows.append(pg * va[kvh:kvh + 1, :])           # (g, hd)
         acc_fin = acc_scr[:] * alpha + jnp.concatenate(pv_rows, axis=0)
-        attn = (acc_fin / l_fin).astype(dt)                   # (H, hd)
-        o = jnp.dot(attn.reshape(1, -1),
+        attn = _merge_heads(acc_fin / l_fin).astype(dt)       # (1, H*hd)
+        o = jnp.dot(attn,
                     _kernel_weight(wo_ref, wq_bits, dt),
                     preferred_element_type=jnp.float32)
         if wq_bits:
             o = o * sow_ref[:]
         # the residual-in-VMEM contract: the attn->MLP handoff stays
         # f32 in scratch for the rest of the launch
-        resid = x_ref[:].astype(jnp.float32) + o              # (1, D)
+        resid = x_ref[0].astype(jnp.float32) + o              # (1, D)
         r_scr[:] = resid
         ms2 = jnp.mean(jnp.square(resid), axis=-1, keepdims=True)
         h_scr[:] = (resid * jax.lax.rsqrt(ms2 + epsf)
@@ -822,8 +859,7 @@ def _block_fused_kernel(bt_ref, len_ref, x_ref, nw_ref, wq_ref, wk_ref,
         if wq_bits:
             g = g * sg_ref[:]
             u = u * su_ref[:]
-        g, u = g.astype(dt), u.astype(dt)
-        ff = jax.nn.silu(g) * u
+        ff = _silu_mul(g.astype(dt), u.astype(dt))
         dn = jnp.dot(ff, _kernel_weight(wd_ref, wq_bits, dt, axis=1),
                      preferred_element_type=jnp.float32)
         if wq_bits:
@@ -832,7 +868,7 @@ def _block_fused_kernel(bt_ref, len_ref, x_ref, nw_ref, wq_ref, wk_ref,
 
     @pl.when(s == jnp.int32(np_ + nf - 1))
     def _fin():
-        xo_ref[:] = (r_scr[:] + f_scr[:]).astype(dt)
+        xo_ref[0] = (r_scr[:] + f_scr[:]).astype(dt)
 
 
 def block_autotune_key(B, D, H, KV, hd, F, BS, MB, dtype, pool_dtype,
@@ -962,7 +998,9 @@ def fused_decode_block_pallas(x, nw, wq, wk, wv, wo, pw, wg, wu, wd,
     sin_b = jnp.take(jnp.asarray(sin), seq_lens, axis=0)     # (B, hd2)
     cos_b = jnp.take(jnp.asarray(cos), seq_lens, axis=0)
 
-    row = lambda b, s, bt, ln: (b, 0)                    # noqa: E731
+    # (1, 1, W) row blocks of (B, 1, W) views, as in
+    # fused_attn_block_pallas
+    row = lambda b, s, bt, ln: (b, 0, 0)                 # noqa: E731
     const = lambda b, s, bt, ln: (0, 0)                  # noqa: E731
 
     def _mlp_jf(s):
@@ -983,7 +1021,7 @@ def fused_decode_block_pallas(x, nw, wq, wk, wv, wo, pw, wg, wu, wd,
     gu_rows = wg.shape[0]
     wd_cols = wd.shape[1]
     in_specs = [
-        pl.BlockSpec((1, D), row),                        # x
+        pl.BlockSpec((1, 1, D), row),                     # x
         pl.BlockSpec((1, D), const),                      # input norm
         pl.BlockSpec(tuple(wq.shape), const),             # wq
         pl.BlockSpec(tuple(wk.shape), const),             # wk
@@ -993,11 +1031,12 @@ def fused_decode_block_pallas(x, nw, wq, wk, wv, wo, pw, wg, wu, wd,
         pl.BlockSpec((gu_rows, bf), mlp_col),             # wg tile
         pl.BlockSpec((gu_rows, bf), mlp_col),             # wu tile
         pl.BlockSpec((bf, wd_cols), mlp_row),             # wd tile
-        pl.BlockSpec((1, hd // 2), row),                  # sin row
-        pl.BlockSpec((1, hd // 2), row),                  # cos row
+        pl.BlockSpec((1, 1, hd // 2), row),               # sin row
+        pl.BlockSpec((1, 1, hd // 2), row),               # cos row
     ]
-    inputs = [x, nw.reshape(1, D), wq, wk, wv, wo,
-              pw.reshape(1, D), wg, wu, wd, sin_b, cos_b]
+    inputs = [x.reshape(B, 1, D), nw.reshape(1, D), wq, wk, wv, wo,
+              pw.reshape(1, D), wg, wu, wd,
+              sin_b.reshape(B, 1, hd // 2), cos_b.reshape(B, 1, hd // 2)]
     if bits:
         for s_ in (sqw, skw, svw, sow):
             in_specs.append(pl.BlockSpec((1, s_.shape[-1]), const))
@@ -1027,9 +1066,9 @@ def fused_decode_block_pallas(x, nw, wq, wk, wv, wo, pw, wg, wu, wd,
         grid=(B, np_ + nf),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, KV, hd), lambda b, s, bt, ln: (b, 0, 0)),
-            pl.BlockSpec((1, KV, hd), lambda b, s, bt, ln: (b, 0, 0)),
+            pl.BlockSpec((1, 1, D), row),
+            pl.BlockSpec((1, KV, hd), row),
+            pl.BlockSpec((1, KV, hd), row),
         ],
         scratch_shapes=[
             pltpu.VMEM((H, hd), jnp.float32),     # q
@@ -1045,13 +1084,13 @@ def fused_decode_block_pallas(x, nw, wq, wk, wv, wo, pw, wg, wu, wd,
         # all three outputs are per-sequence blocks revisited across
         # the combined grid (prologue/epilogue writes under pl.when)
         accum_outputs=(0, 1, 2),
-        out_shape=[jax.ShapeDtypeStruct((B, D), x.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, 1, D), x.dtype),
                    jax.ShapeDtypeStruct((B, KV, hd), x.dtype),
                    jax.ShapeDtypeStruct((B, KV, hd), x.dtype)],
         interpret=_interpret(),
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(seq_lens, jnp.int32), *inputs)
-    return xo, kn, vn
+    return xo.reshape(B, D), kn, vn
 
 
 def decode_block_composed(x, nw, wq, wk, wv, wo, pw, wg, wu, wd, sin,

@@ -584,6 +584,16 @@ def _supports_prefill_attn(meta):
     hd = meta["hd"]
     if hd % 8 != 0 or hd < 16:
         return False, f"head_dim {hd} not a multiple of 8 (lane tiling)"
+    if hd % 128 != 0:
+        # the kernel splits (P, n*hd) panels into (P, n, hd) heads with
+        # a reshape; the chip's compiler has a layout for it only when
+        # a head fills whole 128-lane tiles (compiled for v5e: hd=128
+        # passes, hd=64 is refused). A per-head column-slice redesign
+        # would lift this (ROADMAP S3).
+        return False, (f"head_dim {hd} is narrower than a 128-lane tile:"
+                       " Mosaic refuses the per-head split "
+                       "('infer-vector-layout: unsupported shape cast', "
+                       "tpu.reshape (P, H*hd) -> (P, H, hd))")
     if meta["H"] % meta["KV"] != 0:
         return False, "H not a multiple of KV"
     if meta["P"] % 8 != 0:

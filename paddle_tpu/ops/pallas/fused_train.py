@@ -172,8 +172,11 @@ def _ce_dh_kernel(x_ref, h_ref, lab_ref, lse_ref, coef_ref, dh_ref,
 
 # (block_t, block_v) candidates; filtered against the VMEM budget like
 # the fused-MLP tiles (the sweep and the predicate consume one list)
+# (64, 256) last: the one tile inside the budget at D=4096 bf16 (the
+# published Llama-7B width), where (128, 256) models 10.1 MiB; compiled
+# for v5e it passes there, as does every narrower model's first fit
 _CE_BLOCK_CANDIDATES = ((256, 512), (128, 512), (256, 1024),
-                        (512, 512), (128, 256))
+                        (512, 512), (128, 256), (64, 256))
 
 
 def linear_ce_autotune_key(T, D, V, dtype, budget=None) -> str:
@@ -477,8 +480,11 @@ def _swiglu_row_block(R, bf, dtype):
     well inside the 16MiB scoped-VMEM envelope (a 2MiB/buffer budget
     would pipeline ~20MiB and OOM a v5e at the flagship F)."""
     it = jnp.dtype(dtype).itemsize
-    br = max(8, (512 * 1024) // max(1, bf * it))
-    return min(br, _round_up(R, 8))
+    # whole sublane tiles only (8 rows of 32-bit, 16 of bf16): Mosaic
+    # refuses a block whose row count is not a multiple of the tile
+    sub = 8 * max(1, 4 // it)
+    br = max(sub, (512 * 1024) // max(1, bf * it) // sub * sub)
+    return min(br, _round_up(R, sub))
 
 
 def _swiglu_bf(g2, u2):

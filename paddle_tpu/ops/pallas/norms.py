@@ -41,7 +41,10 @@ def _row_block(n, d, itemsize):
     comfortably. Callers pad the row count up to a block multiple
     (``_pad_rows``) rather than shrinking the block: the old
     largest-divisor fallback degraded to block=1 for prime n."""
-    cap = max(8, (2 * 1024 * 1024) // max(1, d * itemsize))
+    # whole sublane tiles (16 rows covers bf16's packing and f32's 8):
+    # Mosaic refuses a row block that is neither a tile multiple nor
+    # the whole array (d=1536 bf16 gave 682 rows)
+    cap = max(16, (2 * 1024 * 1024) // max(1, d * itemsize) // 16 * 16)
     return min(cap, n)
 
 

@@ -44,6 +44,11 @@ class KernelVariant:
 
     def check(self, meta: Dict[str, Any]):
         """-> (supported: bool, reason: str)."""
+        if "pallas" in self.tags:
+            from ._util import gspmd_refusal
+            why = gspmd_refusal()
+            if why is not None:
+                return False, why
         if self.supports is None:
             return True, "unconditional"
         r = self.supports(dict(meta))

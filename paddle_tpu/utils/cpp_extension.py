@@ -42,18 +42,18 @@ import ctypes
 import hashlib
 import os
 import subprocess
-import tempfile
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..core.backend import cache_dir
 from ..core.tensor import Tensor, dispatch, to_value
 
 __all__ = ["load", "load_inline", "CustomOp", "get_build_directory"]
 
-_build_dir = [os.path.join(tempfile.gettempdir(), "paddle_tpu_extensions")]
+_build_dir = [os.path.join(cache_dir(), "extensions")]
 
 
 def get_build_directory() -> str:

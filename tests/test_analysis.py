@@ -157,7 +157,7 @@ def test_collective_rule_unknown_axis():
         return jax.lax.psum(x, "dp")
 
     fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("dp", "tp"),
-                           out_specs=P(None, "tp"), check_rep=False))
+                           out_specs=P(None, "tp"), check_vma=False))
     # clean: axis exists in the shard_map mesh
     rep = audit_program(fn, F32(8, 8), name="psum_ok")
     assert rep.findings == []
@@ -188,7 +188,7 @@ def test_collective_rule_cond_divergence():
         return jax.lax.cond(y[0, 0] > 0, yes, no, y)
 
     fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("dp", "tp"),
-                           out_specs=P(), check_rep=False))
+                           out_specs=P(), check_vma=False))
     rep = audit_program(fn, F32(8, 8), name="cond_div")
     assert "COND_COLLECTIVE_DIVERGENCE" in _codes(rep)
     f = next(f for f in rep.findings

@@ -1,0 +1,268 @@
+"""Real-chip compiles, without the chip: the TPU's compiler is installed
+here and compiles for a DESCRIBED ``v5e:2x2`` topology (the
+on-chip-measurement guide, section 2). What interpret mode cannot show —
+a block shape the tiling refuses, a Mosaic op with no layout, a kernel
+over the scoped-VMEM limit, a kernel GSPMD cannot partition — the
+compiler says here, at no chip time.
+
+One case per main-path kernel at the ``chip_smoke.py`` widths (Llama-7B:
+D=4096, 32 heads x 128, F=11008, V=32000), one per fused serving kernel
+at the shape class where ``supports()`` selects it, each asserting that
+dispatch selects what is compiled and that the compiled program holds
+the named ``tpu_custom_call``. A compile that passes is not a chip run.
+
+The routers ask ``jax.default_backend()`` and see the CPU here, so the
+cases compile the kernels themselves, with ``interpret`` steered by the
+test (``set_force_interpret(False)``), not by an option of the program.
+"""
+import dataclasses
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+import paddle_tpu  # noqa: F401 — x64 mode, as every kernel caller has it
+from paddle_tpu.analysis import kernel_catalog as kc
+from paddle_tpu.ops.pallas import _util
+from paddle_tpu.ops.pallas import fused_adamw as fa
+from paddle_tpu.ops.pallas import fused_decode_block as fdb
+from paddle_tpu.ops.pallas import fused_prefill_block as fpb
+from paddle_tpu.ops.pallas import fused_train as ft
+from paddle_tpu.ops.pallas import norms
+from paddle_tpu.ops.pallas.flash_attention import flash_supports
+from paddle_tpu.ops.pallas.registry import KERNELS
+
+# chip_smoke.py's widths (LlamaConfig defaults) and its engine geometry
+D, H, KV, HD, F, V = 4096, 32, 32, 128, 11008, 32000
+CAP, BS, MB, NPAGES = 4, 16, 40, 129       # ServingEngine defaults
+T, SEQ = 4096, 2048                        # batch 2 x seq 2048
+BF16 = "bfloat16"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _compile_for_the_chip():
+    """Kernels lower through Mosaic (not the interpreter), and the
+    persistent compile cache stays out of it: an entry compiled for a
+    described chip is written but cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    _util.set_force_interpret(False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    _util.set_force_interpret(None)
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _kernels(compiled):
+    """Audited launch names of the Pallas custom calls in a program."""
+    return set(_util.compiled_kernel_counts(compiled.as_text()))
+
+
+def _compile(topo, build):
+    fn, args = build()
+    one = SingleDeviceSharding(topo.devices[0])
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        args)
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _decode_meta(b=CAP, d=D, h=H, kv=KV, hd=HD, f=F, bs=BS, mb=MB,
+                 wq=None):
+    return fdb.decode_meta_dims(b, d, h, kv, hd, f, bs, mb, BF16, BF16,
+                                False, weight_dtype=wq)
+
+
+def _selects(op, meta, variant="pallas_fused"):
+    return KERNELS.dispatch(op, meta)[0] == variant
+
+
+# (id, builder, launch names the program must hold, "does dispatch
+# select this kernel on the chip?" or None where no registry op routes it)
+CASES = [
+    # -- the unfused decode route at the smoke widths -------------------
+    ("paged_attention", kc._paged_case(CAP, H, KV, HD, BS, NPAGES, MB,
+                                       BF16),
+     {"paged_attention_decode"}, None),
+    ("rms_norm_decode_rows", kc._rms_case(CAP, D, BF16),
+     {"rms_norm_fwd", "rms_norm_bwd"}, None),
+    ("decode_mlp_block", kc._mlp_block_case(CAP, D, F, BF16),
+     {"decode_mlp_block"},
+     lambda: _selects("decode_mlp_block", _decode_meta())),
+    # -- the training step at the smoke widths --------------------------
+    ("flash_attention", kc._flash_case(2, SEQ, H, KV, HD, BF16),
+     set(kc._FLASH_KERNELS),
+     lambda: flash_supports(SEQ, SEQ)[0]),
+    ("rms_norm", kc._rms_case(T, D, BF16),
+     {"rms_norm_fwd", "rms_norm_bwd"},
+     lambda: _selects("rms_norm_bwd", norms.rms_bwd_meta(T, D, BF16))),
+    ("rms_norm_residual", kc._res_rms_case(T, D, BF16),
+     {"residual_rms_norm_fwd", "rms_norm_bwd"},
+     lambda: _selects("rms_norm_residual",
+                      norms.rms_bwd_meta(T, D, BF16))),
+    ("fused_linear_ce", kc._linear_ce_case(T, D, V, BF16),
+     set(kc._CE_KERNELS),
+     lambda: _selects("fused_linear_ce", ft.ce_meta(T, D, V, BF16))),
+    ("fused_swiglu", kc._swiglu_case(T, F, BF16),
+     {"swiglu_fwd", "swiglu_bwd"},
+     lambda: _selects("fused_swiglu", ft.swiglu_meta(T, F, BF16))),
+    ("fused_adamw", kc._adamw_case(4 << 20, "float32", BF16, BF16),
+     {"fused_adamw"},
+     lambda: _selects("fused_adamw",
+                      fa.adamw_meta(4 << 20, "float32", BF16, True))),
+    # -- the fused serving kernels, where supports() selects them (the
+    #    catalog's D=1024 serving class; at the smoke widths their
+    #    resident weights are refused on the VMEM budget) ---------------
+    ("decode_attn_block",
+     kc._attn_block_case(8, 1024, 16, 16, 64, 16, 128, 24, BF16),
+     {"decode_attn_block"},
+     lambda: _selects("decode_attn_block",
+                      _decode_meta(8, 1024, 16, 16, 64, 4096, 16, 24))),
+    ("decode_attn_block_int8_kv",
+     kc._attn_block_case(8, 1024, 16, 16, 64, 16, 128, 24, BF16,
+                         quant=True),
+     {"decode_attn_block"}, None),
+    ("decode_block_fused_int8_weights",
+     kc._block_case(8, 1024, 16, 16, 64, 4096, 16, 128, 24, BF16,
+                    wq="int8"),
+     {"decode_block_fused"},
+     lambda: _selects(
+         "decode_block_fused",
+         _decode_meta(8, 1024, 16, 16, 64, 4096, 16, 24, wq="int8"),
+         "pallas_block")),
+    ("decode_block_fused_int4_weights",
+     kc._block_case(8, 1024, 16, 16, 64, 4096, 16, 128, 24, BF16,
+                    wq="int4"),
+     {"decode_block_fused"}, None),
+    ("prefill_attn_block_hd128",
+     kc._prefill_attn_case(64, 1024, 8, 8, 128, 16, 129, 24, BF16,
+                           pos0=128),
+     {"prefill_attn_block"},
+     lambda: _selects("prefill_attn_block", fpb.prefill_meta_dims(
+         64, 1024, 8, 8, 128, 4096, 16, 24, BF16, BF16, False))),
+    ("prefill_mlp_block", kc._mlp_block_case(64, 1024, 4096, BF16),
+     {"decode_mlp_block"},
+     lambda: _selects("prefill_mlp_block", fpb.prefill_meta_dims(
+         64, 1024, 16, 16, 64, 4096, 16, 24, BF16, BF16, False))),
+]
+
+
+@pytest.mark.parametrize("name,build,want,selected", CASES,
+                         ids=[c[0] for c in CASES])
+def test_kernel_compiles_for_v5e(topo, name, build, want, selected):
+    if selected is not None:
+        assert selected(), f"dispatch does not select {name} on the chip"
+    assert want <= _kernels(_compile(topo, build))
+
+
+def test_every_refusal_on_the_chip_names_its_reason():
+    """What dispatch refuses at the smoke widths, and what the chip's
+    compiler refused outright, falls back with a reason a person can
+    act on — so ``decode_variant`` and ``explain()`` tell the truth."""
+    d = _decode_meta()
+    p64 = fpb.prefill_meta_dims(64, 1024, 16, 16, 64, 4096, 16, 24, BF16,
+                                BF16, False)
+    p7b = fpb.prefill_meta_dims(128, D, H, KV, HD, F, BS, MB, BF16, BF16,
+                                False)
+    refused = {
+        ("decode_attn_block", "pallas_fused"): (d, "VMEM"),
+        ("decode_block_fused", "pallas_block"): (d, "scoped-VMEM"),
+        ("prefill_attn_block", "pallas_fused"): (p7b, "VMEM"),
+        ("prefill_mlp_block", "pallas_fused"): (p7b, "VMEM"),
+    }
+    for (op, variant), (meta, word) in refused.items():
+        (row,) = [r for r in KERNELS.explain(op, meta)
+                  if r["name"] == variant]
+        assert not row["supported"] and word in row["reason"], row
+        assert not [r for r in KERNELS.explain(op, meta)
+                    if r["selected"] and r["name"] == variant]
+    # the compiler's own refusal, quoted: heads narrower than a lane tile
+    ok, why = fpb._supports_prefill_attn(p64)
+    assert not ok and "unsupported shape cast" in why
+    # a sequence the flash grid cannot tile exactly goes to the ref
+    ok, why = flash_supports(600, 600)
+    assert not ok and "600" in why
+
+
+def test_refused_prefill_shape_is_what_the_compiler_refuses(topo):
+    """The hd=64 refusal above is the compiler's, not ours: forcing the
+    kernel at that shape raises the quoted Mosaic error."""
+    build = kc._prefill_attn_case(64, 1024, 16, 16, 64, 16, 129, 24, BF16,
+                                  pos0=128)
+    with pytest.raises(Exception, match="unsupported shape cast"):
+        _compile(topo, build)
+
+
+# ---------------------------------------------------------------------------
+# whole programs: the Trainer step, on one device and GSPMD-sharded on four
+# ---------------------------------------------------------------------------
+def _lowered_train_step(topo, mesh_cfg):
+    """The Trainer's jitted step lowered for described devices: shapes
+    only (``jax.device_put`` to a described device fails), shardings
+    attached by hand as ``init_state`` would place them."""
+    from paddle_tpu.distributed.trainer import Trainer, make_mesh
+    from paddle_tpu.models import llama
+
+    cfg = dataclasses.replace(llama.LLAMA_TINY, num_key_value_heads=4,
+                              max_position_embeddings=512)
+    mesh = make_mesh(mesh_cfg, devices=list(topo.devices))
+    specs = llama.param_shardings(mesh, cfg)
+    tr = Trainer(lambda p, t, l: llama.loss_fn(p, t, l, cfg), mesh, specs,
+                 fused_optimizer=False)
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0)))
+
+    def placed(dtype=None):
+        return jax.tree_util.tree_map(
+            lambda v, s: jax.ShapeDtypeStruct(
+                v.shape, dtype or v.dtype, sharding=NamedSharding(mesh, s)),
+            params, specs)
+
+    rep = NamedSharding(mesh, P())
+    state = (placed(), placed(jnp.float32), placed(jnp.float32),
+             placed(jnp.float32),
+             jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))
+    toks = jax.ShapeDtypeStruct((4, 512), jnp.int32,
+                                sharding=NamedSharding(mesh, tr.data_spec))
+    tr._build()
+    return tr._step_fn.lower(state, np.float32(1e-3), toks, toks)
+
+
+def test_train_step_on_one_device_runs_the_training_kernels(
+        topo, monkeypatch):
+    from paddle_tpu.distributed.trainer import MeshConfig
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    found = _kernels(_lowered_train_step(topo, MeshConfig()).compile())
+    assert set(kc._FLASH_KERNELS) | set(kc._CE_KERNELS) <= found
+
+
+def test_gspmd_sharded_train_step_compiles_without_mosaic_kernels(
+        topo, monkeypatch):
+    """JAX refuses to lower a Mosaic kernel into a program GSPMD
+    partitions ("Mosaic kernels cannot be automatically partitioned"):
+    on a 4-device mesh the Trainer's routing takes the compositions,
+    and the step compiles for the 2x2 chips with its collectives."""
+    from paddle_tpu.distributed.trainer import MeshConfig
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _lowered_train_step(
+        topo, MeshConfig(fsdp=2, tp=2)).compile()
+    assert _kernels(compiled) == set()
+    assert re.search(r"\ball-reduce(-start)?\(", compiled.as_text())

@@ -166,7 +166,7 @@ def test_dispatch_falls_back_under_interpret_with_reason():
 def test_dispatch_vmem_budget_fallback():
     """A bucket whose weights + scratch exceed the budget falls back
     with the budget named; a generous budget admits it."""
-    meta = fpb.prefill_meta_dims(128, 1024, 16, 16, 64, 4096, 16, 24,
+    meta = fpb.prefill_meta_dims(128, 1024, 8, 8, 128, 4096, 16, 24,
                                  jnp.bfloat16, jnp.bfloat16, False)
     meta["interpret"] = False
     meta["vmem_budget"] = 1 << 20          # 1 MiB: nothing fits
@@ -183,11 +183,23 @@ def test_dispatch_rejects_bad_head_dim_and_ragged_bucket():
     meta["interpret"] = False
     ok, why = fpb._supports_prefill_attn(meta)
     assert not ok and "head_dim" in why
-    meta2 = fpb.prefill_meta_dims(13, 64, 4, 2, 16, 128, 8, 8,
+    meta2 = fpb.prefill_meta_dims(13, 512, 4, 2, 128, 128, 8, 8,
                                   jnp.float32, jnp.float32, False)
     meta2["interpret"] = False
     ok, why = fpb._supports_prefill_attn(meta2)
     assert not ok and "P=13" in why
+
+
+def test_dispatch_refuses_sub_lane_head_dim_with_the_compilers_reason():
+    """The chip's compiler has no layout for the per-head split of a
+    head narrower than a 128-lane tile (tests/test_chip_compile.py
+    compiles the hd=128 class): hd=64 falls back, naming the error."""
+    meta = fpb.prefill_meta_dims(64, 1024, 16, 16, 64, 4096, 16, 24,
+                                 jnp.bfloat16, jnp.bfloat16, False)
+    meta["interpret"] = False
+    ok, why = fpb._supports_prefill_attn(meta)
+    assert not ok and "unsupported shape cast" in why
+    assert not fpb.prefill_fused_selected(meta, "auto")
 
 
 def test_resolve_modes_and_selected_gate():

@@ -579,7 +579,7 @@ def test_supports_boundary_exact_vmem_budget_edge():
     window bytes of the first fitting tile are <= budget by
     construction, budget-1 rejects it (with the budget named), and the
     fused_mlp candidate list obeys the same edge."""
-    need = ft._ce_vmem_need(128, 256, 2048, 2)
+    need = ft._ce_vmem_need(64, 256, 2048, 2)       # the smallest tile
     meta = ft.ce_meta(4096, 2048, 32000, jnp.bfloat16)
     meta["interpret"] = False
     meta["vmem_budget"] = need

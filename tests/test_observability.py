@@ -192,10 +192,13 @@ def test_metrics_schema_frozen_tp(params):
 
 
 @pytest.mark.roofline
-def test_metrics_roofline_schema(params):
+def test_metrics_roofline_schema(params, monkeypatch):
     """The roofline sub-dict (r21) is schema-stable in BOTH obs modes:
     per-arm modeled bytes/step + the bandwidth-bound step-time floor,
-    the labelled peak pair, the active dispatch arm and layer count."""
+    the labelled peak pair, the active dispatch arm and layer count.
+    The CPU has no peak on record, so the operator override names one
+    (the arithmetic is what is under test, not the number)."""
+    monkeypatch.setenv("PADDLE_TPU_PEAK_HBM_BW", "819e9")
     for obs in (False, True):
         eng = _engine(params, observability=obs)
         _run_stream(eng)
@@ -433,12 +436,15 @@ def test_disabled_mode_allocates_no_event_objects(params, monkeypatch):
 
 # -- acceptance: full stream with observability on ---------------------
 
-def test_enabled_stream_parity_traces_and_exports(params, tmp_path):
+def test_enabled_stream_parity_traces_and_exports(monkeypatch, params,
+                                                  tmp_path):
     """30-request mixed-arrival stream with observability ENABLED:
     greedy outputs stay bit-identical to generate(), steady state stays
     1 decode program + <=1 trace per prefill bucket, latency/gauge
     distributions are populated, and the chrome trace + JSONL exports
     are valid."""
+    # the roofline header needs a peak; the CPU has none on record
+    monkeypatch.setenv("PADDLE_TPU_PEAK_HBM_BW", "819e9")
     rng = np.random.RandomState(14)
     eng = _engine(params, capacity=3, observability=True)
     pending = []

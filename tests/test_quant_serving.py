@@ -325,7 +325,8 @@ def test_flagship_dispatch_int8_and_int4():
             "pallas_fused"
         assert KERNELS.dispatch("decode_mlp_block", meta)[0] == \
             "pallas_fused"
-        pmeta = fpb.prefill_meta_dims(64, 1024, 16, 16, 64, 4096, 16,
+        # the prefill kernel serves heads that fill a 128-lane tile
+        pmeta = fpb.prefill_meta_dims(64, 1024, 8, 8, 128, 4096, 16,
                                       24, jnp.bfloat16, jnp.bfloat16,
                                       False, weight_dtype=wd)
         pmeta["interpret"] = False
