@@ -32,7 +32,7 @@ from ..core.backend import on_tpu
 from ..observability import (CompileWatcher, HostGapDetector,
                              Observability, TRAIN_HISTOGRAMS,
                              TelemetryConfig, TelemetryPlane,
-                             live_hbm_bytes)
+                             live_hbm_bytes, span)
 
 __all__ = ["MeshConfig", "make_mesh", "TrainState", "Trainer"]
 
@@ -552,12 +552,13 @@ class Trainer:
             return out
         if self._t_first is None:
             self._t_first = time.perf_counter()
-        batch = tuple(self._stage_batch(b) for b in batch)
+        with span("train/stage"):
+            batch = tuple(self._stage_batch(b) for b in batch)
         if getattr(self, "_lr_cache", None) is None or \
                 self._lr_cache[0] != self.lr:
             # one h2d when lr changes, not one per step
             self._lr_cache = (self.lr, jnp.float32(self.lr))
-        with self.mesh:
+        with span("train/dispatch"), self.mesh:
             new_tree, metrics = self._step_fn(state.tree(),
                                               self._lr_cache[1], *batch)
         self._count_step(batch, time.perf_counter())
@@ -628,16 +629,16 @@ class Trainer:
         dispatch (compiled call returning) and sync (the wait for the
         device) — the split the host-vs-device gap detector reads."""
         obs = self._obs
-        t0 = obs.now()
         if self._t_first is None:
-            self._t_first = t0
-        staged = tuple(self._stage_batch(b) for b in batch)
-        t_stage = obs.now()
-        if getattr(self, "_lr_cache", None) is None or \
-                self._lr_cache[0] != self.lr:
-            self._lr_cache = (self.lr, jnp.float32(self.lr))
-        tree = state.tree()
-        with self.mesh:
+            self._t_first = obs.now()
+        with span("train/stage", obs, hist="stage_ms",
+                  ring=False) as stage:
+            staged = tuple(self._stage_batch(b) for b in batch)
+        with span("train/dispatch", obs, ring=False) as disp, self.mesh:
+            if getattr(self, "_lr_cache", None) is None or \
+                    self._lr_cache[0] != self.lr:
+                self._lr_cache = (self.lr, jnp.float32(self.lr))
+            tree = state.tree()
             if self._aot_fallback:
                 # a previous sharding mismatch demoted this trainer to
                 # the plain jit path (one-time warning below): same
@@ -669,22 +670,19 @@ class Trainer:
                     self._aot_fallback = True
                     new_tree, metrics = self._step_fn(
                         tree, self._lr_cache[1], *staged)
-        t_disp = obs.now()
-        jax.block_until_ready(metrics)
+        with span("train/sync", obs, hist="sync_ms", ring=False) as sync:
+            jax.block_until_ready(metrics)
         t_sync = obs.now()
-        stage_ms = (t_stage - t0) * 1e3
+        stage_ms, sync_ms = stage.dur_ms, sync.dur_ms
         # dispatch = key-build + cache lookup + the compiled call
         # returning; a compile this step is timed by the watcher and
         # excluded here rather than masquerading as dispatch work
-        dispatch_ms = max((t_disp - t_stage) * 1e3 - compile_ms, 0.0)
-        sync_ms = (t_sync - t_disp) * 1e3
-        step_ms = (t_sync - t0) * 1e3
+        dispatch_ms = max(disp.dur_ms - compile_ms, 0.0)
+        step_ms = stage_ms + disp.dur_ms + sync_ms
         self._count_step(batch, t_sync)
         step_idx = self.counters["steps"]
-        for name, v in (("step_ms", step_ms), ("stage_ms", stage_ms),
-                        ("dispatch_ms", dispatch_ms),
-                        ("sync_ms", sync_ms)):
-            obs.hist(name).observe(v)
+        obs.hist("step_ms").observe(step_ms)
+        obs.hist("dispatch_ms").observe(dispatch_ms)
         loss = float(metrics["loss"])
         gnorm = float(metrics["grad_norm"])
         vals = {"loss": loss, "grad_norm": gnorm}

@@ -69,7 +69,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core.jax_compat import shard_map_norep
-from ..observability import Observability, TelemetryConfig, TelemetryPlane
+from ..observability import (Observability, TelemetryConfig,
+                             TelemetryPlane, span)
 from ..ops.paged_attention import (BlockManager, dequant_cache,
                                    quant_cache)
 from .admission import AdmissionQueue
@@ -349,16 +350,16 @@ class ServingEngine:
                      cfg.head_dim)
         pool_dtype = jnp.int8 if self._quant else cfg.dtype
         shape = (L, self.num_blocks, BS, KV, hd)
-        self._k_pools = jnp.zeros(shape, pool_dtype)
-        self._v_pools = jnp.zeros(shape, pool_dtype)
-        if self._mesh is not None:
-            # pools shard their head-dim CONTENTS; page indices stay
-            # host-global, so BlockManager/prefix-cache logic below is
-            # identical with or without a mesh
-            self._k_pools = self._mesh.shard(self._k_pools,
-                                             self._mesh.pool_spec)
-            self._v_pools = self._mesh.shard(self._v_pools,
-                                             self._mesh.pool_spec)
+        # pools shard their head-dim CONTENTS; page indices stay
+        # host-global, so BlockManager/prefix-cache logic below is
+        # identical with or without a mesh. A sharded pool is made in
+        # place: whole on one device first, the two pools of a
+        # deployment that needs the mesh would not fit beside the
+        # weights
+        where = (None if self._mesh is None
+                 else self._mesh.sharding(self._mesh.pool_spec))
+        self._k_pools = jnp.zeros(shape, pool_dtype, device=where)
+        self._v_pools = jnp.zeros(shape, pool_dtype, device=where)
         self._kv_scales = None       # (k [L,KV], v [L,KV]) once calibrated
 
         self.mgr = BlockManager(self.num_blocks, BS, self.max_blocks)
@@ -471,6 +472,9 @@ class ServingEngine:
             # spill) and the bytes moved each direction
             "offload_traces": 0, "kv_spill_bytes": 0,
             "kv_restore_bytes": 0,
+            # steps that ran a prefill chunk AND a decode step: what
+            # stretches a token gap
+            "mixed_steps": 0,
         }
         self._t_first = None
         self._t_last = None
@@ -723,16 +727,61 @@ class ServingEngine:
         count as scheduler progress (a drain() whose last step only
         expires a request must finish cleanly, not report starvation)."""
         obs = self._obs
-        t0 = self._clock() if obs is not None else 0.0
-        if self._t_first is None:
-            self._t_first = self._clock()
-        expired = self._admit()
-        did = self._run_prefill()
-        did = self._run_decode() or did
-        if did:
-            self._t_last = self._clock()
+        with span("serve/step", obs, hist="step_ms") as whole:
+            if self._t_first is None:
+                self._t_first = self._clock()
+            with span("serve/admit", obs):
+                expired = self._admit()
+            chunk = self._run_prefill()
+            did = self._run_decode()
+            if chunk and did:
+                self.counters["mixed_steps"] += 1
+            did = did or chunk
+            if did:
+                self._t_last = self._clock()
+            else:
+                whole.drop()      # an idle poll is no step of the window
+            if obs is not None or self._check_inv:   # telemetry has obs
+                with span("serve/observe", obs):
+                    self._observe()
+        if whole.dur_ms is not None \
+                and obs.step_deadline_s is not None \
+                and whole.dur_ms > obs.step_deadline_s * 1e3:
+            obs.stall_dump(
+                f"step took {whole.dur_ms:.1f} ms "
+                f"(deadline {obs.step_deadline_s * 1e3:.1f} ms)",
+                self.scheduler_snapshot())
+        return did or expired > 0
+
+    def _observe(self):
+        """What a step pays for being watched: gauges and the retrace
+        watchdog (pure host bookkeeping — host mirrors only, never the
+        device), the telemetry plane's sample, the invariant check."""
+        obs = self._obs
         if obs is not None:
-            self._observe_step(t0, did)
+            free = len(self.mgr.free)
+            vals = {
+                "pages_free": free,
+                "pages_in_use": self.num_blocks - free,
+                "kv_refcount_total": int(self.mgr.refcount.sum()),
+                "queue_depth": len(self._queue),
+                "live_slots": sum(1 for s in self._slots
+                                  if s.phase != "idle"),
+            }
+            if self._slo[0]:
+                vals["slo_attainment"] = self._slo[1] / self._slo[0]
+            if self._pcache is not None:
+                st = self._pcache.stats
+                looked = st["hits"] + st["misses"]
+                vals["prefix_tree_pages"] = self._pcache.cached_pages
+                vals["prefix_hit_ratio"] = (round(st["hits"] / looked, 4)
+                                            if looked else 0.0)
+                if self._kv_offload:
+                    vals["prefix_host_pages"] = self._pcache.host_pages
+            obs.sample_gauges(self._clock(), vals)
+            if obs.watchdog.check(self.counters):
+                obs.timeline.record("retrace",
+                                    events=len(obs.watchdog.events))
         if self._telemetry is not None:
             self._telemetry.on_step()
         if self._check_inv:
@@ -741,46 +790,6 @@ class ServingEngine:
             self.mgr.check()
             if self._pcache is not None:
                 self._pcache.check()
-        return did or expired > 0
-
-    def _observe_step(self, t0: float, did: bool):
-        """Post-step observability: gauges, watchdog, step deadline.
-        Pure host bookkeeping — reads only host mirrors, never the
-        device."""
-        obs = self._obs
-        now = self._clock()
-        free = len(self.mgr.free)
-        vals = {
-            "pages_free": free,
-            "pages_in_use": self.num_blocks - free,
-            "kv_refcount_total": int(self.mgr.refcount.sum()),
-            "queue_depth": len(self._queue),
-            "live_slots": sum(1 for s in self._slots
-                              if s.phase != "idle"),
-        }
-        if self._slo[0]:
-            vals["slo_attainment"] = self._slo[1] / self._slo[0]
-        if self._pcache is not None:
-            st = self._pcache.stats
-            looked = st["hits"] + st["misses"]
-            vals["prefix_tree_pages"] = self._pcache.cached_pages
-            vals["prefix_hit_ratio"] = (round(st["hits"] / looked, 4)
-                                        if looked else 0.0)
-            if self._kv_offload:
-                vals["prefix_host_pages"] = self._pcache.host_pages
-        obs.sample_gauges(now, vals)
-        if obs.watchdog.check(self.counters):
-            obs.timeline.record("retrace",
-                                events=len(obs.watchdog.events))
-        if did:
-            dur = now - t0
-            obs.hist("step_ms").observe(dur * 1e3)
-            if obs.step_deadline_s is not None \
-                    and dur > obs.step_deadline_s:
-                obs.stall_dump(
-                    f"step took {dur * 1e3:.1f} ms "
-                    f"(deadline {obs.step_deadline_s * 1e3:.1f} ms)",
-                    self.scheduler_snapshot())
 
     def _resolve_variant(self) -> Dict:
         from ..ops.pallas.fused_decode_block import (decode_meta,
@@ -1010,10 +1019,13 @@ class ServingEngine:
     def metrics(self) -> Dict:
         # the flight recorder parks raw collective_calls/bytes counters
         # in the adopted dict; they surface ONLY under the structured
-        # "collectives" key below (the Trainer.metrics contract)
+        # "collectives" key below (the Trainer.metrics contract).
+        # mixed_steps is read from ``counters`` itself (the benchmark's
+        # mixed_step_pct.*): the key set of metrics() is frozen
         c = {k: (dict(v) if isinstance(v, dict) else v)
              for k, v in self.counters.items()
-             if k not in ("collective_calls", "collective_bytes")}
+             if k not in ("collective_calls", "collective_bytes",
+                          "mixed_steps")}
         if self._mesh is not None:
             c["mesh"] = self._mesh.describe()
         wall = ((self._t_last - self._t_first)
@@ -1098,7 +1110,7 @@ class ServingEngine:
                   "requests_submitted", "requests_completed",
                   "drain_truncations", "preemptions", "requeues",
                   "deadline_expired", "kv_spill_bytes",
-                  "kv_restore_bytes"):
+                  "kv_restore_bytes", "mixed_steps"):
             self.counters[k] = 0
         self._sched_cls = {}
         self._slo = [0, 0]
@@ -1373,28 +1385,37 @@ class ServingEngine:
         self._record_admit(req, slot_id, now)
 
     def _run_prefill(self) -> bool:
+        obs = self._obs
         for slot_id, slot in enumerate(self._slots):
             if slot.phase != "prefill":
                 continue
             req = slot.req
             S = req.prompt.size
             pos0 = slot.prefill_pos
-            n = min(S - pos0, self.buckets[-1])
-            P = self._bucket_for(n)
-            # the program cache keys the bucket AND the kernel route
-            # (force pins / VMEM budget / interpret override) exactly
-            # like generation.py's _PAGED_CACHE: a program traced under
-            # a pin must not be replayed for unpinned calls
-            pk = (P,) + self._prefill_route_key()
-            fn = self._prefill_fns.get(pk)
-            if fn is None:
-                fn = self._prefill_fns[pk] = self._make_prefill_fn(P)
-                self._prefill_kind[pk] = ("pallas"
-                                          if self._prefill_fused_for(P)
-                                          else "ref")
-            toks = np.zeros((1, P), np.int32)
-            toks[0, :n] = req.prompt[pos0:pos0 + n]
-            t0 = self._clock() if self._obs is not None else 0.0
+            with span("serve/prefill_stage", obs):
+                n = min(S - pos0, self.buckets[-1])
+                P = self._bucket_for(n)
+                # the program cache keys the bucket AND the kernel
+                # route (force pins / VMEM budget / interpret override)
+                # exactly like generation.py's _PAGED_CACHE: a program
+                # traced under a pin must not be replayed for unpinned
+                # calls
+                pk = (P,) + self._prefill_route_key()
+                fn = self._prefill_fns.get(pk)
+                if fn is None:
+                    fn = self._prefill_fns[pk] = self._make_prefill_fn(P)
+                    self._prefill_kind[pk] = (
+                        "pallas" if self._prefill_fused_for(P) else "ref")
+                toks = np.zeros((1, P), np.int32)
+                toks[0, :n] = req.prompt[pos0:pos0 + n]
+                # pos0/last_idx ride at the platform default int width
+                # so the literal indices inside cached_forward's dynamic
+                # slices promote consistently whether or not x64 is on
+                args = (jnp.asarray(toks), jnp.asarray(pos0),
+                        jnp.asarray(self._slot_tables[slot_id].copy()),
+                        jnp.asarray(self._slot_wtables[slot_id].copy()),
+                        jnp.asarray(n - 1),
+                        jnp.asarray(self._temp_of(req.gen), jnp.float32))
             if self._flight is not None:
                 inv = self._coll_prefill.get(P)
                 if inv is None:
@@ -1404,27 +1425,22 @@ class ServingEngine:
                 tasks = self._record_collectives(inv)
             else:
                 tasks = None
-            # pos0/last_idx ride at the platform default int width so
-            # the literal indices inside cached_forward's dynamic
-            # slices promote consistently whether or not x64 is on
-            tok, self._d_key, self._k_pools, self._v_pools = fn(
-                self.params, jnp.asarray(toks), jnp.asarray(pos0),
-                jnp.asarray(self._slot_tables[slot_id].copy()),
-                jnp.asarray(self._slot_wtables[slot_id].copy()),
-                jnp.asarray(n - 1),
-                jnp.asarray(self._temp_of(req.gen), jnp.float32),
-                self._d_key, self._k_pools, self._v_pools)
+            # host dispatch time only (the chunk completes async on
+            # device; forcing it here would ADD a sync to the loop)
+            with span("serve/prefill_dispatch", obs,
+                      hist="prefill_chunk_ms", ring=False,
+                      req_id=req.req_id, pos0=pos0, n=n,
+                      bucket=P) as disp:
+                tok, self._d_key, self._k_pools, self._v_pools = fn(
+                    self.params, *args,
+                    self._d_key, self._k_pools, self._v_pools)
             self._end_collectives(tasks)
             self.counters["prefill_chunks"] += 1
             self.counters["prefill_tokens"] += n
             self.counters["prefill_pad_tokens"] += P - n
-            if self._obs is not None:
-                # host dispatch time only (the chunk completes async on
-                # device; forcing it here would ADD a sync to the loop)
-                dur_ms = (self._clock() - t0) * 1e3
-                self._obs.hist("prefill_chunk_ms").observe(dur_ms)
-                self._obs.timeline.record(
-                    "prefill_chunk", req.req_id, dur_ms=dur_ms,
+            if obs is not None:
+                obs.timeline.record(
+                    "prefill_chunk", req.req_id, dur_ms=disp.dur_ms,
                     pos0=pos0, n=n, bucket=P,
                     variant=self._prefill_kind.get(pk, "ref"))
             slot.prefill_pos += n
@@ -1434,12 +1450,14 @@ class ServingEngine:
                 # group while later chunks still run). No-op here.
                 self._on_prefill_chunk(slot_id)
             if slot.prefill_pos == S:
-                first = int(np.asarray(tok))
+                with span("serve/first_token_sync", obs,
+                          req_id=req.req_id):
+                    first = int(np.asarray(tok))
                 req.first_token_t = self._clock()
                 req.ttft = req.first_token_t - req.submit_t
                 req.tokens.append(first)
-                if self._obs is not None:
-                    self._obs.timeline.record(
+                if obs is not None:
+                    obs.timeline.record(
                         "first_token", req.req_id,
                         ttft_ms=round(req.ttft * 1e3, 3))
                 self.counters["tokens_generated"] += 1
@@ -1504,51 +1522,54 @@ class ServingEngine:
                 if s.phase == "decode"]
         if not live:
             return False
+        obs = self._obs
         if self._decode_fn is None:
             self._decode_fn = self._make_decode_fn()
         if self._dirty:
-            self._d_tok = self._upload(self._h_tok.copy())
-            self._d_seq = self._upload(self._h_seq.copy())
-            self._d_tables = self._upload(self._h_tables.copy())
-            self._d_temps = self._upload(self._h_temps.copy())
+            with span("serve/table_upload", obs):
+                self._d_tok = self._upload(self._h_tok.copy())
+                self._d_seq = self._upload(self._h_seq.copy())
+                self._d_tables = self._upload(self._h_tables.copy())
+                self._d_temps = self._upload(self._h_temps.copy())
             self._dirty = False
-        t0 = self._clock() if self._obs is not None else 0.0
         tasks = self._record_collectives(self._coll_decode)
-        (self._d_tok, self._d_seq, self._d_key, self._k_pools,
-         self._v_pools) = self._decode_fn(
-            self.params, self._d_tok, self._d_seq, self._d_tables,
-            self._d_temps, self._d_key, self._k_pools, self._v_pools)
-        nxt = np.asarray(self._d_tok)       # the per-step host sync
+        with span("serve/decode_dispatch", obs, ring=False) as disp:
+            (self._d_tok, self._d_seq, self._d_key, self._k_pools,
+             self._v_pools) = self._decode_fn(
+                self.params, self._d_tok, self._d_seq, self._d_tables,
+                self._d_temps, self._d_key, self._k_pools, self._v_pools)
+        with span("serve/token_sync", obs, ring=False) as sync:
+            nxt = np.asarray(self._d_tok)       # the per-step host sync
         self._end_collectives(tasks)
         self.counters["decode_steps"] += 1
         self.counters["live_slot_steps"] += len(live)
-        if self._obs is not None:
+        if obs is not None:
             # dispatch-to-sync wall time: the d2h read above already
             # synchronizes every step, so this measures real step
             # latency without adding any device round-trip
-            dur_ms = (self._clock() - t0) * 1e3
-            self._obs.hist("decode_step_ms").observe(dur_ms)
+            dur_ms = disp.dur_ms + sync.dur_ms
+            obs.hist("decode_step_ms").observe(dur_ms)
             # per-variant attribution, mirroring the prefill chunk's
             # ``variant`` stamp: which decode-block implementation
             # served this step (tools/trace_summary.py --mode serving)
             v = self.decode_variant
             dv = v["block"] if v["block"] == "pallas_block" \
                 else v["attn"]
-            self._obs.timeline.record("decode_step", dur_ms=dur_ms,
-                                      live_slots=len(live),
-                                      decode_variant=dv)
-        for i in live:
-            slot = self._slots[i]
-            req = slot.req
-            t = int(nxt[i])
-            req.tokens.append(t)
-            self.counters["tokens_generated"] += 1
-            slot.seq_len += 1
-            self._h_seq[i] = slot.seq_len
-            self._h_tok[i] = t
-            if (t == req.gen.eos_token_id
-                    or len(req.tokens) >= req.gen.max_new_tokens):
-                self._finish(i)
+            obs.timeline.record("decode_step", dur_ms=dur_ms,
+                                live_slots=len(live), decode_variant=dv)
+        with span("serve/emit", obs):
+            for i in live:
+                slot = self._slots[i]
+                req = slot.req
+                t = int(nxt[i])
+                req.tokens.append(t)
+                self.counters["tokens_generated"] += 1
+                slot.seq_len += 1
+                self._h_seq[i] = slot.seq_len
+                self._h_tok[i] = t
+                if (t == req.gen.eos_token_id
+                        or len(req.tokens) >= req.gen.max_new_tokens):
+                    self._finish(i)
         return True
 
     def _finish(self, slot_id: int):

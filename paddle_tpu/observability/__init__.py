@@ -28,6 +28,7 @@ from .compile import (CompileWatcher, HostGapDetector, device_peak_flops,
 from .metrics import Gauge, Histogram, MetricsRegistry
 from .roofline import (capture_kernel_costs, decode_roofline,
                        decode_step_bytes, kernel_cost, roofline_point)
+from .spans import SERVE_SPANS, TRAIN_SPANS, span
 from .stall import dump_path_for, dump_stall
 from .telemetry import (TelemetryConfig, TelemetryPlane, flatten_metrics,
                         lint_exposition, render_exposition)
@@ -41,7 +42,8 @@ __all__ = ["Observability", "MetricsRegistry", "Histogram", "Gauge",
            "roofline_point", "capture_kernel_costs", "decode_step_bytes",
            "decode_roofline", "LATENCY_HISTOGRAMS", "TRAIN_HISTOGRAMS",
            "TelemetryConfig", "TelemetryPlane", "flatten_metrics",
-           "render_exposition", "lint_exposition"]
+           "render_exposition", "lint_exposition",
+           "span", "SERVE_SPANS", "TRAIN_SPANS"]
 
 # the latency histograms every engine window reports (schema-stable:
 # tests freeze this set — extend deliberately, never ad hoc)
