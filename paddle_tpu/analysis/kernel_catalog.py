@@ -501,12 +501,13 @@ def _prod(shape) -> int:
 
 
 def _pool_dims(spec):
-    """(page_size, head_dim) from the first 4-d (N, BS, KV, hd) KV-pool
-    operand of a paged kernel."""
+    """(page_size, head_dim) from the first KV-pool operand of a paged
+    kernel: (N, BS, KV, hd), or the stacked (L, N, BS, KV, hd) of the
+    launches that address their layer themselves."""
     for op in spec.inputs:
-        if len(op.shape) == 4:
-            return int(op.shape[1]), int(op.shape[3])
-    raise ValueError(f"{spec.name}: no 4-d KV-pool operand")
+        if len(op.shape) in (4, 5):
+            return int(op.shape[-3]), int(op.shape[-1])
+    raise ValueError(f"{spec.name}: no KV-pool operand")
 
 
 def _flops_rms_fwd(spec):
@@ -563,7 +564,7 @@ def _flops_decode_attn_block(spec):
 
 def _flops_decode_mlp_block(spec):
     B, D = (int(s) for s in spec.inputs[0].shape)
-    F = int(spec.inputs[2].shape[1])
+    F = int(spec.inputs[2].shape[-1])       # gate: the stacked (L, D, F)
     # norm + gate/up/down matmuls + silu·mul epilogue (~4/f-elem)
     return B * (4.0 * D + 6.0 * D * F + 4.0 * F)
 
@@ -869,7 +870,9 @@ def build_demo_kernel_regression() -> AuditReport:
     def prefix_mlp(x, nw, wg, wu, wd, eps=1e-6):
         const = lambda j: (0, 0)                          # noqa: E731
         return audited_pallas_call(
-            functools.partial(_mlp_block_kernel, eps=eps, residual=True),
+            # (None: the layer operand today's launch prefetches)
+            functools.partial(_mlp_block_kernel, None, eps=eps,
+                              residual=True),
             name="demo_prefix_mlp_block",
             accum_outputs=(0,),
             grid=(F // bf,),           # the bug: floor, not cdiv+guard

@@ -479,9 +479,45 @@ def _paged_chunk_runner(cfg, gen, quant=False, fused=False, sm=None,
     return chunk_fn
 
 
-def _paged_decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
-                       seq_lens, kv_scales=None):
-    """One decode token per sequence over paged pools.
+def _layer_loop(params, x, k_pools, v_pools, kv_scales, layer,
+                stacked=()):
+    """The decode program's ONE loop over layers, which copies nothing.
+
+    ``layer(x, l, lp, kp, vp, scales) -> (x, kp, vp)`` runs layer ``l``
+    (an int32 scalar). The KV pools ``[L, N, BS, KV, hd]`` are loop
+    CARRY beside ``x``: a layer writes its token into them in place
+    (``write_to_pool(..., layer=l)``) and the program's donated input
+    pool is its output pool. As a scan's stacked input and output they
+    were copied whole every step and held twice in HBM. ``lp`` holds
+    layer ``l``'s slice of each leaf of ``params["layers"]`` (free where
+    an XLA matmul consumes it: the slice fuses into the dot) except the
+    leaves named in ``stacked``, which it holds WHOLE, for launches
+    that address the layer themselves (a Pallas launch needs a whole
+    buffer, so a slice would be copied out for it). ``scales``: layer
+    ``l``'s int8-pool scales, or None.
+    """
+    layers = params["layers"]
+    whole = {k: layers[k] for k in stacked}
+    sliced = {k: v for k, v in layers.items() if k not in whole}
+
+    def body(carry, xs):
+        x, kp, vp = carry
+        l, lp, scales = xs
+        return layer(x, l, {**lp, **whole}, kp, vp, scales), None
+
+    n = k_pools.shape[0]
+    (x, k_pools, v_pools), _ = jax.lax.scan(
+        body, (x, k_pools, v_pools),
+        (jnp.arange(n, dtype=jnp.int32), sliced, kv_scales))
+    return x, k_pools, v_pools
+
+
+def _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
+                 seq_lens, kv_scales=None, mode=False, axis=None,
+                 collective=None):
+    """One decode token per sequence over paged pools: every decode
+    program (single-device fused and unfused, and the per-shard body of
+    both tensor-parallel placements) is this one function.
 
     tok: [B] int32 current tokens; k_pools/v_pools: [L, N, BS, KV, hd];
     block_tables: [B, MB]; seq_lens: [B] lengths INCLUDING the current
@@ -490,142 +526,137 @@ def _paged_decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
     ``kv_scales``: (k_scale [L, KV], v_scale [L, KV]) when the pools are
     int8 (static per-head cache quantization — reference block_attn.h
     int8 cache mode): halves KV HBM, the attention math stays fp32.
+    ``mode``: False runs the unfused composition (the bit-identical
+    test reference); otherwise the kernel registry picks each stage at
+    trace time (:func:`...fused_decode_block.resolve_decode_step`):
+    ONE single-launch megakernel for the block, or a fused attention
+    kernel and a fused MLP kernel, or the composition where a kernel
+    does not fit. ``axis`` / ``collective``: inside ``shard_map`` over
+    that mesh axis every array is the local shard (inference/tp.py has
+    the placements): "psum" all-reduces each stage's partial
+    projection, "gather" all-gathers before o_proj / down_proj and
+    always runs the composition.
+
+    Operands reach the launches of the layer loop without a copy where
+    the resolved variant can take them so
+    (:func:`...fused_decode_block.launch_operands` is the record): the
+    paged-attention kernel reads its layer out of the carried pools,
+    the fused MLP kernel its weights out of the stacked tree; the
+    variants that take one layer's array get ``pool[l]``.
     Returns (logits [B, V], k_pools, v_pools).
     """
-    from ..ops import rms_norm as fused_rms_norm, swiglu as fused_swiglu
-    from ..ops.paged_attention import (paged_attention_decode,
-                                      paged_attention_decode_quant,
-                                      write_to_pool, write_to_pool_quant)
+    from ..core.jax_compat import axis_size
+    from ..ops import rms_norm as fused_rms_norm
+    from ..ops.paged_attention import write_to_pool, write_to_pool_quant
+    from ..ops.pallas import fused_decode_block as fdb
+    from .tp import _lm_head, _local_dims
 
-    H, KV, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                 cfg.head_dim)
-    B = tok.shape[0]
-    x = jnp.take(params["embed_tokens"], tok, axis=0)  # [B, D]
-    pos_ids = seq_lens[:, None]  # [B, 1] rope position per sequence
-    # one rope table for all layers/steps (XLA hoists it as a constant)
+    tp = 1 if axis is None else int(axis_size(axis))
+    names = dict(fdb.UNFUSED)
+    block_fn, attn_fn, mlp_fn = None, fdb.attn_block_ref, fdb.mlp_block_ref
+    if mode and collective != "gather":
+        H, KV, F = _local_dims(params, cfg)
+        meta = fdb.decode_meta_dims(
+            tok.shape[0], cfg.hidden_size, H, KV, cfg.head_dim, F,
+            k_pools.shape[2], block_tables.shape[1], cfg.dtype,
+            k_pools.dtype, kv_scales is not None, tp=tp,
+            weight_dtype=_wq_mode(params))
+        if tp == 1:
+            block_fn, attn_fn, mlp_fn, names = fdb.resolve_decode_step(
+                meta, mode)
+        else:    # the single-launch kernel is single-device by contract
+            attn_fn, mlp_fn, stages = fdb.resolve_decode_blocks(meta, mode)
+            names.update(stages)
+    mlp_w = ("post_norm", "gate_proj", "up_proj", "down_proj")
+    mlp_by_index = fdb.launch_operands(
+        names, kv_scales is not None).get("decode_mlp_block") == "index"
+    eps = cfg.rms_norm_eps
     sin, cos = build_rope_cache(cfg.max_position_embeddings,
                                 cfg.head_dim, base=cfg.rope_theta)
+    # "psum": a stage returns its bare projection partial, ONE
+    # all-reduce rebuilds the replicated residual stream (the partial
+    # sums associate differently than the single-device reduction:
+    # roundoff-parity, documented in inference/tp.py)
+    residual = collective != "psum"
+    gather = None
+    if collective == "gather":
+        # heads / columns shard contiguously, so a tiled all-gather
+        # rebuilds the exact single-device tensor
+        def gather(t):
+            return jax.lax.all_gather(t, axis, axis=1, tiled=True)
 
-    def layer(x, xs):
-        if kv_scales is None:
-            lp, kp, vp = xs
-        else:
-            lp, kp, vp, ksc, vsc = xs
-        h = fused_rms_norm(x[:, None], lp["input_norm"].astype(x.dtype),
-                           cfg.rms_norm_eps)[:, 0]
-        q = _mm(h, lp["q_proj"]).reshape(B, 1, H, hd)
-        k = _mm(h, lp["k_proj"]).reshape(B, 1, KV, hd)
-        v = _mm(h, lp["v_proj"]).reshape(B, 1, KV, hd)
-        q = apply_rope(q, sin, cos, position_ids=pos_ids)
-        k = apply_rope(k, sin, cos, position_ids=pos_ids)
-        if kv_scales is None:
-            kp, vp = write_to_pool(kp, vp, block_tables, seq_lens,
-                                   k[:, 0].astype(kp.dtype),
-                                   v[:, 0].astype(vp.dtype))
-            attn = paged_attention_decode(q[:, 0], kp, vp, block_tables,
-                                          seq_lens + 1)
-        else:
-            kp, vp = write_to_pool_quant(kp, vp, block_tables, seq_lens,
-                                         k[:, 0], v[:, 0], ksc, vsc)
-            attn = paged_attention_decode_quant(
-                q[:, 0], kp, vp, block_tables, seq_lens + 1, ksc, vsc)
-        x = x + _mm(attn.reshape(B, H * hd).astype(x.dtype),
-                    lp["o_proj"])
-        h = fused_rms_norm(x[:, None], lp["post_norm"].astype(x.dtype),
-                           cfg.rms_norm_eps)[:, 0]
-        ff = fused_swiglu(_mm(h, lp["gate_proj"]), _mm(h, lp["up_proj"]))
-        x = x + _mm(ff, lp["down_proj"])
-        return x, (kp, vp)
+    def add(x, out):
+        return out if residual else x + jax.lax.psum(out, axis)
 
-    scan_xs = (params["layers"], k_pools, v_pools) if kv_scales is None \
-        else (params["layers"], k_pools, v_pools) + tuple(kv_scales)
-    x, (k_pools, v_pools) = jax.lax.scan(layer, x, scan_xs)
+    def append(kp, vp, k_new, v_new, scales, l):
+        if scales is None:
+            return write_to_pool(kp, vp, block_tables, seq_lens,
+                                 k_new.astype(kp.dtype),
+                                 v_new.astype(vp.dtype), layer=l)
+        return write_to_pool_quant(kp, vp, block_tables, seq_lens,
+                                   k_new, v_new, *scales, layer=l)
+
+    def layer(x, l, lp, kp, vp, scales):
+        nw = lp["input_norm"].astype(x.dtype)
+        mlp_args = [lp["post_norm"].astype(x.dtype)] \
+            + [lp[k] for k in mlp_w[1:]] + [eps]
+        attn_w = [lp[k] for k in ("q_proj", "k_proj", "v_proj", "o_proj")]
+        if attn_fn is fdb.attn_block_ref:
+            # the composition reads the new token from the pool: write
+            # it first (once, in place), then attend over the carried
+            # pools at this layer
+            q, k_new, v_new = fdb.attn_qkv_ref(x, nw, *attn_w[:3], sin,
+                                               cos, seq_lens, eps)
+            kp, vp = append(kp, vp, k_new, v_new, scales, l)
+            x = add(x, fdb.attn_out_ref(
+                x, q, attn_w[3], kp, vp, block_tables, seq_lens, scales,
+                residual, layer=l, gather=gather))
+        else:
+            # these kernels fold the new token in from VMEM and take
+            # one layer's pools (a slice); the pool write follows
+            pool_args = (sin, cos, kp[l], vp[l], block_tables, seq_lens,
+                         scales, eps)
+            if block_fn is not None:
+                x, k_new, v_new = block_fn(x, nw, *attn_w, *mlp_args[:-1],
+                                           *pool_args)
+            else:
+                out, k_new, v_new = attn_fn(x, nw, *attn_w, *pool_args,
+                                            residual=residual)
+                x = add(x, out)
+            kp, vp = append(kp, vp, k_new, v_new, scales, l)
+        if block_fn is not None:
+            return x, kp, vp
+        kw = {}
+        if mlp_by_index:            # lp holds the MLP leaves whole
+            kw["layer"] = l
+        elif gather is not None:    # always the composition (above)
+            kw["gather"] = gather
+        return add(x, mlp_fn(x, *mlp_args, residual=residual, **kw)), \
+            kp, vp
+
+    x = jnp.take(params["embed_tokens"], tok, axis=0)        # [B, D]
+    x, k_pools, v_pools = _layer_loop(
+        params, x, k_pools, v_pools, kv_scales, layer,
+        stacked=mlp_w if mlp_by_index else ())
     x = fused_rms_norm(x[:, None], params["final_norm"].astype(x.dtype),
                        cfg.rms_norm_eps)[:, 0]
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed_tokens"].T
-    return x @ head, k_pools, v_pools
+    return x @ _lm_head(params), k_pools, v_pools
+
+
+def _paged_decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
+                       seq_lens, kv_scales=None):
+    """:func:`_decode_step` through the unfused composition."""
+    return _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
+                        seq_lens, kv_scales)
 
 
 def _fused_decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
                        seq_lens, kv_scales=None, mode="auto"):
-    """``_paged_decode_step`` through the fused decode-block kernels.
-
-    Per block, instead of ~6 separate programs: either ONE single-launch
-    megakernel for the whole block (attn + MLP, the residual handoff in
-    VMEM — where ``decode_block_fused`` dispatches, or mode="block"
-    forces it) with the pool append in between left exactly where it is
-    today, or the two-stage route: ONE fused attention kernel (RMSNorm
-    + QKV + RoPE + paged attention incl. the new token + o_proj +
-    residual), the pool append for the new token's K/V, and ONE fused
-    MLP kernel (RMSNorm + SwiGLU + residual). Variant choice (Pallas
-    megakernel(s) vs the bit-identical unfused composition) comes from
-    the kernel registry at trace time; ``mode`` forwards to
-    :func:`paddle_tpu.ops.pallas.fused_decode_block
-    .resolve_decode_step`. Signature and carried state match
-    ``_paged_decode_step`` exactly, so callers swap freely.
-    """
-    from ..ops import rms_norm as fused_rms_norm
-    from ..ops.paged_attention import write_to_pool, write_to_pool_quant
-    from ..ops.pallas.fused_decode_block import (decode_meta,
-                                                 resolve_decode_step)
-
-    B = tok.shape[0]
-    meta = decode_meta(cfg, B=B, BS=k_pools.shape[2],
-                       MB=block_tables.shape[1],
-                       pool_dtype=k_pools.dtype,
-                       quant=kv_scales is not None,
-                       weight_dtype=_wq_mode(params))
-    block_fn, attn_fn, mlp_fn, _ = resolve_decode_step(meta, mode)
-    x = jnp.take(params["embed_tokens"], tok, axis=0)        # [B, D]
-    sin, cos = build_rope_cache(cfg.max_position_embeddings,
-                                cfg.head_dim, base=cfg.rope_theta)
-
-    def layer(x, xs):
-        if kv_scales is None:
-            lp, kp, vp = xs
-            scales = None
-        else:
-            lp, kp, vp, ksc, vsc = xs
-            scales = (ksc, vsc)
-        if block_fn is not None:
-            # one launch per block; the pool write stays with the
-            # caller (the megakernel's MLP phase reads no pool state,
-            # so writing after it is the same math as between stages)
-            x, k_new, v_new = block_fn(
-                x, lp["input_norm"].astype(x.dtype), lp["q_proj"],
-                lp["k_proj"], lp["v_proj"], lp["o_proj"],
-                lp["post_norm"].astype(x.dtype), lp["gate_proj"],
-                lp["up_proj"], lp["down_proj"], sin, cos, kp, vp,
-                block_tables, seq_lens, scales, cfg.rms_norm_eps)
-        else:
-            x, k_new, v_new = attn_fn(
-                x, lp["input_norm"].astype(x.dtype), lp["q_proj"],
-                lp["k_proj"], lp["v_proj"], lp["o_proj"], sin, cos, kp,
-                vp, block_tables, seq_lens, scales, cfg.rms_norm_eps)
-        if scales is None:
-            kp, vp = write_to_pool(kp, vp, block_tables, seq_lens,
-                                   k_new.astype(kp.dtype),
-                                   v_new.astype(vp.dtype))
-        else:
-            kp, vp = write_to_pool_quant(kp, vp, block_tables, seq_lens,
-                                         k_new, v_new, ksc, vsc)
-        if block_fn is None:
-            x = mlp_fn(x, lp["post_norm"].astype(x.dtype),
-                       lp["gate_proj"], lp["up_proj"], lp["down_proj"],
-                       cfg.rms_norm_eps)
-        return x, (kp, vp)
-
-    scan_xs = (params["layers"], k_pools, v_pools) if kv_scales is None \
-        else (params["layers"], k_pools, v_pools) + tuple(kv_scales)
-    x, (k_pools, v_pools) = jax.lax.scan(layer, x, scan_xs)
-    x = fused_rms_norm(x[:, None], params["final_norm"].astype(x.dtype),
-                       cfg.rms_norm_eps)[:, 0]
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed_tokens"].T
-    return x @ head, k_pools, v_pools
+    """:func:`_decode_step` through the fused decode-block kernels the
+    registry selects under ``mode``; same signature and carried state
+    as ``_paged_decode_step``, so callers swap freely."""
+    return _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
+                        seq_lens, kv_scales, mode=mode)
 
 
 def _decode_variant_name(cfg, B, BS, MB, pool_dtype, quant, fused,

@@ -792,16 +792,23 @@ class ServingEngine:
                 self._pcache.check()
 
     def _resolve_variant(self) -> Dict:
-        from ..ops.pallas.fused_decode_block import (decode_meta,
+        from ..ops.pallas.fused_decode_block import (UNFUSED, decode_meta,
+                                                     decode_meta_dims,
+                                                     launch_operands,
                                                      resolve_decode_step)
-        from ..ops.pallas.fused_decode_block import decode_meta_dims
+
+        def report(mode, names):
+            return {"mode": mode, **names,
+                    "operands": launch_operands(names, self._quant)}
+
         sm = self._mesh
+        if not self._fused:
+            return report("unfused", UNFUSED)
         if sm is not None and sm.collective == "gather":
             # the gather placement's bit-parity contract IS the
             # single-device op sequence — it always runs the exact
             # composition, whatever the fused knob says
-            return {"mode": str(self._fused), "block": "composed",
-                    "attn": "unfused", "mlp": "unfused"}
+            return report(str(self._fused), UNFUSED)
         cfg, tp = self.cfg, (1 if sm is None else sm.tp)
         if tp == 1:
             meta = decode_meta(cfg, B=self.capacity,
@@ -821,23 +828,24 @@ class ServingEngine:
                 self.max_blocks, cfg.dtype, self._k_pools.dtype,
                 self._quant, tp=tp, weight_dtype=self._wq)
         _, _, _, names = resolve_decode_step(meta, self._fused)
-        return {"mode": str(self._fused), **names}
+        return report(str(self._fused), names)
 
     @property
     def decode_variant(self) -> Dict:
         """Which decode-block implementation this engine's decode
         program runs: ``{"mode": ..., "block": ..., "attn": ...,
-        "mlp": ...}`` — "block" is the single-launch megakernel's slot
-        ("pallas_block" when it serves the step, "composed" when the
-        two-stage route does). Captured when the decode program TRACES
+        "mlp": ..., "operands": {...}}`` — "block" is the single-launch
+        megakernel's slot ("pallas_block" when it serves the step,
+        "composed" when the two-stage route does); "operands" says, for
+        each Pallas launch of the layer loop, whether it takes its
+        layer of the KV pools / stacked weights by "index" (no copy) or
+        as a "slice" (``fused_decode_block.launch_operands``). Captured
+        when the decode program TRACES
         (dispatch is consulted at trace time), so later env changes —
         the VMEM budget, a ``KERNELS.force`` pin around a ``metrics()``
         call — cannot make the report drift from the compiled program.
         Before the first decode step it reports what dispatch would
         pick now."""
-        if not self._fused:
-            return {"mode": "unfused", "block": "composed",
-                    "attn": "unfused", "mlp": "unfused"}
         if self._decode_variant is not None:
             return dict(self._decode_variant)
         return self._resolve_variant()
@@ -1670,7 +1678,7 @@ class ServingEngine:
         def step(params, tok, seq_lens, tables, temps, key,
                  k_pools, v_pools):
             counters["decode_traces"] += 1
-            if fused and record_variant:
+            if record_variant:
                 # trace-time snapshot: the same dispatch the
                 # decode_step below consults, captured in the same
                 # context, so decode_variant reports compiled reality.
@@ -1711,7 +1719,7 @@ class ServingEngine:
         def step(params, tok, seq_lens, tables, temps, key,
                  k_pools, v_pools):
             counters["decode_traces"] += 1
-            if fused and record_variant:
+            if record_variant:
                 self._decode_variant = self._resolve_variant()
             extra = tuple(scales) if scales is not None else ()
             logits, k_pools, v_pools = sharded(

@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.jax_compat import axis_size, shard_map_norep
+from ..core.jax_compat import shard_map_norep
 
 __all__ = ["ServingMesh", "tp_reject_reason", "normalize_mesh"]
 
@@ -310,22 +310,21 @@ def _tp_decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
                     seq_lens, kv_scales=None, axis="tp",
                     collective="psum", fused=False):
     """One tensor-parallel decode token per slot — the per-shard body
-    of the engine's single jitted decode program. Mirrors
-    ``generation._paged_decode_step`` / ``_fused_decode_step`` exactly,
-    with the collective placement documented in the module docstring.
+    of the engine's single jitted decode program: the ONE decode step
+    (``generation._decode_step``, its layer loop included) over the
+    local shards, with the collective placement documented in the
+    module docstring.
 
     ``fused``: the decode-block route (False = the exact composition,
     "auto"/"pallas"/"ref" = registry dispatch over the PER-SHARD meta).
     The "gather" placement always runs the composition — its bit-parity
-    contract IS the single-device op sequence.
+    contract IS the single-device op sequence, with the per-shard heads
+    / SwiGLU columns all-gathered BEFORE o_proj / down_proj so those
+    matmuls see exactly the single-device operands. No collective but
+    the declared ones is emitted (the audited jaxpr carries exactly
+    those).
     """
-    from ..ops import rms_norm as fused_rms_norm
-    from ..ops.paged_attention import write_to_pool, write_to_pool_quant
-    from ..ops.pallas.fused_decode_block import (attn_block_ref,
-                                                 decode_meta_dims,
-                                                 mlp_block_ref,
-                                                 resolve_decode_blocks)
-    from ..ops.rope import build_rope_cache
+    from .generation import _decode_step
 
     if fused == "block":
         # the single-launch block kernel is single-device by contract
@@ -336,131 +335,9 @@ def _tp_decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
         raise ValueError("fused_decode='block' is single-device: "
                          "tensor-parallel decode runs the per-stage "
                          "kernels")
-    # static axis-env lookup (jax_compat): NO collective may be emitted
-    # here — the audited jaxpr carries exactly the declared collectives
-    tp = int(axis_size(axis))
-    B = tok.shape[0]
-    H_loc, KV_loc, F_loc = _local_dims(params, cfg)
-    quant = kv_scales is not None
-    if collective == "gather":
-        return _tp_decode_step_gather(params, tok, cfg, k_pools,
-                                      v_pools, block_tables, seq_lens,
-                                      kv_scales, axis)
-    if fused:
-        from .generation import _wq_mode
-        meta = decode_meta_dims(
-            B, cfg.hidden_size, H_loc, KV_loc, cfg.head_dim, F_loc,
-            k_pools.shape[2], block_tables.shape[1], cfg.dtype,
-            k_pools.dtype, quant, tp=tp,
-            weight_dtype=_wq_mode(params))
-        attn_fn, mlp_fn, _ = resolve_decode_blocks(meta, fused)
-    else:
-        attn_fn, mlp_fn = attn_block_ref, mlp_block_ref
-
-    x = jnp.take(params["embed_tokens"], tok, axis=0)          # [B, D]
-    sin, cos = build_rope_cache(cfg.max_position_embeddings,
-                                cfg.head_dim, base=cfg.rope_theta)
-
-    def layer(x, xs):
-        if kv_scales is None:
-            lp, kp, vp = xs
-            scales = None
-        else:
-            lp, kp, vp, ksc, vsc = xs
-            scales = (ksc, vsc)
-        part, k_new, v_new = attn_fn(
-            x, lp["input_norm"].astype(x.dtype), lp["q_proj"],
-            lp["k_proj"], lp["v_proj"], lp["o_proj"], sin, cos, kp, vp,
-            block_tables, seq_lens, scales, cfg.rms_norm_eps,
-            residual=False)
-        # ONE all-reduce for the attention sub-block, then the
-        # replicated residual add (partial sums associate differently
-        # than the single-device reduction: roundoff-parity, documented)
-        x = x + jax.lax.psum(part, axis)
-        if scales is None:
-            kp, vp = write_to_pool(kp, vp, block_tables, seq_lens,
-                                   k_new.astype(kp.dtype),
-                                   v_new.astype(vp.dtype))
-        else:
-            kp, vp = write_to_pool_quant(kp, vp, block_tables, seq_lens,
-                                         k_new, v_new, ksc, vsc)
-        part = mlp_fn(x, lp["post_norm"].astype(x.dtype),
-                      lp["gate_proj"], lp["up_proj"], lp["down_proj"],
-                      cfg.rms_norm_eps, residual=False)
-        x = x + jax.lax.psum(part, axis)       # the MLP sub-block's one
-        return x, (kp, vp)
-
-    scan_xs = (params["layers"], k_pools, v_pools) if kv_scales is None \
-        else (params["layers"], k_pools, v_pools) + tuple(kv_scales)
-    x, (k_pools, v_pools) = jax.lax.scan(layer, x, scan_xs)
-    x = fused_rms_norm(x[:, None], params["final_norm"].astype(x.dtype),
-                       cfg.rms_norm_eps)[:, 0]
-    return x @ _lm_head(params), k_pools, v_pools
-
-
-def _tp_decode_step_gather(params, tok, cfg, k_pools, v_pools,
-                           block_tables, seq_lens, kv_scales, axis):
-    """The "gather" placement decode body: per-shard heads/columns,
-    all-gather BEFORE o_proj/down_proj so those matmuls see exactly the
-    single-device operands — bit-identical greedy output by
-    construction (every float op has the same inputs, shapes and
-    reduction order as ``_paged_decode_step``)."""
-    from ..ops import rms_norm as fused_rms_norm, swiglu as fused_swiglu
-    from ..ops.paged_attention import (paged_attention_decode,
-                                       paged_attention_decode_quant,
-                                       write_to_pool, write_to_pool_quant)
-    from ..ops.rope import apply_rope, build_rope_cache
-    from .generation import _mm
-
-    H, hd = cfg.num_attention_heads, cfg.head_dim
-    B = tok.shape[0]
-    H_loc, KV_loc, _ = _local_dims(params, cfg)
-    x = jnp.take(params["embed_tokens"], tok, axis=0)
-    pos_ids = seq_lens[:, None]
-    sin, cos = build_rope_cache(cfg.max_position_embeddings,
-                                cfg.head_dim, base=cfg.rope_theta)
-
-    def layer(x, xs):
-        if kv_scales is None:
-            lp, kp, vp = xs
-        else:
-            lp, kp, vp, ksc, vsc = xs
-        h = fused_rms_norm(x[:, None], lp["input_norm"].astype(x.dtype),
-                           cfg.rms_norm_eps)[:, 0]
-        q = _mm(h, lp["q_proj"]).reshape(B, 1, H_loc, hd)
-        k = _mm(h, lp["k_proj"]).reshape(B, 1, KV_loc, hd)
-        v = _mm(h, lp["v_proj"]).reshape(B, 1, KV_loc, hd)
-        q = apply_rope(q, sin, cos, position_ids=pos_ids)
-        k = apply_rope(k, sin, cos, position_ids=pos_ids)
-        if kv_scales is None:
-            kp, vp = write_to_pool(kp, vp, block_tables, seq_lens,
-                                   k[:, 0].astype(kp.dtype),
-                                   v[:, 0].astype(vp.dtype))
-            attn = paged_attention_decode(q[:, 0], kp, vp, block_tables,
-                                          seq_lens + 1)
-        else:
-            kp, vp = write_to_pool_quant(kp, vp, block_tables, seq_lens,
-                                         k[:, 0], v[:, 0], ksc, vsc)
-            attn = paged_attention_decode_quant(
-                q[:, 0], kp, vp, block_tables, seq_lens + 1, ksc, vsc)
-        # heads shard contiguously, so tiled all-gather on the head
-        # axis rebuilds the exact single-device [B, H, hd] tensor
-        attn = jax.lax.all_gather(attn, axis, axis=1, tiled=True)
-        x = x + _mm(attn.reshape(B, H * hd).astype(x.dtype),
-                    lp["o_proj"])
-        h = fused_rms_norm(x[:, None], lp["post_norm"].astype(x.dtype),
-                           cfg.rms_norm_eps)[:, 0]
-        ff = fused_swiglu(_mm(h, lp["gate_proj"]), _mm(h, lp["up_proj"]))
-        ff = jax.lax.all_gather(ff, axis, axis=1, tiled=True)  # [B, F]
-        x = x + _mm(ff, lp["down_proj"])
-        return x, (kp, vp)
-
-    scan_xs = (params["layers"], k_pools, v_pools) if kv_scales is None \
-        else (params["layers"], k_pools, v_pools) + tuple(kv_scales)
-    x, (k_pools, v_pools) = jax.lax.scan(layer, x, scan_xs)
-    x = fused_rms_norm(x[:, None], params["final_norm"].astype(x.dtype),
-                       cfg.rms_norm_eps)[:, 0]
-    return x @ _lm_head(params), k_pools, v_pools
+    return _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
+                        seq_lens, kv_scales, mode=fused, axis=axis,
+                        collective=collective)
 
 
 def _tp_cached_layer(lp, x, sin, cos, cfg, kc, vc, pos, axis,

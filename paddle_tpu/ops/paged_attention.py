@@ -33,14 +33,24 @@ _FLAGS.define(
     "(0 = XLA gather+einsum composition, for A/B perf diagnosis)")
 
 
+def paged_kernel_routed() -> bool:
+    """Whether :func:`paged_attention_decode` launches the Pallas
+    kernel in the program being traced (the flag, a TPU backend, a
+    program a Mosaic kernel can be lowered into)."""
+    return bool(_FLAGS.get("use_paged_kernel")) and pallas_route()
+
+
 def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
-                           scale: Optional[float] = None):
+                           scale: Optional[float] = None, layer=None):
     """Single-step decode attention over a paged cache.
 
     q:            [B, H, hd]     query for the current position
     k_pool/v_pool:[N, BS, KV, hd] physical block pools
     block_tables: [B, MB] int32  physical block id per logical block
     seq_lens:     [B]    int32   valid tokens per sequence (incl. current)
+    layer:        the pools are the stacked [L, N, BS, KV, hd] and this
+                  is the layer to attend over (the decode loop's carried
+                  pools: the kernel addresses the layer itself)
     returns       [B, H, hd]
 
     On TPU this routes to the Pallas kernel (ops/pallas/paged_attention.py)
@@ -48,21 +58,24 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
     gather+einsum below runs off-TPU and when FLAGS_use_paged_kernel is
     off. A kernel failure on TPU raises.
     """
-    if _FLAGS.get("use_paged_kernel") and pallas_route():
+    if paged_kernel_routed():
         from .pallas.paged_attention import paged_attention_decode_pallas
         return paged_attention_decode_pallas(
-            q, k_pool, v_pool, block_tables, seq_lens, scale=scale)
+            q, k_pool, v_pool, block_tables, seq_lens, scale=scale,
+            layer=layer)
     return paged_attention_decode_xla(q, k_pool, v_pool, block_tables,
-                                      seq_lens, scale=scale)
+                                      seq_lens, scale=scale, layer=layer)
 
 
 def paged_attention_decode_xla(q, k_pool, v_pool, block_tables, seq_lens,
                                scale: Optional[float] = None,
-                               k_scale=None, v_scale=None):
+                               k_scale=None, v_scale=None, layer=None):
     """Gather+einsum reference path (always XLA, any backend).
     ``k_scale``/``v_scale`` [KV]: per-head dequant for int8 pools —
     applied right after the gather so the rest of the math is shared
-    with the bf16 path."""
+    with the bf16 path. ``layer``: stacked pools, read at that layer."""
+    if layer is not None:
+        k_pool, v_pool = k_pool[layer], v_pool[layer]
     B, H, hd = q.shape
     N, BS, KV, _ = k_pool.shape
     MB = block_tables.shape[1]
@@ -93,21 +106,26 @@ def paged_attention_decode_xla(q, k_pool, v_pool, block_tables, seq_lens,
     return out.astype(q.dtype)
 
 
-def write_to_pool(k_pool, v_pool, block_tables, seq_lens, k_new, v_new):
+def write_to_pool(k_pool, v_pool, block_tables, seq_lens, k_new, v_new,
+                  layer=None):
     """Append one token's K/V per sequence into the paged pools.
 
     k_new/v_new: [B, KV, hd] for the token at position seq_lens[b] (0-based
     position == current length before append). Returns updated pools.
+    ``layer``: the pools are the stacked [L, N, BS, KV, hd] and the
+    rows land at ``[layer, page, slot]``: one scatter into the whole
+    buffer, which a loop that carries the pools performs in place and
+    which touches no other layer's pages.
     """
-    B = k_new.shape[0]
-    BS = k_pool.shape[1]
+    BS = k_pool.shape[-3]
     pos = seq_lens                       # position to write
     blk_idx = pos // BS                  # logical block
     offset = pos % BS
     phys = jnp.take_along_axis(block_tables, blk_idx[:, None],
                                axis=1)[:, 0]          # [B]
-    k_pool = k_pool.at[phys, offset].set(k_new)
-    v_pool = v_pool.at[phys, offset].set(v_new)
+    at = (phys, offset) if layer is None else (layer, phys, offset)
+    k_pool = k_pool.at[at].set(k_new)
+    v_pool = v_pool.at[at].set(v_new)
     return k_pool, v_pool
 
 
@@ -190,7 +208,7 @@ def quant_cache(x, scale):
 
 
 def write_to_pool_quant(k_pool, v_pool, block_tables, seq_lens,
-                        k_new, v_new, k_scale, v_scale):
+                        k_new, v_new, k_scale, v_scale, layer=None):
     """``write_to_pool`` for int8 pools: the new token's K/V quantize
     with the static per-head scales on the way in."""
     def q(x, s):
@@ -198,17 +216,20 @@ def write_to_pool_quant(k_pool, v_pool, block_tables, seq_lens,
                                   / s[None, :, None]),
                         -127, 127).astype(jnp.int8)
     return write_to_pool(k_pool, v_pool, block_tables, seq_lens,
-                         q(k_new, k_scale), q(v_new, v_scale))
+                         q(k_new, k_scale), q(v_new, v_scale),
+                         layer=layer)
 
 
 def paged_attention_decode_quant(q, k_pool, v_pool, block_tables,
                                  seq_lens, k_scale, v_scale,
-                                 scale: Optional[float] = None):
+                                 scale: Optional[float] = None,
+                                 layer=None):
     """Decode attention over int8 pools: gather int8 (the HBM win),
     dequant per head, then the SAME attention math as the bf16 path."""
     return paged_attention_decode_xla(q, k_pool, v_pool, block_tables,
                                       seq_lens, scale=scale,
-                                      k_scale=k_scale, v_scale=v_scale)
+                                      k_scale=k_scale, v_scale=v_scale,
+                                      layer=layer)
 
 
 class BlockManager:

@@ -85,13 +85,19 @@ def clamped_page_index(BS, pp, j):
     fail MLIR verification. Shared by the unfused paged-decode kernel
     and the fused attention megakernel — the clamp must not be able to
     drift between the two, or their bit-parity contract breaks.
+
+    A third scalar-prefetch operand, where the launch has one, is the
+    layer of a STACKED pool ``[L, N, BS, KV, hd]``: the map then
+    returns ``(layer, page, 0, 0, 0)`` and the kernel reads its layer's
+    pages out of the whole buffer, with no slice made for it.
     """
-    def f(b, mi, bt_ref, len_ref):
+    def f(b, mi, bt_ref, len_ref, *layer_ref):
         last = jnp.maximum(len_ref[b] - jnp.int32(1),
                            jnp.int32(0)) // jnp.int32(BS)
         idx = jnp.minimum(mi.astype(jnp.int32) * jnp.int32(pp)
                           + jnp.int32(j), last)
-        return (bt_ref[b, idx], 0, 0, 0)
+        page = (bt_ref[b, idx], 0, 0, 0)
+        return (layer_ref[0][0], *page) if layer_ref else page
     return f
 
 

@@ -69,6 +69,7 @@ __all__ = [
     "fused_decode_block_pallas", "decode_block_composed",
     "attn_block_ref", "mlp_block_ref", "decode_meta",
     "decode_meta_dims",
+    "attn_qkv_ref", "attn_out_ref", "launch_operands", "UNFUSED",
     "resolve_decode_blocks", "resolve_decode_step",
     "mlp_autotune_key", "attn_autotune_key", "block_autotune_key",
     "weight_dtype_of", "scoped_vmem_budget",
@@ -501,8 +502,8 @@ def fused_attn_block_pallas(x, nw, wq, wk, wv, wo, sin, cos,
 # ---------------------------------------------------------------------------
 # MLP-stage megakernel
 # ---------------------------------------------------------------------------
-def _mlp_block_kernel(x_ref, nw_ref, wg_ref, wu_ref, wd_ref, *rest,
-                      eps, residual, wq_bits=0):
+def _mlp_block_kernel(_layer_ref, x_ref, nw_ref, wg_ref, wu_ref, wd_ref,
+                      *rest, eps, residual, wq_bits=0):
     if wq_bits:
         sg_ref, su_ref, sd_ref = rest[:3]
         rest = rest[3:]
@@ -610,7 +611,7 @@ def _mlp_fitting_candidates(B: int, D: int, F: int, itemsize: int,
 
 @no_x64
 def fused_mlp_block_pallas(x, nw, wg, wu, wd, eps=1e-6, block_f=None,
-                           residual=True):
+                           residual=True, layer=None):
     """Fused MLP stage of one decode block: RMSNorm + SwiGLU + residual.
 
     x: [B, D]; nw: [D] at x.dtype; wg/wu: [D, F]; wd: [F, D]. Tiled over
@@ -618,6 +619,12 @@ def fused_mlp_block_pallas(x, nw, wg, wu, wd, eps=1e-6, block_f=None,
     3*D*block_f weight elements are VMEM-resident per grid step.
     ``residual=False`` returns the bare down-projection (tensor-parallel
     partial — the caller all-reduces, then adds the residual).
+    ``layer``: nw / wg / wu / wd (plain or quantized leaves) are the
+    STACKED per-layer arrays ([L, D], [L, D, F], [L, F, D]) and this is
+    the layer to run (an int or a traced int32 scalar): the launch
+    takes the whole arrays and its index maps address the layer, so
+    inside a loop over layers no one-layer copy of the weights is made.
+    Bit-identical to passing each array's ``[layer]`` slice.
     """
     B, D = x.shape
     # weight-quant normalization (the attn wrapper's idiom): original
@@ -627,7 +634,7 @@ def fused_mlp_block_pallas(x, nw, wg, wu, wd, eps=1e-6, block_f=None,
     wu, su, _, _ = _wq_parts(wu)
     wd, sd, _, _ = _wq_parts(wd)
     weight_dtype = weight_dtype_of(wg_in, wu_in, wd_in)
-    F = wg.shape[1]
+    F = wg.shape[-1]
     w_it = {8: 1.0, 4: 0.5}.get(bits)
     if block_f is None:
         it = jnp.dtype(x.dtype).itemsize
@@ -643,7 +650,8 @@ def fused_mlp_block_pallas(x, nw, wg, wu, wd, eps=1e-6, block_f=None,
         def build(bf):
             return lambda *a: fused_mlp_block_pallas(*a, eps=eps,
                                                      block_f=bf,
-                                                     residual=residual)
+                                                     residual=residual,
+                                                     layer=layer)
 
         block_f = _tuned_pages(ck, cands, build,
                                (x, nw, wg_in, wu_in, wd_in))
@@ -658,31 +666,41 @@ def fused_mlp_block_pallas(x, nw, wg, wu, wd, eps=1e-6, block_f=None,
         raise ValueError(f"block_f={bf} must divide the intermediate "
                          f"dim F={F}")
 
-    const = lambda j: (0, 0)                              # noqa: E731
+    # every per-layer operand is a stack [L, ...] with a squeezed block
+    # dimension that the layer (the scalar-prefetch operand) indexes;
+    # one layer's arrays are a stack of one
+    per_layer = [nw, wg, wu, wd] + ([sg, su, sd] if bits else [])
+    if layer is None:
+        per_layer, layer = [a[None] for a in per_layer], 0
+    nw, wg, wu, wd, *scales = per_layer
+    const = lambda j, l: (0, 0)                           # noqa: E731
+    row = lambda j, l: (l[0], 0, 0)                       # noqa: E731
+    f_cols = lambda j, l: (l[0], 0, j)                    # noqa: E731
+    f_rows = lambda j, l: (l[0], j, 0)                    # noqa: E731
     # stored-shape tiles: int4 halves gate/up rows (pack axis 0 = the
     # contraction dim, fully covered by every tile) and down COLUMNS
     # (pack axis 1 = its output dim); the F-axis tiling is over the
     # UNPACKED coordinate for gate/up and over wd's packed rows 1:1
-    gu_rows = wg.shape[0]
-    wd_cols = wd.shape[1]
+    gu_rows = wg.shape[-2]
+    wd_cols = wd.shape[-1]
     bf_wd = bf                            # wd rows tile the F axis 1:1
     in_specs = [pl.BlockSpec((B, D), const),
-                pl.BlockSpec((1, D), const),
-                pl.BlockSpec((gu_rows, bf), lambda j: (0, j)),
-                pl.BlockSpec((gu_rows, bf), lambda j: (0, j)),
-                pl.BlockSpec((bf_wd, wd_cols), lambda j: (j, 0))]
-    inputs = [x, nw.reshape(1, D), wg, wu, wd]
+                pl.BlockSpec((None, 1, D), row),
+                pl.BlockSpec((None, gu_rows, bf), f_cols),
+                pl.BlockSpec((None, gu_rows, bf), f_cols),
+                pl.BlockSpec((None, bf_wd, wd_cols), f_rows)]
+    inputs = [x, nw.reshape(-1, 1, D), wg, wu, wd]
     if bits:
-        in_specs += [pl.BlockSpec((1, bf), lambda j: (0, j)),
-                     pl.BlockSpec((1, bf), lambda j: (0, j)),
-                     pl.BlockSpec((1, D), const)]
-        inputs += [jnp.asarray(sg, jnp.float32).reshape(1, F),
-                   jnp.asarray(su, jnp.float32).reshape(1, F),
-                   jnp.asarray(sd, jnp.float32).reshape(1, D)]
+        in_specs += [pl.BlockSpec((None, 1, bf), f_cols),
+                     pl.BlockSpec((None, 1, bf), f_cols),
+                     pl.BlockSpec((None, 1, D), row)]
+        inputs += [jnp.asarray(sc, jnp.float32).reshape(-1, 1, n)
+                   for sc, n in zip(scales, (F, F, D))]
     out = audited_pallas_call(
         functools.partial(_mlp_block_kernel, eps=eps, residual=residual,
                           wq_bits=bits),
         name="decode_mlp_block",
+        num_scalar_prefetch=1,
         # the output block is revisited every intermediate tile (down-
         # projection accumulated in scratch, written at the last tile)
         accum_outputs=(0,),
@@ -693,7 +711,7 @@ def fused_mlp_block_pallas(x, nw, wg, wu, wd, eps=1e-6, block_f=None,
         scratch_shapes=[pltpu.VMEM((B, D), x.dtype),
                         pltpu.VMEM((B, D), jnp.float32)],
         interpret=_interpret(),
-    )(*inputs)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
     return out
 
 
@@ -1127,54 +1145,83 @@ def decode_block_composed(x, nw, wq, wk, wv, wo, pw, wg, wu, wd, sin,
 # sequence, so dispatch falling back here is bit-identical to the
 # original ``_paged_decode_step`` math
 # ---------------------------------------------------------------------------
-def attn_block_ref(x, nw, wq, wk, wv, wo, sin, cos, k_pool, v_pool,
-                   block_tables, seq_lens, kv_scales=None, eps=1e-6,
-                   residual=True):
+def attn_qkv_ref(x, nw, wq, wk, wv, sin, cos, seq_lens, eps=1e-6):
+    """First half of the unfused attention stage: RMSNorm, the q/k/v
+    projections and RoPE at each slot's position. Returns (q [B, H, hd],
+    k_new, v_new [B, KV, hd]); head counts are read off the weights, so
+    a tensor-parallel shard gets its local heads."""
     from .. import rms_norm as fused_rms_norm
-    from ..paged_attention import (paged_attention_decode,
-                                   paged_attention_decode_quant,
-                                   write_to_pool, write_to_pool_quant)
     from ..rope import apply_rope
     from ...quantization.quanters import maybe_dequantize
 
     # quantized weight leaves take the DEQUANTIZE-THEN-MATMUL route
     # here — the priority-0 fallback contract is bit-identical to that
     # composition by construction
-    wq = maybe_dequantize(wq, x.dtype)
-    wk = maybe_dequantize(wk, x.dtype)
-    wv = maybe_dequantize(wv, x.dtype)
-    wo = maybe_dequantize(wo, x.dtype)
-    B, D = x.shape
-    _, _, KV, hd = k_pool.shape
-    H = wq.shape[1] // hd
+    B = x.shape[0]
+    hd = sin.shape[-1] * 2               # the rope table is [T, hd // 2]
     pos_ids = seq_lens[:, None]
     h = fused_rms_norm(x[:, None], nw, eps)[:, 0]
-    q = (h @ wq).reshape(B, 1, H, hd)
-    k = (h @ wk).reshape(B, 1, KV, hd)
-    v = (h @ wv).reshape(B, 1, KV, hd)
+    q, k, v = ((h @ maybe_dequantize(w, x.dtype)).reshape(B, 1, -1, hd)
+               for w in (wq, wk, wv))
     q = apply_rope(q, sin, cos, position_ids=pos_ids)
     k = apply_rope(k, sin, cos, position_ids=pos_ids)
-    k_new, v_new = k[:, 0], v[:, 0]
-    # the internal write below makes attention see the new token; the
-    # caller performs the SAME write for the carried pools, and XLA
-    # CSEs the duplicate scatter away
+    return q[:, 0], k[:, 0], v[:, 0]
+
+
+def attn_out_ref(x, q, wo, k_pool, v_pool, block_tables, seq_lens,
+                 kv_scales=None, residual=True, layer=None, gather=None):
+    """Second half: paged attention over pools that ALREADY hold the
+    new token (so it runs over ``seq_lens + 1``), the output projection
+    and the residual. ``layer``: the pools are the decode loop's
+    carried stack and the attention launch addresses that layer.
+    ``gather``: applied to the [B, H_loc, hd] heads before the
+    projection (the tensor-parallel "gather" placement's all-gather)."""
+    from ..paged_attention import (paged_attention_decode,
+                                   paged_attention_decode_quant)
+    from ...quantization.quanters import maybe_dequantize
+
+    if kv_scales is None:
+        attn = paged_attention_decode(q, k_pool, v_pool, block_tables,
+                                      seq_lens + 1, layer=layer)
+    else:
+        attn = paged_attention_decode_quant(
+            q, k_pool, v_pool, block_tables, seq_lens + 1, *kv_scales,
+            layer=layer)
+    if gather is not None:
+        attn = gather(attn)
+    o = attn.reshape(x.shape[0], -1).astype(x.dtype) \
+        @ maybe_dequantize(wo, x.dtype)
+    return x + o if residual else o
+
+
+def attn_block_ref(x, nw, wq, wk, wv, wo, sin, cos, k_pool, v_pool,
+                   block_tables, seq_lens, kv_scales=None, eps=1e-6,
+                   residual=True):
+    """The unfused attention stage over ONE layer's pools, with the
+    stage kernels' contract: returns (x, k_new, v_new) and leaves the
+    pool write to the caller. Attention has to see the new token, so it
+    runs over a local copy of the pools that holds it; the decode loop,
+    which carries the pools, calls the two halves itself around its one
+    in-place write instead."""
+    from ..paged_attention import write_to_pool, write_to_pool_quant
+
+    q, k_new, v_new = attn_qkv_ref(x, nw, wq, wk, wv, sin, cos, seq_lens,
+                                   eps)
     if kv_scales is None:
         kp, vp = write_to_pool(k_pool, v_pool, block_tables, seq_lens,
                                k_new.astype(k_pool.dtype),
                                v_new.astype(v_pool.dtype))
-        attn = paged_attention_decode(q[:, 0], kp, vp, block_tables,
-                                      seq_lens + 1)
     else:
-        ksc, vsc = kv_scales
         kp, vp = write_to_pool_quant(k_pool, v_pool, block_tables,
-                                     seq_lens, k_new, v_new, ksc, vsc)
-        attn = paged_attention_decode_quant(
-            q[:, 0], kp, vp, block_tables, seq_lens + 1, ksc, vsc)
-    o = attn.reshape(B, H * hd).astype(x.dtype) @ wo
-    return (x + o if residual else o), k_new, v_new
+                                     seq_lens, k_new, v_new, *kv_scales)
+    return attn_out_ref(x, q, wo, kp, vp, block_tables, seq_lens,
+                        kv_scales, residual), k_new, v_new
 
 
-def mlp_block_ref(x, nw, wg, wu, wd, eps=1e-6, residual=True):
+def mlp_block_ref(x, nw, wg, wu, wd, eps=1e-6, residual=True,
+                  gather=None):
+    """``gather``: applied to the [B, F_loc] SwiGLU columns before the
+    down projection (the "gather" placement's all-gather)."""
     from .. import rms_norm as fused_rms_norm, swiglu as fused_swiglu
     from ...quantization.quanters import maybe_dequantize
 
@@ -1183,6 +1230,8 @@ def mlp_block_ref(x, nw, wg, wu, wd, eps=1e-6, residual=True):
     wd = maybe_dequantize(wd, x.dtype)
     h = fused_rms_norm(x[:, None], nw, eps)[:, 0]
     ff = fused_swiglu(h @ wg, h @ wu)
+    if gather is not None:
+        ff = gather(ff)
     o = ff @ wd
     return x + o if residual else o
 
@@ -1343,9 +1392,10 @@ def _attn_pallas_variant(x, nw, wq, wk, wv, wo, sin, cos, k_pool,
                                    eps=eps, residual=residual)
 
 
-def _mlp_pallas_variant(x, nw, wg, wu, wd, eps=1e-6, residual=True):
+def _mlp_pallas_variant(x, nw, wg, wu, wd, eps=1e-6, residual=True,
+                        layer=None):
     return fused_mlp_block_pallas(x, nw, wg, wu, wd, eps=eps,
-                                  residual=residual)
+                                  residual=residual, layer=layer)
 
 
 def _block_pallas_variant(x, nw, wq, wk, wv, wo, pw, wg, wu, wd, sin,
@@ -1449,3 +1499,31 @@ def resolve_decode_step(meta: dict, mode="auto"):
             return b_fn, None, None, {"block": b_name, "attn": b_name,
                                       "mlp": b_name}
     return None, a_fn, m_fn, {"block": "composed", **names}
+
+
+#: the names :func:`resolve_decode_step` reports for the composition
+UNFUSED = {"block": "composed", "attn": "unfused", "mlp": "unfused"}
+
+
+def launch_operands(names: dict, quant: bool = False) -> dict:
+    """How each Pallas launch of one layer of the decode loop gets its
+    per-layer operands (a layer of the carried KV pools, of the stacked
+    MLP weights) under the resolved variants ``names``:
+    ``{launch name: "index" | "slice"}``. "index": the launch takes the
+    whole stacked array and addresses the layer itself; "slice": its
+    wrapper takes one layer's array, which XLA has to copy out for it.
+    The loop (``inference.generation._decode_step``) hands operands
+    over by these same variant names, so this is its record of which
+    launches the no-copy mechanism reaches. Variants that launch
+    nothing (the XLA compositions, off the TPU) are not listed."""
+    from ..paged_attention import paged_kernel_routed
+    if names["block"] == "pallas_block":
+        return {"decode_block_fused": "slice"}
+    out = {}
+    if names["attn"] == "pallas_fused":
+        out["decode_attn_block"] = "slice"
+    elif not quant and paged_kernel_routed():   # int8 pools attend in XLA
+        out["paged_attention_decode"] = "index"
+    if names["mlp"] == "pallas_fused":
+        out["decode_mlp_block"] = "index"
+    return out

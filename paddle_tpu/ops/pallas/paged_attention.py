@@ -17,6 +17,10 @@ each sequence's pages through VMEM directly from the pool:
   revisit-elision skips the HBM copy.
 - GQA-aware: per KV head, the ``group`` query heads attend the same page
   (one [g, BS] matmul per KV head per page).
+- the pool operand is the STACKED ``[L, N, BS, KV, hd]`` buffer and the
+  layer's index a third scalar-prefetch operand: inside a loop over
+  layers the launch reads its layer's pages out of the whole carried
+  pool, so XLA never has to make a one-layer slice for it.
 
 The per-sequence work is proportional to its real length in pages, not
 MB, and the only HBM traffic is one read of the live pages.
@@ -36,8 +40,8 @@ from ._util import (PAGE_STEP_CANDIDATES, audited_pallas_call,
                     no_x64, online_softmax_page_update)
 
 
-def _decode_kernel(bt_ref, len_ref, q_ref, *rest, scale, bs, kv, groups,
-                   pp):
+def _decode_kernel(bt_ref, len_ref, _layer_ref, q_ref, *rest, scale, bs,
+                   kv, groups, pp):
     k_refs = rest[:pp]
     v_refs = rest[pp:2 * pp]
     o_ref, m_scr, l_scr, acc_scr = rest[2 * pp:]
@@ -88,21 +92,22 @@ def paged_autotune_key(B, H, KV, hd, BS, MB, dtype) -> str:
     return f"paged_decode|{(B, H, KV, hd, BS, MB, str(dtype))}"
 
 
-def _tuned_page_step(q, k_pool, v_pool, block_tables, seq_lens, MB):
+def _tuned_page_step(q, k_pool, v_pool, block_tables, seq_lens, MB,
+                     layer):
     """Pages-per-grid-step for this shape, resolved through the shared
     :func:`.autotune.resolve_candidate` (traced/interpret calls read
     the persistent cache; eager calls with FLAGS_kernel_autotune sweep
     the candidates on device — reference: phi/kernels/autotune)."""
     from .autotune import resolve_candidate
     B, H, hd = q.shape
-    _, BS, KV, _ = k_pool.shape
+    BS, KV = k_pool.shape[-3:-1]
     cands = [p for p in PAGE_STEP_CANDIDATES if p <= MB]
     if len(cands) <= 1:
         return 1
 
     def build(pp):
         return lambda *a: paged_attention_decode_pallas(
-            *a, pages_per_step=pp)
+            *a, pages_per_step=pp, layer=layer)
 
     return resolve_candidate(
         paged_autotune_key(B, H, KV, hd, BS, MB, q.dtype), cands,
@@ -112,22 +117,30 @@ def _tuned_page_step(q, k_pool, v_pool, block_tables, seq_lens, MB):
 @no_x64
 def paged_attention_decode_pallas(q, k_pool, v_pool, block_tables,
                                   seq_lens, scale=None,
-                                  pages_per_step=None):
+                                  pages_per_step=None, layer=None):
     """q: [B, H, hd]; pools: [N, BS, KV, hd]; block_tables: [B, MB] int32;
     seq_lens: [B] int32 → [B, H, hd]. seq_len 0 slots return 0.
+
+    ``layer``: the pools are the stacked [L, N, BS, KV, hd] and this is
+    the layer to attend over (an int or a traced int32 scalar); the
+    result is bit-identical to passing ``pool[layer]``, and no slice of
+    the pool is made.
 
     ``pages_per_step``: KV pages fetched per grid step (1/2/4). None
     resolves through the autotune cache (``paged_autotune_key``); the
     choice only affects pipelining, never numerics."""
     B, H, hd = q.shape
-    N, BS, KV, _ = k_pool.shape
+    BS, KV = k_pool.shape[-3:-1]
     MB = block_tables.shape[1]
     groups = H // KV
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     if pages_per_step is None:
         pages_per_step = _tuned_page_step(q, k_pool, v_pool,
-                                          block_tables, seq_lens, MB)
+                                          block_tables, seq_lens, MB,
+                                          layer)
     pp = max(1, min(int(pages_per_step), MB))
+    if layer is None:       # one layer's pool is a stack of one
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
 
     def kv_index(j):
         return clamped_page_index(BS, pp, j)
@@ -136,16 +149,16 @@ def paged_attention_decode_pallas(q, k_pool, v_pool, block_tables,
         functools.partial(_decode_kernel, scale=scale, bs=BS, kv=KV,
                           groups=groups, pp=pp),
         name="paged_attention_decode",
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, pl.cdiv(MB, pp)),
         in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, mi, bt, ln: (b, 0, 0)),
-            *[pl.BlockSpec((1, BS, KV, hd), kv_index(j))
+            pl.BlockSpec((1, H, hd), lambda b, mi, *_: (b, 0, 0)),
+            *[pl.BlockSpec((None, 1, BS, KV, hd), kv_index(j))
               for j in range(pp)],
-            *[pl.BlockSpec((1, BS, KV, hd), kv_index(j))
+            *[pl.BlockSpec((None, 1, BS, KV, hd), kv_index(j))
               for j in range(pp)],
         ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, mi, bt, ln: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, hd), lambda b, mi, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
@@ -157,6 +170,7 @@ def paged_attention_decode_pallas(q, k_pool, v_pool, block_tables,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         interpret=_interpret(),
     )(jnp.asarray(block_tables, jnp.int32),
-      jnp.asarray(seq_lens, jnp.int32), q,
+      jnp.asarray(seq_lens, jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q,
       *([k_pool] * pp), *([v_pool] * pp))
     return out

@@ -266,3 +266,81 @@ def test_gspmd_sharded_train_step_compiles_without_mosaic_kernels(
         topo, MeshConfig(fsdp=2, tp=2)).compile()
     assert _kernels(compiled) == set()
     assert re.search(r"\ball-reduce(-start)?\(", compiled.as_text())
+
+
+# ---------------------------------------------------------------------------
+# the decode program of the benchmark's serving configurations: its layer
+# loop carries the KV pools and hands the kernels whole buffers (PR 26)
+# ---------------------------------------------------------------------------
+def _lowered_decode_program(topo, config):
+    """The engine's decode program (``serving._make_decode_fn*``: the
+    decode step, greedy sampling, the engine's donation) lowered at a
+    benchmark configuration's shapes for described devices."""
+    import json
+    from jax.sharding import Mesh
+    from paddle_tpu.inference import generation as G
+    from paddle_tpu.inference.serving import ServingEngine
+    from paddle_tpu.inference.tp import ServingMesh
+    from paddle_tpu.models import llama
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           config + ".json")) as f:
+        conf = json.load(f)
+    cfg = llama.LlamaConfig(**{k: conf[k]
+                               for k in conf["program"]["config_keys"]})
+    eng, tp = conf["engine"], conf["engine"].get("mesh", 1)
+    mesh = Mesh(np.array(topo.devices[:tp]), ("tp",))
+    params = jax.eval_shape(lambda: llama.init_params(cfg))
+    if tp == 1:
+        specs = jax.tree_util.tree_map(lambda _: P(), params)
+        pool_spec = P()
+        step = lambda p, tok, seq, tab, kp, vp: G._fused_decode_step(  # noqa: E731
+            p, tok, cfg, kp, vp, tab, seq)
+    else:
+        sm = ServingMesh(mesh)
+        specs, pool_spec = sm.param_specs(cfg), sm.pool_spec
+        step = sm.sharded_decode_fn(cfg, "auto", quant=False)
+
+    def sds(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    params = jax.tree_util.tree_map(
+        lambda v, s: sds(v.shape, v.dtype, s), params, specs)
+    C, BS = eng["capacity"], eng["block_size"]
+    pool = sds((cfg.num_hidden_layers, eng["num_blocks"], BS,
+                cfg.num_key_value_heads, cfg.head_dim), cfg.dtype,
+               pool_spec)
+
+    def program(params, tok, seq_lens, tables, temps, key, k_pools,
+                v_pools):
+        logits, k_pools, v_pools = step(params, tok, seq_lens, tables,
+                                        k_pools, v_pools)
+        return (jnp.argmax(logits, -1).astype(jnp.int32),
+                jnp.where(seq_lens > 0, seq_lens + 1, 0), key, k_pools,
+                v_pools)
+
+    lowered = jax.jit(
+        program, donate_argnums=ServingEngine._DECODE_DONATE).lower(
+        params, sds((C,), jnp.int32), sds((C,), jnp.int32),
+        sds((C, -(-eng["max_seq_len"] // BS)), jnp.int32),
+        sds((C,), jnp.float32), sds((2,), jnp.uint32), pool, pool)
+    return lowered, int(np.prod(pool.shape)) * 2 // tp
+
+
+@pytest.mark.parametrize("config", ["mistral-7b-v0.3-l16",
+                                    "mistral-7b-v0.3-tp4"])
+def test_decode_program_holds_no_second_copy_of_a_pool(
+        topo, monkeypatch, config):
+    """The layer loop's pools are carried and written in place and its
+    kernels read whole buffers by layer index: the compiled program's
+    temporaries are far smaller than one KV pool (as scan inputs and
+    outputs both pools were held twice: 4.1 GB of temporaries at l16),
+    and both kernels are in it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lowered, pool_bytes = _lowered_decode_program(topo, config)
+    compiled = lowered.compile()
+    assert {"paged_attention_decode", "decode_mlp_block"} \
+        <= _kernels(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8

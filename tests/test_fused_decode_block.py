@@ -469,7 +469,8 @@ def test_engine_stream_fused_vs_unfused_bit_parity(params, cdt):
     assert eng_u.decode_variant == {"mode": "unfused",
                                     "block": "composed",
                                     "attn": "unfused",
-                                    "mlp": "unfused"}
+                                    "mlp": "unfused",
+                                    "operands": {}}
 
 
 def test_engine_forced_pallas_smoke(params):
@@ -481,7 +482,10 @@ def test_engine_forced_pallas_smoke(params):
     assert eng.decode_variant == {"mode": "pallas",
                                   "block": "composed",
                                   "attn": "pallas_fused",
-                                  "mlp": "pallas_fused"}
+                                  "mlp": "pallas_fused",
+                                  "operands": {
+                                      "decode_attn_block": "slice",
+                                      "decode_mlp_block": "index"}}
     assert any(s.name == "serving_decode_fused"
                for s in eng.program_specs(register=False))
     rng = np.random.RandomState(8)
@@ -503,7 +507,9 @@ def test_engine_forced_block_smoke(params):
     assert eng.decode_variant == {"mode": "block",
                                   "block": "pallas_block",
                                   "attn": "pallas_block",
-                                  "mlp": "pallas_block"}
+                                  "mlp": "pallas_block",
+                                  "operands": {
+                                      "decode_block_fused": "slice"}}
     assert any(s.name == "serving_decode_block"
                for s in eng.program_specs(register=False))
     rng = np.random.RandomState(12)

@@ -122,9 +122,12 @@ def test_metrics_schema_frozen_disabled(params):
     m = eng.metrics()
     assert set(m.keys()) == BASE_KEYS
     # r20: decode_variant gained the single-launch "block" slot beside
-    # the per-stage names — extended, not loosened
+    # the per-stage names — extended, not loosened; PR 26: "operands",
+    # how each Pallas launch of the layer loop gets its layer (by index
+    # or as a slice); off the TPU the compositions launch nothing
     assert set(m["decode_variant"].keys()) == {"mode", "block", "attn",
-                                               "mlp"}
+                                               "mlp", "operands"}
+    assert m["decode_variant"]["operands"] == {}
     assert m["decode_variant"]["block"] in ("pallas_block", "composed")
     assert m["weight_quant_variant"] == {"mode": "off"}
 
@@ -135,7 +138,7 @@ def test_metrics_schema_frozen_enabled(params):
     m = eng.metrics()
     assert set(m.keys()) == BASE_KEYS | OBS_KEYS
     assert set(m["decode_variant"].keys()) == {"mode", "block", "attn",
-                                               "mlp"}
+                                               "mlp", "operands"}
     assert m["decode_variant"]["block"] in ("pallas_block", "composed")
     assert set(m["latency"].keys()) == LATENCY_KEYS
     for name, snap in m["latency"].items():
