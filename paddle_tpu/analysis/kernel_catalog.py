@@ -136,6 +136,23 @@ def _paged_case(B, H, KV, hd, BS, N, MB, dtype, pp=None):
     return build
 
 
+def _ssm_case(S, H, hp, N, Lm, dtype):
+    """The Mamba-2 state pool's three launches: one decode token of
+    every slot, and a prefill chunk's read and write of one slot."""
+    def build():
+        from ..ops.pallas import mamba2 as pm
+
+        def fn(decay, xdt, b, c, pool):
+            y, pool = pm.ssm_update_pallas(decay, xdt, b, c, pool, 1)
+            state = pm.slot_state_read(pool, 1, 0)
+            return y, pm.slot_state_write(pool, 1, 0, state)
+        R = H * hp
+        return fn, (_sds((S, R), "float32"), _sds((S, R), "float32"),
+                    _sds((S, N), "float32"), _sds((S, N), "float32"),
+                    _sds((Lm, S, N, R), dtype))
+    return build
+
+
 def _flash_case(B, S, H, KVH, hd, dtype, causal=True, bias=False,
                 seg=False):
     def build():
@@ -332,6 +349,7 @@ def _swiglu_case(R, F, dtype):
 _CE_KERNELS = ("linear_ce_fwd", "linear_ce_bwd_dx", "linear_ce_bwd_dh")
 _FLASH_KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dq",
                   "flash_attention_bwd_dkv")
+_SSM_KERNELS = ("ssm_update", "ssm_state_read", "ssm_state_write")
 
 
 def kernel_cases() -> List[KernelCase]:
@@ -366,6 +384,10 @@ def kernel_cases() -> List[KernelCase]:
         C("paged_attention", "flagship_serving_pp4",
           ("paged_attention_decode",),
           _paged_case(8, 16, 16, 64, 16, 128, 24, "bfloat16", pp=4)),
+        C("ssm_state", "tiny", _SSM_KERNELS,
+          _ssm_case(3, 4, 32, 16, 2, "float32")),
+        C("ssm_state", "flagship_serving", _SSM_KERNELS,
+          _ssm_case(64, 128, 64, 128, 9, "float32")),
         C("flash_attention", "tiny", _FLASH_KERNELS,
           _flash_case(1, 128, 4, 2, 64, "float32")),
         C("flash_attention", "tiny_bias_seg", _FLASH_KERNELS,
@@ -649,7 +671,22 @@ def _flops_swiglu_bwd(spec):
 #: launch name -> FLOPs formula over the captured spec. The coverage
 #: contract: every ALL_KERNEL_NAMES member must have an entry —
 #: :func:`flop_formula_findings` turns a gap into a gate finding.
+def _flops_ssm_update(spec):
+    # decay multiply, outer product (multiply, add), readout
+    # (multiply, add) and the store's convert: 6 a state element of
+    # the one layer the launch touches (its grid covers exactly it)
+    pool = spec.inputs[-1].shape
+    return 6.0 * _prod(pool[1:])
+
+
+def _flops_none(spec):
+    return 0.0          # a copy: bytes only
+
+
 FLOP_FORMULAS: Dict[str, Callable] = {
+    "ssm_update": _flops_ssm_update,
+    "ssm_state_read": _flops_none,
+    "ssm_state_write": _flops_none,
     "rms_norm_fwd": _flops_rms_fwd,
     "rms_norm_bwd": _flops_rms_bwd,
     "residual_rms_norm_fwd": _flops_res_rms_fwd,

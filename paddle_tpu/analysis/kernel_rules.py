@@ -279,8 +279,12 @@ def _output_findings(spec, program, memo) -> List[Finding]:
             continue  # malformed arity: already an OOB_BLOCK finding —
             # comparing wrong-arity coords would fabricate coverage/
             # race findings on top of the real one
-        f = _coverage_finding(spec, program, label, op, coords,
-                              "written")
+        # an output aliased to an input keeps that input's contents in
+        # every block the grid does not write (an in-place update of a
+        # part of a pool): only a fresh output has to be covered
+        aliased = i in set((spec.input_output_aliases or {}).values())
+        f = None if aliased else _coverage_finding(
+            spec, program, label, op, coords, "written")
         if f is not None:
             out.append(f)
         covered = set(coords.values())

@@ -110,6 +110,37 @@ def _collectives_snapshot(counters: Dict, obs: Observability) -> Dict:
                 and name.endswith("_ms")}}
 
 
+_NO_SNAPSHOTS = (
+    "a recurrent layer's state lives in its slot and there are no "
+    "state snapshots yet (a copy of a slot's state kept beside its KV "
+    "pages), which {what} needs to {need}")
+
+
+def _refuse_for_recurrent(mesh, weight_quant, cache_dtype, kv_offload):
+    """What a model with recurrent layers cannot be served with yet,
+    each refused by the mechanism that is missing."""
+    if kv_offload:
+        raise ValueError("ServingEngine(kv_offload=...): " +
+                         _NO_SNAPSHOTS.format(
+                             what="the host tier",
+                             need="restore a spilled prefix"))
+    if mesh is not None:
+        raise ValueError(
+            "ServingEngine(mesh=...): the recurrent and expert layers "
+            "have no sharded placement yet (inference/tp.py shards "
+            "attention heads and MLP columns, and has no expert "
+            "exchange)")
+    if weight_quant is not None:
+        raise ValueError(
+            "ServingEngine(weight_quant=...): the quantized leaves' "
+            "dequantize-then-matmul route does not cover the expert "
+            "stacks or the Mamba projections")
+    if cache_dtype in ("int8", jnp.int8):
+        raise ValueError(
+            'ServingEngine(cache_dtype="int8"): the int8 pools\' scales '
+            "are calibrated through the dense decoder's forward pass")
+
+
 def _drain_loop(eng, max_steps: Optional[int], starve_reason: str,
                 starve_error: str) -> int:
     """The shared drain loop (ServingEngine and DisaggregatedEngine):
@@ -213,7 +244,20 @@ class ServingEngine:
                  observability=False, fused_decode=None, mesh=None,
                  fused_prefill=None, weight_quant=None,
                  aging_s: Optional[float] = None, telemetry=False,
-                 clock=None):
+                 clock=None, state_dtype=None):
+        # a model with recurrent layers (models/granite_hybrid.py)
+        # keeps a second kind of per-request state, indexed by slot
+        # (inference/hybrid.py); what cannot serve it yet is refused
+        # here, by the mechanism that is missing
+        self._recurrent = getattr(cfg, "num_recurrent_layers", 0) > 0
+        if self._recurrent:
+            _refuse_for_recurrent(mesh=mesh, weight_quant=weight_quant,
+                                  cache_dtype=cache_dtype,
+                                  kv_offload=kv_offload)
+            fused_decode = fused_prefill = False
+        elif state_dtype is not None:
+            raise ValueError("state_dtype is the recurrent state's type: "
+                             f"{type(cfg).__name__} has no recurrent layer")
         # tensor parallelism (inference/tp.py): a ServingMesh shards
         # the KV pools, projections and per-slot attention along the
         # head axis; programs wrap in shard_map. None = single device.
@@ -346,8 +390,9 @@ class ServingEngine:
         else:
             raise ValueError(f"cache_dtype must be bfloat16|float32|int8,"
                              f" got {cache_dtype!r}")
-        L, KV, hd = (cfg.num_hidden_layers, cfg.num_key_value_heads,
-                     cfg.head_dim)
+        # the pools are as deep as the layers that hold keys and values
+        L = getattr(cfg, "num_kv_layers", cfg.num_hidden_layers)
+        KV, hd = cfg.num_key_value_heads, cfg.head_dim
         pool_dtype = jnp.int8 if self._quant else cfg.dtype
         shape = (L, self.num_blocks, BS, KV, hd)
         # pools shard their head-dim CONTENTS; page indices stay
@@ -385,16 +430,26 @@ class ServingEngine:
         import os as _os
         self._offload_window = max(1, int(_os.environ.get(
             "PADDLE_TPU_OFFLOAD_WINDOW", "8")))
-        L_, KV_, hd_ = (cfg.num_hidden_layers,
-                        cfg.num_key_value_heads, cfg.head_dim)
         # one physical page across BOTH pools, in bytes (the spill/
-        # restore byte counters)
-        self._page_nbytes = int(2 * L_ * BS * KV_ * hd_
+        # restore byte counters): over the layers that hold KV
+        self._page_nbytes = int(2 * L * BS * KV * hd
                                 * jnp.dtype(pool_dtype).itemsize)
         if kv_offload and not prefix_cache:
             raise ValueError(
                 "kv_offload requires prefix_cache=True: the host tier "
                 "spills radix-tree pages, not per-request tables")
+        # a prefix match skips tokens; nobody stored the recurrent state
+        # after them, so with recurrent layers the cache stays off and
+        # every request it would have looked up is counted
+        self._prefix_skipped = bool(prefix_cache) and self._recurrent
+        self._state = None
+        if self._recurrent:
+            from . import hybrid
+            prefix_cache = False
+            self._state = hybrid.init_state(
+                cfg, self.capacity, jnp.dtype(state_dtype or jnp.float32))
+            self._state_reset_fn = jax.jit(hybrid.reset_slot,
+                                           donate_argnums=(0,))
         if prefix_cache:
             from .prefix_cache import PrefixCache, make_page_copier
             self._copy_fn = make_page_copier()
@@ -475,7 +530,18 @@ class ServingEngine:
             # steps that ran a prefill chunk AND a decode step: what
             # stretches a token gap
             "mixed_steps": 0,
+            # prompt tokens of fresh admissions the prefix cache was
+            # asked about, and those it matched
+            "prefix_lookup_tokens": 0, "prefix_hit_tokens": 0,
         }
+        if self._recurrent:
+            # the expert_* three are summed on the device (the state's
+            # "stats", carried by the decode program) and folded in
+            # here only when metrics() reads them
+            self.counters.update(
+                state_resets=0, prefix_skipped_recurrent=0,
+                expert_assignments=0, expert_assignments_held=0,
+                expert_load_max=0)
         self._t_first = None
         self._t_last = None
         self._metrics_reset_t = None   # TTFTs from before this are warmup
@@ -892,12 +958,23 @@ class ServingEngine:
                                               decode_step_bytes)
 
         cfg = self.cfg
+        if self._recurrent:
+            # the arm model is a dense decoder's (attention + one MLP a
+            # layer): it does not reckon expert or recurrent layers
+            return {"active": "unfused", "layers": cfg.num_hidden_layers,
+                    "reckoned": False,
+                    "why": "decode_step_bytes models dense-MLP layers "
+                           "with KV; this model has expert layers and "
+                           f"{cfg.num_recurrent_layers} recurrent "
+                           "layers (see metrics()['recurrent'])"}
         tp = 1 if self._mesh is None else self._mesh.tp
         act = jnp.dtype(cfg.dtype).itemsize
         pool = jnp.dtype(self._k_pools.dtype).itemsize
         wbytes = {"int8": 1.0, "int4": 0.5}.get(self._wq or "",
                                                 float(act))
-        L = cfg.num_hidden_layers
+        # every layer of a dense decoder holds KV; a pattern's other
+        # layers are not this model's to reckon
+        L = getattr(cfg, "num_kv_layers", cfg.num_hidden_layers)
         per_layer = decode_step_bytes(
             self.capacity, cfg.hidden_size,
             cfg.num_attention_heads // tp,
@@ -1030,10 +1107,13 @@ class ServingEngine:
         # "collectives" key below (the Trainer.metrics contract).
         # mixed_steps is read from ``counters`` itself (the benchmark's
         # mixed_step_pct.*): the key set of metrics() is frozen
+        if self._recurrent:
+            self._fold_expert_stats()
         c = {k: (dict(v) if isinstance(v, dict) else v)
              for k, v in self.counters.items()
-             if k not in ("collective_calls", "collective_bytes",
-                          "mixed_steps")}
+             if k not in self._COUNTERS_ONLY}
+        if self._recurrent:
+            c["recurrent"] = self._recurrent_metrics()
         if self._mesh is not None:
             c["mesh"] = self._mesh.describe()
         wall = ((self._t_last - self._t_first)
@@ -1087,6 +1167,53 @@ class ServingEngine:
                                                          obs)
         return c
 
+    # counters that are read from ``counters`` itself (the benchmark's
+    # counter ratios) and stay out of metrics(), whose key set is frozen
+    _COUNTERS_ONLY = frozenset((
+        "collective_calls", "collective_bytes", "mixed_steps",
+        "prefix_lookup_tokens", "prefix_hit_tokens", "state_resets",
+        "prefix_skipped_recurrent", "expert_assignments",
+        "expert_assignments_held", "expert_load_max"))
+
+    def _fold_expert_stats(self):
+        """The routing counts the decode program summed on the device
+        since the last reset, into ``counters`` (one small read)."""
+        stats = np.asarray(self._state["stats"])
+        for k, v in zip(("expert_assignments", "expert_assignments_held",
+                         "expert_load_max"), stats):
+            self.counters[k] = int(v)
+
+    def _recurrent_metrics(self) -> Dict:
+        """What a model with recurrent layers adds to ``metrics()``:
+        the state pools the engine holds beside the KV pools, and the
+        expert layer's routing over the decode steps since the reset
+        (``load_skew``: the largest number of tokens one expert got in
+        a step, over the mean an expert got)."""
+        c, cfg = self.counters, self.cfg
+        nbytes = sum(int(self._state[k].nbytes) for k in ("ssm", "conv"))
+        layers = cfg.num_hidden_layers
+        mean = (c["expert_assignments"]
+                / (cfg.num_experts * layers * c["decode_steps"])
+                if c["decode_steps"] else 0.0)
+        return {
+            "state_bytes": nbytes,
+            "state_dtype": str(self._state["ssm"].dtype),
+            "recurrent_layers": cfg.num_recurrent_layers,
+            "kv_layers": cfg.num_kv_layers,
+            "state_resets": c["state_resets"],
+            "prefix_skipped_recurrent": c["prefix_skipped_recurrent"],
+            "experts": {
+                "held": cfg.num_local_experts, "of": cfg.num_experts,
+                "offset": cfg.expert_offset,
+                "assignments": c["expert_assignments"],
+                "assignments_held": c["expert_assignments_held"],
+                "held_share": (round(c["expert_assignments_held"]
+                                     / c["expert_assignments"], 4)
+                               if c["expert_assignments"] else None),
+                "load_max": c["expert_load_max"],
+                "load_skew": (round(c["expert_load_max"] / mean, 3)
+                              if mean else None)}}
+
     def _scheduler_metrics(self) -> Dict:
         """The SLO-admission window report: per-class queue-wait stats
         (running O(1) sums — never a request-list scan), deadline
@@ -1118,8 +1245,16 @@ class ServingEngine:
                   "requests_submitted", "requests_completed",
                   "drain_truncations", "preemptions", "requeues",
                   "deadline_expired", "kv_spill_bytes",
-                  "kv_restore_bytes", "mixed_steps"):
+                  "kv_restore_bytes", "mixed_steps",
+                  "prefix_lookup_tokens", "prefix_hit_tokens"):
             self.counters[k] = 0
+        if self._recurrent:
+            for k in ("state_resets", "prefix_skipped_recurrent",
+                      "expert_assignments", "expert_assignments_held",
+                      "expert_load_max"):
+                self.counters[k] = 0
+            self._state = {**self._state, "stats": jnp.zeros_like(
+                self._state["stats"])}
         self._sched_cls = {}
         self._slo = [0, 0]
         if self._pcache is not None:
@@ -1253,6 +1388,17 @@ class ServingEngine:
                 # matched pages join the block table directly; their
                 # references transfer to this request's table entries
                 self.mgr.attach(req.req_id, pages, owned=True)
+                self.counters["prefix_lookup_tokens"] += int(
+                    req.prompt.size)
+                self.counters["prefix_hit_tokens"] += int(matched)
+            if self._prefix_skipped:
+                self.counters["prefix_skipped_recurrent"] += 1
+            if self._state is not None:
+                # the slot's last request left its state there
+                with span("serve/state_reset", self._obs, slot=slot_id):
+                    self._state = self._state_reset_fn(
+                        self._state, jnp.asarray(slot_id, jnp.int32))
+                self.counters["state_resets"] += 1
             table = self.mgr.allocate(req.req_id,
                                       self._alloc_tokens(req))
             slot.req = req
@@ -1332,7 +1478,10 @@ class ServingEngine:
         lower-priority (HIGHER class) live decode slot, worst class
         first, latest-admitted within a class (least progress lost).
         Raw classes compare — aging promotes queue ORDER, not the right
-        to evict running work. None when no slot is evictable."""
+        to evict running work. None when no slot is evictable (never,
+        with recurrent layers: see :meth:`_preempt`)."""
+        if self._recurrent:
+            return None
         cand = [(s.req.priority, s.req.admit_t or 0.0, i)
                 for i, s in enumerate(self._slots)
                 if s.phase == "decode"]
@@ -1347,6 +1496,10 @@ class ServingEngine:
         saved on the request, so the requeued entry — re-inserted at
         its ORIGINAL line position within its class — resumes decode
         bit-identically to the un-preempted run."""
+        if self._recurrent:
+            raise RuntimeError("preemption: " + _NO_SNAPSHOTS.format(
+                what="a preempted request",
+                need="resume where it was evicted"))
         slot = self._slots[slot_id]
         req = slot.req
         req.resume = (slot.seq_len, int(self._h_tok[slot_id]))
@@ -1439,9 +1592,16 @@ class ServingEngine:
                       hist="prefill_chunk_ms", ring=False,
                       req_id=req.req_id, pos0=pos0, n=n,
                       bucket=P) as disp:
-                tok, self._d_key, self._k_pools, self._v_pools = fn(
-                    self.params, *args,
-                    self._d_key, self._k_pools, self._v_pools)
+                if self._state is None:
+                    tok, self._d_key, self._k_pools, self._v_pools = fn(
+                        self.params, *args,
+                        self._d_key, self._k_pools, self._v_pools)
+                else:
+                    (tok, self._d_key, self._k_pools, self._v_pools,
+                     self._state) = fn(
+                        self.params, *args, self._d_key, self._k_pools,
+                        self._v_pools, jnp.asarray(slot_id, jnp.int32),
+                        self._state)
             self._end_collectives(tasks)
             self.counters["prefill_chunks"] += 1
             self.counters["prefill_tokens"] += n
@@ -1542,10 +1702,14 @@ class ServingEngine:
             self._dirty = False
         tasks = self._record_collectives(self._coll_decode)
         with span("serve/decode_dispatch", obs, ring=False) as disp:
-            (self._d_tok, self._d_seq, self._d_key, self._k_pools,
-             self._v_pools) = self._decode_fn(
+            out = self._decode_fn(
                 self.params, self._d_tok, self._d_seq, self._d_tables,
-                self._d_temps, self._d_key, self._k_pools, self._v_pools)
+                self._d_temps, self._d_key, self._k_pools, self._v_pools,
+                *(() if self._state is None else (self._state,)))
+            (self._d_tok, self._d_seq, self._d_key, self._k_pools,
+             self._v_pools) = out[:5]
+            if self._state is not None:
+                self._state = out[5]
         with span("serve/token_sync", obs, ring=False) as sync:
             nxt = np.asarray(self._d_tok)       # the per-step host sync
         self._end_collectives(tasks)
@@ -1664,6 +1828,8 @@ class ServingEngine:
     _PREFILL_CARRY = {1: 7, 2: 8, 3: 9}
 
     def _make_decode_fn(self, record_variant=True):
+        if self._recurrent:
+            return self._make_decode_fn_hybrid()
         if self._mesh is not None:
             return self._make_decode_fn_tp(record_variant)
         cfg, counters = self.cfg, self.counters
@@ -1700,6 +1866,49 @@ class ServingEngine:
         # mutation the mirrors re-upload fresh arrays), so the old
         # buffers update in place — the donation audit's own finding
         return jax.jit(step, donate_argnums=self._DECODE_DONATE)
+
+    def _make_decode_fn_hybrid(self):
+        """The decode program of a model with recurrent layers: the
+        same signature, donation and carry as the others, with the
+        slots' recurrent state (inference/hybrid.py) as one more
+        donated argument and output. Still ONE jitted program."""
+        from .hybrid import decode_step
+        cfg, counters = self.cfg, self.counters
+
+        def step(params, tok, seq_lens, tables, temps, key,
+                 k_pools, v_pools, state):
+            counters["decode_traces"] += 1
+            logits, k_pools, v_pools, state = decode_step(
+                params, tok, cfg, k_pools, v_pools, tables, seq_lens,
+                state)
+            key, sub = jax.random.split(key)
+            nxt = _sample_slots(logits, sub, temps)
+            seq_lens = jnp.where(seq_lens > 0, seq_lens + 1, 0)
+            return nxt, seq_lens, key, k_pools, v_pools, state
+
+        return jax.jit(step, donate_argnums=self._DECODE_DONATE + (8,))
+
+    def _make_prefill_fn_hybrid(self, P: int):
+        """The chunk program of a model with recurrent layers: the
+        chunk reads and writes its slot's recurrent state, so a prompt
+        longer than a bucket continues its recurrence across chunks,
+        and bucket padding advances nothing."""
+        from .hybrid import prefill_chunk
+        cfg, counters = self.cfg, self.counters
+        counters["prefill_traces"].setdefault(P, 0)
+
+        def chunk(params, toks, pos0, table, wtable, last_idx, temp,
+                  key, k_pools, v_pools, slot, state):
+            counters["prefill_traces"][P] += 1
+            n_valid = jnp.asarray(last_idx, jnp.int32) + jnp.int32(1)
+            lg, k_pools, v_pools, state = prefill_chunk(
+                params, toks[0], cfg, k_pools, v_pools, table, wtable,
+                pos0, n_valid, slot, state)
+            key, sub = jax.random.split(key)
+            tok = _sample_slots(lg, sub, temp[None])[0]
+            return tok, key, k_pools, v_pools, state
+
+        return jax.jit(chunk, donate_argnums=self._PREFILL_DONATE + (11,))
 
     def _make_decode_fn_tp(self, record_variant=True):
         """The tensor-parallel decode program: the SAME signature,
@@ -1813,6 +2022,8 @@ class ServingEngine:
         return jax.jit(chunk, donate_argnums=self._PREFILL_DONATE)
 
     def _make_prefill_fn(self, P: int, record_variant=True):
+        if self._recurrent:
+            return self._make_prefill_fn_hybrid(P)
         if self._prefill_fused_for(P):
             return self._make_prefill_fn_fused(
                 P, record_variant=record_variant)
@@ -1994,14 +2205,32 @@ class ServingEngine:
         prefill_base = ("serving_prefill_fused"
                         if self._fused_prefill in ("pallas",)
                         else "serving_prefill")
+        # a model with recurrent layers: the slots' state is one more
+        # donated argument (a pytree) behind each program's own, and
+        # its leaves come back as the outputs after the program's own
+        decode_extra = prefill_extra = ()
+        decode_donate, prefill_donate = (self._DECODE_DONATE,
+                                         self._PREFILL_DONATE)
+        decode_carry = {o: flat(a) for o, a in self._DECODE_CARRY.items()}
+        prefill_carry = {o: flat(a)
+                         for o, a in self._PREFILL_CARRY.items()}
+        if self._recurrent:
+            state_sd = abstract_signature(self._state)
+            n_s = len(jax.tree_util.tree_leaves(state_sd))
+            decode_extra = (state_sd,)
+            prefill_extra = (sds((), jnp.int32), state_sd)
+            decode_donate += (8,)
+            prefill_donate += (11,)
+            decode_carry.update({5 + i: flat(8) + i for i in range(n_s)})
+            prefill_carry.update({4 + i: flat(11) + i
+                                  for i in range(n_s)})
         specs = [ProgramSpec(
             name=decode_name + tp_sfx, fn=self._make_decode_fn(
                 record_variant=False),
             args=(params_sd, sds((C,), jnp.int32), sds((C,), jnp.int32),
                   sds((C, MB), jnp.int32), sds((C,), jnp.float32),
-                  key_sd, pools_sd, pools_sd),
-            donate_argnums=self._DECODE_DONATE,
-            carry={o: flat(a) for o, a in self._DECODE_CARRY.items()},
+                  key_sd, pools_sd, pools_sd) + decode_extra,
+            donate_argnums=decode_donate, carry=decode_carry,
             mesh_axes=axes, tags=tags)]
         # pos0/last_idx ride at the platform default int width
         # (serving._run_prefill stages them with a bare jnp.asarray)
@@ -2013,10 +2242,8 @@ class ServingEngine:
                 args=(params_sd, sds((1, P), jnp.int32), sds((), idx_dt),
                       sds((MB,), jnp.int32), sds((MB,), jnp.int32),
                       sds((), idx_dt), sds((), jnp.float32), key_sd,
-                      pools_sd, pools_sd),
-                donate_argnums=self._PREFILL_DONATE,
-                carry={o: flat(a)
-                       for o, a in self._PREFILL_CARRY.items()},
+                      pools_sd, pools_sd) + prefill_extra,
+                donate_argnums=prefill_donate, carry=prefill_carry,
                 mesh_axes=axes, tags=tags))
         if self._pcache is not None:
             specs.append(ProgramSpec(

@@ -34,6 +34,7 @@ __all__ = ["span", "SERVE_SPANS", "TRAIN_SPANS"]
 SERVE_SPANS = (
     "serve/step",            # the whole call
     "serve/admit",           # expiry, queue pop, prefix match, pages
+    "serve/state_reset",     # zeroing an admitted slot's recurrent state
     "serve/prefill_stage",   # bucket choice, padding, chunk uploads
     "serve/prefill_dispatch",    # the jitted chunk call returning
     "serve/first_token_sync",    # blocking read of a prompt's 1st token

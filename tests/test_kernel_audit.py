@@ -109,7 +109,9 @@ def test_catalog_captures_every_declared_kernel(catalog_reports):
         "flash_attention_bwd_dkv", "decode_attn_block",
         "decode_mlp_block", "decode_block_fused", "prefill_attn_block",
         "linear_ce_fwd", "linear_ce_bwd_dx", "linear_ce_bwd_dh",
-        "swiglu_fwd", "swiglu_bwd"}
+        "swiglu_fwd", "swiglu_bwd",
+        # PR 27: the Mamba-2 state pool's launches (ops/pallas/mamba2.py)
+        "ssm_update", "ssm_state_read", "ssm_state_write"}
     captured = set()
     for r in catalog_reports:
         assert not any(f.code in ("COVERAGE_GAP", "TRACE_ERROR")
@@ -166,6 +168,20 @@ def test_rule_grid_floor_drop_output_and_input():
     assert found[0].site == "synthetic/in0"
     # divisor grid: silent
     spec = _spec((4,), [_op((128,), (32,), lambda i: (i,))])
+    assert check_launch(spec) == []
+
+
+def test_rule_coverage_exempts_an_aliased_output():
+    """An in-place launch writes one part of a pool that is aliased to
+    its output: the blocks it does not write keep the input's contents
+    (ops/pallas/mamba2.py updates one layer of the state pool so)."""
+    import dataclasses
+    from paddle_tpu.analysis.kernel_rules import check_launch
+    pool = _op((2, 128), (1, 32), lambda i: (1, i))
+    spec = _spec((4,), [pool], ins=[pool])
+    assert _codes(check_launch(spec)) == ["GRID_FLOOR_DROP"] * 2
+    spec = dataclasses.replace(spec, num_scalar_prefetch=1,
+                               input_output_aliases={1: 0})
     assert check_launch(spec) == []
 
 

@@ -109,14 +109,20 @@ def served(params, tmp_path_factory):
 
 def test_span_names_are_frozen():
     assert SERVE_SPANS == (
-        "serve/step", "serve/admit", "serve/prefill_stage",
-        "serve/prefill_dispatch", "serve/first_token_sync",
+        "serve/step", "serve/admit", "serve/state_reset",
+        "serve/prefill_stage", "serve/prefill_dispatch", "serve/first_token_sync",
         "serve/table_upload", "serve/decode_dispatch", "serve/token_sync",
         "serve/emit", "serve/observe")
     assert TRAIN_SPANS == ("train/stage", "train/dispatch", "train/sync")
 
 
-@pytest.mark.parametrize("name", SERVE_SPANS)
+# serve/state_reset is entered only by an engine whose model has
+# recurrent layers: tests/test_granite_hybrid_serving.py holds it to
+# the same two sinks
+DENSE_SPANS = tuple(n for n in SERVE_SPANS if n != "serve/state_reset")
+
+
+@pytest.mark.parametrize("name", DENSE_SPANS)
 def test_served_request_yields_every_span_inside_a_step(served, name):
     events, _ = served
     mine = [e for e in events if e[2] == name]
@@ -159,7 +165,7 @@ def test_mixed_steps_counts_what_it_says(params):
     assert eng.counters["mixed_steps"] == 0
 
 
-@pytest.mark.parametrize("name", SERVE_SPANS)
+@pytest.mark.parametrize("name", DENSE_SPANS)
 def test_observability_holds_the_same_phases(params, name):
     """The second sink: the Timeline holds each phase once with a
     duration, under the span's name or, where the engine had an event
