@@ -121,18 +121,23 @@ def _adamw_case(n, dtype, mdtype, shadow_dtype):
     return build
 
 
-def _paged_case(B, H, KV, hd, BS, N, MB, dtype, pp=None):
+def _paged_case(B, H, KV, hd, BS, N, MB, dtype, pp=None, L=None,
+                scale=None):
+    """``L``: the pools are the stacked ``[L, N, BS, KV, hd]`` and the
+    layer a traced operand, as the decode programs' layer loops call
+    the launch."""
     def build():
         from ..ops.pallas.paged_attention import (
             paged_attention_decode_pallas)
 
-        def fn(q, kp, vp, bt, ln):
-            return paged_attention_decode_pallas(q, kp, vp, bt, ln,
-                                                 pages_per_step=pp)
-        return fn, (_sds((B, H, hd), dtype),
-                    _sds((N, BS, KV, hd), dtype),
-                    _sds((N, BS, KV, hd), dtype),
-                    _sds((B, MB), "int32"), _sds((B,), "int32"))
+        def fn(q, kp, vp, bt, ln, *layer):
+            return paged_attention_decode_pallas(
+                q, kp, vp, bt, ln, scale=scale, pages_per_step=pp,
+                layer=layer[0] if layer else None)
+        pool = _sds(((L,) if L else ()) + (N, BS, KV, hd), dtype)
+        return fn, (_sds((B, H, hd), dtype), pool, pool,
+                    _sds((B, MB), "int32"), _sds((B,), "int32"),
+                    *([_sds((), "int32")] if L else []))
     return build
 
 
@@ -384,6 +389,9 @@ def kernel_cases() -> List[KernelCase]:
         C("paged_attention", "flagship_serving_pp4",
           ("paged_attention_decode",),
           _paged_case(8, 16, 16, 64, 16, 128, 24, "bfloat16", pp=4)),
+        C("paged_attention", "stacked_pool_layer_operand",
+          ("paged_attention_decode",),
+          _paged_case(32, 32, 8, 128, 16, 3072, 160, "bfloat16", L=16)),
         C("ssm_state", "tiny", _SSM_KERNELS,
           _ssm_case(3, 4, 32, 16, 2, "float32")),
         C("ssm_state", "flagship_serving", _SSM_KERNELS,
@@ -561,7 +569,9 @@ def _flops_paged_decode(spec):
     B, H, hd = (int(s) for s in spec.inputs[0].shape)
     MB = int(spec.prefetch[0][0][1])
     BS, _ = _pool_dims(spec)
-    # q·K (2) + p·V (2) over the full block table per head
+    # q·K (2) + p·V (2) per head over every page of the table: the
+    # lengths of the bytes model's probe (the launch's ``fetched_bytes``
+    # on the ``full`` sample), where all B * MB pages are live
     return 4.0 * B * H * hd * MB * BS
 
 

@@ -105,6 +105,12 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
+#: operand / scratch spaces that take no part of the VMEM window: SMEM
+#: scalars, DMA semaphores, and an input left in HBM (``pl.ANY``) that
+#: the kernel copies out of for itself, through VMEM scratch it declares
+_NOT_VMEM = ("smem", "semaphore", "any")
+
+
 class _ClampedTable:
     """ndarray stand-in whose ``__getitem__`` clamps every integer
     index component into the array's extent. The ``full`` prefetch
@@ -354,7 +360,7 @@ def _vmem_findings(spec, program, memo) -> List[Finding]:
     parts = []
     for kind, ops in (("in", spec.inputs), ("out", spec.outputs)):
         for i, op in enumerate(ops):
-            if op.space == "smem":
+            if op.space in _NOT_VMEM:
                 continue
             if op.block_shape is None:
                 nbytes = int(np.prod(op.shape or (1,), dtype=np.int64)) \
@@ -379,7 +385,7 @@ def _vmem_findings(spec, program, memo) -> List[Finding]:
             if windows * nbytes >= (64 << 10):
                 parts.append(f"{kind}{i}:{windows}x{nbytes >> 10}KiB")
     for shape, dtype, space in spec.scratch:
-        if space == "smem":
+        if space in _NOT_VMEM:
             continue
         sbytes = int(np.prod(shape or (1,), dtype=np.int64)) \
             * _itemsize(dtype)
@@ -475,7 +481,10 @@ def modeled_launch_bytes(spec, memo: Optional[Dict] = None) -> Dict:
     operand is charged ``block_bytes ×`` its :func:`_operand_fetches`
     transition count (streamed operands pay once per revisit-elided
     refetch, resident constant-index operands pay exactly once),
-    whole-array operands are charged their array bytes once, SMEM
+    whole-array operands are charged their array bytes once — except
+    one the kernel fetches from for itself (``any`` space), which is
+    charged what the launch declares it copies (``fetched_bytes``, on
+    the ``full`` prefetch sample: every page live) — and SMEM
     operands and scratch charge nothing (scalars / VMEM-only). The
     model deliberately ignores accumulator read-modify-write traffic
     (revisited output blocks stay in VMEM between visits — that is
@@ -493,8 +502,14 @@ def modeled_launch_bytes(spec, memo: Optional[Dict] = None) -> Dict:
         for i, op in enumerate(ops):
             if op.space == "smem":
                 continue
-            fetches = _operand_fetches(spec, op, memo)
-            if fetches is None:
+            fetches = None if op.fetched_bytes is not None \
+                else _operand_fetches(spec, op, memo)
+            if op.fetched_bytes is not None:
+                # self-fetched (``any`` space): no blocks to count; the
+                # launch's own declaration, on the max-traffic table
+                nbytes = int(op.fetched_bytes(
+                    *_prefetch_samples(spec, full=True)))
+            elif fetches is None:
                 fetches = 1
                 nbytes = int(np.prod(op.shape or (1,),
                                      dtype=np.int64)) \

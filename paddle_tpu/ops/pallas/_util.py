@@ -65,11 +65,12 @@ def dispatch_fused_variant(op: str, meta, mode=None):
     return KERNELS.variant(
         op, "pallas_fused" if mode == "pallas" else "unfused").fn
 
-# Pages-per-grid-step autotune candidates for the page-streaming decode
-# kernels (paged_attention's unfused kernel and the fused decode-block
-# attention kernel key the SAME persistent table and must sweep the
-# same space — pages are processed sequentially, so the choice only
-# affects pipelining, never numerics).
+# Pages-per-grid-step autotune candidates for the fused page-streaming
+# kernels (decode-block attention, prefill attention: a grid step
+# fetches this many pages through BlockSpecs — pages are processed
+# sequentially, so the choice only affects pipelining, never numerics).
+# The unfused paged-decode kernel fetches for itself and has its own
+# space (``paged_attention.PAGE_BLOCK_CANDIDATES``).
 PAGE_STEP_CANDIDATES = (1, 2, 4)
 
 
@@ -82,22 +83,17 @@ def clamped_page_index(BS, pp, j):
     entries out of the fetch. All-int32 arithmetic: index maps are
     retraced at LOWERING time, outside the kernels' no_x64 trace
     window, where a bare python-int operand would promote to i64 and
-    fail MLIR verification. Shared by the unfused paged-decode kernel
-    and the fused attention megakernel — the clamp must not be able to
-    drift between the two, or their bit-parity contract breaks.
-
-    A third scalar-prefetch operand, where the launch has one, is the
-    layer of a STACKED pool ``[L, N, BS, KV, hd]``: the map then
-    returns ``(layer, page, 0, 0, 0)`` and the kernel reads its layer's
-    pages out of the whole buffer, with no slice made for it.
+    fail MLIR verification. The fused attention megakernel's fetch (and
+    the reference grid of tests/test_paged_attention_kernel.py); the
+    unfused paged-decode kernel fetches its live pages for itself, in
+    the same order.
     """
-    def f(b, mi, bt_ref, len_ref, *layer_ref):
+    def f(b, mi, bt_ref, len_ref):
         last = jnp.maximum(len_ref[b] - jnp.int32(1),
                            jnp.int32(0)) // jnp.int32(BS)
         idx = jnp.minimum(mi.astype(jnp.int32) * jnp.int32(pp)
                           + jnp.int32(j), last)
-        page = (bt_ref[b, idx], 0, 0, 0)
-        return (layer_ref[0][0], *page) if layer_ref else page
+        return (bt_ref[b, idx], 0, 0, 0)
     return f
 
 
@@ -228,12 +224,17 @@ class KernelOperand:
     """One blocked operand of a captured Pallas launch: the array's
     abstract geometry plus its BlockSpec's (block_shape, index_map).
     ``block_shape`` None = whole-array operand (memory-space spec, no
-    index map). ``space`` is a best-effort label ("vmem"/"smem"/"any")."""
+    index map). ``space`` is a best-effort label ("vmem"/"smem"/"any").
+    An operand in ``any`` space stays in HBM and the kernel copies out
+    of it for itself: ``fetched_bytes(*prefetch)`` is the launch's own
+    declaration of how many bytes that is, given the scalar-prefetch
+    operands (``audited_pallas_call(fetched_bytes=...)``)."""
     shape: Tuple[int, ...]
     dtype: str
     block_shape: Optional[Tuple] = None
     index_map: Optional[Callable] = None
     space: str = "vmem"
+    fetched_bytes: Optional[Callable] = None
 
 
 @dataclasses.dataclass
@@ -301,7 +302,7 @@ def _space_label(block_spec) -> str:
     return s or "vmem"
 
 
-def _operand(arg, block_spec) -> KernelOperand:
+def _operand(arg, block_spec, fetched_bytes=None) -> KernelOperand:
     shape = tuple(getattr(arg, "shape", ()) or ())
     dtype = str(getattr(arg, "dtype", "?"))
     bs = getattr(block_spec, "block_shape", None)
@@ -309,7 +310,7 @@ def _operand(arg, block_spec) -> KernelOperand:
         shape=shape, dtype=dtype,
         block_shape=tuple(bs) if bs is not None else None,
         index_map=getattr(block_spec, "index_map", None),
-        space=_space_label(block_spec))
+        space=_space_label(block_spec), fetched_bytes=fetched_bytes)
 
 
 def _scratch_record(s):
@@ -318,9 +319,12 @@ def _scratch_record(s):
         dtype = str(jnp.dtype(getattr(s, "dtype", None)))
     except TypeError:
         dtype = str(getattr(s, "dtype", "?"))
-    ms = str(getattr(s, "memory_space", "")).lower()
-    space = "smem" if "smem" in (ms or type(s).__name__.lower()) \
-        else "vmem"
+    ms = str(getattr(s, "memory_space", "")).lower() \
+        or type(s).__name__.lower()
+    # a semaphore array (DMA completion) lives in semaphore memory:
+    # like SMEM it is no part of the VMEM window
+    space = next((label for label in ("smem", "semaphore")
+                  if label in ms), "vmem")
     return (shape, dtype, space)
 
 
@@ -328,7 +332,8 @@ def audited_pallas_call(kernel, *, name: str = None, grid,
                         in_specs, out_specs, out_shape,
                         scratch_shapes=None, num_scalar_prefetch: int = 0,
                         input_output_aliases=None, interpret: bool = False,
-                        accum_outputs: Tuple[int, ...] = ()):
+                        accum_outputs: Tuple[int, ...] = (),
+                        fetched_bytes: Optional[Dict[int, Callable]] = None):
     """The ONE ``pl.pallas_call`` gateway for every kernel in this
     package (the coverage test asserts no other call site exists).
 
@@ -339,6 +344,9 @@ def audited_pallas_call(kernel, *, name: str = None, grid,
     the output indices whose index map intentionally revisits a block
     across grid steps (sequential accumulation / write-once-at-last-
     step patterns) — the WRITE_RACE rule flags any undeclared revisit.
+    ``fetched_bytes`` DECLARES, per input index, what the kernel copies
+    for itself out of an input it keeps in ``pl.ANY`` space (see
+    :class:`KernelOperand`): such an input has no blocks to count.
 
     When a :class:`capture_kernel_launches` context is active on this
     thread, invoking the returned callable records a
@@ -399,8 +407,9 @@ def audited_pallas_call(kernel, *, name: str = None, grid,
                 prefetch=tuple(
                     (tuple(getattr(a, "shape", ()) or ()),
                      str(getattr(a, "dtype", "?"))) for a in pre),
-                inputs=tuple(_operand(a, s)
-                             for a, s in zip(blocked, in_specs)),
+                inputs=tuple(
+                    _operand(a, s, (fetched_bytes or {}).get(i))
+                    for i, (a, s) in enumerate(zip(blocked, in_specs))),
                 outputs=tuple(_operand(sh, s) for sh, s in
                               zip(out_shape_flat, out_specs_flat)),
                 scratch=tuple(_scratch_record(s) for s in scratch),

@@ -102,6 +102,19 @@ CASES = [
     ("paged_attention", kc._paged_case(CAP, H, KV, HD, BS, NPAGES, MB,
                                        BF16),
      {"paged_attention_decode"}, None),
+    # -- the same launch as the benchmark's cells make it: the stacked
+    #    pools left in HBM, the layer a traced operand, the block of
+    #    pages resolved from the shapes (B, H, KV, hd, BS, pages, MB) --
+    ("paged_attention_mistral_one_chip",
+     kc._paged_case(32, 32, 8, 128, 16, 3072, 160, BF16, L=16),
+     {"paged_attention_decode"}, None),
+    ("paged_attention_mistral_tp4_shard",
+     kc._paged_case(32, 8, 2, 128, 16, 5120, 160, BF16, L=32),
+     {"paged_attention_decode"}, None),
+    ("paged_attention_granite_layer",
+     kc._paged_case(64, 32, 8, 128, 16, 8192, 128, BF16, L=1,
+                    scale=1.0 / 128),
+     {"paged_attention_decode"}, None),
     ("rms_norm_decode_rows", kc._rms_case(CAP, D, BF16),
      {"rms_norm_fwd", "rms_norm_bwd"}, None),
     ("decode_mlp_block", kc._mlp_block_case(CAP, D, F, BF16),

@@ -60,17 +60,21 @@ def test_flop_formula_full_coverage():
 
 
 def test_paged_decode_bytes_flops_hand_checked():
-    """Streamed-operand model, pinned geometry (pages_per_step=1,
-    B=2, H=4, KV=2, hd=16, BS=8, MB=4, f32):
+    """Self-fetched-operand model, pinned geometry (pages_per_step=2,
+    B=2, H=4, KV=2, hd=16, BS=8, MB=4, f32; the grid is (B,) = (2,)):
 
     - q [2,4,16]: one (1,4,16) block per batch row -> 2 x 256 B
-    - k/v pools: the grid walks B*MB=8 DISTINCT pages (the full
-      prefetch probe defeats the page-index length clamp) ->
-      8 x (8*2*16*4) = 8192 B each
+    - k/v pools: left in HBM (``any`` space), no blocks to count; the
+      launch declares what it copies out of each: the slots' live
+      pages, which on the full prefetch probe (every length past the
+      table) are all B*MB=8 pages -> 8 x (8*2*16*4) = 8192 B each
     - out [2,4,16]: 2 x 256 B
+    - scratch: the K and V double buffers [2,2,8,2,16] f32 (4096 B
+      each) and m/l/acc are VMEM; the [2,2,2] DMA semaphores are not
 
     total 17408 B; FLOPs = 4*B*H*hd*MB*BS = 16384 (QK^T + PV over the
-    full table)."""
+    same probe: the full table)."""
+    from paddle_tpu.analysis.kernel_rules import check_launch
     from paddle_tpu.ops.pallas.paged_attention import (
         paged_attention_decode_pallas)
     B, H, KV, hd, BS, NP, MB = 2, 4, 2, 16, 8, 8, 4
@@ -81,9 +85,13 @@ def test_paged_decode_bytes_flops_hand_checked():
     with capture_kernel_launches() as specs:
         jax.eval_shape(
             lambda *a: paged_attention_decode_pallas(
-                *a, pages_per_step=1), q, pool, pool, bt, ln)
+                *a, pages_per_step=2), q, pool, pool, bt, ln)
     (spec,) = specs
     assert spec.name == "paged_attention_decode"
+    assert tuple(spec.grid) == (B,)
+    assert [op.space for op in spec.inputs] == ["vmem", "any", "any"]
+    assert ((2, 2, 2), "dma_sem", "semaphore") in spec.scratch
+    assert check_launch(spec) == []
     bm = modeled_launch_bytes(spec)
     assert bm["total_bytes"] == 512 + 8192 + 8192 + 512 == 17408
     assert bm["read_bytes"] == 17408 - 512
