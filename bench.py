@@ -677,16 +677,17 @@ def bench_ernie_infer(batch=8, ctx=512, gen=64):
 
 def bench_paged_decode():
     """VERDICT r4 Next #5: time generate_paged on chip at serving shapes,
-    Pallas paged-attention kernel vs the XLA gather composition
-    (FLAGS_use_paged_kernel=0). Reference capability: the paged-KV fused
+    Pallas paged-attention kernel vs the XLA gather composition (the
+    registry op's ``xla`` variant, pinned). Reference capability: the paged-KV fused
     decode in paddle/phi/kernels/fusion/ (block_multihead_attention).
     Each (batch, ctx) point reports tokens/s for both paths."""
     import jax
     import jax.numpy as jnp
-    import paddle_tpu.ops.paged_attention  # noqa: F401 — defines the flag
-    from paddle_tpu.core.flags import GLOBAL_FLAGS
+    import contextlib
+    import paddle_tpu.ops.paged_attention  # noqa: F401 — registers the op
     from paddle_tpu.inference.generation import (GenerationConfig,
                                                  generate_paged)
+    from paddle_tpu.ops.pallas.registry import KERNELS
     from paddle_tpu.models.llama import LlamaConfig, init_params
 
     gen_n = int(os.environ.get("BENCH_PAGED_GEN", "64"))
@@ -707,21 +708,19 @@ def bench_paged_decode():
                            jnp.int32)
         g = GenerationConfig(max_new_tokens=gen_n, greedy=True)
         point = {}
-        for label, flag, cdt in (("pallas", True, None),
-                                 ("xla_gather", False, None),
-                                 ("int8_cache", False, "int8")):
-            prev = GLOBAL_FLAGS.get("use_paged_kernel")
-            GLOBAL_FLAGS.set("use_paged_kernel", flag)
+        xla = KERNELS.force("paged_attention_decode", "xla")
+        for label, pin, cdt in (("pallas", contextlib.nullcontext(), None),
+                                ("xla_gather", xla, None),
+                                ("int8_cache", xla, "int8")):
             try:
-                ms = _timed_host_synced(
-                    lambda: generate_paged(params, toks, cfg, g,
-                                           cache_dtype=cdt),
-                    steps=3)
+                with pin:
+                    ms = _timed_host_synced(
+                        lambda: generate_paged(params, toks, cfg, g,
+                                               cache_dtype=cdt),
+                        steps=3)
                 point[label] = round(batch * gen_n / (ms / 1e3), 1)
             except Exception as e:  # noqa: BLE001
                 point[label] = f"{type(e).__name__}: {e}"[:160]
-            finally:
-                GLOBAL_FLAGS.set("use_paged_kernel", prev)
         if isinstance(point.get("pallas"), float) and \
                 isinstance(point.get("xla_gather"), float):
             point["speedup"] = round(point["pallas"]
@@ -836,12 +835,11 @@ def bench_serving_engine():
             os.path.dirname(os.path.abspath(__file__)),
             "BENCH_SERVING_TELEMETRY.jsonl"))
 
-    # -- fused-vs-unfused decode A/B (BENCH_SERVE_AB=0 opts out): the
-    # same full-capacity burst through the (already warm) fused-decode
-    # engine and a fresh engine pinned to the pre-fusion step, per-step
-    # decode timing read from the observability histograms — the
-    # capture carries both sides of the megakernel claim, not just the
-    # fused number
+    # -- kernels-vs-compositions decode A/B (BENCH_SERVE_AB=0 opts
+    # out): the same full-capacity burst through the (already warm)
+    # engine and a fresh engine whose two decode launches are pinned to
+    # their XLA compositions, per-step decode timing read from the
+    # observability histograms — the capture carries both sides
     ab = None
     if os.environ.get("BENCH_SERVE_AB", "1") != "0":
         def _burst_decode_ms(e):
@@ -852,52 +850,27 @@ def bench_serving_engine():
             return e.metrics()["latency"]["decode_step_ms"]
 
         try:
+            from paddle_tpu.ops.pallas.registry import KERNELS
             fused_ms = _burst_decode_ms(eng)
             eng_u = ServingEngine(params, cfg, capacity=cap,
                                   block_size=16,
                                   max_seq_len=ctx + gen_n,
                                   cache_dtype=cdt,
                                   prefill_buckets=(ctx,),
-                                  observability=True,
-                                  fused_decode=False)
-            eng_u.submit(prompts[0], GenerationConfig(max_new_tokens=2,
-                                                      greedy=True))
-            eng_u.drain()            # compile outside the measured burst
-            unfused_ms = _burst_decode_ms(eng_u)
+                                  observability=True)
+            with KERNELS.force("paged_attention_decode", "xla"), \
+                    KERNELS.force("decode_mlp_block", "unfused"):
+                eng_u.submit(prompts[0],
+                             GenerationConfig(max_new_tokens=2,
+                                              greedy=True))
+                eng_u.drain()        # compile outside the measured burst
+                unfused_ms = _burst_decode_ms(eng_u)
             f50, u50 = fused_ms.get("p50"), unfused_ms.get("p50")
             ab = {"variant": eng.decode_variant,
                   "fused_decode_step_ms": fused_ms,
                   "unfused_decode_step_ms": unfused_ms,
                   **({"fused_decode_speedup": round(u50 / f50, 3)}
                      if f50 and u50 else {})}
-            # third arm: when auto dispatch serves the single-launch
-            # block kernel, pin the two-kernel composition so the
-            # capture carries block vs two-kernel vs unfused — guarded
-            # on the dispatched variant (pinning "block" where the
-            # combined windows exceed the scoped-VMEM envelope would
-            # just OOM the compile, and auto never runs it there)
-            if eng.decode_variant.get("block") == "pallas_block":
-                eng_2k = ServingEngine(params, cfg, capacity=cap,
-                                       block_size=16,
-                                       max_seq_len=ctx + gen_n,
-                                       cache_dtype=cdt,
-                                       prefill_buckets=(ctx,),
-                                       observability=True,
-                                       fused_decode="pallas")
-                eng_2k.submit(prompts[0],
-                              GenerationConfig(max_new_tokens=2,
-                                               greedy=True))
-                eng_2k.drain()   # compile outside the measured burst
-                two_ms = _burst_decode_ms(eng_2k)
-                ab["two_kernel_decode_step_ms"] = two_ms
-                b50, t50 = fused_ms.get("p50"), two_ms.get("p50")
-                if b50 and t50:
-                    ab["block_vs_two_kernel_speedup"] = \
-                        round(t50 / b50, 3)
-            else:
-                ab["block_arm"] = ("skipped: dispatch -> "
-                                   + str(eng.decode_variant
-                                         .get("block")))
         except Exception as e:  # noqa: BLE001 — A/B is evidence, not
             ab = {"error": f"{type(e).__name__}: {e}"[:200]}  # the bench
 
@@ -1893,20 +1866,17 @@ def bench_flash_tune():
         except Exception as e:  # noqa: BLE001
             tuned[f"{B}x{S}x{H}x{D}"] = f"{type(e).__name__}: {e}"[:120]
 
-    # decode-path tunables (pages-per-grid-step for the paged/fused
-    # attention kernels, block_f for the fused MLP): the serving read
+    # decode-path tunables (pages per loop iteration for the paged
+    # attention kernel, block_f for the fused MLP): the serving read
     # sites are all TRACED (the jitted chunk runner / engine decode fn)
     # and can only READ the persistent table — this eager sweep is what
-    # writes it, exactly like flash's above. The paged kernel (the
-    # unfused fallback's attention) sweeps at the serving_engine/llama
-    # bench shapes; the fused megakernels sweep at shapes inside their
-    # VMEM budget (where registry dispatch actually selects them — a
-    # direct eager call past the budget would just VMEM-OOM the
-    # compiler, sweeping a key no traced program ever reads). int8
-    # pools are a distinct shape class with their own cache key.
+    # writes it, exactly like flash's above. The paged kernel sweeps at
+    # the serving_engine/llama bench shapes; the fused MLP sweeps where
+    # registry dispatch selects it (a direct eager call past the VMEM
+    # budget would just VMEM-OOM the compiler, sweeping a key no traced
+    # program ever reads).
     from paddle_tpu.ops.pallas.fused_decode_block import (
-        decode_meta_dims, fused_attn_block_pallas,
-        fused_decode_block_pallas, fused_mlp_block_pallas)
+        decode_meta_dims, fused_mlp_block_pallas)
     from paddle_tpu.ops.pallas.registry import KERNELS
     from paddle_tpu.ops.pallas.paged_attention import (
         paged_attention_decode_pallas)
@@ -1953,7 +1923,6 @@ def bench_flash_tune():
         wk = jax.random.normal(ks[5], (D, KV * hd), dt) * 0.02
         wv = jax.random.normal(ks[6], (D, KV * hd), dt) * 0.02
         wo = jax.random.normal(ks[7], (H * hd, D), dt) * 0.02
-        sc = (jnp.ones((KV,), jnp.float32),) * 2
         for MB in MBs:
             T = BS * MB
             q = jax.random.normal(ks[0], (B, H, hd), dt)
@@ -1965,36 +1934,6 @@ def bench_flash_tune():
             _sweep(f"paged_decode|{tag}",
                    lambda: paged_attention_decode_pallas(q, kp, vp,
                                                          bt, sl))
-            half = jnp.arange(hd // 2, dtype=jnp.float32)[None, :]
-            pos = jnp.arange(T, dtype=jnp.float32)[:, None]
-            ang = pos / (10000.0 ** (2 * half / hd))
-            sin = jnp.sin(ang).astype(dt)
-            cos = jnp.cos(ang).astype(dt)
-            for quant in (False, True):
-                # the SAME builder decode_meta() delegates to, so this
-                # eager sweep's dispatch cannot drift from the traced
-                # serving readers'
-                m = decode_meta_dims(B, D, H, KV, hd, 4 * D, BS, MB,
-                                     dt, jnp.int8 if quant else dt,
-                                     quant)
-                sel_name, _ = KERNELS.dispatch("decode_attn_block", m)
-                if sel_name != "pallas_fused":
-                    decode_tuned[f"fused_attn"
-                                 f"{'_int8' if quant else ''}|{tag}"] \
-                        = f"skipped: dispatch -> {sel_name}"
-                    continue
-                if quant:
-                    _sweep(f"fused_attn_int8|{tag}",
-                           lambda: fused_attn_block_pallas(
-                               x, nw, wq, wk, wv, wo, sin, cos,
-                               kp.astype(jnp.int8),
-                               vp.astype(jnp.int8),
-                               bt, sl, kv_scales=sc)[0])
-                else:
-                    _sweep(f"fused_attn|{tag}",
-                           lambda: fused_attn_block_pallas(
-                               x, nw, wq, wk, wv, wo, sin, cos,
-                               kp, vp, bt, sl)[0])
         wg = jax.random.normal(ks[8], (D, 4 * D), dt) * 0.02
         wu = jax.random.normal(ks[9], (D, 4 * D), dt) * 0.02
         wd = jax.random.normal(ks[10], (4 * D, D), dt) * 0.02
@@ -2008,28 +1947,7 @@ def bench_flash_tune():
         for wq_name, wq_bits in (("int8", 8), ("int4", 4)):
             tag = (f"{B}x{H}x{KV}x{hd}x{jnp.dtype(dt).name}"
                    f"x{wq_name}w")
-            mq = decode_meta_dims(B, D, H, KV, hd, 4 * D, BS, MBs[-1],
-                                  dt, dt, False, weight_dtype=wq_name)
-            if KERNELS.dispatch("decode_attn_block", mq)[0] \
-                    != "pallas_fused":
-                decode_tuned[f"fused_attn_{wq_name}w|{tag}"] = \
-                    "skipped: dispatch -> unfused"
-            else:
-                qw = {k: _ptq.quantize_leaf(v, wq_bits)
-                      for k, v in (("q", wq), ("k", wk), ("v", wv),
-                                   ("o", wo))}
-                MBq = MBs[-1]
-                kpq = jax.random.normal(ks[1], (B * MBq, BS, KV, hd),
-                                        dt)
-                vpq = jax.random.normal(ks[2], (B * MBq, BS, KV, hd),
-                                        dt)
-                btq = jnp.arange(B * MBq,
-                                 dtype=jnp.int32).reshape(B, MBq)
-                slq = jnp.full((B,), BS * MBq - 2, jnp.int32)
-                _sweep(f"fused_attn_{wq_name}w|{tag}",
-                       lambda: fused_attn_block_pallas(
-                           x, nw, qw["q"], qw["k"], qw["v"], qw["o"],
-                           sin, cos, kpq, vpq, btq, slq)[0])
+            mq = decode_meta_dims(B, D, 4 * D, dt, weight_dtype=wq_name)
             if KERNELS.dispatch("decode_mlp_block", mq)[0] \
                     != "pallas_fused":
                 decode_tuned[f"fused_mlp_{wq_name}w|{tag}"] = \
@@ -2041,50 +1959,6 @@ def bench_flash_tune():
                            _ptq.quantize_leaf(wu, wq_bits),
                            _ptq.quantize_leaf(wd, wq_bits,
                                               pack_axis=1)))
-        # single-launch decode-block tunables: the combined kernel's
-        # (pages_per_step, block_f) is ONE joint autotune key — swept
-        # per MB page-count class AND per weight class (plain / int8 /
-        # int4 tiles are distinct cache keys via weight_dtype), guarded
-        # on registry dispatch like every sweep above (past the
-        # combined scoped-VMEM envelope the registry serves the
-        # two-kernel composition, so no traced program ever reads the
-        # block key — and the eager call would just VMEM-OOM)
-        pw_ = jnp.ones((D,), dt)
-        for MB in MBs:
-            Tb = BS * MB
-            kpb = jax.random.normal(ks[1], (B * MB, BS, KV, hd), dt)
-            vpb = jax.random.normal(ks[2], (B * MB, BS, KV, hd), dt)
-            btb = jnp.arange(B * MB, dtype=jnp.int32).reshape(B, MB)
-            slb = jnp.full((B,), Tb - 2, jnp.int32)
-            angb = (np.arange(Tb)[:, None]
-                    / (10000.0 ** (np.arange(0, hd, 2) / hd)))
-            sinb = jnp.asarray(np.sin(angb), dt)
-            cosb = jnp.asarray(np.cos(angb), dt)
-            for bwq_name in (None, "int8", "int4"):
-                m = decode_meta_dims(B, D, H, KV, hd, 4 * D, BS, MB,
-                                     dt, dt, False,
-                                     weight_dtype=bwq_name)
-                btag = (f"{B}x{H}x{KV}x{hd}x{jnp.dtype(dt).name}"
-                        f"{'x' + bwq_name + 'w' if bwq_name else ''}"
-                        f"xMB{MB}")
-                sel_name, _ = KERNELS.dispatch("decode_block_fused", m)
-                if sel_name != "pallas_block":
-                    decode_tuned[f"fused_block|{btag}"] = \
-                        f"skipped: dispatch -> {sel_name}"
-                    continue
-                if bwq_name:
-                    bits = 8 if bwq_name == "int8" else 4
-                    bw = [_ptq.quantize_leaf(w_, bits)
-                          for w_ in (wq, wk, wv, wo, wg, wu)]
-                    bw.append(_ptq.quantize_leaf(wd, bits,
-                                                 pack_axis=1))
-                else:
-                    bw = [wq, wk, wv, wo, wg, wu, wd]
-                _sweep(f"fused_block|{btag}",
-                       lambda: fused_decode_block_pallas(
-                           x, nw, bw[0], bw[1], bw[2], bw[3], pw_,
-                           bw[4], bw[5], bw[6], sinb, cosb, kpb,
-                           vpb, btb, slb)[0])
         # fused-prefill tunables ((block_q, pages_per_step) pairs) at
         # the serving bucket widths — the engine's chunk runners are
         # traced and only READ the table; dispatch-guarded like the
@@ -2413,45 +2287,17 @@ def bench_kernels():
            jax.jit(ref_paged),
            dq, kp, vp, tol=3e-2, bytes_moved=paged_bytes)
 
-    # ---- fused decode-block megakernels (serving hot path) -------------
-    # one transformer block's decode step per kernel vs the unfused
-    # composition it replaces — the same A/B the registry dispatches
+    # ---- fused decode MLP block (serving hot path) ---------------------
+    # the decode step's MLP launch vs the composition it replaces — the
+    # same A/B the registry dispatches
     from paddle_tpu.ops.pallas.fused_decode_block import (
-        attn_block_ref, fused_attn_block_pallas, fused_mlp_block_pallas,
-        mlp_block_ref)
+        fused_mlp_block_pallas, mlp_block_ref)
 
-    FB, FD, FKV, Fhd, FBS, FMB = (8, 1024, 16, 64, 16, 16) if not interp \
-        else (2, 64, 2, 16, 8, 4)
-    FH, FF = FKV, FD * 4              # MHA layout (groups=1), SwiGLU 4x
+    FB, FD = (8, 1024) if not interp else (2, 64)
+    FF = FD * 4                       # SwiGLU 4x
     fk = jax.random.split(jax.random.PRNGKey(1), 10)
     fx = jax.random.normal(fk[0], (FB, FD), jnp.bfloat16)
     fnw = jnp.ones((FD,), jnp.bfloat16)
-    fwq = jax.random.normal(fk[1], (FD, FH * Fhd), jnp.bfloat16) * 0.05
-    fwk = jax.random.normal(fk[2], (FD, FKV * Fhd), jnp.bfloat16) * 0.05
-    fwv = jax.random.normal(fk[3], (FD, FKV * Fhd), jnp.bfloat16) * 0.05
-    fwo = jax.random.normal(fk[4], (FH * Fhd, FD), jnp.bfloat16) * 0.05
-    fpos = np.arange(FBS * FMB)[:, None] / (
-        10000.0 ** (np.arange(0, Fhd, 2) / Fhd))
-    fsin = jnp.asarray(np.sin(fpos), jnp.float32)
-    fcos = jnp.asarray(np.cos(fpos), jnp.float32)
-    FN = FB * FMB + 2
-    fkp = jax.random.normal(fk[5], (FN, FBS, FKV, Fhd), jnp.bfloat16)
-    fvp = jax.random.normal(fk[6], (FN, FBS, FKV, Fhd), jnp.bfloat16)
-    frng = np.random.RandomState(3)
-    ftab = jnp.asarray(frng.permutation(FN)[:FB * FMB].reshape(FB, FMB),
-                       jnp.int32)
-    flens = jnp.asarray(frng.randint(1, FBS * FMB, (FB,)), jnp.int32)
-    # HBM traffic: the block weights (the part fusion keeps resident)
-    # + the live KV pages, both sides of the residual stream
-    fused_live = int(np.sum(np.ceil(np.asarray(flens) / FBS)))
-    attn_bytes = (2 * FD * FH * Fhd + 2 * FD * FKV * Fhd) * 2 \
-        + fused_live * FBS * FKV * Fhd * 2 * 2 + 2 * FB * FD * 2
-    record("fused_attn_block",
-           jax.jit(lambda *a: fused_attn_block_pallas(*a)[0]),
-           jax.jit(lambda *a: attn_block_ref(*a)[0]),
-           fx, fnw, fwq, fwk, fwv, fwo, fsin, fcos, fkp, fvp, ftab,
-           flens, tol=5e-2, bytes_moved=attn_bytes)
-
     fwg = jax.random.normal(fk[7], (FD, FF), jnp.bfloat16) * 0.05
     fwu = jax.random.normal(fk[8], (FD, FF), jnp.bfloat16) * 0.05
     fwd_ = jax.random.normal(fk[9], (FF, FD), jnp.bfloat16) * 0.05
@@ -2460,25 +2306,13 @@ def bench_kernels():
            fx, fnw, fwg, fwu, fwd_, tol=5e-2,
            bytes_moved=3 * FD * FF * 2 + 2 * FB * FD * 2)
 
-    # ---- quantized-WEIGHT megakernel variants (r18) --------------------
+    # ---- quantized-WEIGHT variants (r18) -------------------------------
     # int8 / packed-int4 weight tiles with in-register dequant vs the
     # dequantize-then-matmul composition (both sides see the SAME
     # quantized tree, so the diff is kernel-vs-composition roundoff,
     # not quantization error) — same kernel_bench_gate trajectory
     from paddle_tpu.quantization import ptq as _ptq
     for wq_tag, wq_bits, wbytes in (("w8", 8, 1.0), ("w4", 4, 0.5)):
-        qwq = _ptq.quantize_leaf(fwq, wq_bits)
-        qwk = _ptq.quantize_leaf(fwk, wq_bits)
-        qwv = _ptq.quantize_leaf(fwv, wq_bits)
-        qwo = _ptq.quantize_leaf(fwo, wq_bits)
-        attn_q_bytes = int((2 * FD * FH * Fhd + 2 * FD * FKV * Fhd)
-                           * wbytes) \
-            + fused_live * FBS * FKV * Fhd * 2 * 2 + 2 * FB * FD * 2
-        record(f"fused_attn_block_{wq_tag}",
-               jax.jit(lambda *a: fused_attn_block_pallas(*a)[0]),
-               jax.jit(lambda *a: attn_block_ref(*a)[0]),
-               fx, fnw, qwq, qwk, qwv, qwo, fsin, fcos, fkp, fvp, ftab,
-               flens, tol=5e-2, bytes_moved=attn_q_bytes)
         qwg = _ptq.quantize_leaf(fwg, wq_bits)
         qwu = _ptq.quantize_leaf(fwu, wq_bits)
         qwd = _ptq.quantize_leaf(fwd_, wq_bits, pack_axis=1)
@@ -2487,46 +2321,6 @@ def bench_kernels():
                fx, fnw, qwg, qwu, qwd, tol=5e-2,
                bytes_moved=int(3 * FD * FF * wbytes) + 2 * FB * FD * 2)
 
-    # ---- single-launch decode block vs the two-kernel composition ------
-    # the WHOLE block in one launch (RMSNorm+QKV+RoPE+paged attn+o_proj
-    # +residual+RMSNorm+SwiGLU+residual, residual in f32 VMEM scratch)
-    # vs the priority-0 composed route — the exact two-stage sequence
-    # the registry would otherwise serve. Dispatch-guarded at the bench
-    # shape: past the combined scoped-VMEM envelope the compile would
-    # just VMEM-OOM, and no traced program runs the block kernel there
-    # anyway. Feeds the same kernel_bench_gate trajectory.
-    from paddle_tpu.ops.pallas.fused_decode_block import (
-        decode_block_composed, decode_meta_dims, fused_decode_block_pallas)
-    from paddle_tpu.ops.pallas.registry import KERNELS as _KERNELS
-    fpw = jnp.ones((FD,), jnp.bfloat16)
-    for blk_tag, blk_wq, blk_bits, blk_wb in (
-            ("", None, 0, 2.0), ("_w8", "int8", 8, 1.0),
-            ("_w4", "int4", 4, 0.5)):
-        bm = decode_meta_dims(FB, FD, FH, FKV, Fhd, FF, FBS, FMB,
-                              jnp.bfloat16, jnp.bfloat16, False,
-                              weight_dtype=blk_wq)
-        sel_name, _ = _KERNELS.dispatch("decode_block_fused", bm)
-        if sel_name != "pallas_block" and not interp:
-            res["cases"][f"decode_block_fused{blk_tag}"] = {
-                "skipped": f"dispatch -> {sel_name}"}
-            continue
-        if blk_wq:
-            bws = [_ptq.quantize_leaf(w_, blk_bits)
-                   for w_ in (fwq, fwk, fwv, fwo, fwg, fwu)]
-            bws.append(_ptq.quantize_leaf(fwd_, blk_bits, pack_axis=1))
-        else:
-            bws = [fwq, fwk, fwv, fwo, fwg, fwu, fwd_]
-        # all seven weight tiles once + the live KV pages + residual I/O
-        blk_bytes = int((2 * FD * FH * Fhd + 2 * FD * FKV * Fhd
-                         + 3 * FD * FF) * blk_wb) \
-            + fused_live * FBS * FKV * Fhd * 2 * 2 + 2 * FB * FD * 2
-        record(f"decode_block_fused{blk_tag}",
-               jax.jit(lambda *a: fused_decode_block_pallas(*a)[0]),
-               jax.jit(lambda *a: decode_block_composed(*a)[0]),
-               fx, fnw, bws[0], bws[1], bws[2], bws[3], fpw, bws[4],
-               bws[5], bws[6], fsin, fcos, fkp, fvp, ftab, flens,
-               tol=5e-2, bytes_moved=blk_bytes)
-
     # ---- fused prefill-block megakernel (ragged chunked prefill) -------
     # one transformer block's prefill chunk (warm mid-window start,
     # ragged valid rows) vs the dense gather composition it replaces —
@@ -2534,6 +2328,12 @@ def bench_kernels():
     from paddle_tpu.ops.pallas.fused_prefill_block import (
         fused_prefill_attn_pallas, prefill_attn_block_ref)
 
+    FKV, Fhd, FBS = (16, 64, 16) if not interp else (2, 16, 8)
+    FH = FKV                          # MHA layout (groups=1)
+    fwq = jax.random.normal(fk[1], (FD, FH * Fhd), jnp.bfloat16) * 0.05
+    fwk = jax.random.normal(fk[2], (FD, FKV * Fhd), jnp.bfloat16) * 0.05
+    fwv = jax.random.normal(fk[3], (FD, FKV * Fhd), jnp.bfloat16) * 0.05
+    fwo = jax.random.normal(fk[4], (FH * Fhd, FD), jnp.bfloat16) * 0.05
     PP, PMB = (64, 24) if not interp else (16, 6)
     p_pos0, p_valid = (PMB * FBS) // 2, PP - 3
     pk = jax.random.split(jax.random.PRNGKey(4), 2)

@@ -220,14 +220,6 @@ def run_engine(eng, waves, gen):
     return reqs
 
 
-def decode_kernels_expected(variant):
-    if variant["block"] == "pallas_block":
-        return {"decode_block_fused"}
-    return {"decode_attn_block" if variant["attn"] == "pallas_fused"
-            else "paged_attention_decode"} | (
-        {"decode_mlp_block"} if variant["mlp"] == "pallas_fused" else set())
-
-
 def compiled_decode_kernels(eng):
     (spec,) = [s for s in eng.program_specs(register=False)
                if s.name.startswith("serving_decode")]
@@ -258,8 +250,7 @@ def phase_serving(jax, np, args, clock):
            for r in reqs]
     first_eq, share = token_match([r.tokens for r in reqs], ref)
     found = compiled_decode_kernels(eng)
-    want = decode_kernels_expected(m["decode_variant"]) \
-        if jax.devices()[0].platform == "tpu" else set()
+    want = set(m["decode_variant"]["operands"])   # its Pallas launches
     say("serving", **describe(cfg, full_depth, args.tiny),
         requests=len(reqs), finished=sum(r.done for r in reqs),
         prompt_lens=[int(r.prompt.size) for r in reqs],

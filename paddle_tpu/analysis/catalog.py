@@ -29,7 +29,6 @@ __all__ = ["build_catalog", "build_demo_regression",
 CATALOG_PROGRAMS = ("train_step", "train_step_fused",
                     "fused_optimizer_step",
                     "serving_decode", "serving_decode_fused",
-                    "serving_decode_block",
                     "serving_decode_wq",
                     "serving_prefill_16", "serving_prefill_32",
                     "serving_prefill_fused",
@@ -119,6 +118,19 @@ def _fused_optimizer_spec(register: bool):
     return opt.audit_spec(register=register)
 
 
+def _pinned(spec, name: str, tags=()):
+    """``spec`` renamed, its program traced with both decode launches
+    pinned to their Pallas variants (the registry's ``force``)."""
+    import dataclasses as _dc
+    from ..ops.pallas.registry import KERNELS
+
+    def fn(*args):
+        with KERNELS.force("paged_attention_decode", "pallas"), \
+                KERNELS.force("decode_mlp_block", "pallas_fused"):
+            return spec.fn(*args)
+    return _dc.replace(spec, name=name, fn=fn, tags=spec.tags + tags)
+
+
 def _serving_specs(register: bool):
     import jax
     from ..inference.serving import ServingEngine
@@ -130,48 +142,36 @@ def _serving_specs(register: bool):
                         max_seq_len=64, prefill_buckets=(16, 32),
                         prefix_cache=True)
     specs = eng.program_specs(register=register)
-    # the fused decode-block program, FORCED onto the Pallas megakernel
-    # variant so the audited jaxpr contains the fused kernels even on
-    # CPU (auto-dispatch would fall back to the composition there) —
-    # the gate must cover the program production TPUs actually run.
-    # Register ONLY the filtered fused-decode spec: the fused engine's
-    # other programs (its own prefill buckets) would latest-wins
-    # replace the main engine's entries in the global REGISTRY while
-    # the gate list kept auditing the main engine's versions
-    fused_eng = ServingEngine(params, cfg, capacity=2, block_size=8,
-                              max_seq_len=64, prefill_buckets=(16,),
-                              fused_decode="pallas")
-    fused = [s for s in fused_eng.program_specs(register=False)
-             if s.name == "serving_decode_fused"]
-    # the SINGLE-LAUNCH decode-block program the same way: a forced
-    # fused_decode="block" engine pins the whole-block megakernel, so
-    # the audited jaxpr contains the single-launch kernel even on CPU
-    block_eng = ServingEngine(params, cfg, capacity=2, block_size=8,
-                              max_seq_len=64, prefill_buckets=(16,),
-                              fused_decode="block")
-    fused += [s for s in block_eng.program_specs(register=False)
-              if s.name == "serving_decode_block"]
+    # the decode program with BOTH launches forced onto their Pallas
+    # variants, so the audited jaxpr contains the kernels even on CPU
+    # (auto-dispatch picks the compositions there) — the gate must
+    # cover the program production TPUs actually run. Registered
+    # renamed, next to (never latest-wins clobbering) the default
+    # program's entry
+    # (a FRESH jit instance: one the unpinned audit has traced would
+    # replay that trace under the pins)
+    import dataclasses as _dc
+    fused = [_pinned(s, "serving_decode_fused")
+             for s in eng.program_specs(register=False)
+             if s.name == "serving_decode"]
     # the fused PREFILL chunk the same way: a forced-pallas-prefill
     # engine's bucket program, renamed to its catalog entry (the
     # audited jaxpr contains the prefill megakernels even on CPU)
-    import dataclasses as _dc
     fp_eng = ServingEngine(params, cfg, capacity=2, block_size=8,
                            max_seq_len=64, prefill_buckets=(16,),
                            fused_prefill="pallas")
     fused += [_dc.replace(s, name="serving_prefill_fused")
               for s in fp_eng.program_specs(register=False)
               if s.name == "serving_prefill_fused_16"]
-    # the quantized-WEIGHT decode program (r18): an int8 weight tree's
-    # decode step — the quantized param signature (integer leaves +
-    # scale leaves) and the dequantize-then-matmul route feed the
-    # dtype/donation/retrace rules. Registered renamed, the
-    # serving_decode_fused idiom (never latest-wins clobbering the fp
-    # engine's entry).
+    # the quantized-WEIGHT decode program: an int8 weight tree's
+    # decode step pinned the same way, so the quantized param signature
+    # (integer leaves + scale leaves), the MLP launch's in-kernel
+    # dequantization and the attention stage's dequantize-then-matmul
+    # route feed the dtype/donation/retrace rules
     wq_eng = ServingEngine(params, cfg, capacity=2, block_size=8,
                            max_seq_len=64, prefill_buckets=(16,),
                            weight_quant="int8")
-    fused += [_dc.replace(s, name="serving_decode_wq",
-                          tags=s.tags + ("weight_quant",))
+    fused += [_pinned(s, "serving_decode_wq", tags=("weight_quant",))
               for s in wq_eng.program_specs(register=False)
               if s.name == "serving_decode"]
     if register:
@@ -340,7 +340,7 @@ def build_catalog(names: Optional[List[str]] = None,
     if "fused_optimizer_step" in wanted:
         specs.append(_fused_optimizer_spec(register))
     if wanted & {"serving_decode", "serving_decode_fused",
-                 "serving_decode_block", "serving_decode_wq",
+                 "serving_decode_wq",
                  "serving_prefill_16", "serving_prefill_32",
                  "serving_prefill_fused", "serving_page_copy"}:
         specs.extend(s for s in _serving_specs(register)
@@ -439,7 +439,7 @@ def build_demo_tp_regression(register: bool = False):
     }
     pools_sd = sds(L, NB, BS, KV // tp, hd)
     fn = functools.partial(_tp_decode_step, cfg=cfg, axis="tp",
-                           collective="psum", fused=False)
+                           collective="psum")
     spec = ProgramSpec(
         name="demo_regression_tp_axis",
         fn=lambda params, tok, kp, vp, tables, seq: fn(

@@ -205,36 +205,6 @@ def _wq_sds(shape, wq, pack_axis=0):
             "scale": _sds((shape[-1],), "float32")}
 
 
-def _attn_block_case(B, D, H, KV, hd, BS, N, MB, dtype, quant=False,
-                     pp=None, wq=None):
-    def build():
-        from ..ops.pallas.fused_decode_block import fused_attn_block_pallas
-
-        pool_dt = "int8" if quant else dtype
-
-        def fn(x, nw, wq_, wk_, wv_, wo_, sin, cos, kp, vp, bt, ln,
-               *sc):
-            kv_scales = (sc[0], sc[1]) if quant else None
-            return fused_attn_block_pallas(
-                x, nw, wq_, wk_, wv_, wo_, sin, cos, kp, vp, bt, ln,
-                kv_scales=kv_scales, pages_per_step=pp)
-
-        def w(shape):
-            return _wq_sds(shape, wq) if wq else _sds(shape, dtype)
-        args = [_sds((B, D), dtype), _sds((D,), dtype),
-                w((D, H * hd)), w((D, KV * hd)),
-                w((D, KV * hd)), w((H * hd, D)),
-                _sds((MB * BS + 1, hd // 2), "float32"),
-                _sds((MB * BS + 1, hd // 2), "float32"),
-                _sds((N, BS, KV, hd), pool_dt),
-                _sds((N, BS, KV, hd), pool_dt),
-                _sds((B, MB), "int32"), _sds((B,), "int32")]
-        if quant:
-            args += [_sds((KV,), "float32"), _sds((KV,), "float32")]
-        return fn, tuple(args)
-    return build
-
-
 def _prefill_attn_case(P, D, H, KV, hd, BS, N, MB, dtype, quant=False,
                        pos0=0, bq=None, pp=None, wq=None):
     def build():
@@ -282,44 +252,6 @@ def _mlp_block_case(B, D, F, dtype, wq=None):
                     # down_proj packs its OUTPUT axis (the F tiles
                     # never split it — the ptq.WQ_KEYS contract)
                     w((F, D), pack_axis=1))
-    return build
-
-
-def _block_case(B, D, H, KV, hd, F, BS, N, MB, dtype, quant=False,
-                pp=None, bf=None, wq=None):
-    """The SINGLE-LAUNCH decode block (attn + MLP in one grid, residual
-    in VMEM scratch). Tunables are pinned for the non-tiny cases so the
-    audited geometry cannot drift with the autotune env."""
-    def build():
-        from ..ops.pallas.fused_decode_block import (
-            fused_decode_block_pallas)
-
-        pool_dt = "int8" if quant else dtype
-
-        def fn(x, nw, wq_, wk_, wv_, wo_, pw, wg_, wu_, wd_, sin, cos,
-               kp, vp, bt, ln, *sc):
-            kv_scales = (sc[0], sc[1]) if quant else None
-            return fused_decode_block_pallas(
-                x, nw, wq_, wk_, wv_, wo_, pw, wg_, wu_, wd_, sin, cos,
-                kp, vp, bt, ln, kv_scales=kv_scales, pages_per_step=pp,
-                block_f=bf)
-
-        def w(shape, pack_axis=0):
-            return _wq_sds(shape, wq, pack_axis) if wq \
-                else _sds(shape, dtype)
-        args = [_sds((B, D), dtype), _sds((D,), dtype),
-                w((D, H * hd)), w((D, KV * hd)),
-                w((D, KV * hd)), w((H * hd, D)),
-                _sds((D,), dtype),
-                w((D, F)), w((D, F)), w((F, D), pack_axis=1),
-                _sds((MB * BS + 1, hd // 2), "float32"),
-                _sds((MB * BS + 1, hd // 2), "float32"),
-                _sds((N, BS, KV, hd), pool_dt),
-                _sds((N, BS, KV, hd), pool_dt),
-                _sds((B, MB), "int32"), _sds((B,), "int32")]
-        if quant:
-            args += [_sds((KV,), "float32"), _sds((KV,), "float32")]
-        return fn, tuple(args)
     return build
 
 
@@ -402,56 +334,6 @@ def kernel_cases() -> List[KernelCase]:
           _flash_case(1, 128, 4, 2, 64, "float32", bias=True, seg=True)),
         C("flash_attention", "flagship_train", _FLASH_KERNELS,
           _flash_case(4, 2048, 16, 8, 128, "bfloat16")),
-        C("decode_attn_block", "tiny", ("decode_attn_block",),
-          _attn_block_case(2, 32, 2, 2, 16, 8, 8, 4, "float32")),
-        C("decode_attn_block", "flagship_serving", ("decode_attn_block",),
-          _attn_block_case(8, 1024, 16, 16, 64, 16, 128, 24, "bfloat16")),
-        C("decode_attn_block", "flagship_serving_pp4",
-          ("decode_attn_block",),
-          _attn_block_case(8, 1024, 16, 16, 64, 16, 128, 24, "bfloat16",
-                           pp=4)),
-        C("decode_attn_block", "flagship_serving_int8",
-          ("decode_attn_block",),
-          _attn_block_case(8, 1024, 16, 16, 64, 16, 128, 24, "bfloat16",
-                           quant=True)),
-        # quantized-WEIGHT variants (r18): int8/int4 tiles + scale rows
-        # at the tiny and flagship serving shape classes — the launches
-        # the weight_quant routes actually dispatch on TPU
-        C("decode_attn_block", "tiny_int8_weights",
-          ("decode_attn_block",),
-          _attn_block_case(2, 32, 2, 2, 16, 8, 8, 4, "float32",
-                           wq="int8")),
-        C("decode_attn_block", "flagship_serving_int8_weights",
-          ("decode_attn_block",),
-          _attn_block_case(8, 1024, 16, 16, 64, 16, 128, 24, "bfloat16",
-                           wq="int8")),
-        C("decode_attn_block", "flagship_serving_int4_weights",
-          ("decode_attn_block",),
-          _attn_block_case(8, 1024, 16, 16, 64, 16, 128, 24, "bfloat16",
-                           wq="int4")),
-        # the SINGLE-LAUNCH block kernel (attn + MLP in one grid): the
-        # flagship bf16 geometry is audited even though dispatch falls
-        # back there (the conservative double-buffer charge in
-        # supports() binds before the auditor's resident model does);
-        # int8/int4 are the classes dispatch actually serves fused
-        C("decode_block_fused", "tiny", ("decode_block_fused",),
-          _block_case(2, 32, 2, 2, 16, 64, 8, 8, 4, "float32")),
-        C("decode_block_fused", "flagship_serving",
-          ("decode_block_fused",),
-          _block_case(8, 1024, 16, 16, 64, 4096, 16, 128, 24,
-                      "bfloat16", pp=4, bf=512)),
-        C("decode_block_fused", "flagship_serving_int8",
-          ("decode_block_fused",),
-          _block_case(8, 1024, 16, 16, 64, 4096, 16, 128, 24,
-                      "bfloat16", quant=True, pp=4, bf=512)),
-        C("decode_block_fused", "flagship_serving_int8_weights",
-          ("decode_block_fused",),
-          _block_case(8, 1024, 16, 16, 64, 4096, 16, 128, 24,
-                      "bfloat16", pp=4, bf=512, wq="int8")),
-        C("decode_block_fused", "flagship_serving_int4_weights",
-          ("decode_block_fused",),
-          _block_case(8, 1024, 16, 16, 64, 4096, 16, 128, 24,
-                      "bfloat16", pp=4, bf=512, wq="int4")),
         C("decode_mlp_block", "tiny", ("decode_mlp_block",),
           _mlp_block_case(2, 32, 64, "float32")),
         C("decode_mlp_block", "flagship_serving", ("decode_mlp_block",),
@@ -575,43 +457,11 @@ def _flops_paged_decode(spec):
     return 4.0 * B * H * hd * MB * BS
 
 
-def _row_dims(spec):
-    """(B, D) of a decode kernel's residual rows, which ride as a
-    (B, 1, D) view (one (1, 1, D) block per sequence)."""
-    shape = spec.inputs[0].shape
-    return int(shape[0]), int(shape[-1])
-
-
-def _flops_decode_attn_block(spec):
-    B, D = _row_dims(spec)
-    Hhd = int(spec.inputs[2].shape[1])
-    KVhd = int(spec.inputs[3].shape[1])
-    MB = int(spec.prefetch[0][0][1])
-    BS, _ = _pool_dims(spec)
-    # norm (4/elem) + q/k/v/o projections (2mkn each) + full-table
-    # attention (4 per head-dim element per key position)
-    return B * (4.0 * D + 2.0 * D * Hhd + 4.0 * D * KVhd
-                + 2.0 * Hhd * D + 4.0 * Hhd * MB * BS)
-
-
 def _flops_decode_mlp_block(spec):
     B, D = (int(s) for s in spec.inputs[0].shape)
     F = int(spec.inputs[2].shape[-1])       # gate: the stacked (L, D, F)
     # norm + gate/up/down matmuls + silu·mul epilogue (~4/f-elem)
     return B * (4.0 * D + 6.0 * D * F + 4.0 * F)
-
-
-def _flops_decode_block_fused(spec):
-    B, D = _row_dims(spec)
-    Hhd = int(spec.inputs[2].shape[1])
-    KVhd = int(spec.inputs[3].shape[1])
-    F = int(spec.inputs[7].shape[1])
-    MB = int(spec.prefetch[0][0][1])
-    BS, _ = _pool_dims(spec)
-    # the attn-block sum + the mlp-block sum (two norms: 4D each)
-    return B * (8.0 * D + 2.0 * D * Hhd + 4.0 * D * KVhd
-                + 2.0 * Hhd * D + 4.0 * Hhd * MB * BS
-                + 6.0 * D * F + 4.0 * F)
 
 
 def _flops_prefill_attn_block(spec):
@@ -703,9 +553,7 @@ FLOP_FORMULAS: Dict[str, Callable] = {
     "layer_norm_fwd": _flops_layer_norm_fwd,
     "fused_adamw": _flops_adamw,
     "paged_attention_decode": _flops_paged_decode,
-    "decode_attn_block": _flops_decode_attn_block,
     "decode_mlp_block": _flops_decode_mlp_block,
-    "decode_block_fused": _flops_decode_block_fused,
     "prefill_attn_block": _flops_prefill_attn_block,
     "flash_attention_fwd": _flops_flash_fwd,
     "flash_attention_bwd_dq": _flops_flash_bwd_dq,
@@ -809,14 +657,12 @@ def _lint_metas() -> Dict[str, dict]:
     from ..ops.pallas.fused_train import ce_meta, swiglu_meta
     from ..ops.pallas.norms import rms_bwd_meta
 
-    decode = decode_meta_dims(8, 1024, 16, 16, 64, 4096, 16, 24,
-                              jnp.bfloat16, jnp.bfloat16, False)
+    from ..ops.paged_attention import decode_attention_meta
     prefill = prefill_meta_dims(64, 1024, 16, 16, 64, 4096, 16, 24,
                                 jnp.bfloat16, jnp.bfloat16, False)
     return {
-        "decode_attn_block": decode,
-        "decode_mlp_block": decode,
-        "decode_block_fused": decode,
+        "paged_attention_decode": decode_attention_meta(jnp.bfloat16),
+        "decode_mlp_block": decode_meta_dims(8, 1024, 4096, jnp.bfloat16),
         "prefill_attn_block": prefill,
         "prefill_mlp_block": prefill,
         "fused_linear_ce": ce_meta(4096, 2048, 32000, jnp.bfloat16),

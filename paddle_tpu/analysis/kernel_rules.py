@@ -115,8 +115,8 @@ class _ClampedTable:
     """ndarray stand-in whose ``__getitem__`` clamps every integer
     index component into the array's extent. The ``full`` prefetch
     sample (below) fills sequence lengths with huge values so a
-    length-clamped page walk (``clamped_page_index``: ``idx =
-    min(step, (len-1)//BS)``) advances a FRESH table entry per grid
+    length-clamped page walk (the fused prefill attention kernel's
+    ``page_index``: ``idx = min(step, (len-1)//BS)``) advances a FRESH table entry per grid
     step instead of collapsing onto entry 0 — but that same huge
     length lets the computed table index run past the table extent on
     ragged last steps, which would IndexError on a bare ndarray. The
@@ -343,8 +343,8 @@ def _vmem_findings(spec, program, memo) -> List[Finding]:
     pool operand would wrongly look like a resident constant. Σ must
     fit the scoped-VMEM envelope.
 
-    Combined multi-window launches (the single-launch decode block:
-    resident attention weights + streamed MLP tiles in ONE grid) are
+    Combined multi-window launches (resident weights + streamed tiles
+    or pages in ONE grid, as the fused prefill attention kernel) are
     additionally held to the dispatch-budget side of the
     :func:`scoped_vmem_envelope` contract: the RESIDENT share alone
     (1-window operands + scratch — what stays in VMEM for the whole

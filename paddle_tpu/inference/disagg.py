@@ -196,8 +196,7 @@ class DisaggregatedEngine:
                  prefill_buckets=(32, 128), seed: int = 0,
                  prefix_cache: bool = False, kv_offload=False,
                  observability=False,
-                 fused_decode=None, fused_prefill=None,
-                 weight_quant=None,
+                 fused_prefill=None, weight_quant=None,
                  aging_s: Optional[float] = None, telemetry=False,
                  clock=None):
         # injectable scheduler clock, threaded through BOTH group
@@ -256,7 +255,7 @@ class DisaggregatedEngine:
             cache_dtype=cache_dtype, prefill_buckets=prefill_buckets,
             seed=seed, prefix_cache=prefix_cache, kv_offload=kv_offload,
             observability=pre_obs,
-            fused_decode=False, fused_prefill=fused_prefill,
+            fused_prefill=fused_prefill,
             mesh=pre_mesh, aging_s=aging_s, clock=clock,
             on_complete=self._on_prefilled,
             on_chunk=self._on_prefill_chunk)
@@ -265,7 +264,7 @@ class DisaggregatedEngine:
             num_blocks=num_blocks, max_seq_len=msl,
             cache_dtype=cache_dtype, prefill_buckets=prefill_buckets,
             seed=seed + 1, prefix_cache=False, observability=dec_obs,
-            fused_decode=fused_decode, fused_prefill=fused_prefill,
+            fused_prefill=fused_prefill,
             mesh=dec_mesh, aging_s=aging_s, clock=clock)
         if self._obs is not None:
             # one timeline ring + one request-record log for the whole

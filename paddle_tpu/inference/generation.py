@@ -258,24 +258,6 @@ def generate(params: Dict, input_ids, cfg: _llama.LlamaConfig,
 # ---------------------------------------------------------------------------
 # Paged-KV serving path
 # ---------------------------------------------------------------------------
-def _fused_mode(fused_decode):
-    """Normalize a ``fused_decode`` knob: None reads the global flag
-    (default ON — "on where supported": auto-dispatch still falls back
-    to the unfused composition off-TPU / for unsupported shapes)."""
-    from ..core.flags import GLOBAL_FLAGS
-    from ..ops.pallas import fused_decode_block  # noqa: F401 — defines flag
-    if fused_decode is None:
-        fused_decode = bool(GLOBAL_FLAGS.get("fused_decode"))
-    if fused_decode is False:
-        return False
-    if fused_decode is True:
-        return "auto"
-    if fused_decode in ("auto", "pallas", "ref", "block"):
-        return fused_decode
-    raise ValueError(f"fused_decode must be bool|auto|pallas|ref|block, "
-                     f"got {fused_decode!r}")
-
-
 def _fused_prefill_mode(fused_prefill):
     """Normalize a ``fused_prefill`` knob: None reads the global flag
     (default ON — "on where supported": dispatch still falls back to
@@ -294,20 +276,26 @@ def _fused_prefill_mode(fused_prefill):
                      f"got {fused_prefill!r}")
 
 
+def kernel_route():
+    """The trace-time inputs, beyond the jit signature, that can reshape
+    a program whose kernels the registry dispatches: the registry's
+    force-pin stack, the VMEM budget (reshapes supports() and the tile
+    candidate lists) and the interpret override. Every cache that holds
+    such a program folds this into its key, so a changed route
+    retraces and never replays."""
+    from ..ops.pallas._util import fused_vmem_budget, interpret_mode
+    from ..ops.pallas.registry import KERNELS
+    return (KERNELS.forced_state(), fused_vmem_budget(),
+            bool(interpret_mode()))
+
+
 def _prefill_route(mode):
-    """The trace-time inputs (beyond the jit signature) that can
-    reshape a fused-prefill chunk program: the registry's force-pin
-    stack (consulted by dispatch in "auto" mode), the VMEM budget
-    (reshapes supports() and the tile candidate lists) and the
-    interpret override — every program cache holding a fused-prefill
-    trace must fold this in (the ``_PAGED_CACHE`` route contract)."""
+    """:func:`kernel_route` for a fused-prefill chunk program (empty
+    when the knob is off; a forced mode consults no pin)."""
     if not mode:
         return ()
-    from ..ops.pallas._util import interpret_mode
-    from ..ops.pallas.fused_decode_block import _vmem_budget
-    from ..ops.pallas.registry import KERNELS
-    pins = KERNELS.forced_state() if mode in ("auto", True) else ()
-    return (pins, _vmem_budget(), bool(interpret_mode()))
+    route = kernel_route()
+    return route if mode in ("auto", True) else ((),) + route[1:]
 
 
 def _fused_prefill_forward(params, toks, cfg, k_pools, v_pools, table,
@@ -393,8 +381,7 @@ def _mesh_route(sm):
             tuple(int(d.id) for d in sm.mesh.devices.flat))
 
 
-def _paged_chunk_runner(cfg, gen, quant=False, fused=False, sm=None,
-                        wq=None):
+def _paged_chunk_runner(cfg, gen, quant=False, sm=None, wq=None):
     """Jitted n-step decode scan, cached per (cfg values, gen values) —
     a fresh jit per generate_paged call would re-trace the whole L-layer
     scan every serving request. ``sm``: an optional ServingMesh — the
@@ -403,43 +390,16 @@ def _paged_chunk_runner(cfg, gen, quant=False, fused=False, sm=None,
     ``wq``: the weight-quant mode ("int8"/"int4"/None) — it rides in
     the param tree's STRUCTURE (the jit signature would retrace
     anyway), but it also reshapes kernel dispatch at trace time, so it
-    keys this cache explicitly (the ``_PAGED_CACHE`` route contract —
-    a flipped quant mode must retrace, never replay)."""
-    from ..core.flags import GLOBAL_FLAGS
-    # the kernel-route flags are traced INTO the compiled scan, so they
-    # must key the cache — an A/B flip (bench_paged_decode) would
-    # otherwise silently reuse the first-compiled path. Same for the
-    # registry's force pins: in "auto" mode dispatch consults the
-    # thread-local pin at trace time, so a program traced inside a
-    # KERNELS.force(...) block must not be replayed for unpinned calls
-    if fused:
-        from ..ops.pallas.fused_decode_block import (_vmem_budget,
-                                                     scoped_vmem_budget)
-        from ..ops.pallas.registry import KERNELS
-        from ..ops.pallas._util import interpret_mode
-        # every trace-time input that can reshape the program: the pin
-        # stack (consulted by dispatch in "auto" mode only), the VMEM
-        # budget (reshapes the supports predicates AND the fused MLP's
-        # block_f candidate list, which forced "pallas" mode still
-        # reads), the scoped envelope (reshapes the single-launch
-        # kernel's combined-window predicate + block_f pairs) and the
-        # interpret override (flips pallas variants off in "auto",
-        # flips interpret compilation in forced modes)
-        pins = (KERNELS.forced_state() if fused in ("auto", True)
-                else ())
-        route = (pins, _vmem_budget(), scoped_vmem_budget(),
-                 bool(interpret_mode()))
-    else:
-        route = ()
-    ck = (dataclasses.astuple(cfg), dataclasses.astuple(gen),
-          bool(GLOBAL_FLAGS.get("use_paged_kernel")), bool(quant),
-          fused, route, _mesh_route(sm), wq)
+    keys this cache explicitly, beside :func:`kernel_route` (a program
+    traced inside a ``KERNELS.force(...)`` block must not be replayed
+    for unpinned calls)."""
+    ck = (dataclasses.astuple(cfg), dataclasses.astuple(gen), bool(quant),
+          kernel_route(), _mesh_route(sm), wq)
     cached = _cache_get(_PAGED_CACHE, ck)
     if cached is not None:
         return cached
     if sm is None:
-        step = _paged_decode_step if not fused else functools.partial(
-            _fused_decode_step, mode=fused)
+        step = _decode_step
     else:
         def step(params, tok, cfg_, kp, vp, block_tables, seq_lens,
                  kv_scales=None):
@@ -450,7 +410,7 @@ def _paged_chunk_runner(cfg, gen, quant=False, fused=False, sm=None,
             # across jax versions, and logits are replicated anyway
             extra = tuple(kv_scales) if kv_scales is not None else ()
             return sm.sharded_decode_fn(
-                cfg_, fused, quant=kv_scales is not None)(
+                cfg_, quant=kv_scales is not None)(
                 params, tok, seq_lens, block_tables, kp, vp, *extra)
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(5, 6))
@@ -513,11 +473,10 @@ def _layer_loop(params, x, k_pools, v_pools, kv_scales, layer,
 
 
 def _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
-                 seq_lens, kv_scales=None, mode=False, axis=None,
-                 collective=None):
-    """One decode token per sequence over paged pools: every decode
-    program (single-device fused and unfused, and the per-shard body of
-    both tensor-parallel placements) is this one function.
+                 seq_lens, kv_scales=None, axis=None, collective=None):
+    """One decode token per sequence over paged pools: every dense
+    decode program (single-device, and the per-shard body of both
+    tensor-parallel placements) is this one function.
 
     tok: [B] int32 current tokens; k_pools/v_pools: [L, N, BS, KV, hd];
     block_tables: [B, MB]; seq_lens: [B] lengths INCLUDING the current
@@ -526,50 +485,38 @@ def _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
     ``kv_scales``: (k_scale [L, KV], v_scale [L, KV]) when the pools are
     int8 (static per-head cache quantization — reference block_attn.h
     int8 cache mode): halves KV HBM, the attention math stays fp32.
-    ``mode``: False runs the unfused composition (the bit-identical
-    test reference); otherwise the kernel registry picks each stage at
-    trace time (:func:`...fused_decode_block.resolve_decode_step`):
-    ONE single-launch megakernel for the block, or a fused attention
-    kernel and a fused MLP kernel, or the composition where a kernel
-    does not fit. ``axis`` / ``collective``: inside ``shard_map`` over
-    that mesh axis every array is the local shard (inference/tp.py has
-    the placements): "psum" all-reduces each stage's partial
-    projection, "gather" all-gathers before o_proj / down_proj and
-    always runs the composition.
+    ``axis`` / ``collective``: inside ``shard_map`` over that mesh axis
+    every array is the local shard (inference/tp.py has the
+    placements): "psum" all-reduces each stage's partial projection,
+    "gather" all-gathers before o_proj / down_proj and runs the MLP
+    composition, whose matmuls then see the single-device operands.
 
-    Operands reach the launches of the layer loop without a copy where
-    the resolved variant can take them so
-    (:func:`...fused_decode_block.launch_operands` is the record): the
-    paged-attention kernel reads its layer out of the carried pools,
-    the fused MLP kernel its weights out of the stacked tree; the
-    variants that take one layer's array get ``pool[l]``.
+    A layer is q/k/v projections and RoPE in XLA, the token's one
+    in-place pool write, ``paged_attention_decode`` over the carried
+    pools at that layer, the output projection, then
+    ``decode_mlp_block``. The kernel registry picks both launches at
+    trace time from what it can observe (``KERNELS.record`` around a
+    trace is the record of what it picked). A Pallas ``decode_mlp_block``
+    takes the stacked MLP weights whole and addresses the layer itself,
+    like the attention launch its pools
+    (:func:`...fused_decode_block.launch_operands`).
     Returns (logits [B, V], k_pools, v_pools).
     """
-    from ..core.jax_compat import axis_size
     from ..ops import rms_norm as fused_rms_norm
     from ..ops.paged_attention import write_to_pool, write_to_pool_quant
     from ..ops.pallas import fused_decode_block as fdb
+    from ..ops.pallas.registry import KERNELS
     from .tp import _lm_head, _local_dims
 
-    tp = 1 if axis is None else int(axis_size(axis))
-    names = dict(fdb.UNFUSED)
-    block_fn, attn_fn, mlp_fn = None, fdb.attn_block_ref, fdb.mlp_block_ref
-    if mode and collective != "gather":
-        H, KV, F = _local_dims(params, cfg)
-        meta = fdb.decode_meta_dims(
-            tok.shape[0], cfg.hidden_size, H, KV, cfg.head_dim, F,
-            k_pools.shape[2], block_tables.shape[1], cfg.dtype,
-            k_pools.dtype, kv_scales is not None, tp=tp,
-            weight_dtype=_wq_mode(params))
-        if tp == 1:
-            block_fn, attn_fn, mlp_fn, names = fdb.resolve_decode_step(
-                meta, mode)
-        else:    # the single-launch kernel is single-device by contract
-            attn_fn, mlp_fn, stages = fdb.resolve_decode_blocks(meta, mode)
-            names.update(stages)
     mlp_w = ("post_norm", "gate_proj", "up_proj", "down_proj")
-    mlp_by_index = fdb.launch_operands(
-        names, kv_scales is not None).get("decode_mlp_block") == "index"
+    mlp_name, mlp_fn = "unfused", fdb.mlp_block_ref
+    if collective != "gather":
+        mlp_name, mlp_fn = KERNELS.dispatch(
+            "decode_mlp_block", fdb.decode_meta_dims(
+                tok.shape[0], cfg.hidden_size, _local_dims(params, cfg)[2],
+                cfg.dtype, weight_dtype=_wq_mode(params)))
+    mlp_by_index = "decode_mlp_block" in fdb.launch_operands(
+        {"decode_mlp_block": mlp_name})
     eps = cfg.rms_norm_eps
     sin, cos = build_rope_cache(cfg.max_position_embeddings,
                                 cfg.head_dim, base=cfg.rope_theta)
@@ -588,51 +535,32 @@ def _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
     def add(x, out):
         return out if residual else x + jax.lax.psum(out, axis)
 
-    def append(kp, vp, k_new, v_new, scales, l):
-        if scales is None:
-            return write_to_pool(kp, vp, block_tables, seq_lens,
-                                 k_new.astype(kp.dtype),
-                                 v_new.astype(vp.dtype), layer=l)
-        return write_to_pool_quant(kp, vp, block_tables, seq_lens,
-                                   k_new, v_new, *scales, layer=l)
-
     def layer(x, l, lp, kp, vp, scales):
-        nw = lp["input_norm"].astype(x.dtype)
-        mlp_args = [lp["post_norm"].astype(x.dtype)] \
-            + [lp[k] for k in mlp_w[1:]] + [eps]
-        attn_w = [lp[k] for k in ("q_proj", "k_proj", "v_proj", "o_proj")]
-        if attn_fn is fdb.attn_block_ref:
-            # the composition reads the new token from the pool: write
-            # it first (once, in place), then attend over the carried
-            # pools at this layer
-            q, k_new, v_new = fdb.attn_qkv_ref(x, nw, *attn_w[:3], sin,
-                                               cos, seq_lens, eps)
-            kp, vp = append(kp, vp, k_new, v_new, scales, l)
-            x = add(x, fdb.attn_out_ref(
-                x, q, attn_w[3], kp, vp, block_tables, seq_lens, scales,
-                residual, layer=l, gather=gather))
+        # the composition reads the new token from the pool: write it
+        # first (once, in place), then attend over the carried pools at
+        # this layer
+        q, k_new, v_new = fdb.attn_qkv_ref(
+            x, lp["input_norm"].astype(x.dtype), lp["q_proj"],
+            lp["k_proj"], lp["v_proj"], sin, cos, seq_lens, eps)
+        if scales is None:
+            kp, vp = write_to_pool(kp, vp, block_tables, seq_lens,
+                                   k_new.astype(kp.dtype),
+                                   v_new.astype(vp.dtype), layer=l)
         else:
-            # these kernels fold the new token in from VMEM and take
-            # one layer's pools (a slice); the pool write follows
-            pool_args = (sin, cos, kp[l], vp[l], block_tables, seq_lens,
-                         scales, eps)
-            if block_fn is not None:
-                x, k_new, v_new = block_fn(x, nw, *attn_w, *mlp_args[:-1],
-                                           *pool_args)
-            else:
-                out, k_new, v_new = attn_fn(x, nw, *attn_w, *pool_args,
-                                            residual=residual)
-                x = add(x, out)
-            kp, vp = append(kp, vp, k_new, v_new, scales, l)
-        if block_fn is not None:
-            return x, kp, vp
+            kp, vp = write_to_pool_quant(kp, vp, block_tables, seq_lens,
+                                         k_new, v_new, *scales, layer=l)
+        x = add(x, fdb.attn_out_ref(
+            x, q, lp["o_proj"], kp, vp, block_tables, seq_lens, scales,
+            residual, layer=l, gather=gather))
         kw = {}
         if mlp_by_index:            # lp holds the MLP leaves whole
             kw["layer"] = l
-        elif gather is not None:    # always the composition (above)
+        elif gather is not None:    # the composition (above)
             kw["gather"] = gather
-        return add(x, mlp_fn(x, *mlp_args, residual=residual, **kw)), \
-            kp, vp
+        out = mlp_fn(x, lp["post_norm"].astype(x.dtype), lp["gate_proj"],
+                     lp["up_proj"], lp["down_proj"], eps,
+                     residual=residual, **kw)
+        return add(x, out), kp, vp
 
     x = jnp.take(params["embed_tokens"], tok, axis=0)        # [B, D]
     x, k_pools, v_pools = _layer_loop(
@@ -641,39 +569,6 @@ def _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
     x = fused_rms_norm(x[:, None], params["final_norm"].astype(x.dtype),
                        cfg.rms_norm_eps)[:, 0]
     return x @ _lm_head(params), k_pools, v_pools
-
-
-def _paged_decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
-                       seq_lens, kv_scales=None):
-    """:func:`_decode_step` through the unfused composition."""
-    return _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
-                        seq_lens, kv_scales)
-
-
-def _fused_decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
-                       seq_lens, kv_scales=None, mode="auto"):
-    """:func:`_decode_step` through the fused decode-block kernels the
-    registry selects under ``mode``; same signature and carried state
-    as ``_paged_decode_step``, so callers swap freely."""
-    return _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
-                        seq_lens, kv_scales, mode=mode)
-
-
-def _decode_variant_name(cfg, B, BS, MB, pool_dtype, quant, fused,
-                         wq=None, tp=1):
-    """The kernel variant one decode step's trace would select — a
-    single attribution string for the decode_step timeline events
-    (mirroring the prefill chunk's ``variant`` stamp): "pallas_block"
-    (single-launch megakernel), "pallas_fused" (two-stage megakernels)
-    or "unfused" (the building-block composition)."""
-    if not fused:
-        return "unfused"
-    from ..ops.pallas.fused_decode_block import (decode_meta,
-                                                 resolve_decode_step)
-    meta = decode_meta(cfg, B=B, BS=BS, MB=MB, pool_dtype=pool_dtype,
-                       quant=quant, tp=tp, weight_dtype=wq)
-    block_fn, _, _, names = resolve_decode_step(meta, fused)
-    return names["block"] if block_fn is not None else names["attn"]
 
 
 _FUSED_PREFILL_CACHE: Dict = {}
@@ -746,7 +641,7 @@ def generate_paged(params: Dict, input_ids, cfg: _llama.LlamaConfig,
                    gen: Optional[GenerationConfig] = None,
                    block_size: int = 16, seed: int = 0,
                    cache_dtype=None, prefix_cache=None,
-                   observability=None, fused_decode=None, mesh=None,
+                   observability=None, mesh=None,
                    fused_prefill=None, weight_quant=None):
     """vLLM-style serving loop over a paged KV cache.
 
@@ -776,12 +671,6 @@ def generate_paged(params: Dict, input_ids, cfg: _llama.LlamaConfig,
     its timeline/histograms and samples pool gauges — purely
     observational: no extra device syncs, identical outputs.
 
-    ``fused_decode``: route each decode block through the fused
-    decode-block kernels (ops/pallas/fused_decode_block.py). None reads
-    FLAGS_fused_decode (default ON); dispatch picks the Pallas
-    megakernels where supported and the bit-identical unfused
-    composition elsewhere. "pallas"/"ref" force a variant.
-
     ``fused_prefill``: route the PREFIX-STORE suffix prefill through
     the fused prefill-block kernels (ops/pallas/fused_prefill_block.py)
     where dispatch supports them — the suffix runs pool-direct (no
@@ -804,9 +693,9 @@ def generate_paged(params: Dict, input_ids, cfg: _llama.LlamaConfig,
     fp tree is quantized in ONE shot on the way in (host-side absmax);
     an already-quantized tree (``ptq.quantize_weights``, e.g. with
     activation-aware clipping) rides as-is and None adopts its mode.
-    Where the fused kernels dispatch, int8/int4 tiles stream through
-    VMEM and dequantize in-register; everywhere else the unfused route
-    is dequantize-then-matmul by construction.
+    Where the registry dispatches the fused MLP / prefill kernels,
+    int8/int4 tiles stream through VMEM and dequantize in-register;
+    everywhere else the route is dequantize-then-matmul by construction.
     """
     import time as _time
 
@@ -819,7 +708,6 @@ def generate_paged(params: Dict, input_ids, cfg: _llama.LlamaConfig,
     if observability is True:      # mirror ServingEngine's normalization
         from ..observability import Observability
         observability = Observability()
-    fused = _fused_mode(fused_decode)
     sm = normalize_mesh(mesh)
     params, wq_mode = ensure_quantized(params, weight_quant)
     if wq_mode is not None and sm is not None:
@@ -842,7 +730,7 @@ def generate_paged(params: Dict, input_ids, cfg: _llama.LlamaConfig,
     if prefix_cache is not None:
         return _generate_paged_prefix(
             params, input_ids, cfg, gen, block_size, seed, cache_dtype,
-            prefix_cache, observability, fused=fused,
+            prefix_cache, observability,
             fused_prefill=_fused_prefill_mode(fused_prefill),
             wq=wq_mode)
     obs = observability or None
@@ -930,7 +818,7 @@ def generate_paged(params: Dict, input_ids, cfg: _llama.LlamaConfig,
     # runner is cached per (config values, sampling knobs) like
     # generate()'s — shapes and the static n key jit's own cache.
     chunk_fn = _paged_chunk_runner(cfg, gen, quant=kv_scales is not None,
-                                   fused=fused, sm=sm, wq=wq_mode)
+                                   sm=sm, wq=wq_mode)
 
     key = _key_for(seed)
     tok = sample_token(logits[:, -1], key, gen)
@@ -944,10 +832,6 @@ def generate_paged(params: Dict, input_ids, cfg: _llama.LlamaConfig,
         obs.sample_gauges(_time.perf_counter(), {
             "pages_free": len(mgr.free),
             "pages_in_use": num_blocks - len(mgr.free)})
-        dv = _decode_variant_name(cfg, B, BS, MB, k_pools.dtype,
-                                  kv_scales is not None, fused,
-                                  wq=wq_mode,
-                                  tp=(sm.tp if sm is not None else 1))
     while left > 0:
         n = min(chunk, left)
         t0 = _time.perf_counter() if obs is not None else 0.0
@@ -958,8 +842,7 @@ def generate_paged(params: Dict, input_ids, cfg: _llama.LlamaConfig,
             dur = (_time.perf_counter() - t0) * 1e3
             obs.hist("decode_step_ms").observe(dur / n)
             obs.timeline.record("decode_step", dur_ms=dur,
-                                live_slots=B, tokens=int(n * B),
-                                decode_variant=dv)
+                                live_slots=B, tokens=int(n * B))
         chunks.append(toks.transpose(1, 0))  # [n, B] -> [B, n]
         left -= n
     toks = jnp.concatenate(chunks, axis=1)
@@ -981,8 +864,8 @@ def _scatter_prefill_pages(kp, vp, wtable, kc, vc):
 
 def _generate_paged_prefix(params, input_ids, cfg, gen, block_size,
                            seed, cache_dtype, store,
-                           observability=None, fused=False,
-                           fused_prefill=False, wq=None):
+                           observability=None, fused_prefill=False,
+                           wq=None):
     """``generate_paged`` over a persistent ``PagedKVCacheStore``.
 
     Admission longest-prefix-matches each prompt against the store's
@@ -1104,14 +987,10 @@ def _generate_paged_prefix(params, input_ids, cfg, gen, block_size,
     chunks = [tok[:, None]]
     seq_lens = jnp.full((B,), S, jnp.int32)
     bt = jnp.asarray(tables, jnp.int32)
-    chunk_fn = _paged_chunk_runner(cfg, gen, quant=False, fused=fused,
-                                   wq=wq)
+    chunk_fn = _paged_chunk_runner(cfg, gen, quant=False, wq=wq)
     k_pools, v_pools = store.k_pools, store.v_pools
     chunk = max(1, int(os.environ.get("PADDLE_TPU_DECODE_CHUNK", "32")))
     left = gen.max_new_tokens - 1
-    if obs is not None:
-        dv = _decode_variant_name(cfg, B, BS, MB, k_pools.dtype, False,
-                                  fused, wq=wq)
     while left > 0:
         n = min(chunk, left)
         if obs is not None:
@@ -1123,8 +1002,7 @@ def _generate_paged_prefix(params, input_ids, cfg, gen, block_size,
             dur = (_time.perf_counter() - t0) * 1e3
             obs.hist("decode_step_ms").observe(dur / n)
             obs.timeline.record("decode_step", dur_ms=dur,
-                                live_slots=B, tokens=int(n * B),
-                                decode_variant=dv)
+                                live_slots=B, tokens=int(n * B))
         chunks.append(toks.transpose(1, 0))
         left -= n
     store.k_pools, store.v_pools = k_pools, v_pools

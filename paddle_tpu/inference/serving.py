@@ -59,7 +59,7 @@ length-done and recycles slots.
 """
 from __future__ import annotations
 
-import functools
+import os
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
@@ -74,10 +74,10 @@ from ..observability import (Observability, TelemetryConfig,
 from ..ops.paged_attention import (BlockManager, dequant_cache,
                                    quant_cache)
 from .admission import AdmissionQueue
-from .generation import (GenerationConfig, _fused_decode_step,
-                         _fused_mode, _fused_prefill_forward,
-                         _fused_prefill_mode, _paged_decode_step,
-                         _prefill_route, cached_forward, init_cache)
+from .generation import (GenerationConfig, _decode_step,
+                         _fused_prefill_forward, _fused_prefill_mode,
+                         _prefill_route, cached_forward, init_cache,
+                         kernel_route)
 
 __all__ = ["Request", "ServingEngine"]
 
@@ -241,7 +241,7 @@ class ServingEngine:
                  max_seq_len: Optional[int] = None, cache_dtype=None,
                  prefill_buckets=(32, 128), seed: int = 0,
                  prefix_cache: bool = False, kv_offload=False,
-                 observability=False, fused_decode=None, mesh=None,
+                 observability=False, mesh=None,
                  fused_prefill=None, weight_quant=None,
                  aging_s: Optional[float] = None, telemetry=False,
                  clock=None, state_dtype=None):
@@ -254,7 +254,7 @@ class ServingEngine:
             _refuse_for_recurrent(mesh=mesh, weight_quant=weight_quant,
                                   cache_dtype=cache_dtype,
                                   kv_offload=kv_offload)
-            fused_decode = fused_prefill = False
+            fused_prefill = False
         elif state_dtype is not None:
             raise ValueError("state_dtype is the recurrent state's type: "
                              f"{type(cfg).__name__} has no recurrent layer")
@@ -275,8 +275,7 @@ class ServingEngine:
         # checker's manager+cache invariant set (BlockManager.check /
         # PrefixCache.check) asserted after every step. Off by default
         # (it walks the tree and the page pool each step).
-        import os as _os_env
-        self._check_inv = _os_env.environ.get(
+        self._check_inv = os.environ.get(
             "PADDLE_TPU_CHECK_INVARIANTS", "") == "1"
         # weight quantization (quantization/ptq.py): "int8"/"int4"
         # quantizes a plain fp tree in ONE shot (host-side per-channel
@@ -300,38 +299,12 @@ class ServingEngine:
                 # clean rejection, same reason-string contract as the
                 # kernel registry's supports() predicates
                 raise ValueError(f"ServingEngine(mesh=...): {reason}")
-            if self._mesh.collective == "gather" \
-                    and _fused_mode(fused_decode) == "pallas":
-                # an explicit pin must never silently no-op (the PR-7
-                # rms_norm precedent): the gather placement runs the
-                # exact unfused composition BY CONTRACT (bit-parity is
-                # defined by the single-device op sequence)
-                raise ValueError(
-                    'fused_decode="pallas" cannot be honored under '
-                    'collective="gather" — that placement runs the '
-                    "exact unfused composition (its bit-parity "
-                    'contract); use collective="psum" or drop the pin')
-            if _fused_mode(fused_decode) == "block":
-                # same never-silently-no-op rule: the single-launch
-                # block kernel is single-device (its supports() rejects
-                # tp != 1, and the sharded decode body runs the
-                # per-stage kernels)
-                raise ValueError(
-                    'fused_decode="block" is single-device: the '
-                    "single-launch decode-block kernel runs outside "
-                    "shard_map — drop the mesh or the pin")
             params = self._mesh.shard(
                 params, self._mesh.param_specs(cfg, params))
         self.params = params
         self.cfg = cfg
-        # decode-block kernel routing: False = the pre-fusion unfused
-        # step; "auto" (default, via FLAGS_fused_decode) = fused step
-        # with registry dispatch (Pallas megakernels where supported,
-        # bit-identical composition elsewhere); "pallas"/"ref" force a
-        # variant (tests, audit catalog)
-        self._fused = _fused_mode(fused_decode)
-        # prefill-chunk kernel routing, mirroring fused_decode: False =
-        # always the verbatim gather/cached_forward/scatter chunk;
+        # prefill-chunk kernel routing: False = always the verbatim
+        # gather/cached_forward/scatter chunk;
         # "auto" (default, FLAGS_fused_prefill) = pool-direct fused
         # chunk where the registry supports BOTH prefill-block kernels,
         # the verbatim chunk elsewhere (bit-identical by construction);
@@ -358,8 +331,8 @@ class ServingEngine:
         # attribution (tools/trace_summary.py) — keyed exactly like
         # _prefill_fns so a route change cannot stale the attribution
         self._prefill_kind: Dict[tuple, str] = {}
-        # registry dispatch outcome captured when the decode program
-        # traces (see _make_decode_fn); None until the first trace
+        # what the registry picked while the decode program traced
+        # (see _make_decode_fn); None until the first trace
         self._decode_variant = None
         self.capacity = int(capacity)
         self.block_size = int(block_size)
@@ -427,8 +400,7 @@ class ServingEngine:
         # jitted gather + one host transfer per window instead of a
         # program per page; padded index entries point at scratch page
         # 0 — the disagg handoff idiom)
-        import os as _os
-        self._offload_window = max(1, int(_os.environ.get(
+        self._offload_window = max(1, int(os.environ.get(
             "PADDLE_TPU_OFFLOAD_WINDOW", "8")))
         # one physical page across BOTH pools, in bytes (the spill/
         # restore byte counters): over the layers that hold KV
@@ -443,8 +415,14 @@ class ServingEngine:
         # every request it would have looked up is counted
         self._prefix_skipped = bool(prefix_cache) and self._recurrent
         self._state = None
+        # the decode program's forward, picked once: dense, the same
+        # per-shard body under shard_map, or the hybrid model's (which
+        # carries the slots' recurrent state)
+        self._decode_forward = (self._dense_forward if self._mesh is None
+                                else self._tp_forward)
         if self._recurrent:
             from . import hybrid
+            self._decode_forward = self._hybrid_forward
             prefix_cache = False
             self._state = hybrid.init_state(
                 cfg, self.capacity, jnp.dtype(state_dtype or jnp.float32))
@@ -503,6 +481,7 @@ class ServingEngine:
             self._d_key = self._mesh.replicate(self._d_key)
 
         self._decode_fn = None
+        self._decode_route = None
         self._prefill_fns: Dict[int, object] = {}
         self._calib_fn = None
         self._calib_bucket = None
@@ -857,92 +836,45 @@ class ServingEngine:
             if self._pcache is not None:
                 self._pcache.check()
 
-    def _resolve_variant(self) -> Dict:
-        from ..ops.pallas.fused_decode_block import (UNFUSED, decode_meta,
-                                                     decode_meta_dims,
-                                                     launch_operands,
-                                                     resolve_decode_step)
-
-        def report(mode, names):
-            return {"mode": mode, **names,
-                    "operands": launch_operands(names, self._quant)}
-
-        sm = self._mesh
-        if not self._fused:
-            return report("unfused", UNFUSED)
-        if sm is not None and sm.collective == "gather":
-            # the gather placement's bit-parity contract IS the
-            # single-device op sequence — it always runs the exact
-            # composition, whatever the fused knob says
-            return report(str(self._fused), UNFUSED)
-        cfg, tp = self.cfg, (1 if sm is None else sm.tp)
-        if tp == 1:
-            meta = decode_meta(cfg, B=self.capacity,
-                               BS=self.block_size, MB=self.max_blocks,
-                               pool_dtype=self._k_pools.dtype,
-                               quant=self._quant,
-                               weight_dtype=self._wq)
-        else:
-            # dispatch consults the PER-SHARD shape class: local head
-            # and intermediate counts, tp riding in the meta — the
-            # same dims _tp_decode_step derives inside shard_map
-            meta = decode_meta_dims(
-                self.capacity, cfg.hidden_size,
-                cfg.num_attention_heads // tp,
-                cfg.num_key_value_heads // tp, cfg.head_dim,
-                cfg.intermediate_size // tp, self.block_size,
-                self.max_blocks, cfg.dtype, self._k_pools.dtype,
-                self._quant, tp=tp, weight_dtype=self._wq)
-        _, _, _, names = resolve_decode_step(meta, self._fused)
-        return report(str(self._fused), names)
-
     @property
     def decode_variant(self) -> Dict:
-        """Which decode-block implementation this engine's decode
-        program runs: ``{"mode": ..., "block": ..., "attn": ...,
-        "mlp": ..., "operands": {...}}`` — "block" is the single-launch
-        megakernel's slot ("pallas_block" when it serves the step,
-        "composed" when the two-stage route does); "operands" says, for
-        each Pallas launch of the layer loop, whether it takes its
-        layer of the KV pools / stacked weights by "index" (no copy) or
-        as a "slice" (``fused_decode_block.launch_operands``). Captured
-        when the decode program TRACES
-        (dispatch is consulted at trace time), so later env changes —
-        the VMEM budget, a ``KERNELS.force`` pin around a ``metrics()``
-        call — cannot make the report drift from the compiled program.
-        Before the first decode step it reports what dispatch would
-        pick now."""
+        """Which launches this engine's decode program holds:
+        ``{"attn": ..., "mlp": ..., "operands": {...}}`` — the variant
+        of ``paged_attention_decode`` ("pallas" | "xla") and of
+        ``decode_mlp_block`` ("pallas_fused" | "unfused": also where the
+        program has no such stage, the "gather" placement and a model
+        with expert layers) that the kernel registry picked, and for
+        each Pallas launch of the layer loop how it takes its layer of
+        the KV pools / stacked weights (``fused_decode_block
+        .launch_operands``). It IS the registry's record of the
+        dispatches made while the decode program traced, so later env
+        changes — the VMEM budget, a ``KERNELS.force`` pin around a
+        ``metrics()`` call — cannot make the report drift from the
+        compiled program. Before the first decode step there is no
+        program, and the names are None."""
         if self._decode_variant is not None:
             return dict(self._decode_variant)
-        return self._resolve_variant()
+        return {"attn": None, "mlp": None, "operands": {}}
 
     @property
     def weight_quant_variant(self) -> Dict:
         """Which weight-dtype class the engine's programs run:
         ``{"mode": "off"}`` for plain fp weights, else ``{"mode":
         "int8"|"int4", "weight_dtype": ..., "attn": ..., "mlp": ...}``
-        with the decode-block variants that serve the quantized tree.
-        Derives from :attr:`decode_variant`, which is snapshotted when
-        the decode program TRACES — a trace-time report of compiled
-        reality, never live dispatch (the ``decode_variant``
-        contract)."""
+        with the decode variants that serve the quantized tree (of
+        :attr:`decode_variant`, the trace-time record)."""
         if not self._wq:
             return {"mode": "off"}
         v = self.decode_variant
         return {"mode": self._wq, "weight_dtype": self._wq,
-                "block": v["block"], "attn": v["attn"],
-                "mlp": v["mlp"]}
+                "attn": v["attn"], "mlp": v["mlp"]}
 
     def _active_arm(self) -> str:
-        """Which roofline arm the live decode step runs: the
-        single-launch block kernel, the two-kernel fused composition,
-        or the unfused reference."""
+        """Which roofline arm the live decode step runs: both launches
+        Pallas kernels, or the reference compositions."""
         v = self.decode_variant
-        if v.get("block") == "pallas_block":
-            return "pallas_block"
-        if str(v.get("attn", "")).startswith("pallas"):
-            return "pallas_fused"
-        return "unfused"
+        return "pallas_fused" if (v["attn"], v["mlp"]) == (
+            "pallas", "pallas_fused") else "unfused"
 
     def _roofline_metrics(self) -> Dict:
         """Per-decode-variant modeled HBM bytes/step + the
@@ -1691,8 +1623,13 @@ class ServingEngine:
         if not live:
             return False
         obs = self._obs
-        if self._decode_fn is None:
+        route = kernel_route()
+        if self._decode_route != route:
+            # a registry pin, the VMEM budget or the interpret override
+            # changed what dispatch would pick: trace again, never
+            # replay the program the old route compiled
             self._decode_fn = self._make_decode_fn()
+            self._decode_route = route
         if self._dirty:
             with span("serve/table_upload", obs):
                 self._d_tok = self._upload(self._h_tok.copy())
@@ -1722,13 +1659,11 @@ class ServingEngine:
             dur_ms = disp.dur_ms + sync.dur_ms
             obs.hist("decode_step_ms").observe(dur_ms)
             # per-variant attribution, mirroring the prefill chunk's
-            # ``variant`` stamp: which decode-block implementation
-            # served this step (tools/trace_summary.py --mode serving)
-            v = self.decode_variant
-            dv = v["block"] if v["block"] == "pallas_block" \
-                else v["attn"]
+            # ``variant`` stamp: which arm served this step
+            # (tools/trace_summary.py --mode serving)
             obs.timeline.record("decode_step", dur_ms=dur_ms,
-                                live_slots=len(live), decode_variant=dv)
+                                live_slots=len(live),
+                                decode_variant=self._active_arm())
         with span("serve/emit", obs):
             for i in live:
                 slot = self._slots[i]
@@ -1827,66 +1762,66 @@ class ServingEngine:
     _PREFILL_DONATE = (7, 8, 9)
     _PREFILL_CARRY = {1: 7, 2: 8, 3: 9}
 
+    def _dense_forward(self, params, tok, seq_lens, tables, k_pools,
+                       v_pools):
+        return _decode_step(params, tok, self.cfg, k_pools, v_pools,
+                            tables, seq_lens, kv_scales=self._kv_scales)
+
+    def _tp_forward(self, params, tok, seq_lens, tables, k_pools,
+                    v_pools):
+        """The same step per shard (inference/tp.py), under shard_map
+        over the ServingMesh; sampling runs on the replicated logits
+        outside it."""
+        scales = self._kv_scales
+        sharded = self._mesh.sharded_decode_fn(
+            self.cfg, quant=scales is not None, params=self.params)
+        return sharded(params, tok, seq_lens, tables, k_pools, v_pools,
+                       *(scales or ()))
+
+    def _hybrid_forward(self, params, tok, seq_lens, tables, k_pools,
+                        v_pools, state):
+        from .hybrid import decode_step
+        return decode_step(params, tok, self.cfg, k_pools, v_pools,
+                           tables, seq_lens, state)
+
     def _make_decode_fn(self, record_variant=True):
-        if self._recurrent:
-            return self._make_decode_fn_hybrid()
-        if self._mesh is not None:
-            return self._make_decode_fn_tp(record_variant)
-        cfg, counters = self.cfg, self.counters
-        scales = self._kv_scales    # closed over: fixed after calibration
-        fused = self._fused
-        if fused:
-            decode_step = functools.partial(_fused_decode_step,
-                                            mode=fused)
-        else:
-            decode_step = _paged_decode_step
+        """THE decode program: one jitted ``step`` around the forward
+        picked in ``__init__``. A model with recurrent layers carries
+        the slots' state (inference/hybrid.py) as one more donated
+        argument and output. Admission/completion never change shapes,
+        so steady state stays zero retraces."""
+        from ..ops.pallas.fused_decode_block import launch_operands
+        from ..ops.pallas.registry import KERNELS
+        counters, forward = self.counters, self._decode_forward
 
         def step(params, tok, seq_lens, tables, temps, key,
-                 k_pools, v_pools):
+                 k_pools, v_pools, *state):
             counters["decode_traces"] += 1
+            with KERNELS.record() as picked:
+                logits, k_pools, v_pools, *state = forward(
+                    params, tok, seq_lens, tables, k_pools, v_pools,
+                    *state)
             if record_variant:
-                # trace-time snapshot: the same dispatch the
-                # decode_step below consults, captured in the same
-                # context, so decode_variant reports compiled reality.
-                # Audit clones (program_specs) trace under their own
-                # pins/env and must not clobber the live report
-                self._decode_variant = self._resolve_variant()
-            logits, k_pools, v_pools = decode_step(
-                params, tok, cfg, k_pools, v_pools, tables, seq_lens,
-                kv_scales=scales)
+                # what dispatch picked for THIS trace. Audit clones
+                # (program_specs) trace under their own pins/env and
+                # must not clobber the live report
+                self._decode_variant = {
+                    "attn": picked.get("paged_attention_decode"),
+                    "mlp": picked.get("decode_mlp_block", "unfused"),
+                    "operands": launch_operands(picked)}
             key, sub = jax.random.split(key)
             nxt = _sample_slots(logits, sub, temps)
             # inactive (padded) slots hold seq 0 and stay there; their
             # write above landed in scratch page 0, never read
             seq_lens = jnp.where(seq_lens > 0, seq_lens + 1, 0)
-            return nxt, seq_lens, key, k_pools, v_pools
+            return (nxt, seq_lens, key, k_pools, v_pools, *state)
 
         # donate the whole carried state, not just the pools: tok/seq/
         # key are replaced by this call's outputs every step (on host
         # mutation the mirrors re-upload fresh arrays), so the old
         # buffers update in place — the donation audit's own finding
-        return jax.jit(step, donate_argnums=self._DECODE_DONATE)
-
-    def _make_decode_fn_hybrid(self):
-        """The decode program of a model with recurrent layers: the
-        same signature, donation and carry as the others, with the
-        slots' recurrent state (inference/hybrid.py) as one more
-        donated argument and output. Still ONE jitted program."""
-        from .hybrid import decode_step
-        cfg, counters = self.cfg, self.counters
-
-        def step(params, tok, seq_lens, tables, temps, key,
-                 k_pools, v_pools, state):
-            counters["decode_traces"] += 1
-            logits, k_pools, v_pools, state = decode_step(
-                params, tok, cfg, k_pools, v_pools, tables, seq_lens,
-                state)
-            key, sub = jax.random.split(key)
-            nxt = _sample_slots(logits, sub, temps)
-            seq_lens = jnp.where(seq_lens > 0, seq_lens + 1, 0)
-            return nxt, seq_lens, key, k_pools, v_pools, state
-
-        return jax.jit(step, donate_argnums=self._DECODE_DONATE + (8,))
+        donate = self._DECODE_DONATE + ((8,) if self._recurrent else ())
+        return jax.jit(step, donate_argnums=donate)
 
     def _make_prefill_fn_hybrid(self, P: int):
         """The chunk program of a model with recurrent layers: the
@@ -1909,36 +1844,6 @@ class ServingEngine:
             return tok, key, k_pools, v_pools, state
 
         return jax.jit(chunk, donate_argnums=self._PREFILL_DONATE + (11,))
-
-    def _make_decode_fn_tp(self, record_variant=True):
-        """The tensor-parallel decode program: the SAME signature,
-        donation and carry contract as the single-device one — the
-        per-shard forward (inference/tp.py) runs under shard_map over
-        the ServingMesh, sampling runs on the replicated logits outside
-        it. Still ONE jitted program; admission/completion never change
-        shapes, so steady state stays zero retraces."""
-        cfg, counters = self.cfg, self.counters
-        scales = self._kv_scales
-        fused = self._fused
-        sm = self._mesh
-        sharded = sm.sharded_decode_fn(cfg, fused,
-                                       quant=scales is not None,
-                                       params=self.params)
-
-        def step(params, tok, seq_lens, tables, temps, key,
-                 k_pools, v_pools):
-            counters["decode_traces"] += 1
-            if record_variant:
-                self._decode_variant = self._resolve_variant()
-            extra = tuple(scales) if scales is not None else ()
-            logits, k_pools, v_pools = sharded(
-                params, tok, seq_lens, tables, k_pools, v_pools, *extra)
-            key, sub = jax.random.split(key)
-            nxt = _sample_slots(logits, sub, temps)
-            seq_lens = jnp.where(seq_lens > 0, seq_lens + 1, 0)
-            return nxt, seq_lens, key, k_pools, v_pools
-
-        return jax.jit(step, donate_argnums=self._DECODE_DONATE)
 
     def _prefill_route_key(self):
         """The fused-prefill route's contribution to the per-bucket
@@ -2185,20 +2090,13 @@ class ServingEngine:
         # n_p + (k - 1) — the class-level carry maps (argnum-keyed, the
         # same declarations the jit donate_argnums read) convert here
         flat = lambda argnum: n_p + argnum - 1          # noqa: E731
-        # a FORCED-pallas engine registers the fused decode program
-        # under its own name so the audit gate covers the megakernel
-        # path next to (not instead of) the default program; a mesh'd
-        # engine suffixes _tp the same way (the collective-consistency
-        # rule gates the sharded programs against the DECLARED axes)
+        # a mesh'd engine suffixes its programs _tp (the
+        # collective-consistency rule gates the sharded programs
+        # against the DECLARED axes)
         sm = self._mesh
         tp_sfx = "_tp" if sm is not None else ""
         axes = (sm.axis,) if sm is not None else ()
         tags = ("serving",) + (("tp",) if sm is not None else ())
-        decode_name = ("serving_decode_fused"
-                       if self._fused in ("pallas",)
-                       else "serving_decode_block"
-                       if self._fused in ("block",)
-                       else "serving_decode")
         # a forced-pallas-PREFILL engine registers its bucket programs
         # under their own name the same way (the audit gate covers the
         # fused chunk next to, not instead of, the default program)
@@ -2225,7 +2123,7 @@ class ServingEngine:
             prefill_carry.update({4 + i: flat(11) + i
                                   for i in range(n_s)})
         specs = [ProgramSpec(
-            name=decode_name + tp_sfx, fn=self._make_decode_fn(
+            name="serving_decode" + tp_sfx, fn=self._make_decode_fn(
                 record_variant=False),
             args=(params_sd, sds((C,), jnp.int32), sds((C,), jnp.int32),
                   sds((C, MB), jnp.int32), sds((C,), jnp.float32),

@@ -39,14 +39,13 @@ Collective placement — ``ServingMesh.collective``:
   Greedy output is BIT-identical to the single-device engine (the
   tier-1 suite asserts it over a mixed-arrival stream).
 
-Both placements run the transformer math through the PR-6 kernel
-registry: the per-shard dims (local head/intermediate counts) plus the
-``tp`` degree feed ``decode_meta_dims``, so on TPU the fused decode
-megakernels dispatch per shard — ``residual=False`` returns the bare
-o/down projection partial for the psum placement — and everywhere else
-the EXACT unfused composition runs (``"gather"`` always uses the
-composition: its bit-parity contract is defined by the single-device
-op sequence).
+Both placements run the one decode step (``generation._decode_step``)
+per shard, and the kernel registry chooses its two launches from the
+per-shard shapes: ``paged_attention_decode`` over the local heads, and
+under "psum" ``decode_mlp_block`` over the local intermediate columns
+(``residual=False`` returns the bare down-projection partial). The
+"gather" placement runs the MLP composition: its bit-parity contract is
+defined by the single-device op sequence.
 """
 from __future__ import annotations
 
@@ -212,7 +211,7 @@ class ServingMesh:
         return jax.device_put(x, self.sharding(P()))
 
     # -- sharded program wiring ---------------------------------------
-    def sharded_decode_fn(self, cfg, fused, quant: bool, params=None):
+    def sharded_decode_fn(self, cfg, quant: bool, params=None):
         """The shard_map'd per-step decode forward: ``(params, tok,
         seq_lens, tables, k_pools, v_pools, *scales) -> (logits,
         k_pools, v_pools)`` — the ONE wiring of in/out specs around
@@ -231,7 +230,7 @@ class ServingMesh:
             return _tp_decode_step(
                 params, tok, cfg, k_pools, v_pools, tables, seq_lens,
                 kv_scales=(tuple(sc) if sc else None), axis=self.axis,
-                collective=self.collective, fused=fused)
+                collective=self.collective)
 
         return shard_map_norep(fwd, self.mesh, in_specs,
                                (rep, self.pool_spec, self.pool_spec))
@@ -308,35 +307,23 @@ def _lm_head(params):
 
 def _tp_decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
                     seq_lens, kv_scales=None, axis="tp",
-                    collective="psum", fused=False):
+                    collective="psum"):
     """One tensor-parallel decode token per slot — the per-shard body
     of the engine's single jitted decode program: the ONE decode step
     (``generation._decode_step``, its layer loop included) over the
     local shards, with the collective placement documented in the
     module docstring.
 
-    ``fused``: the decode-block route (False = the exact composition,
-    "auto"/"pallas"/"ref" = registry dispatch over the PER-SHARD meta).
-    The "gather" placement always runs the composition — its bit-parity
-    contract IS the single-device op sequence, with the per-shard heads
-    / SwiGLU columns all-gathered BEFORE o_proj / down_proj so those
-    matmuls see exactly the single-device operands. No collective but
-    the declared ones is emitted (the audited jaxpr carries exactly
-    those).
+    The "gather" placement's bit-parity contract IS the single-device op
+    sequence, with the per-shard heads / SwiGLU columns all-gathered
+    BEFORE o_proj / down_proj so those matmuls see exactly the
+    single-device operands. No collective but the declared ones is
+    emitted (the audited jaxpr carries exactly those).
     """
     from .generation import _decode_step
 
-    if fused == "block":
-        # the single-launch block kernel is single-device by contract
-        # (its supports() rejects tp != 1); a forced "block" under a
-        # mesh is a configuration error, not a silent fallback —
-        # checked before the axis-env lookup so the error fires even
-        # outside shard_map
-        raise ValueError("fused_decode='block' is single-device: "
-                         "tensor-parallel decode runs the per-stage "
-                         "kernels")
     return _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
-                        seq_lens, kv_scales, mode=fused, axis=axis,
+                        seq_lens, kv_scales, axis=axis,
                         collective=collective)
 
 

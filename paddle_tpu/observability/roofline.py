@@ -172,13 +172,9 @@ def decode_step_bytes(B: int, D: int, H: int, KV: int, hd: int, F: int,
     model). The arms differ exactly where the transition-count model
     says they differ:
 
-    - ``pallas_block`` (single launch): attention weights resident
-      once, but the MLP weight tiles REFETCH per batch row (the grid
-      walks ``(B, attn_steps + mlp_tiles)``, so every row re-streams
-      the MLP weights) — the B× term that makes block-vs-two-kernel
-      arbitration a bytes question;
-    - ``pallas_fused`` (attn kernel + mlp kernel): every weight read
-      once, one extra residual round-trip between the launches;
+    - ``pallas_fused`` (``paged_attention_decode`` + ``decode_mlp_block``
+      launches): every weight read once, one residual round-trip
+      between the stages;
     - ``unfused`` (reference composition): every weight read once plus
       the materialised intermediates (q/k/v/attn-out activations and
       the (B, F) gate/up/swish tensors) round-tripping through HBM.
@@ -192,9 +188,8 @@ def decode_step_bytes(B: int, D: int, H: int, KV: int, hd: int, F: int,
     kv = 2 * B * MB * BS * KVhd * pool_itemsize
     x = B * D * act_itemsize
     return {
-        # x in + out, new k/v rows out are ~B*KVhd (ignored: << kv)
-        "pallas_block": int(w_attn + B * w_mlp + kv + 2 * x),
-        # attn: x in, x' out; mlp: x' in, y out
+        # attn: x in, x' out; mlp: x' in, y out (new k/v rows out are
+        # ~B*KVhd, ignored: << kv)
         "pallas_fused": int(w_attn + w_mlp + kv + 4 * x),
         # norms + q/k/v/o + attn-out + mlp in/out: ~10 activation
         # round-trips of (B, D) + gate/up/swish (B, F) materialised

@@ -24,24 +24,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..core.flags import GLOBAL_FLAGS as _FLAGS
-from .pallas._util import pallas_route
-
-_FLAGS.define(
-    "use_paged_kernel", True,
-    "route paged-KV decode attention through the Pallas kernel on TPU "
-    "(0 = XLA gather+einsum composition, for A/B perf diagnosis)")
-
-
-def paged_kernel_routed() -> bool:
-    """Whether :func:`paged_attention_decode` launches the Pallas
-    kernel in the program being traced (the flag, a TPU backend, a
-    program a Mosaic kernel can be lowered into)."""
-    return bool(_FLAGS.get("use_paged_kernel")) and pallas_route()
+from .pallas._util import interpret_mode
+from .pallas.registry import KERNELS
 
 
 def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
-                           scale: Optional[float] = None, layer=None):
+                           scale: Optional[float] = None, layer=None,
+                           k_scale=None, v_scale=None):
     """Single-step decode attention over a paged cache.
 
     q:            [B, H, hd]     query for the current position
@@ -51,20 +40,54 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
     layer:        the pools are the stacked [L, N, BS, KV, hd] and this
                   is the layer to attend over (the decode loop's carried
                   pools: the kernel addresses the layer itself)
+    k_scale/v_scale: [KV] per-head dequant scales of int8 pools
     returns       [B, H, hd]
 
-    On TPU this routes to the Pallas kernel (ops/pallas/paged_attention.py)
-    that streams pages through VMEM via scalar-prefetched block tables; the
-    gather+einsum below runs off-TPU and when FLAGS_use_paged_kernel is
-    off. A kernel failure on TPU raises.
+    The kernel registry chooses the launch (op ``paged_attention_decode``):
+    ``pallas`` (ops/pallas/paged_attention.py, pages streamed through
+    VMEM off scalar-prefetched block tables) where
+    :func:`_supports_pallas` holds, else the ``xla`` gather+einsum.
+    Every decode program gets its attention here, so they all get the
+    same choice. A kernel failure on TPU raises.
     """
-    if paged_kernel_routed():
-        from .pallas.paged_attention import paged_attention_decode_pallas
-        return paged_attention_decode_pallas(
-            q, k_pool, v_pool, block_tables, seq_lens, scale=scale,
-            layer=layer)
-    return paged_attention_decode_xla(q, k_pool, v_pool, block_tables,
-                                      seq_lens, scale=scale, layer=layer)
+    _, fn = KERNELS.dispatch("paged_attention_decode",
+                             decode_attention_meta(k_pool.dtype))
+    return fn(q, k_pool, v_pool, block_tables, seq_lens, scale=scale,
+              k_scale=k_scale, v_scale=v_scale, layer=layer)
+
+
+def decode_attention_meta(pool_dtype) -> dict:
+    """What the ``paged_attention_decode`` variants' predicates read."""
+    return {"backend": jax.default_backend(),
+            "interpret": bool(interpret_mode()),
+            "pool_dtype": str(jnp.dtype(pool_dtype))}
+
+
+def _supports_pallas(meta):
+    # a program GSPMD partitions is refused by the registry itself, for
+    # every variant tagged "pallas" (_util.gspmd_refusal)
+    if meta["interpret"]:
+        return False, "interpret mode (off-TPU): composition is faster"
+    if meta["backend"] != "tpu":
+        return False, (f"default backend is {meta['backend']!r}: a "
+                       "Mosaic kernel compiles for a TPU only")
+    if meta["pool_dtype"] == "int8":
+        return False, ("int8 pools: the kernel fetches pages at the "
+                       "pool's dtype and takes no scales; the "
+                       "composition dequantizes in its gather")
+    return True, "TPU backend, compiled kernel, pools at the model dtype"
+
+
+def _pallas_variant(q, k_pool, v_pool, block_tables, seq_lens, scale=None,
+                    k_scale=None, v_scale=None, layer=None):
+    from .pallas.paged_attention import paged_attention_decode_pallas
+    if k_scale is not None or v_scale is not None:
+        raise ValueError("paged_attention_decode variant 'pallas' takes "
+                         "no int8 pools (its supports() refuses them); "
+                         "the 'xla' variant dequantizes in its gather")
+    return paged_attention_decode_pallas(
+        q, k_pool, v_pool, block_tables, seq_lens, scale=scale,
+        layer=layer)
 
 
 def paged_attention_decode_xla(q, k_pool, v_pool, block_tables, seq_lens,
@@ -104,6 +127,19 @@ def paged_attention_decode_xla(q, k_pool, v_pool, block_tables, seq_lens,
     out = jnp.einsum("bht,bthd->bhd", probs, v.astype(jnp.float32))
     out = jnp.where(seq_lens[:, None, None] > 0, out, 0.0)
     return out.astype(q.dtype)
+
+
+KERNELS.register("paged_attention_decode", "pallas", _pallas_variant,
+                 priority=10, supports=_supports_pallas,
+                 tags=("serving", "pallas"))
+KERNELS.register("paged_attention_decode", "xla",
+                 paged_attention_decode_xla, priority=0,
+                 tags=("serving",))
+# "interpret" rides in every decode program cache's route key
+# (generation.kernel_route); the backend is the process's and the pool
+# dtype is in the jit signature
+KERNELS.declare_cache_key("paged_attention_decode",
+                          ("backend", "interpret", "pool_dtype"))
 
 
 def write_to_pool(k_pool, v_pool, block_tables, seq_lens, k_new, v_new,
@@ -226,10 +262,9 @@ def paged_attention_decode_quant(q, k_pool, v_pool, block_tables,
                                  layer=None):
     """Decode attention over int8 pools: gather int8 (the HBM win),
     dequant per head, then the SAME attention math as the bf16 path."""
-    return paged_attention_decode_xla(q, k_pool, v_pool, block_tables,
-                                      seq_lens, scale=scale,
-                                      k_scale=k_scale, v_scale=v_scale,
-                                      layer=layer)
+    return paged_attention_decode(q, k_pool, v_pool, block_tables,
+                                  seq_lens, scale=scale, layer=layer,
+                                  k_scale=k_scale, v_scale=v_scale)
 
 
 class BlockManager:

@@ -14,10 +14,9 @@ import jax.numpy as jnp
 from ...core.backend import on_tpu
 from ...core.flags import GLOBAL_FLAGS
 
-# The training-side analog of FLAGS_fused_decode: routes the training
-# hot path (chunked lm-head+CE, SwiGLU, RMSNorm backward, the
-# residual+norm epilogue) through the fused Pallas kernels where the
-# registry supports them. Defined here — the ONE shared home — because
+# Routes the training hot path (chunked lm-head+CE, SwiGLU, RMSNorm
+# backward, the residual+norm epilogue) through the fused Pallas kernels
+# where the registry supports them. Defined here — the ONE shared home — because
 # both norms.py and fused_train.py consult it and neither may import
 # the other.
 GLOBAL_FLAGS.define(
@@ -65,47 +64,20 @@ def dispatch_fused_variant(op: str, meta, mode=None):
     return KERNELS.variant(
         op, "pallas_fused" if mode == "pallas" else "unfused").fn
 
-# Pages-per-grid-step autotune candidates for the fused page-streaming
-# kernels (decode-block attention, prefill attention: a grid step
-# fetches this many pages through BlockSpecs — pages are processed
-# sequentially, so the choice only affects pipelining, never numerics).
-# The unfused paged-decode kernel fetches for itself and has its own
-# space (``paged_attention.PAGE_BLOCK_CANDIDATES``).
+# Pages-per-grid-step autotune candidates of the fused prefill attention
+# kernel (a grid step fetches this many pages through BlockSpecs; pages
+# are processed sequentially, so the choice only affects pipelining,
+# never numerics). The paged-decode kernel fetches for itself and has
+# its own space (``paged_attention.PAGE_BLOCK_CANDIDATES``).
 PAGE_STEP_CANDIDATES = (1, 2, 4)
-
-
-def clamped_page_index(BS, pp, j):
-    """BlockSpec index map for the ``j``-th KV-page input of a
-    pages-per-step decode grid ``(B, cdiv(MB, pp))``.
-
-    Clamps dead pages to the sequence's last live page so Mosaic's
-    revisit-elision skips the copy, and keeps garbage block-table
-    entries out of the fetch. All-int32 arithmetic: index maps are
-    retraced at LOWERING time, outside the kernels' no_x64 trace
-    window, where a bare python-int operand would promote to i64 and
-    fail MLIR verification. The fused attention megakernel's fetch (and
-    the reference grid of tests/test_paged_attention_kernel.py); the
-    unfused paged-decode kernel fetches its live pages for itself, in
-    the same order.
-    """
-    def f(b, mi, bt_ref, len_ref):
-        last = jnp.maximum(len_ref[b] - jnp.int32(1),
-                           jnp.int32(0)) // jnp.int32(BS)
-        idx = jnp.minimum(mi.astype(jnp.int32) * jnp.int32(pp)
-                          + jnp.int32(j), last)
-        return (bt_ref[b, idx], 0, 0, 0)
-    return f
 
 
 def online_softmax_page_update(q, k, v, pg, bs, seq_len, scale,
                                kv, groups, m_scr, l_scr, acc_scr):
     """One KV page's online-softmax update against ``m/l/acc`` scratch.
 
-    THE page-streaming reduction body, shared by the unfused
-    paged-decode kernel and the fused attention megakernel: their
-    bit-parity contract requires the two reductions to stay
-    numerically identical op-for-op, so the math has exactly one
-    definition (like :func:`clamped_page_index` for the fetch clamp).
+    The page-streaming reduction body of the paged-decode kernel and
+    of the fused prefill attention kernel (its paged history).
     ``q`` [H, hd], ``k``/``v`` [BS, KV, hd] — all f32 (callers dequant/
     upcast first); ``pg`` is the page index, tokens at/after
     ``seq_len`` are masked out. All literals explicitly f32/i32: the
@@ -188,7 +160,7 @@ def gspmd_program(n_devices: int):
 def gspmd_refusal():
     """None, or why a compiled Mosaic kernel cannot be emitted into the
     program being traced. Read by the registry for every variant tagged
-    "pallas" and by the three direct routers (``pallas_route``)."""
+    "pallas" and by the direct routers (``pallas_route``)."""
     n = getattr(_GSPMD, "n", 1)
     if n > 1 and not interpret_mode():
         return (f"GSPMD program over {n} devices: Mosaic kernels cannot "
@@ -198,9 +170,9 @@ def gspmd_refusal():
 
 
 def pallas_route() -> bool:
-    """Whether a direct router (rms_norm, flash_attention,
-    paged_attention_decode) takes its Pallas kernel: a TPU backend, and
-    a program a Mosaic kernel can be lowered into."""
+    """Whether a direct router (rms_norm, flash_attention, the Mamba-2
+    state launches) takes its Pallas kernel: a TPU backend, and a
+    program a Mosaic kernel can be lowered into."""
     return on_tpu() and gspmd_refusal() is None
 
 

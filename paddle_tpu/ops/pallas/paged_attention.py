@@ -97,9 +97,7 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
             c.wait()
 
     def reduce_page(blk, j):
-        # the reduction body is SHARED with the fused decode-block
-        # attention kernel (their bit-parity contract); pages go
-        # through it in page order whatever pp is
+        # pages go through the reduction in page order whatever pp is
         half = blk % i32(2)
         online_softmax_page_update(
             q_ref[0].astype(f32),                         # [H, hd]

@@ -2,7 +2,7 @@
 
 TPU-native analog of the reference's kernel-factory selection
 (paddle/phi/core/kernel_factory.cc picks a kernel by backend/layout/
-dtype key): an OP (e.g. ``decode_attn_block``) owns several VARIANTS
+dtype key): an OP (e.g. ``decode_mlp_block``) owns several VARIANTS
 (a Pallas megakernel, a jnp composition, ...), each with a ``supports``
 predicate over a static shape/dtype/platform *meta* dict. ``dispatch``
 returns the highest-priority supported variant — so the serving decode
@@ -22,6 +22,7 @@ tests and the audit catalog use it to trace the Pallas path on CPU
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -150,11 +151,31 @@ class KernelRegistry:
         return None
 
     # -- dispatch ------------------------------------------------------
+    @contextlib.contextmanager
+    def record(self):
+        """Collect ``{op: variant}`` for every :meth:`dispatch` this
+        thread makes inside the block. Wrapped around the trace of a
+        program it is the record of what that program compiled in:
+        the same calls, not a second reading of the predicates."""
+        prev = getattr(self._forced, "picked", None)
+        picked = self._forced.picked = {}
+        try:
+            yield picked
+        finally:
+            self._forced.picked = prev
+
     def dispatch(self, op: str, meta: Dict[str, Any]
                  ) -> Tuple[str, Callable]:
         """Highest-priority supported variant -> (name, fn). Raises if
         the op is unknown or NO variant supports ``meta`` (every op
         should register an unconditional fallback)."""
+        name, fn = self._select(op, meta)
+        picked = getattr(self._forced, "picked", None)
+        if picked is not None:
+            picked[op] = name
+        return name, fn
+
+    def _select(self, op, meta):
         forced = self._forced_for(op)
         if forced is not None:
             return forced, self.variant(op, forced).fn
@@ -174,7 +195,7 @@ class KernelRegistry:
         for tests and ``ServingEngine.metrics`` style introspection."""
         sel = None
         try:
-            sel, _ = self.dispatch(op, meta)
+            sel, _ = self._select(op, meta)
         except (KeyError, RuntimeError):
             pass
         out = []

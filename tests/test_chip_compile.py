@@ -6,10 +6,13 @@ over the scoped-VMEM limit, a kernel GSPMD cannot partition — the
 compiler says here, at no chip time.
 
 One case per main-path kernel at the ``chip_smoke.py`` widths (Llama-7B:
-D=4096, 32 heads x 128, F=11008, V=32000), one per fused serving kernel
-at the shape class where ``supports()`` selects it, each asserting that
-dispatch selects what is compiled and that the compiled program holds
-the named ``tpu_custom_call``. A compile that passes is not a chip run.
+D=4096, 32 heads x 128, F=11008, V=32000), the decode launches at the
+benchmark cells' own shapes, one per fused prefill kernel at the shape
+class where ``supports()`` selects it, each asserting that dispatch
+selects what is compiled and that the compiled program holds the named
+``tpu_custom_call``; then whole programs, the engine's decode program
+among them, whose ``decode_variant`` must name the launches the
+compiled program holds. A compile that passes is not a chip run.
 
 The routers ask ``jax.default_backend()`` and see the CPU here, so the
 cases compile the kernels themselves, with ``interpret`` steered by the
@@ -85,10 +88,8 @@ def _compile(topo, build):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _decode_meta(b=CAP, d=D, h=H, kv=KV, hd=HD, f=F, bs=BS, mb=MB,
-                 wq=None):
-    return fdb.decode_meta_dims(b, d, h, kv, hd, f, bs, mb, BF16, BF16,
-                                False, weight_dtype=wq)
+def _decode_meta(b=CAP, d=D, f=F, wq=None):
+    return fdb.decode_meta_dims(b, d, f, BF16, weight_dtype=wq)
 
 
 def _selects(op, meta, variant="pallas_fused"):
@@ -120,6 +121,25 @@ CASES = [
     ("decode_mlp_block", kc._mlp_block_case(CAP, D, F, BF16),
      {"decode_mlp_block"},
      lambda: _selects("decode_mlp_block", _decode_meta())),
+    # -- the same launch at the Mistral cells' widths (32 slots, D=4096,
+    #    F=14336): the weight_int8 control's tiles and packed int4 with
+    #    the dequantization in the kernel, and the four-chip shard's
+    #    quarter of the intermediate columns -----------------------------
+    ("decode_mlp_block_int8_weights",
+     kc._mlp_block_case(32, 4096, 14336, BF16, wq="int8"),
+     {"decode_mlp_block"},
+     lambda: _selects("decode_mlp_block",
+                      _decode_meta(32, 4096, 14336, wq="int8"))),
+    ("decode_mlp_block_int4_weights",
+     kc._mlp_block_case(32, 4096, 14336, BF16, wq="int4"),
+     {"decode_mlp_block"},
+     lambda: _selects("decode_mlp_block",
+                      _decode_meta(32, 4096, 14336, wq="int4"))),
+    ("decode_mlp_block_tp4_shard",
+     kc._mlp_block_case(32, 4096, 14336 // 4, BF16),
+     {"decode_mlp_block"},
+     lambda: _selects("decode_mlp_block",
+                      _decode_meta(32, 4096, 14336 // 4))),
     # -- the training step at the smoke widths --------------------------
     ("flash_attention", kc._flash_case(2, SEQ, H, KV, HD, BF16),
      set(kc._FLASH_KERNELS),
@@ -141,30 +161,9 @@ CASES = [
      {"fused_adamw"},
      lambda: _selects("fused_adamw",
                       fa.adamw_meta(4 << 20, "float32", BF16, True))),
-    # -- the fused serving kernels, where supports() selects them (the
+    # -- the fused prefill kernels, where supports() selects them (the
     #    catalog's D=1024 serving class; at the smoke widths their
     #    resident weights are refused on the VMEM budget) ---------------
-    ("decode_attn_block",
-     kc._attn_block_case(8, 1024, 16, 16, 64, 16, 128, 24, BF16),
-     {"decode_attn_block"},
-     lambda: _selects("decode_attn_block",
-                      _decode_meta(8, 1024, 16, 16, 64, 4096, 16, 24))),
-    ("decode_attn_block_int8_kv",
-     kc._attn_block_case(8, 1024, 16, 16, 64, 16, 128, 24, BF16,
-                         quant=True),
-     {"decode_attn_block"}, None),
-    ("decode_block_fused_int8_weights",
-     kc._block_case(8, 1024, 16, 16, 64, 4096, 16, 128, 24, BF16,
-                    wq="int8"),
-     {"decode_block_fused"},
-     lambda: _selects(
-         "decode_block_fused",
-         _decode_meta(8, 1024, 16, 16, 64, 4096, 16, 24, wq="int8"),
-         "pallas_block")),
-    ("decode_block_fused_int4_weights",
-     kc._block_case(8, 1024, 16, 16, 64, 4096, 16, 128, 24, BF16,
-                    wq="int4"),
-     {"decode_block_fused"}, None),
     ("prefill_attn_block_hd128",
      kc._prefill_attn_case(64, 1024, 8, 8, 128, 16, 129, 24, BF16,
                            pos0=128),
@@ -190,14 +189,11 @@ def test_every_refusal_on_the_chip_names_its_reason():
     """What dispatch refuses at the smoke widths, and what the chip's
     compiler refused outright, falls back with a reason a person can
     act on — so ``decode_variant`` and ``explain()`` tell the truth."""
-    d = _decode_meta()
     p64 = fpb.prefill_meta_dims(64, 1024, 16, 16, 64, 4096, 16, 24, BF16,
                                 BF16, False)
     p7b = fpb.prefill_meta_dims(128, D, H, KV, HD, F, BS, MB, BF16, BF16,
                                 False)
     refused = {
-        ("decode_attn_block", "pallas_fused"): (d, "VMEM"),
-        ("decode_block_fused", "pallas_block"): (d, "scoped-VMEM"),
         ("prefill_attn_block", "pallas_fused"): (p7b, "VMEM"),
         ("prefill_mlp_block", "pallas_fused"): (p7b, "VMEM"),
     }
@@ -286,34 +282,41 @@ def test_gspmd_sharded_train_step_compiles_without_mosaic_kernels(
 # loop carries the KV pools and hands the kernels whole buffers (PR 26)
 # ---------------------------------------------------------------------------
 def _lowered_decode_program(topo, config):
-    """The engine's decode program (``serving._make_decode_fn*``: the
-    decode step, greedy sampling, the engine's donation) lowered at a
-    benchmark configuration's shapes for described devices."""
+    """The engine's decode program (``serving._make_decode_fn``: the
+    configuration's decode forward, greedy sampling, the engine's
+    donation) lowered at a benchmark configuration's shapes for
+    described devices."""
+    import importlib
     import json
     from jax.sharding import Mesh
-    from paddle_tpu.inference import generation as G
+    from paddle_tpu.inference import generation as G, hybrid
     from paddle_tpu.inference.serving import ServingEngine
     from paddle_tpu.inference.tp import ServingMesh
-    from paddle_tpu.models import llama
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            config + ".json")) as f:
         conf = json.load(f)
-    cfg = llama.LlamaConfig(**{k: conf[k]
-                               for k in conf["program"]["config_keys"]})
+    module, cls = conf["program"]["config"].split(":")
+    model = importlib.import_module(module)
+    cfg = getattr(model, cls)(**{k: conf[k]
+                                 for k in conf["program"]["config_keys"]})
+    recurrent = getattr(cfg, "num_recurrent_layers", 0) > 0
     eng, tp = conf["engine"], conf["engine"].get("mesh", 1)
     mesh = Mesh(np.array(topo.devices[:tp]), ("tp",))
-    params = jax.eval_shape(lambda: llama.init_params(cfg))
-    if tp == 1:
-        specs = jax.tree_util.tree_map(lambda _: P(), params)
-        pool_spec = P()
-        step = lambda p, tok, seq, tab, kp, vp: G._fused_decode_step(  # noqa: E731
+    params = jax.eval_shape(lambda: model.init_params(cfg))
+    specs = jax.tree_util.tree_map(lambda _: P(), params)
+    pool_spec = P()
+    if recurrent:
+        step = lambda p, tok, seq, tab, kp, vp, st: hybrid.decode_step(  # noqa: E731
+            p, tok, cfg, kp, vp, tab, seq, st)
+    elif tp == 1:
+        step = lambda p, tok, seq, tab, kp, vp: G._decode_step(  # noqa: E731
             p, tok, cfg, kp, vp, tab, seq)
     else:
         sm = ServingMesh(mesh)
         specs, pool_spec = sm.param_specs(cfg), sm.pool_spec
-        step = sm.sharded_decode_fn(cfg, "auto", quant=False)
+        step = sm.sharded_decode_fn(cfg, quant=False)
 
     def sds(shape, dtype, spec=P()):
         return jax.ShapeDtypeStruct(shape, dtype,
@@ -322,38 +325,149 @@ def _lowered_decode_program(topo, config):
     params = jax.tree_util.tree_map(
         lambda v, s: sds(v.shape, v.dtype, s), params, specs)
     C, BS = eng["capacity"], eng["block_size"]
-    pool = sds((cfg.num_hidden_layers, eng["num_blocks"], BS,
-                cfg.num_key_value_heads, cfg.head_dim), cfg.dtype,
-               pool_spec)
+    pool = sds((getattr(cfg, "num_kv_layers", cfg.num_hidden_layers),
+                eng["num_blocks"], BS, cfg.num_key_value_heads,
+                cfg.head_dim), cfg.dtype, pool_spec)
+    state = ()
+    if recurrent:       # the slots' state: one more donated argument
+        state = (jax.tree_util.tree_map(
+            lambda v: sds(v.shape, v.dtype), jax.eval_shape(
+                lambda: hybrid.init_state(cfg, C, jnp.float32))),)
 
     def program(params, tok, seq_lens, tables, temps, key, k_pools,
-                v_pools):
-        logits, k_pools, v_pools = step(params, tok, seq_lens, tables,
-                                        k_pools, v_pools)
+                v_pools, *state):
+        logits, k_pools, v_pools, *state = step(
+            params, tok, seq_lens, tables, k_pools, v_pools, *state)
         return (jnp.argmax(logits, -1).astype(jnp.int32),
                 jnp.where(seq_lens > 0, seq_lens + 1, 0), key, k_pools,
-                v_pools)
+                v_pools, *state)
 
-    lowered = jax.jit(
-        program, donate_argnums=ServingEngine._DECODE_DONATE).lower(
+    donate = ServingEngine._DECODE_DONATE + ((8,) if recurrent else ())
+    lowered = jax.jit(program, donate_argnums=donate).lower(
         params, sds((C,), jnp.int32), sds((C,), jnp.int32),
         sds((C, -(-eng["max_seq_len"] // BS)), jnp.int32),
-        sds((C,), jnp.float32), sds((2,), jnp.uint32), pool, pool)
+        sds((C,), jnp.float32), sds((2,), jnp.uint32), pool, pool, *state)
     return lowered, int(np.prod(pool.shape)) * 2 // tp
 
 
-@pytest.mark.parametrize("config", ["mistral-7b-v0.3-l16",
-                                    "mistral-7b-v0.3-tp4"])
+_DENSE_LAUNCHES = {"paged_attention_decode", "decode_mlp_block"}
+
+
+@pytest.mark.parametrize("config,launches,share", [
+    pytest.param(c, k, n, id=c) for c, k, n in (
+        ("mistral-7b-v0.3-l16", _DENSE_LAUNCHES, 8),
+        ("mistral-7b-v0.3-tp4", _DENSE_LAUNCHES, 8),
+        # one attention layer's pool (0.27 GB) beside nine expert layers'
+        # buffers (0.06 GB of temporaries): under half a pool
+        ("granite-4.0-h-small-l10-e36",
+         {"paged_attention_decode", "ssm_update"}, 2))])
 def test_decode_program_holds_no_second_copy_of_a_pool(
-        topo, monkeypatch, config):
+        topo, monkeypatch, config, launches, share):
     """The layer loop's pools are carried and written in place and its
     kernels read whole buffers by layer index: the compiled program's
     temporaries are far smaller than one KV pool (as scan inputs and
     outputs both pools were held twice: 4.1 GB of temporaries at l16),
-    and both kernels are in it."""
+    and the launches the registry picks on the chip are in it — the
+    hybrid model's attention layer through the same dispatch."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     lowered, pool_bytes = _lowered_decode_program(topo, config)
     compiled = lowered.compile()
-    assert {"paged_attention_decode", "decode_mlp_block"} \
-        <= _kernels(compiled)
-    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+    assert launches <= _kernels(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < pool_bytes // share
+
+
+# ---------------------------------------------------------------------------
+# decode_variant is compiled reality: what an engine reports of its decode
+# program names the launches the program compiled for the chip holds
+# ---------------------------------------------------------------------------
+DECODE_LAUNCHES = {"paged_attention_decode", "decode_mlp_block"}
+ENGINES = {     # id -> (engine options, the launches the chip runs)
+    "single_device": ({}, DECODE_LAUNCHES),
+    "tp_psum": ({"mesh": ("psum",)}, DECODE_LAUNCHES),
+    # the MLP composition: its matmuls see the single-device operands
+    "tp_gather": ({"mesh": ("gather",)}, {"paged_attention_decode"}),
+    # the attention kernel takes no int8 pool
+    "int8_pool": ({"cache_dtype": "int8"}, {"decode_mlp_block"}),
+    # expert layers: no decode_mlp_block stage
+    "hybrid": ({"hybrid": True}, {"paged_attention_decode"}),
+}
+
+
+def _engine(options):
+    """An engine small enough to build here and wide enough for Mosaic
+    (head_dim 128, 1024-wide rows), on the CPU's devices."""
+    from paddle_tpu.inference import ServingEngine, ServingMesh
+    from paddle_tpu.models import granite_hybrid as gh, llama
+    options = dict(options)
+    if options.pop("hybrid", False):
+        cfg = gh.GraniteHybridConfig(
+            vocab_size=512, hidden_size=512, intermediate_size=128,
+            shared_intermediate_size=256, num_hidden_layers=3,
+            layer_types=("mamba", "attention", "mamba"),
+            num_attention_heads=4, num_key_value_heads=2,
+            num_local_experts=4, num_experts_per_tok=2,
+            mamba_n_heads=16, mamba_d_head=64, mamba_d_state=128,
+            max_position_embeddings=256)
+        params = gh.init_params(cfg)
+    else:
+        cfg = llama.LlamaConfig(
+            vocab_size=512, hidden_size=1024, intermediate_size=4096,
+            num_hidden_layers=2, num_attention_heads=8,
+            num_key_value_heads=8, max_position_embeddings=256)
+        params = llama.init_params(cfg, jax.random.key(0))
+    if "mesh" in options:
+        options["mesh"] = ServingMesh.make(tp=2,
+                                           collective=options["mesh"][0])
+    eng = ServingEngine(params, cfg, capacity=8, block_size=16,
+                        max_seq_len=128, prefill_buckets=(32,), **options)
+    if eng._quant:      # what the first prompt's calibration leaves
+        L, KV = cfg.num_hidden_layers, cfg.num_key_value_heads
+        eng._kv_scales = (jnp.ones((L, KV)), jnp.ones((L, KV)))
+    return eng
+
+
+def _decode_program_for_the_chip(topo, eng):
+    """The engine's own decode program (its one maker, recording what
+    dispatch picks as it traces) lowered for described devices: the
+    arguments are the audit spec's shapes, placed as the engine places
+    them, and a sharded engine's mesh is the described chips'."""
+    from jax.sharding import Mesh
+    from paddle_tpu.inference import ServingMesh
+    (spec,) = [s for s in eng.program_specs(register=False)
+               if s.name.startswith("serving_decode")]
+    sm = eng._mesh
+    if sm is None:
+        mesh = Mesh(np.array(topo.devices[:1]), ("one",))
+        specs = [P()] * len(spec.args)
+    else:
+        mesh = Mesh(np.array(topo.devices[:sm.tp]), (sm.axis,))
+        eng._mesh = sm = ServingMesh(mesh, sm.axis, sm.collective)
+        specs = [sm.param_specs(eng.cfg, eng.params)] + [P()] * 5 \
+            + [sm.pool_spec] * 2
+
+    def place(tree, spec):      # one spec for every leaf, or a tree
+        if isinstance(spec, P):
+            spec = jax.tree_util.tree_map(lambda _: spec, tree)
+        return jax.tree_util.tree_map(
+            lambda v, s: jax.ShapeDtypeStruct(
+                v.shape, v.dtype, sharding=NamedSharding(mesh, s)),
+            tree, spec)
+
+    args = [place(a, s) for a, s in zip(spec.args, specs)]
+    return eng._make_decode_fn().lower(*args).compile()
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+def test_decode_variant_names_the_compiled_launches(topo, monkeypatch,
+                                                    engine):
+    options, launches = ENGINES[engine]
+    eng = _engine(options)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    found = _kernels(_decode_program_for_the_chip(topo, eng))
+    v = eng.decode_variant
+    assert set(v["operands"]) == found & DECODE_LAUNCHES == launches
+    assert (v["attn"], v["mlp"]) == (
+        "pallas" if "paged_attention_decode" in found else "xla",
+        "pallas_fused" if "decode_mlp_block" in found else "unfused")
+    assert eng.counters["decode_traces"] == 1

@@ -26,7 +26,6 @@ from paddle_tpu.inference import generation as G
 from paddle_tpu.inference import tp as TP
 from paddle_tpu.models import llama
 from paddle_tpu.ops import paged_attention as PA
-from paddle_tpu.ops.pallas import _util
 from paddle_tpu.ops.pallas import fused_decode_block as fdb
 from paddle_tpu.ops.pallas.paged_attention import (
     paged_attention_decode_pallas)
@@ -65,27 +64,26 @@ def _local_params(tp, collective):
     return sd
 
 
-def _trace(program, monkeypatch):
-    """make_jaxpr of one decode program as the chip would trace it:
-    the paged-attention router takes its kernel (a TPU backend) and the
-    registry selects the fused MLP kernel, as in every serving cell."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(_util, "_FORCE_INTERPRET", True)
+def _trace(program):
+    """make_jaxpr of one decode program as the chip traces it: the
+    registry's two decode ops pinned to what they select there (the
+    Pallas launches, as in every serving cell; "unfused": the MLP
+    composition beside the attention launch)."""
     tp, collective, axis_env = 1, None, None
-    if program == "fused":
-        fn = functools.partial(G._fused_decode_step, mode="auto")
-    elif program == "unfused":
-        fn = G._paged_decode_step
-    else:
+    fn = G._decode_step
+    if program.startswith("tp_"):
         tp, collective, axis_env = 2, program.split("_")[1], [("tp", 2)]
         fn = functools.partial(TP._tp_decode_step, axis="tp",
-                               collective=collective, fused="auto")
+                               collective=collective)
+    mlp = "unfused" if program == "unfused" else "pallas_fused"
     L, KV, hd = (CFG.num_hidden_layers, CFG.num_key_value_heads,
                  CFG.head_dim)
     pool = jax.ShapeDtypeStruct((L, NB, BS, KV // tp, hd), jnp.float32)
     ints = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    with KERNELS.force("decode_mlp_block", "pallas_fused"):
-        return jax.make_jaxpr(
+    with KERNELS.force("paged_attention_decode", "pallas"), \
+            KERNELS.force("decode_mlp_block", mlp), \
+            KERNELS.record() as picked:
+        return picked, jax.make_jaxpr(
             lambda p, tok, kp, vp, bt, sl: fn(p, tok, CFG, kp, vp, bt, sl),
             axis_env=axis_env)(
                 _local_params(tp, collective), ints(B), pool, pool,
@@ -125,9 +123,8 @@ def _operand_origins(jaxpr, origin, found):
 
 @pytest.mark.parametrize("program",
                          ["fused", "unfused", "tp_psum", "tp_gather"])
-def test_layer_loop_carries_pools_and_indexes_stacked_operands(
-        program, monkeypatch):
-    closed = _trace(program, monkeypatch)
+def test_layer_loop_carries_pools_and_indexes_stacked_operands(program):
+    picked, closed = _trace(program)
     (loop,) = [s for s in _scans(closed.jaxpr)
                if any(len(v.aval.shape) == 5 for v in s.invars)]
     body = loop.params["jaxpr"].jaxpr
@@ -141,7 +138,7 @@ def test_layer_loop_carries_pools_and_indexes_stacked_operands(
     assert not [v for v in loop.outvars[nk:] if rank(v) >= 4]
     launches = [(n, ops) for n, ops in _operand_origins(
         body, dict(zip(body.invars, kinds)), []) if n in LAUNCHES]
-    names = [n for n, _ in launches]      # (rms_norm launches too)
+    names = [n for n, _ in launches]
     want = {"fused": LAUNCHES, "tp_psum": LAUNCHES}.get(
         program, LAUNCHES[:1])          # the compositions: attention only
     assert sorted(names) == sorted(want), names
@@ -158,11 +155,9 @@ def test_layer_loop_carries_pools_and_indexes_stacked_operands(
             weights = [o for shape, o in operands
                        if len(shape) == 3 and shape[1] > 1]
             assert weights == ["const"] * 3, operands
-    # and the engine's record says the same without a trace
-    assert fdb.launch_operands(
-        {**fdb.UNFUSED, "mlp": "pallas_fused"
-         if "decode_mlp_block" in names else "unfused"}) \
-        == {n: "index" for n in names}
+    # and the engine's record, read off what dispatch picked for this
+    # trace, says the same
+    assert fdb.launch_operands(picked) == {n: "index" for n in names}
 
 
 # ---------------------------------------------------------------------------

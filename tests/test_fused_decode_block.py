@@ -1,16 +1,17 @@
-"""Fused decode-block megakernels (ops/pallas/fused_decode_block.py),
-the kernel registry (ops/pallas/registry.py), and the PR's satellites
-(autotune-cache robustness, per-kernel bench gate, paged-decode
-pages-per-step tuning).
+"""The decode step's two launches (``paged_attention_decode``,
+``decode_mlp_block``: ops/paged_attention.py, ops/pallas/
+fused_decode_block.py), the kernel registry that chooses them
+(ops/pallas/registry.py), and satellites (autotune-cache robustness,
+per-kernel bench gate, paged-decode pages-per-step tuning).
 
-Parity contract: wherever registry dispatch selects the ``unfused``
-composition (always on CPU/interpret), the fused decode step is
-BIT-identical to the pre-fusion ``_paged_decode_step`` — asserted
-through a >=20-request ServingEngine stream and at the step level.
-The Pallas megakernels themselves (forced, interpret mode) match the
-composition to fp32 roundoff across randomized shapes, fp32 and int8
-cache.
+Parity contract: auto dispatch on the CPU selects the XLA compositions,
+so the decode step there is BIT-identical to the step pinned to them
+(``KERNELS.force``) — asserted through a >=20-request ServingEngine
+stream and at the step level. The Pallas launches (pinned, interpret
+mode) match the compositions to fp32 roundoff, and the attention stage
+matches dense float32 attention over the same tokens.
 """
+import contextlib
 import functools
 import importlib.util
 import json
@@ -23,12 +24,11 @@ import jax.numpy as jnp
 
 from paddle_tpu.models import llama
 from paddle_tpu.inference import GenerationConfig, ServingEngine
-from paddle_tpu.inference.generation import (_fused_decode_step,
-                                             _fused_mode,
-                                             _paged_decode_step,
-                                             generate_paged)
+from paddle_tpu.inference import generation as G
+from paddle_tpu.inference.generation import _decode_step, generate_paged
+from paddle_tpu.ops import paged_attention as PA
 from paddle_tpu.ops.pallas import fused_decode_block as fdb
-from paddle_tpu.ops.pallas.registry import KernelRegistry
+from paddle_tpu.ops.pallas.registry import KERNELS, KernelRegistry
 
 pytestmark = pytest.mark.fused
 
@@ -50,6 +50,21 @@ def _engine(params, **kw):
     kw.setdefault("prefill_buckets", (8, 16))
     kw.setdefault("max_seq_len", 64)
     return ServingEngine(params, CFG, **kw)
+
+
+@contextlib.contextmanager
+def _pinned(attn, mlp):
+    """Both decode launches pinned (None leaves one to dispatch)."""
+    with contextlib.ExitStack() as st:
+        if attn:
+            st.enter_context(KERNELS.force("paged_attention_decode", attn))
+        if mlp:
+            st.enter_context(KERNELS.force("decode_mlp_block", mlp))
+        yield
+
+
+PALLAS = ("pallas", "pallas_fused")
+REFERENCE = ("xla", "unfused")
 
 
 def _rope_tables(T, hd):
@@ -90,55 +105,111 @@ def _attn_case(rng, B, D, KV, groups, hd, BS, MB, quant=False):
 
 
 # ---------------------------------------------------------------------------
-# kernel-level parity (forced Pallas, interpret mode) — randomized shapes
+# the attention stage: attn_qkv_ref -> pool write -> attn_out_ref, against
+# dense float32 attention over the same tokens
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_attn_block_parity_randomized(seed):
+def _dense_attention_stage(args, scales):
+    """x + softmax(q K^T) V Wo over each slot's history read out of the
+    pool through its table plus the new token, in numpy float64: the
+    plain reference, sharing no code with the program."""
+    from paddle_tpu.quantization.quanters import maybe_dequantize
+    x, nw, wq, wk, wv, wo, sin, cos, kp, vp, bt, lens = [
+        np.asarray(maybe_dequantize(a, jnp.float32), np.float64)
+        if isinstance(a, dict) else np.asarray(a) for a in args]
+    B, D = x.shape
+    BS, KV, hd = kp.shape[1:]
+    H = wq.shape[1] // hd
+    h = x / np.sqrt((x ** 2).mean(-1, keepdims=True) + 1e-6) * nw
+
+    def rope(t, pos):
+        t1, t2 = t[..., :hd // 2], t[..., hd // 2:]
+        s, c = sin[pos], cos[pos]
+        return np.concatenate([t1 * c - t2 * s, t2 * c + t1 * s], -1)
+
+    out = np.zeros_like(x, dtype=np.float64)
+    for b in range(B):
+        n = int(lens[b])
+        q = rope((h[b] @ wq).reshape(H, hd), n)
+        k_new = rope((h[b] @ wk).reshape(KV, hd), n)
+        v_new = (h[b] @ wv).reshape(KV, hd)
+        pages = kp[bt[b]].reshape(-1, KV, hd)[:n].astype(np.float64)
+        vpages = vp[bt[b]].reshape(-1, KV, hd)[:n].astype(np.float64)
+        if scales is not None:      # what an int8 pool holds of a token
+            ks, vs = (np.asarray(s, np.float64)[:, None] for s in scales)
+            pages, vpages = pages * ks, vpages * vs
+            k_new = np.clip(np.round(k_new / ks), -127, 127) * ks
+            v_new = np.clip(np.round(v_new / vs), -127, 127) * vs
+        k = np.concatenate([pages, k_new[None]]).repeat(H // KV, 1)
+        v = np.concatenate([vpages, v_new[None]]).repeat(H // KV, 1)
+        sc = np.einsum("hd,thd->ht", q, k) / np.sqrt(hd)
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out[b] = x[b] + np.einsum("ht,thd->hd", p, v).reshape(-1) @ wo
+    return out
+
+
+def _attention_stage(args, scales):
+    x, nw, wq, wk, wv, wo, sin, cos, kp, vp, bt, lens = args
+    q, k_new, v_new = fdb.attn_qkv_ref(x, nw, wq, wk, wv, sin, cos, lens)
+    if scales is None:
+        kp, vp = PA.write_to_pool(kp, vp, bt, lens, k_new, v_new)
+    else:
+        kp, vp = PA.write_to_pool_quant(kp, vp, bt, lens, k_new, v_new,
+                                        *scales)
+    return fdb.attn_out_ref(x, q, wo, kp, vp, bt, lens, scales)
+
+
+def _random_dims(seed):
     rng = np.random.RandomState(seed)
-    B = int(rng.randint(1, 4))
-    KV = int(rng.choice([1, 2, 4]))
-    groups = int(rng.choice([1, 2, 3]))
-    hd = int(rng.choice([8, 16, 32]))
-    BS = int(rng.choice([4, 8, 16]))
-    MB = int(rng.randint(2, 5))
-    D = int(rng.choice([32, 48, 64]))
-    args, _ = _attn_case(rng, B, D, KV, groups, hd, BS, MB)
-    xf, kf, vf = fdb.fused_attn_block_pallas(*args)
-    xr, kr, vr = fdb.attn_block_ref(*args)
-    np.testing.assert_allclose(np.asarray(xf), np.asarray(xr),
-                               atol=2e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(kf), np.asarray(kr),
-                               atol=2e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(vf), np.asarray(vr),
-                               atol=2e-5, rtol=1e-5)
+    return dict(B=int(rng.randint(1, 4)), KV=int(rng.choice([1, 2, 4])),
+                groups=int(rng.choice([1, 2, 3])),
+                hd=int(rng.choice([8, 16, 32])),
+                BS=int(rng.choice([4, 8, 16])), MB=int(rng.randint(2, 5)),
+                D=int(rng.choice([32, 48, 64])))
 
 
-def test_attn_block_parity_int8_cache():
-    rng = np.random.RandomState(3)
-    args, scales = _attn_case(rng, B=2, D=64, KV=2, groups=2, hd=16,
-                              BS=8, MB=3, quant=True)
-    xf, kf, vf = fdb.fused_attn_block_pallas(*args, kv_scales=scales)
-    xr, kr, vr = fdb.attn_block_ref(*args, kv_scales=scales)
-    # the fused kernel folds dequant(quant(new K/V)) in VMEM; the ref
-    # reads the same values back from the int8 pool — fp32 roundoff only
-    np.testing.assert_allclose(np.asarray(xf), np.asarray(xr),
-                               atol=2e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(kf), np.asarray(kr),
-                               atol=2e-5, rtol=1e-5)
+STAGE = dict(B=2, D=64, KV=2, groups=2, hd=16, BS=8, MB=3)
+ATTENTION_STAGE_CASES = {
+    "seed0": (_random_dims(0), {}), "seed1": (_random_dims(1), {}),
+    "seed2": (_random_dims(2), {}),
+    "int8_pool": (STAGE, {"quant": True}),
+    # the benchmark's head layouts: Mistral's 32/8, its four-chip shard
+    "gqa_32_8": (dict(STAGE, KV=8, groups=4), {}),
+    "gqa_8_2": (dict(STAGE, KV=2, groups=4), {}),
+    "w8": (STAGE, {"bits": 8}), "w4": (STAGE, {"bits": 4}),
+}
 
 
-def test_attn_block_pages_per_step_invariant():
-    """pages_per_step only changes pipelining: pages are still processed
-    sequentially in order, so the online softmax is bit-identical."""
-    rng = np.random.RandomState(4)
-    args, _ = _attn_case(rng, B=2, D=32, KV=2, groups=2, hd=16, BS=4,
-                         MB=4)
-    outs = [fdb.fused_attn_block_pallas(*args, pages_per_step=pp)[0]
-            for pp in (1, 2, 4)]
-    np.testing.assert_array_equal(np.asarray(outs[0]),
-                                  np.asarray(outs[1]))
-    np.testing.assert_array_equal(np.asarray(outs[0]),
-                                  np.asarray(outs[2]))
+@pytest.mark.parametrize("case", ATTENTION_STAGE_CASES)
+def test_attention_stage_matches_dense_attention(case):
+    """The stage every decode program runs, with the Pallas
+    ``paged_attention_decode`` pinned (interpret mode) wherever its
+    predicate would admit it on a chip. An int8 pool is refused by
+    name and attends through the ``xla`` variant."""
+    dims, opt = ATTENTION_STAGE_CASES[case]
+    rng = np.random.RandomState(sum(map(ord, case)))
+    args, scales = _attn_case(rng, quant=opt.get("quant", False), **dims)
+    if "bits" in opt:
+        from paddle_tpu.quantization import ptq
+        args = args[:2] + tuple(ptq.quantize_leaf(w, opt["bits"])
+                                for w in args[2:6]) + args[6:]
+    if scales is None:
+        with _pinned("pallas", None):
+            got = _attention_stage(args, scales)
+    else:
+        meta = dict(PA.decode_attention_meta(jnp.int8), interpret=False,
+                    backend="tpu")
+        rows = {r["name"]: r for r in
+                KERNELS.explain("paged_attention_decode", meta)}
+        assert rows["xla"]["selected"]
+        assert "int8 pools" in rows["pallas"]["reason"]
+        with pytest.raises(ValueError, match="no int8 pools"), \
+                _pinned("pallas", None):
+            _attention_stage(args, scales)
+        got = _attention_stage(args, scales)
+    np.testing.assert_allclose(np.asarray(got),
+                               _dense_attention_stage(args, scales),
+                               atol=3e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("D,F", [(32, 64), (64, 256), (48, 96)])
@@ -167,110 +238,6 @@ def test_mlp_candidates_divide_evenly():
         assert cands, F
         assert all(F % c == 0 for c in cands), (F, cands)
     assert fdb._mlp_candidates(100) == [100]   # no divisor candidate
-
-
-# ---------------------------------------------------------------------------
-# single-launch decode block (r20): kernel parity, dispatch contract,
-# mode="block" plumbing
-# ---------------------------------------------------------------------------
-def _block_case(rng, wq_bits=0, quant=False):
-    """Full-block args at the clamp-edge decode shapes: the attention
-    case above + post-norm and SwiGLU weights (ragged F), the weight
-    tree optionally PTQ-quantized (down_proj packs its F rows)."""
-    B, D, KV, groups, hd, BS, MB, F = 2, 32, 2, 1, 16, 8, 3, 96
-    args, scales = _attn_case(rng, B, D, KV, groups, hd, BS, MB,
-                              quant=quant)
-    (x, nw, wq, wk, wv, wo, sin, cos, kp, vp, bt, lens) = args
-    mk = lambda *s: jnp.asarray(rng.randn(*s) * 0.07,    # noqa: E731
-                                jnp.float32)
-    pw = jnp.asarray(rng.rand(D) + 0.5, jnp.float32)
-    wg, wu, wd = mk(D, F), mk(D, F), mk(F, D)
-    ws = (wq, wk, wv, wo, wg, wu, wd)
-    if wq_bits:
-        from paddle_tpu.quantization import ptq as _ptq
-        ws = tuple(_ptq.quantize_leaf(w, wq_bits)
-                   for w in (wq, wk, wv, wo, wg, wu)) \
-            + (_ptq.quantize_leaf(wd, wq_bits, pack_axis=1),)
-    return (x, nw, ws[0], ws[1], ws[2], ws[3], pw, ws[4], ws[5],
-            ws[6], sin, cos, kp, vp, bt, lens), scales
-
-
-@pytest.mark.parametrize("wq_bits", [0, 8, 4], ids=["fp", "w8", "w4"])
-def test_decode_block_single_launch_parity(wq_bits):
-    """The single-launch megakernel (forced, interpret) matches the
-    priority-0 composed route to fp32 roundoff — the attn->MLP residual
-    handoff through f32 VMEM scratch changes only op grouping. Plain,
-    int8 and packed-int4 weight trees."""
-    rng = np.random.RandomState(20 + wq_bits)
-    full, _ = _block_case(rng, wq_bits=wq_bits)
-    got = fdb.fused_decode_block_pallas(*full, pages_per_step=2,
-                                        block_f=32)
-    want = fdb.decode_block_composed(*full)
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   atol=5e-5, rtol=1e-5)
-
-
-def test_decode_block_parity_int8_pool_and_tunable_invariance():
-    """int8 KV pool (dequant in VMEM, scales per head) and the joint
-    (pages_per_step, block_f) tunables: every choice is the same math
-    to fp32 roundoff."""
-    rng = np.random.RandomState(30)
-    full, scales = _block_case(rng, quant=True)
-    want = fdb.decode_block_composed(*full, kv_scales=scales)
-    for pp, bf in ((1, 96), (2, 32), (4, 48)):
-        got = fdb.fused_decode_block_pallas(*full, kv_scales=scales,
-                                            pages_per_step=pp,
-                                            block_f=bf)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       atol=5e-5, rtol=1e-5,
-                                       err_msg=f"pp={pp} bf={bf}")
-
-
-def test_block_dispatch_flagship_weight_quant_contract():
-    """The acceptance bar: at the flagship serving class the combined
-    bf16 attn+MLP windows exceed the scoped-VMEM envelope (two-kernel
-    composed route, reason naming the envelope), while int8/int4 weight
-    variants fit and dispatch the single-launch kernel."""
-    from paddle_tpu.ops.pallas.registry import KERNELS
-
-    def m(wq=None):
-        meta = fdb.decode_meta_dims(8, 1024, 16, 16, 64, 4096, 16, 24,
-                                    jnp.bfloat16, jnp.bfloat16, False,
-                                    weight_dtype=wq)
-        meta["interpret"] = False
-        return meta
-    assert KERNELS.dispatch("decode_block_fused", m())[0] == "composed"
-    assert KERNELS.dispatch("decode_block_fused",
-                            m("int8"))[0] == "pallas_block"
-    assert KERNELS.dispatch("decode_block_fused",
-                            m("int4"))[0] == "pallas_block"
-    rej = [r for r in KERNELS.explain("decode_block_fused", m())
-           if r["name"] == "pallas_block"][0]
-    assert not rej["supported"] and "envelope" in rej["reason"]
-
-
-def test_block_mode_resolver_contract():
-    """mode='block' pins the single-launch kernel through
-    resolve_decode_step; auto on CPU keeps the composed tier (per-stage
-    fns, bit parity); the two-stage resolver refuses 'block' with a
-    pointer at resolve_decode_step."""
-    meta = fdb.decode_meta(CFG, B=2, BS=4, MB=4,
-                           pool_dtype=jnp.float32, quant=False)
-    b_fn, a_fn, m_fn, names = fdb.resolve_decode_step(meta, "block")
-    assert b_fn is not None and a_fn is None and m_fn is None
-    assert names == {"block": "pallas_block", "attn": "pallas_block",
-                     "mlp": "pallas_block"}
-    b_fn, a_fn, m_fn, names = fdb.resolve_decode_step(meta, "auto")
-    assert b_fn is None and a_fn is not None and m_fn is not None
-    assert names == {"block": "composed", "attn": "unfused",
-                     "mlp": "unfused"}
-    with pytest.raises(ValueError, match="resolve_decode_step"):
-        fdb.resolve_decode_blocks(meta, "block")
-    with pytest.raises(ValueError, match="auto|pallas|ref|block"):
-        fdb.resolve_decode_step(meta, "bogus")
-    assert _fused_mode("block") == "block"
 
 
 def test_paged_decode_pages_per_step_invariant():
@@ -341,40 +308,40 @@ def test_registry_force_stacks():
 def test_dispatch_interpret_falls_back_unfused():
     """On CPU (interpret mode) auto dispatch must select the unfused
     composition — that is what makes the engine parity exact."""
-    meta = fdb.decode_meta(CFG, B=2, BS=4, MB=4,
-                           pool_dtype=jnp.float32, quant=False)
+    meta = fdb.decode_meta_dims(2, CFG.hidden_size,
+                                CFG.intermediate_size, jnp.float32)
     assert meta["interpret"]
-    attn_fn, mlp_fn, names = fdb.resolve_decode_blocks(meta, "auto")
-    assert names == {"attn": "unfused", "mlp": "unfused"}
-    assert attn_fn is fdb.attn_block_ref
-    assert mlp_fn is fdb.mlp_block_ref
-    # forcing still returns the Pallas variants (tests / audit catalog)
-    _, _, forced = fdb.resolve_decode_blocks(meta, "pallas")
-    assert forced == {"attn": "pallas_fused", "mlp": "pallas_fused"}
-    with pytest.raises(ValueError, match="auto|pallas|ref"):
-        fdb.resolve_decode_blocks(meta, "bogus")
+    assert KERNELS.dispatch("decode_mlp_block", meta) == \
+        ("unfused", fdb.mlp_block_ref)
+    assert KERNELS.dispatch(
+        "paged_attention_decode",
+        PA.decode_attention_meta(jnp.float32)) == \
+        ("xla", PA.paged_attention_decode_xla)
+    # a pin still returns the Pallas variants (tests / audit catalog),
+    # and dispatch() records what it handed out
+    with _pinned(*PALLAS), KERNELS.record() as picked:
+        assert KERNELS.dispatch("decode_mlp_block", meta)[0] == \
+            "pallas_fused"
+    assert picked == {"decode_mlp_block": "pallas_fused"}
+    assert fdb.launch_operands(picked) == {"decode_mlp_block": "index"}
 
 
 def test_vmem_budget_gates_fused_variant(monkeypatch):
     """Oversized block weights must fail the ``supports`` predicate with
     a reason naming the VMEM budget, even off interpret mode. The
-    budget rides IN the meta (decode_meta reads the env at build time —
-    i.e. at trace time, when the _PAGED_CACHE route key is computed),
-    so the shrunken-budget meta is rebuilt the way a retrace would."""
-    meta = fdb.decode_meta(CFG, B=2, BS=4, MB=4,
-                           pool_dtype=jnp.float32, quant=False)
-    meta["interpret"] = False
-    assert meta["vmem_budget"] == fdb._vmem_budget()
-    ok, why = fdb._supports_attn(dict(meta))
+    budget rides IN the meta (decode_meta_dims reads the env at build time —
+    i.e. at trace time, when the programs' route key is computed), so
+    the shrunken-budget meta is rebuilt the way a retrace would."""
+    def meta():
+        return dict(fdb.decode_meta_dims(2, CFG.hidden_size,
+                                         CFG.intermediate_size,
+                                         jnp.float32), interpret=False)
+    assert meta()["vmem_budget"] == fdb._vmem_budget()
+    ok, why = fdb._supports_mlp(meta())
     assert ok, why                               # tiny cfg fits
     monkeypatch.setenv("PADDLE_TPU_FUSED_VMEM_BUDGET", "1024")
-    meta = fdb.decode_meta(CFG, B=2, BS=4, MB=4,
-                           pool_dtype=jnp.float32, quant=False)
-    meta["interpret"] = False
-    assert meta["vmem_budget"] == 1024
-    ok, why = fdb._supports_attn(dict(meta))
-    assert not ok and "VMEM" in why
-    ok, why = fdb._supports_mlp(dict(meta))
+    assert meta()["vmem_budget"] == 1024
+    ok, why = fdb._supports_mlp(meta())
     assert not ok and "VMEM" in why
 
 
@@ -407,28 +374,34 @@ def _step_inputs(params, rng, B=2, BS=4, MB=4, quant=False):
 @pytest.mark.parametrize("quant", [False, True],
                          ids=["fp32", "int8"])
 def test_fused_step_bit_parity_and_pallas_closeness(params, quant):
-    """mode='auto' (composition on CPU) is BIT-identical to the
-    pre-fusion step; mode='pallas' (forced megakernels, interpret)
-    matches to fp32 roundoff — fp32 and int8 cache."""
+    """Auto dispatch (the compositions on CPU) is BIT-identical to the
+    step pinned to them; pinned to the Pallas launches (interpret) it
+    matches to fp32 roundoff — fp32 and int8 cache (whose attention
+    stays with the ``xla`` variant: the kernel takes no int8 pool)."""
     rng = np.random.RandomState(6 + quant)
     tok, kp, vp, bt, lens, scales = _step_inputs(params, rng,
                                                  quant=quant)
-    lg0, kp0, vp0 = _paged_decode_step(params, tok, CFG, kp, vp, bt,
-                                       lens, kv_scales=scales)
-    lg1, kp1, vp1 = _fused_decode_step(params, tok, CFG, kp, vp, bt,
-                                       lens, kv_scales=scales,
-                                       mode="auto")
+
+    def step():
+        return _decode_step(params, tok, CFG, kp, vp, bt, lens,
+                            kv_scales=scales)
+    with _pinned(*REFERENCE):
+        lg0, kp0, vp0 = step()
+    lg1, kp1, vp1 = step()
     np.testing.assert_array_equal(np.asarray(lg0), np.asarray(lg1))
     np.testing.assert_array_equal(np.asarray(kp0), np.asarray(kp1))
     np.testing.assert_array_equal(np.asarray(vp0), np.asarray(vp1))
-    lg2, kp2, vp2 = _fused_decode_step(params, tok, CFG, kp, vp, bt,
-                                       lens, kv_scales=scales,
-                                       mode="pallas")
+    with _pinned(None if quant else "pallas", "pallas_fused"), \
+            KERNELS.record() as picked:
+        lg2, kp2, vp2 = step()
+    assert picked == {"paged_attention_decode":
+                      "xla" if quant else "pallas",
+                      "decode_mlp_block": "pallas_fused"}
     np.testing.assert_allclose(np.asarray(lg2), np.asarray(lg0),
                                atol=5e-5, rtol=1e-5)
-    # the megakernel's QKV+rope op order differs from the composition
-    # by fp32 roundoff, so the written pool values are 1-ulp close (and
-    # EXACTLY equal under int8, where quantization re-snaps them)
+    # a later layer's token is written from a residual stream that
+    # differs by fp32 roundoff, so the written pool values are 1-ulp
+    # close (and EXACTLY equal under int8, where quantization re-snaps)
     assert_pool = np.testing.assert_array_equal if quant else \
         functools.partial(np.testing.assert_allclose, atol=1e-6,
                           rtol=1e-5)
@@ -436,128 +409,123 @@ def test_fused_step_bit_parity_and_pallas_closeness(params, quant):
     assert_pool(np.asarray(vp2), np.asarray(vp0))
 
 
+def _stream(rng, n=22):
+    specs = [(int(rng.randint(3, 15)), int(rng.randint(2, 6)))
+             for _ in range(n)]
+    return [(rng.randint(0, 97, (S,)).astype(np.int32), N)
+            for S, N in specs]
+
+
+def _serve(eng, stream):
+    rs = [eng.submit(p, GenerationConfig(max_new_tokens=N, greedy=True))
+          for p, N in stream]
+    eng.drain()
+    assert all(r.done for r in rs)
+    return [r.tokens for r in rs]
+
+
 @pytest.mark.parametrize("cdt", [None, "int8"], ids=["fp32", "int8"])
 def test_engine_stream_fused_vs_unfused_bit_parity(params, cdt):
-    """>=20-request mixed-length greedy stream: the fused-decode engine
-    (default-on flag) must produce bit-identical tokens to an engine
-    pinned to the pre-fusion step, and keep the zero-retrace steady
+    """>=20-request mixed-length greedy stream: the engine as every
+    caller builds it must produce bit-identical tokens to an engine
+    pinned to the XLA compositions, and keep the zero-retrace steady
     state (1 decode program, <=1 trace per prefill bucket)."""
-    rng = np.random.RandomState(7)
-    specs = [(int(rng.randint(3, 15)), int(rng.randint(2, 6)))
-             for _ in range(22)]
-    prompts = [rng.randint(0, 97, (S,)).astype(np.int32)
-               for S, _ in specs]
-
-    def run(fused):
-        eng = _engine(params, cache_dtype=cdt, fused_decode=fused)
-        rs = [eng.submit(p, GenerationConfig(max_new_tokens=N,
-                                             greedy=True))
-              for p, (_, N) in zip(prompts, specs)]
-        eng.drain()
-        assert all(r.done for r in rs)
-        return eng, [r.tokens for r in rs]
-
-    eng_f, toks_f = run(None)      # flag default: fused auto
-    eng_u, toks_u = run(False)     # pinned pre-fusion step
+    stream = _stream(np.random.RandomState(7))
+    eng_f = _engine(params, cache_dtype=cdt)
+    toks_f = _serve(eng_f, stream)
+    eng_u = _engine(params, cache_dtype=cdt)
+    with _pinned(*REFERENCE):
+        toks_u = _serve(eng_u, stream)
     assert toks_f == toks_u
     c = eng_f.counters
     assert c["requests_completed"] == 22
     assert c["decode_traces"] == 1, c
     assert set(c["prefill_traces"]) <= {8, 16}
     assert all(n <= 1 for n in c["prefill_traces"].values()), c
-    assert eng_f.metrics()["decode_variant"]["mode"] == "auto"
-    assert eng_u.decode_variant == {"mode": "unfused",
-                                    "block": "composed",
-                                    "attn": "unfused",
-                                    "mlp": "unfused",
-                                    "operands": {}}
+    assert eng_f.metrics()["decode_variant"] == eng_u.decode_variant == {
+        "attn": "xla", "mlp": "unfused", "operands": {}}
 
 
 def test_engine_forced_pallas_smoke(params):
-    """fused_decode='pallas' runs the actual megakernel decode program
-    (interpret mode on CPU) end to end and names its program spec for
-    the audit gate."""
-    eng = _engine(params, capacity=2, prefill_buckets=(8,),
-                  fused_decode="pallas")
-    assert eng.decode_variant == {"mode": "pallas",
-                                  "block": "composed",
-                                  "attn": "pallas_fused",
-                                  "mlp": "pallas_fused",
-                                  "operands": {
-                                      "decode_attn_block": "slice",
-                                      "decode_mlp_block": "index"}}
-    assert any(s.name == "serving_decode_fused"
-               for s in eng.program_specs(register=False))
+    """Both launches pinned: the engine runs the actual Pallas decode
+    program (interpret mode on CPU) end to end, and reports what its
+    trace picked — nothing before it has traced."""
+    eng = _engine(params, capacity=2, prefill_buckets=(8,))
+    assert eng.decode_variant == {"attn": None, "mlp": None,
+                                  "operands": {}}
     rng = np.random.RandomState(8)
-    rs = [eng.submit(rng.randint(0, 97, (6,)).astype(np.int32),
-                     GenerationConfig(max_new_tokens=3, greedy=True))
-          for _ in range(2)]
-    eng.drain()
+    with _pinned(*PALLAS):
+        rs = [eng.submit(rng.randint(0, 97, (6,)).astype(np.int32),
+                         GenerationConfig(max_new_tokens=3, greedy=True))
+              for _ in range(2)]
+        eng.drain()
+        # the audit's clone traces under the same pins and leaves the
+        # live report alone
+        assert any(s.name == "serving_decode"
+                   for s in eng.program_specs(register=False))
     assert all(r.done and len(r.tokens) == 3 for r in rs)
     assert eng.counters["decode_traces"] == 1
+    assert eng.decode_variant == {
+        "attn": "pallas", "mlp": "pallas_fused",
+        "operands": {"paged_attention_decode": "index",
+                     "decode_mlp_block": "index"}}
 
 
-def test_engine_forced_block_smoke(params):
-    """fused_decode='block' runs the single-launch decode program end
-    to end (interpret mode on CPU), names the serving_decode_block spec
-    for the audit gate, and its greedy tokens match the auto engine
-    (the composed tier the block kernel is a roundoff variant of)."""
-    eng = _engine(params, capacity=2, prefill_buckets=(8,),
-                  fused_decode="block")
-    assert eng.decode_variant == {"mode": "block",
-                                  "block": "pallas_block",
-                                  "attn": "pallas_block",
-                                  "mlp": "pallas_block",
-                                  "operands": {
-                                      "decode_block_fused": "slice"}}
-    assert any(s.name == "serving_decode_block"
-               for s in eng.program_specs(register=False))
-    rng = np.random.RandomState(12)
-    prompts = [rng.randint(0, 97, (6,)).astype(np.int32)
-               for _ in range(2)]
-    g = GenerationConfig(max_new_tokens=3, greedy=True)
-    rs = [eng.submit(p, g) for p in prompts]
-    eng.drain()
-    assert all(r.done and len(r.tokens) == 3 for r in rs)
+@pytest.mark.parametrize("op,variant", [
+    ("paged_attention_decode", "pallas"),
+    ("decode_mlp_block", "pallas_fused")])
+def test_force_pin_retraces_decode_programs(params, op, variant):
+    """Dispatch reads the pin at TRACE time: a pin on either decode op
+    must retrace the engine's decode program and miss ``_PAGED_CACHE``,
+    never replay the program the unpinned route compiled."""
+    eng = _engine(params, capacity=2, prefill_buckets=(8,))
+    stream = _stream(np.random.RandomState(11), n=2)
+    base = _serve(eng, stream)
     assert eng.counters["decode_traces"] == 1
-    eng_a = _engine(params, capacity=2, prefill_buckets=(8,))
-    rs_a = [eng_a.submit(p, g) for p in prompts]
-    eng_a.drain()
-    assert [r.tokens for r in rs] == [r.tokens for r in rs_a]
+    slot = {"paged_attention_decode": "attn", "decode_mlp_block": "mlp"}
+    assert eng.decode_variant[slot[op]] != variant
+    with KERNELS.force(op, variant):
+        pinned = _serve(eng, stream)
+        assert eng.counters["decode_traces"] == 2
+        assert eng.decode_variant[slot[op]] == variant
+        assert op in eng.decode_variant["operands"]
+        _serve(eng, stream)                       # same pin: replayed
+        assert eng.counters["decode_traces"] == 2
+    assert pinned == base                         # greedy, roundoff apart
+
+    gen = GenerationConfig(max_new_tokens=4, greedy=True)
+    plain = G._paged_chunk_runner(CFG, gen)
+    assert G._paged_chunk_runner(CFG, gen) is plain
+    with KERNELS.force(op, variant):
+        assert G._paged_chunk_runner(CFG, gen) is not plain
+    assert G._paged_chunk_runner(CFG, gen) is plain
 
 
-def test_block_mode_is_single_device(params):
-    """The single-launch kernel runs outside shard_map: a mesh engine
-    pinned to 'block' is rejected at construction, and the TP decode
-    body refuses the mode before tracing anything."""
-    from paddle_tpu.inference import ServingMesh
-    from paddle_tpu.inference import tp as tp_mod
-    with pytest.raises(ValueError, match="single-device"):
-        _engine(params, mesh=ServingMesh.make(tp=2),
-                fused_decode="block")
-    with pytest.raises(ValueError, match="single-device"):
-        tp_mod._tp_decode_step(params, None, CFG, None, None, None,
-                               None, fused="block")
+@pytest.mark.parametrize("call", ["ServingEngine", "generate_paged"])
+def test_removed_option_is_refused_by_name(params, call):
+    """``fused_decode=`` is gone, not swallowed: which kernel runs is
+    the registry's choice, and a test's pin."""
+    prompts = jnp.zeros((1, 4), jnp.int32)
+    with pytest.raises(TypeError, match="fused_decode"):
+        if call == "ServingEngine":
+            ServingEngine(params, CFG, fused_decode="pallas")
+        else:
+            generate_paged(params, prompts, CFG, fused_decode=False)
 
 
 def test_generate_paged_fused_flag_parity(params):
     rng = np.random.RandomState(9)
     prompts = jnp.asarray(rng.randint(0, 97, (2, 8)), jnp.int32)
     g = GenerationConfig(max_new_tokens=6, greedy=True)
-    base = np.asarray(generate_paged(params, prompts, CFG, g,
-                                     fused_decode=False))
-    fused = np.asarray(generate_paged(params, prompts, CFG, g))
-    np.testing.assert_array_equal(base, fused)
-    # the forced single-launch route decodes the same greedy tokens
-    # (roundoff-level logits variant of the composition)
-    block = np.asarray(generate_paged(params, prompts, CFG, g,
-                                      fused_decode="block"))
-    np.testing.assert_array_equal(base, block)
-    with pytest.raises(ValueError, match="fused_decode"):
-        _fused_mode("bogus")
-    assert _fused_mode(None) == "auto"       # flag defaults on
-    assert _fused_mode(True) == "auto"
-    assert _fused_mode(False) is False
+    with _pinned(*REFERENCE):
+        base = np.asarray(generate_paged(params, prompts, CFG, g))
+    auto = np.asarray(generate_paged(params, prompts, CFG, g))
+    np.testing.assert_array_equal(base, auto)
+    # the Pallas launches decode the same greedy tokens (roundoff-level
+    # logits variant of the compositions)
+    with _pinned(*PALLAS):
+        pallas = np.asarray(generate_paged(params, prompts, CFG, g))
+    np.testing.assert_array_equal(base, pallas)
 
 
 # ---------------------------------------------------------------------------

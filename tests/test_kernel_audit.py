@@ -106,8 +106,8 @@ def test_catalog_captures_every_declared_kernel(catalog_reports):
         "rms_norm_fwd", "rms_norm_bwd", "residual_rms_norm_fwd",
         "layer_norm_fwd", "fused_adamw", "paged_attention_decode",
         "flash_attention_fwd", "flash_attention_bwd_dq",
-        "flash_attention_bwd_dkv", "decode_attn_block",
-        "decode_mlp_block", "decode_block_fused", "prefill_attn_block",
+        "flash_attention_bwd_dkv",
+        "decode_mlp_block", "prefill_attn_block",
         "linear_ce_fwd", "linear_ce_bwd_dx", "linear_ce_bwd_dh",
         "swiglu_fwd", "swiglu_bwd",
         # PR 27: the Mamba-2 state pool's launches (ops/pallas/mamba2.py)
@@ -376,7 +376,7 @@ def test_cli_bad_invocations_exit_3_and_list_names_cases():
                 "--quiet").returncode == 3
     names = _run("--list").stdout.split()
     assert "rms_norm@tiny" in names
-    assert "decode_attn_block@flagship_serving_int8" in names
+    assert "decode_mlp_block@flagship_serving_int8_weights" in names
     assert "kernel_registry" in names
 
 
@@ -451,31 +451,17 @@ def _diff_fused_adamw():
     return run, ("fused_adamw",)
 
 
-def _decode_inputs(hd=16):
-    B, D, H, KV, BS, MB = 2, 32, 2, 2, 8, 3          # MB odd: clamp edge
+def _diff_paged_attention_decode():
+    B, H, KV, hd, BS, MB = 2, 4, 2, 16, 8, 3         # MB odd, GQA 2
     N = B * MB + 1
-    x, nw = _f32(B, D), jnp.abs(_f32(D)) + 0.5
-    wq, wk, wv = _f32(D, H * hd), _f32(D, KV * hd), _f32(D, KV * hd)
-    wo = _f32(H * hd, D)
-    T = MB * BS + 1
-    inv = 1.0 / (10000.0 ** (np.arange(0, hd, 2) / hd))
-    ang = np.arange(T)[:, None] * inv[None, :]
-    sin = jnp.asarray(np.sin(ang), jnp.float32)
-    cos = jnp.asarray(np.cos(ang), jnp.float32)
-    kp, vp = _f32(N, BS, KV, hd), _f32(N, BS, KV, hd)
+    q, kp, vp = _f32(B, H, hd), _f32(N, BS, KV, hd), _f32(N, BS, KV, hd)
     bt = jnp.asarray(
         _RNG.permutation(N - 1)[: B * MB].reshape(B, MB) + 1, jnp.int32)
-    ln = jnp.asarray([5, BS * MB - 1], jnp.int32)    # ragged live pages
-    return (x, nw, wq, wk, wv, wo, sin, cos, kp, vp, bt, ln)
-
-
-def _diff_decode_attn_block():
-    args = _decode_inputs()
+    ln = jnp.asarray([5, BS * MB], jnp.int32)    # ragged, and a full table
 
     def run(fn):
-        xo, kn, vn = fn(*args)
-        return xo, kn, vn
-    return run, ("decode_attn_block",)
+        return fn(q, kp, vp, bt, ln)
+    return run, ("paged_attention_decode",)
 
 
 def _diff_decode_mlp_block():
@@ -486,21 +472,6 @@ def _diff_decode_mlp_block():
     def run(fn):
         return fn(*args)
     return run, ("decode_mlp_block",)
-
-
-def _diff_decode_block_fused():
-    # the single-launch block at the same clamp-edge decode shapes,
-    # plus the MLP half on a ragged (non-divisor-tile) intermediate
-    (x, nw, wq, wk, wv, wo, sin, cos, kp, vp, bt, ln) = _decode_inputs()
-    D, F = 32, 96
-    pw = jnp.abs(_f32(D)) + 0.5
-    wg, wu, wd = _f32(D, F), _f32(D, F), _f32(F, D)
-
-    def run(fn):
-        xo, kn, vn = fn(x, nw, wq, wk, wv, wo, pw, wg, wu, wd, sin,
-                        cos, kp, vp, bt, ln)
-        return xo, kn, vn
-    return run, ("decode_block_fused",)
 
 
 def _diff_prefill_attn_block():
@@ -543,9 +514,8 @@ _DIFF_CASES = {
     "fused_linear_ce": _diff_fused_linear_ce,
     "fused_swiglu": _diff_fused_swiglu,
     "fused_adamw": _diff_fused_adamw,
-    "decode_attn_block": _diff_decode_attn_block,
+    "paged_attention_decode": _diff_paged_attention_decode,
     "decode_mlp_block": _diff_decode_mlp_block,
-    "decode_block_fused": _diff_decode_block_fused,
     "prefill_attn_block": _diff_prefill_attn_block,
     "prefill_mlp_block": _diff_prefill_mlp_block,
 }
@@ -561,8 +531,7 @@ def test_differential_sweep_covers_every_registered_op():
 def test_pallas_variant_matches_fallback_at_boundary_shapes(op):
     build = _DIFF_CASES[op]
     run, (op_name,) = build()
-    # the highest-priority variant is the Pallas one ("pallas_fused"
-    # for the per-stage ops, "pallas_block" for the single-launch op)
+    # the highest-priority variant is the Pallas one
     pname = KERNELS.variants(op_name)[0].name
     with KERNELS.force(op_name, pname):
         got = run(KERNELS.variant(op_name, pname).fn)
@@ -613,9 +582,32 @@ def test_supports_boundary_exact_vmem_budget_edge():
         8, 1024, 4096, 2, bneed - 1)[0] == 256
 
 
-def test_supports_boundary_hd_not_multiple_of_8():
-    meta = fdb.decode_meta_dims(2, 32, 2, 2, 20, 96, 8, 4,
-                                jnp.float32, jnp.float32, False)
-    meta["interpret"] = False
-    ok, why = fdb._supports_attn(meta)
-    assert not ok and "head_dim" in why and "8" in why
+def test_paged_attention_refusals_each_name_their_reason():
+    """Every way ``paged_attention_decode`` stays off its kernel says
+    why: the interpreter, a backend that is no TPU, int8 pools, and (by
+    the registry, for every variant tagged "pallas") a program GSPMD
+    partitions."""
+    from paddle_tpu.ops import paged_attention as pa
+    from paddle_tpu.ops.pallas import _util
+
+    def refusal(**kw):
+        meta = {"backend": "tpu", "interpret": False,
+                "pool_dtype": "bfloat16", **kw}
+        rows = {r["name"]: r for r in
+                KERNELS.explain("paged_attention_decode", meta)}
+        assert rows["xla"]["selected"] != rows["pallas"]["selected"]
+        return None if rows["pallas"]["selected"] \
+            else rows["pallas"]["reason"]
+
+    assert set(pa.decode_attention_meta(jnp.bfloat16)) == {
+        "backend", "interpret", "pool_dtype"}
+    assert refusal() is None
+    assert "interpret" in refusal(interpret=True)
+    assert "'cpu'" in refusal(backend="cpu")
+    assert "int8 pools" in refusal(pool_dtype="int8")
+    _util.set_force_interpret(False)
+    try:
+        with _util.gspmd_program(4):
+            assert "GSPMD" in refusal()
+    finally:
+        _util.set_force_interpret(None)

@@ -121,14 +121,11 @@ def test_metrics_schema_frozen_disabled(params):
     _run_stream(eng)
     m = eng.metrics()
     assert set(m.keys()) == BASE_KEYS
-    # r20: decode_variant gained the single-launch "block" slot beside
-    # the per-stage names — extended, not loosened; PR 26: "operands",
-    # how each Pallas launch of the layer loop gets its layer (by index
-    # or as a slice); off the TPU the compositions launch nothing
-    assert set(m["decode_variant"].keys()) == {"mode", "block", "attn",
-                                               "mlp", "operands"}
-    assert m["decode_variant"]["operands"] == {}
-    assert m["decode_variant"]["block"] in ("pallas_block", "composed")
+    # the two launches' variants as the registry picked them for the
+    # trace, and PR 26's "operands": how each Pallas launch of the layer
+    # loop gets its layer; off the TPU the compositions launch nothing
+    assert m["decode_variant"] == {"attn": "xla", "mlp": "unfused",
+                                   "operands": {}}
     assert m["weight_quant_variant"] == {"mode": "off"}
 
 
@@ -137,9 +134,7 @@ def test_metrics_schema_frozen_enabled(params):
     _run_stream(eng)
     m = eng.metrics()
     assert set(m.keys()) == BASE_KEYS | OBS_KEYS
-    assert set(m["decode_variant"].keys()) == {"mode", "block", "attn",
-                                               "mlp", "operands"}
-    assert m["decode_variant"]["block"] in ("pallas_block", "composed")
+    assert set(m["decode_variant"].keys()) == {"attn", "mlp", "operands"}
     assert set(m["latency"].keys()) == LATENCY_KEYS
     for name, snap in m["latency"].items():
         assert set(snap.keys()) == HIST_KEYS, name
@@ -208,8 +203,7 @@ def test_metrics_roofline_schema(params, monkeypatch):
         roof = eng.metrics()["roofline"]
         assert set(roof.keys()) == {"variants", "peak_hbm_bw",
                                     "peak_source", "active", "layers"}
-        assert set(roof["variants"].keys()) == {"pallas_block",
-                                                "pallas_fused",
+        assert set(roof["variants"].keys()) == {"pallas_fused",
                                                 "unfused"}
         for row in roof["variants"].values():
             assert set(row.keys()) == {"bytes_per_step",
@@ -219,9 +213,9 @@ def test_metrics_roofline_schema(params, monkeypatch):
             assert row["step_us_at_peak_bw"] > 0
         assert roof["active"] in roof["variants"]
         assert roof["layers"] >= 1
-        # the single-launch arm re-streams MLP tiles per batch row, so
-        # its modeled step traffic can never undercut the two-kernel arm
-        assert roof["variants"]["pallas_block"]["bytes_per_step"] >= \
+        # the compositions materialise their intermediates, so their
+        # modeled step traffic can never undercut the two launches'
+        assert roof["variants"]["unfused"]["bytes_per_step"] >= \
             roof["variants"]["pallas_fused"]["bytes_per_step"]
         # only the obs-enabled engine has a measured mean to attribute
         if obs:
@@ -506,8 +500,7 @@ def test_enabled_stream_parity_traces_and_exports(monkeypatch, params,
     dsteps = [r for r in recs
               if r["kind"] == "event" and r["name"] == "decode_step"]
     assert dsteps
-    assert all(r.get("decode_variant") in ("pallas_block",
-                                           "pallas_fused", "unfused")
+    assert all(r.get("decode_variant") in ("pallas_fused", "unfused")
                for r in dsteps)
     import sys
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
@@ -523,7 +516,7 @@ def test_enabled_stream_parity_traces_and_exports(monkeypatch, params,
     # r20 per-variant decode attribution: one bucket per variant seen,
     # counts covering every stamped decode_step event
     dec = summary["decode"]["variants"]
-    assert set(dec) <= {"pallas_block", "pallas_fused", "unfused"}
+    assert set(dec) <= {"pallas_fused", "unfused"}
     assert sum(v["count"] for v in dec.values()) == len(dsteps)
     # r21: arms the meta roofline header models also carry modeled
     # bytes/step + the peak-BW step-time floor (and the measured/floor

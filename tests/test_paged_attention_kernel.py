@@ -5,8 +5,7 @@ in HBM and fetched by the kernel itself.
 The reference for "the same bits" is the launch this kernel replaced,
 kept here as a test's reference: a grid of (slots, pages of the table)
 that visits every page of ``max_seq_len`` and sends each live one, in
-page order, through ``online_softmax_page_update`` (the body shared
-with the fused attention kernel). The reference for "the same
+page order, through ``online_softmax_page_update`` (the kernel's own reduction body). The reference for "the same
 attention" is the XLA gather composition."""
 import functools
 import math
@@ -22,7 +21,7 @@ from jax.experimental.pallas import tpu as pltpu
 import paddle_tpu  # noqa: F401 — x64 mode, as every kernel caller has it
 from paddle_tpu.ops.paged_attention import paged_attention_decode_xla
 from paddle_tpu.ops.pallas import paged_attention as pa
-from paddle_tpu.ops.pallas._util import (clamped_page_index, no_x64,
+from paddle_tpu.ops.pallas._util import (no_x64,
                                          online_softmax_page_update)
 
 BS, P, MB = 8, 2, 5
@@ -53,6 +52,17 @@ def _table_grid_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
         o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
+def _clamped_page(bs):
+    """The reference grid's page fetch: dead pages clamp to the slot's
+    last live page (garbage table entries stay out of the fetch)."""
+    def f(b, pg, bt_ref, len_ref):
+        last = jnp.maximum(len_ref[b] - jnp.int32(1),
+                           jnp.int32(0)) // jnp.int32(bs)
+        return (bt_ref[b, jnp.minimum(pg.astype(jnp.int32), last)],
+                0, 0, 0)
+    return f
+
+
 @no_x64
 def _page_by_page(q, k_pool, v_pool, bt, lens, scale=None):
     """The replaced launch: one grid step a page of the table, live or
@@ -61,7 +71,7 @@ def _page_by_page(q, k_pool, v_pool, bt, lens, scale=None):
     bs, KV = k_pool.shape[-3:-1]
     mb = bt.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-    page = pl.BlockSpec((1, bs, KV, hd), clamped_page_index(bs, 1, 0))
+    page = pl.BlockSpec((1, bs, KV, hd), _clamped_page(bs))
     row = pl.BlockSpec((1, H, hd), lambda b, pg, *_: (b, 0, 0))
     out = pl.pallas_call(
         functools.partial(_table_grid_kernel, scale=scale, bs=bs, kv=KV,

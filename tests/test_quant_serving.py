@@ -21,8 +21,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.models import llama
 from paddle_tpu.inference import GenerationConfig, ServingEngine
-from paddle_tpu.inference.generation import (_fused_decode_step,
-                                             _paged_decode_step,
+from paddle_tpu.inference.generation import (_decode_step,
                                              generate_paged)
 from paddle_tpu.ops.pallas import fused_decode_block as fdb
 from paddle_tpu.ops.pallas import fused_prefill_block as fpb
@@ -197,53 +196,6 @@ def test_weight_hbm_bytes_reduction(params):
 # ---------------------------------------------------------------------------
 # kernel parity (forced Pallas, interpret) vs the dequant composition
 # ---------------------------------------------------------------------------
-def _attn_case(rng, B, D, KV, groups, hd, BS, MB, bits):
-    H = KV * groups
-    N = B * MB + 2
-    dt = jnp.float32
-    mk = lambda *s: jnp.asarray(rng.randn(*s) * 0.07, dt)  # noqa: E731
-    x = mk(B, D)
-    nw = jnp.asarray(rng.rand(D) + 0.5, dt)
-    q = lambda w: ptq.quantize_leaf(w, bits)               # noqa: E731
-    wq, wk, wv = q(mk(D, H * hd)), q(mk(D, KV * hd)), q(mk(D, KV * hd))
-    wo = q(mk(H * hd, D))
-    inv = 1.0 / (10000.0 ** (np.arange(0, hd, 2) / hd))
-    t = np.arange(BS * MB)[:, None] * inv[None, :]
-    sin = jnp.asarray(np.sin(t), jnp.float32)
-    cos = jnp.asarray(np.cos(t), jnp.float32)
-    bt = jnp.asarray(rng.permutation(N)[:B * MB].reshape(B, MB),
-                     jnp.int32)
-    lens = jnp.asarray([int(rng.randint(1, BS * MB)), 0][:B], jnp.int32)
-    kp, vp = mk(N, BS, KV, hd), mk(N, BS, KV, hd)
-    return (x, nw, wq, wk, wv, wo, sin, cos, kp, vp, bt, lens)
-
-
-@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
-def test_attn_kernel_parity_quantized_weights(bits):
-    """Randomized ragged shapes: the quantized-weight megakernel
-    (in-register dequant, epilogue scales) vs the dequantize-then-
-    matmul composition — fp32 roundoff only, both sides reading the
-    SAME quantized tree."""
-    for seed in (0, 1):
-        rng = np.random.RandomState(seed + bits)
-        B = int(rng.randint(1, 3))
-        KV = int(rng.choice([1, 2]))
-        groups = int(rng.choice([1, 2]))
-        hd = int(rng.choice([8, 16]))
-        BS = int(rng.choice([4, 8]))
-        MB = int(rng.randint(2, 5))
-        D = int(rng.choice([32, 48, 64]))       # 48: D % 32 != 0 edge
-        args = _attn_case(rng, B, D, KV, groups, hd, BS, MB, bits)
-        xf, kf, vf = fdb.fused_attn_block_pallas(*args)
-        xr, kr, vr = fdb.attn_block_ref(*args)
-        np.testing.assert_allclose(np.asarray(xf), np.asarray(xr),
-                                   atol=3e-5, rtol=1e-5)
-        np.testing.assert_allclose(np.asarray(kf), np.asarray(kr),
-                                   atol=3e-5, rtol=1e-5)
-        np.testing.assert_allclose(np.asarray(vf), np.asarray(vr),
-                                   atol=3e-5, rtol=1e-5)
-
-
 @pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
 @pytest.mark.parametrize("D,F", [(32, 96), (64, 256)])
 def test_mlp_kernel_parity_quantized_weights(bits, D, F):
@@ -308,21 +260,16 @@ def test_prefill_kernel_parity_quantized_weights(bits):
 # ---------------------------------------------------------------------------
 def test_flagship_dispatch_int8_and_int4():
     """Acceptance bar: BOTH quantized classes dispatch the fused
-    variants on the flagship serving shape class (D=1024/H=16/hd=64)
-    off interpret mode — weight quant widens the VMEM fit, never
-    shrinks it."""
+    variants on the flagship serving shape class (D=1024/F=4096) off
+    interpret mode — weight quant widens the VMEM fit, never shrinks
+    it."""
     for wd in ("int8", "int4"):
-        meta = fdb.decode_meta_dims(8, 1024, 16, 16, 64, 4096, 16, 24,
-                                    jnp.bfloat16, jnp.bfloat16, False,
+        meta = fdb.decode_meta_dims(8, 1024, 4096, jnp.bfloat16,
                                     weight_dtype=wd)
         meta["interpret"] = False
-        ok, why = fdb._supports_attn(dict(meta))
-        assert ok, (wd, why)
         ok, why = fdb._supports_mlp(dict(meta))
         assert ok, (wd, why)
         from paddle_tpu.ops.pallas.registry import KERNELS
-        assert KERNELS.dispatch("decode_attn_block", meta)[0] == \
-            "pallas_fused"
         assert KERNELS.dispatch("decode_mlp_block", meta)[0] == \
             "pallas_fused"
         # the prefill kernel serves heads that fill a 128-lane tile
@@ -338,42 +285,36 @@ def test_dispatch_reason_strings_and_int4_odd_reject():
     """VMEM-fallback + packing-constraint reasons are human-readable;
     an odd hidden size cleanly rejects int4 (falls back, never packs
     garbage)."""
-    meta = fdb.decode_meta_dims(2, 36, 2, 2, 20, 96, 8, 4,
-                                jnp.float32, jnp.float32, False,
-                                weight_dtype="int4")
-    meta["interpret"] = False
-    ok, why = fdb._supports_attn(dict(meta))
-    assert not ok and "head_dim" in why            # hd=20 rejects first
-    meta2 = fdb.decode_meta_dims(2, 33, 2, 2, 16, 96, 8, 4,
-                                 jnp.float32, jnp.float32, False,
+    from paddle_tpu.ops.pallas.registry import KERNELS
+    meta2 = fdb.decode_meta_dims(2, 33, 96, jnp.float32,
                                  weight_dtype="int4")
     meta2["interpret"] = False
-    ok, why = fdb._supports_attn(dict(meta2))
-    assert not ok and "even" in why and "int4" in why
     ok, why = fdb._supports_mlp(dict(meta2))
-    assert not ok and "even" in why
+    assert not ok and "even" in why and "int4" in why
     # the VMEM budget reason still names the budget under weight quant
-    meta3 = fdb.decode_meta_dims(8, 1024, 16, 16, 64, 4096, 16, 24,
-                                 jnp.bfloat16, jnp.bfloat16, False,
+    meta3 = fdb.decode_meta_dims(8, 1024, 4096, jnp.bfloat16,
                                  weight_dtype="int8")
     meta3["interpret"] = False
     meta3["vmem_budget"] = 1024
-    ok, why = fdb._supports_attn(dict(meta3))
+    ok, why = fdb._supports_mlp(dict(meta3))
     assert not ok and "VMEM" in why
     # interpret mode: auto dispatch falls back with a reason
-    meta4 = fdb.decode_meta(CFG, B=2, BS=4, MB=4,
-                            pool_dtype=jnp.float32, quant=False,
-                            weight_dtype="int8")
+    meta4 = fdb.decode_meta_dims(2, CFG.hidden_size,
+                                 CFG.intermediate_size, jnp.float32,
+                                 weight_dtype="int8")
     assert meta4["interpret"] and meta4["weight_dtype"] == "int8"
-    _, _, names = fdb.resolve_decode_blocks(meta4, "auto")
-    assert names == {"attn": "unfused", "mlp": "unfused"}
+    (row,) = [r for r in KERNELS.explain("decode_mlp_block", meta4)
+              if r["name"] == "pallas_fused"]
+    assert not row["supported"] and "interpret" in row["reason"]
+    assert KERNELS.dispatch("decode_mlp_block", meta4)[0] == "unfused"
 
 
 def test_weight_dtype_rides_in_declared_cache_keys():
     """The DISPATCH_KEY_GAP contract: weight_dtype is a declared cache
-    key for all four serving ops (the registry lint gates the reads)."""
+    key for the three serving ops that stream weight tiles (the
+    registry lint gates the reads)."""
     from paddle_tpu.ops.pallas.registry import KERNELS
-    for op in ("decode_attn_block", "decode_mlp_block",
+    for op in ("decode_mlp_block",
                "prefill_attn_block", "prefill_mlp_block"):
         fields, _ = KERNELS.cache_key_decl(op)
         assert "weight_dtype" in fields, op
@@ -404,21 +345,25 @@ def _step_inputs(rng, B=2, BS=4, MB=4):
 @pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
 def test_quantized_fallback_bit_identical_to_dequant_matmul(params,
                                                             bits):
-    """The acceptance contract: on CPU (dispatch -> unfused) the fused
-    decode step over a quantized tree is BIT-identical to the plain
-    unfused step over the same tree — both are dequantize-then-matmul
+    """The acceptance contract: on CPU (dispatch -> the compositions)
+    the decode step over a quantized tree is BIT-identical to the step
+    pinned to them over the same tree — both are dequantize-then-matmul
     through the one shared helper."""
+    from paddle_tpu.ops.pallas.registry import KERNELS
     qp = ptq.quantize_weights(params, bits=bits)
     rng = np.random.RandomState(6 + bits)
     tok, kp, vp, bt, lens = _step_inputs(rng)
-    lg0, kp0, vp0 = _paged_decode_step(qp, tok, CFG, kp, vp, bt, lens)
-    lg1, kp1, vp1 = _fused_decode_step(qp, tok, CFG, kp, vp, bt, lens,
-                                       mode="auto")
+    with KERNELS.force("paged_attention_decode", "xla"), \
+            KERNELS.force("decode_mlp_block", "unfused"):
+        lg0, kp0, vp0 = _decode_step(qp, tok, CFG, kp, vp, bt, lens)
+    lg1, kp1, vp1 = _decode_step(qp, tok, CFG, kp, vp, bt, lens)
     np.testing.assert_array_equal(np.asarray(lg0), np.asarray(lg1))
     np.testing.assert_array_equal(np.asarray(kp0), np.asarray(kp1))
-    # and the forced megakernel route stays roundoff-close
-    lg2, _, _ = _fused_decode_step(qp, tok, CFG, kp, vp, bt, lens,
-                                   mode="pallas")
+    # and the pinned Pallas launches (the MLP's in-kernel dequant)
+    # stay roundoff-close
+    with KERNELS.force("paged_attention_decode", "pallas"), \
+            KERNELS.force("decode_mlp_block", "pallas_fused"):
+        lg2, _, _ = _decode_step(qp, tok, CFG, kp, vp, bt, lens)
     np.testing.assert_allclose(np.asarray(lg2), np.asarray(lg0),
                                atol=5e-5, rtol=1e-5)
 
@@ -454,7 +399,7 @@ def test_engine_stream_int8_weights(params):
     m = eng_q.metrics()
     assert m["retrace_warnings"] == 0
     assert m["weight_quant_variant"]["mode"] == "int8"
-    assert m["weight_quant_variant"]["attn"] == "unfused"  # CPU route
+    assert m["weight_quant_variant"]["attn"] == "xla"      # CPU route
     assert eng_f.metrics()["weight_quant_variant"] == {"mode": "off"}
     total = sum(len(t) for t in toks_f)
     flips = sum(a != b for tf, tq in zip(toks_f, toks_q)

@@ -307,10 +307,10 @@ def test_generate_paged_int8_cache_close_logits_and_runs():
     lens = jnp.full((B,), S, jnp.int32)
     tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
 
-    lg_bf, _, _ = G._paged_decode_step(params, tok, cfg, kp, vp,
+    lg_bf, _, _ = G._decode_step(params, tok, cfg, kp, vp,
                                        tables, lens)
     kq, vq, ks, vs = jax.vmap(quantize_pools)(kp, vp)
-    lg_i8, kq2, _ = G._paged_decode_step(params, tok, cfg, kq, vq,
+    lg_i8, kq2, _ = G._decode_step(params, tok, cfg, kq, vq,
                                          tables, lens,
                                          kv_scales=(ks, vs))
     assert kq2.dtype == jnp.int8

@@ -109,6 +109,57 @@ def test_steady_state_traces_over_30_request_stream(params):
     assert m["ttft_ms_mean"] is not None and m["ttft_ms_mean"] > 0
 
 
+@pytest.mark.parametrize("kind", ["dense", "sharded", "hybrid"])
+def test_one_decode_maker_traces_once_and_donates_its_carry(params, kind):
+    """Every engine's decode program comes from the one maker around the
+    forward picked at construction (dense, the same step per shard, the
+    hybrid model's with its recurrent state): over a 30-request
+    mixed-arrival stream each traces exactly one decode program, and
+    every call donates all it carries (tokens, lengths, key, both
+    pools, and the state where there is one)."""
+    from paddle_tpu.inference import ServingMesh
+    from paddle_tpu.models import granite_hybrid as gh
+    assert not [n for n in vars(ServingEngine)
+                if n.startswith("_make_decode_fn_")]
+    cfg, kw = CFG, {}
+    if kind == "sharded":
+        kw["mesh"] = ServingMesh.make(tp=2)
+    if kind == "hybrid":
+        cfg = gh.GRANITE_HYBRID_TINY
+        params = gh.init_params(cfg, jax.random.key(3))
+    eng = ServingEngine(params, cfg, capacity=3, block_size=4,
+                        prefill_buckets=(8, 16), max_seq_len=64, **kw)
+    donated = eng._DECODE_DONATE + ((8,) if kind == "hybrid" else ())
+    make, calls = eng._make_decode_fn, []
+
+    def spying_maker():
+        fn = make()
+
+        def call(*args):
+            out = fn(*args)
+            calls.append(all(
+                leaf.is_deleted() for i in donated
+                for leaf in jax.tree_util.tree_leaves(args[i])))
+            return out
+        return call
+    eng._make_decode_fn = spying_maker
+
+    rng = np.random.RandomState(2)
+    pending = [(rng.randint(0, cfg.vocab_size,
+                            (int(rng.randint(3, 17)),)).astype(np.int32),
+                GenerationConfig(max_new_tokens=int(rng.randint(2, 7)),
+                                 greedy=True)) for _ in range(30)]
+    submitted = []
+    while pending or not eng.idle:
+        for _ in range(min(len(pending), 1 + int(rng.randint(0, 3)))):
+            submitted.append(eng.submit(*pending.pop(0)))
+        eng.step()
+    assert len(submitted) == 30 and all(r.done for r in submitted)
+    assert eng.counters["decode_traces"] == 1, eng.counters
+    assert len(calls) == eng.counters["decode_steps"] and all(calls)
+    assert (eng._state is not None) == (kind == "hybrid")
+
+
 def test_eos_stops_request_early(params):
     rng = np.random.RandomState(3)
     eng = _engine(params)

@@ -181,11 +181,6 @@ def test_mesh_argument_normalization(params):
             np.array(jax.devices()[:4]).reshape(2, 2), ("a", "b")))
     with pytest.raises(ValueError, match="collective"):
         ServingMesh.make(tp=2, collective="allgatherz")
-    # an explicit pallas pin must RAISE under the gather placement
-    # (which runs the exact composition by contract), never no-op
-    with pytest.raises(ValueError, match="gather"):
-        _engine(params, fused_decode="pallas",
-                mesh=ServingMesh.make(tp=2, collective="gather"))
 
 
 # -- collective observability ------------------------------------------
@@ -328,13 +323,13 @@ def test_demo_tp_regression_fires_unknown_axis():
     assert f.detail["in_scope"] == ["model"]
 
 
-def test_fused_meta_grows_tp_field_and_key_declares_it():
-    from paddle_tpu.ops.pallas.fused_decode_block import (
-        _DECODE_KEY_FIELDS, decode_meta_dims)
+def test_shard_mlp_meta_is_local_and_every_key_is_declared():
+    """A shard dispatches ``decode_mlp_block`` on its LOCAL intermediate
+    columns, and every key of the meta is one the op declares its
+    callers' program caches cover."""
+    from paddle_tpu.ops.pallas.fused_decode_block import decode_meta_dims
     from paddle_tpu.ops.pallas.registry import KERNELS
-    meta = decode_meta_dims(2, 64, 2, 2, 16, 64, 8, 8, jnp.float32,
-                            jnp.float32, False, tp=2)
-    assert meta["tp"] == 2
-    assert "tp" in _DECODE_KEY_FIELDS
-    fields, _covers = KERNELS.cache_key_decl("decode_attn_block")
-    assert "tp" in fields
+    meta = decode_meta_dims(2, 64, 64 // 2, jnp.float32)
+    assert meta["F"] == 32
+    fields, covers = KERNELS.cache_key_decl("decode_mlp_block")
+    assert set(meta) == set(fields) | set(covers)

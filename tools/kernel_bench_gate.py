@@ -247,9 +247,9 @@ def build_demo_roofline_regression(threshold: float = DEFAULT_THRESHOLD):
     at 62% of peak HBM bandwidth now achieving 5%) that MUST trip the
     roofline gate — proving the wiring end to end, kernel_audit.py
     --demo-regression style."""
-    banked = {"decode_block_fused": 0.62}
-    src = {"decode_block_fused": "<demo>"}
-    fresh = {"decode_block_fused": 0.05}
+    banked = {"decode_mlp_block": 0.62}
+    src = {"decode_mlp_block": "<demo>"}
+    fresh = {"decode_mlp_block": 0.05}
     res = _diff_roofline(fresh, banked, src, threshold)
     res["demo"] = True
     return res

@@ -147,12 +147,12 @@ def summarize(meta, events, requests, top=10):
 def summarize_decode(events, meta=None):
     """The decode section: per-variant step attribution from the
     ``decode_variant`` field the engines stamp on each decode_step
-    event ("pallas_block" = single-launch block megakernel,
-    "pallas_fused" = the two-kernel attn+MLP route, "unfused" = the
-    composition) — so a capture says WHICH decode kernel its steps ran,
-    mirroring the prefill ``variant`` attribution above. Returns None
-    when no decode_step event carries the stamp (pre-r20 timelines
-    keep their old summary shape)."""
+    event ("pallas_fused" = the paged-attention and MLP launches are
+    both Pallas kernels, "unfused" = the XLA compositions) — so a
+    capture says WHICH decode arm its steps ran, mirroring the prefill
+    ``variant`` attribution above. Returns None when no decode_step
+    event carries the stamp (older timelines keep their summary
+    shape)."""
     steps = [ev for ev in events if ev.get("name") == "decode_step"
              and ev.get("decode_variant") is not None]
     if not steps:
