@@ -67,8 +67,9 @@ def dispatch_fused_variant(op: str, meta, mode=None):
 # Pages-per-grid-step autotune candidates of the fused prefill attention
 # kernel (a grid step fetches this many pages through BlockSpecs; pages
 # are processed sequentially, so the choice only affects pipelining,
-# never numerics). The paged-decode kernel fetches for itself and has
-# its own space (``paged_attention.PAGE_BLOCK_CANDIDATES``).
+# never numerics). The paged-decode kernel fetches for itself, reduces
+# a block of pages at once, and has its own space
+# (``paged_attention.PAGE_BLOCK_CANDIDATES``).
 PAGE_STEP_CANDIDATES = (1, 2, 4)
 
 
@@ -76,8 +77,10 @@ def online_softmax_page_update(q, k, v, pg, bs, seq_len, scale,
                                kv, groups, m_scr, l_scr, acc_scr):
     """One KV page's online-softmax update against ``m/l/acc`` scratch.
 
-    The page-streaming reduction body of the paged-decode kernel and
-    of the fused prefill attention kernel (its paged history).
+    The page-streaming reduction body of the fused prefill attention
+    kernel (its paged history). The paged-decode kernel reduces a block
+    of pages at once (``paged_attention._block_update``); its tests keep
+    this update as a second reference.
     ``q`` [H, hd], ``k``/``v`` [BS, KV, hd] — all f32 (callers dequant/
     upcast first); ``pg`` is the page index, tokens at/after
     ``seq_len`` are masked out. All literals explicitly f32/i32: the

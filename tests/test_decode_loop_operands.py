@@ -102,7 +102,11 @@ def _operand_origins(jaxpr, origin, found):
     """Walk ``jaxpr`` collecting, for each Pallas launch, where each of
     its operands comes from. ``origin`` maps a var to "carry" / "const"
     / "xs" (the loop body's own inputs) or to the name of the primitive
-    that made it; a write into the carried pool keeps its origin."""
+    that made it; a write into the carried pool keeps its origin, and
+    so does a reshape (the attention launch sees a page as the
+    ``[BS * KV, hd]`` rows it is in memory: a merge of neighbouring
+    axes, which ``tests/test_chip_compile.py -k second_copy`` holds to
+    be no copy)."""
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         ins = ["literal" if isinstance(v, Literal)
@@ -117,7 +121,7 @@ def _operand_origins(jaxpr, origin, found):
                 inner = dict(zip(sub.invars, ins[-len(sub.invars):]))
                 _operand_origins(sub, inner, found)
         for v in eqn.outvars:
-            origin[v] = ins[0] if name == "scatter" else name
+            origin[v] = ins[0] if name in ("scatter", "reshape") else name
     return found
 
 
@@ -146,9 +150,12 @@ def test_layer_loop_carries_pools_and_indexes_stacked_operands(program):
         big = {r: [o for shape, o in operands if len(shape) == r]
                for r in (5, 4, 3, 2)}
         if name == "paged_attention_decode":
-            # k and v, once per page of a grid step: the carried pools
-            assert big[5] and set(big[5]) == {"carry"}, operands
-            assert not big[4], operands           # no one-layer pool
+            # k and v: the carried pools whole, every layer and page,
+            # a page's [BS, KV] axes merged; no one-layer pool
+            whole = [o for shape, o in operands if len(shape) == 4
+                     and shape[:2] == (CFG.num_hidden_layers, NB)]
+            assert whole == ["carry", "carry"], operands
+            assert len(big[4]) == 2 and not big[5], operands
         else:
             # norm row, gate, up, down: closed over whole; x is rank 2
             assert len(big[3]) == 4 and not big[4], operands

@@ -241,8 +241,11 @@ def test_mlp_candidates_divide_evenly():
 
 
 def test_paged_decode_pages_per_step_invariant():
-    """Satellite: the unfused paged-decode kernel's pages-per-step is an
-    autotune candidate now — every choice must stay bit-identical."""
+    """The paged-decode kernel's pages-per-step is an autotune
+    candidate: it sets how many pages one softmax update reduces, so
+    every choice gives the reference's attention and differs from
+    another in the last float32 places only."""
+    from paddle_tpu.ops.paged_attention import paged_attention_decode_xla
     from paddle_tpu.ops.pallas.paged_attention import (
         paged_attention_decode_pallas)
     rng = np.random.RandomState(5)
@@ -255,8 +258,9 @@ def test_paged_decode_pages_per_step_invariant():
     lens = jnp.asarray([0, 7, BS * MB - 1], jnp.int32)
     outs = [np.asarray(paged_attention_decode_pallas(
         q, kp, vp, bt, lens, pages_per_step=pp)) for pp in (1, 2, 4)]
-    np.testing.assert_array_equal(outs[0], outs[1])
-    np.testing.assert_array_equal(outs[0], outs[2])
+    want = np.asarray(paged_attention_decode_xla(q, kp, vp, bt, lens))
+    for out in outs:
+        np.testing.assert_allclose(out, want, rtol=2e-6, atol=2e-8)
 
 
 # ---------------------------------------------------------------------------
