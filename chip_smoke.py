@@ -6,7 +6,9 @@ at the full published width of the one LLM family the repo supports
 (``LlamaConfig`` defaults = Llama-7B: D=4096, 32 heads x 128, F=11008,
 V=32000). Depth is cut, never a width; weights are random from ``--seed``.
 
-    python chip_smoke.py              one chip: sync probe, serving, training
+    python chip_smoke.py              one chip: sync probe, serving, a
+                                      window + global + expert model,
+                                      training
     python chip_smoke.py --chips 4    four chips, and ONLY the paths that
                                       exist across chips: tensor-parallel
                                       serving and the sharded Trainer, each
@@ -279,6 +281,73 @@ def phase_serving(jax, np, args, clock):
           f"decode program holds {found}")
 
 
+def phase_pattern(jax, jnp, np, args, clock):
+    """A model run by its layer pattern with two page lifetimes: one
+    period of Mellum 2's published pattern (3 sliding-window layers, 1
+    full-attention layer with YaRN, a 64-expert top-8 layer in each) at
+    its published widths, a prompt LONGER than the window beside a short
+    one, so that chunks and decode steps give window pages back. Greedy
+    tokens against the model file's full-sequence ``forward``."""
+    from paddle_tpu.inference import GenerationConfig, ServingEngine
+    from paddle_tpu.models import mellum
+
+    if args.tiny:
+        cfg = dataclasses.replace(mellum.MELLUM_TINY, num_hidden_layers=4)
+        lens, opts = (30, 5), dict(capacity=2, block_size=4,
+                                   max_seq_len=64, prefill_buckets=(8, 16))
+    else:
+        cfg = mellum.MellumConfig(num_hidden_layers=4)
+        lens, opts = (1100, 40), dict(capacity=4, block_size=16,
+                                      max_seq_len=1536,
+                                      prefill_buckets=(128, 512))
+    n_new = 8
+    params = mellum.init_params(cfg, jax.random.key(args.seed))
+    eng = ServingEngine(params, cfg, **opts)
+    rng = np.random.RandomState(args.seed)
+    gen = GenerationConfig(max_new_tokens=n_new, greedy=True)
+    reqs = [eng.submit(rng.randint(0, cfg.vocab_size, (n,))
+                       .astype(np.int32), gen) for n in lens]
+    eng.drain()
+    eng.mgr.check()
+    check(all(r.done and len(r.tokens) == n_new for r in reqs),
+          "a request did not finish with all its tokens")
+    m = eng.metrics()
+    ref = []
+    for r in reqs:          # teacher-forced on the served tokens
+        seq = np.concatenate([r.prompt, np.asarray(r.tokens[:-1],
+                                                   np.int32)])
+        logits = mellum.forward(params, jnp.asarray(seq), cfg)
+        ref.append(np.asarray(jnp.argmax(
+            logits[r.prompt.size - 1:], axis=-1)))
+    first_eq, share = token_match([r.tokens for r in reqs], ref)
+    found = compiled_decode_kernels(eng)
+    want = set(m["decode_variant"]["operands"])
+    released = eng.counters["window_pages_released"]
+    what = describe(cfg, 28, args.tiny)
+    what["model"] = ("MELLUM_TINY (rehearsal, not a chip size)" if args.tiny
+                     else "Mellum2-12B-A2.5B widths, random weights")
+    what["widths"]["F"] = cfg.moe_intermediate_size     # an expert's
+    say("pattern", **what, pattern=list(cfg.pattern),
+        experts=cfg.num_experts, prompt_lens=list(lens),
+        first_token_equal=first_eq, token_match_share=round(share, 4),
+        window_pages_released=released,
+        window=m["pattern"]["window"],
+        decode_variant=m["decode_variant"],
+        decode_traces=m["decode_traces"],
+        decode_kernels_compiled=found, **clock.lap(),
+        peak_hbm_gib=peak_hbm_gib(jax.devices()[:1]))
+    check(first_eq, "a request's first greedy token differs from the "
+                    "full-sequence forward over the same params")
+    check(share >= TOKEN_MATCH_FLOOR,
+          f"token match share {share:.3f} < {TOKEN_MATCH_FLOOR}")
+    check(released > 0, "no window page went back: the long prompt "
+                        "never left its window")
+    check(m["decode_traces"] == 1, "more than one decode program traced")
+    check(want <= set(found),
+          f"dispatch reported {m['decode_variant']} but the compiled "
+          f"decode program holds {found}")
+
+
 def training_setup(jax, jnp, np, args, mesh_cfg, devices):
     from paddle_tpu.distributed.trainer import Trainer, make_mesh
     from paddle_tpu.models import llama
@@ -477,6 +546,7 @@ def main():
               if args.chips == 4 else
               [lambda: phase_sync(jax, jnp, args.tiny),
                lambda: phase_serving(jax, np, args, clock),
+               lambda: phase_pattern(jax, jnp, np, args, clock),
                lambda: phase_training(jax, jnp, np, args, clock)])
     for phase in phases:
         phase()

@@ -112,6 +112,7 @@ class Scope:
     block_size: int = 2
     chunk: int = 2                      # prefill chunk (bucket) tokens
     prefix_cache: bool = False
+    window: int = 0                     # window layers' reach (0: none)
     spill: bool = False                 # offload tier (implies cache)
     host_budget: Optional[int] = None
     aging: Optional[float] = None
@@ -182,7 +183,15 @@ class _Group:
         self.num_blocks = num_blocks
         self.prompt_only = prompt_only
         self.clock = clock
-        self.mgr = BlockManager(num_blocks, self.bs, num_blocks)
+        # a model with window layers: the second page class, sized as
+        # the engine sizes it (every slot's whole ring + scratch)
+        ring = (-(-(scope.window + self.chunk) // self.bs) + 1
+                if scope.window else 0)
+        self.mgr = BlockManager(num_blocks, self.bs, num_blocks,
+                                window=scope.window or None,
+                                window_blocks=capacity * ring + 1,
+                                window_ring=ring)
+        self.win = self.mgr.window
         scratch = self.mgr.allocate(_SCRATCH, 1)
         assert scratch == [0], "scratch must be page 0"
         self.pcache = None
@@ -223,7 +232,9 @@ class _Group:
         """serving._acquire_pages: (ok, acquired)."""
         need = self.need_pages(req)
         if self.pcache is None:
-            return len(self.mgr.free) >= need, None
+            return (len(self.mgr.free) >= need and (
+                self.win is None
+                or self.win.can_reserve(self.alloc_tokens(req)))), None
         acquired = self.pcache.acquire(
             req.prompt, len(req.prompt) - 1, need)
         return acquired is not None, acquired
@@ -315,6 +326,8 @@ class _Group:
             pages, matched, shared = acquired
             self.mgr.attach(req.rid, pages, owned=True)
         self.mgr.allocate(req.rid, self.alloc_tokens(req))
+        if self.win is not None:
+            self.win.reserve(req.rid, self.alloc_tokens(req))
         slot = self.slots[slot_id]
         slot.req = req
         slot.phase = "prefill"
@@ -331,6 +344,10 @@ class _Group:
         req = slot.req
         S = len(req.prompt)
         n = min(S - slot.prefill_pos, self.chunk)
+        if self.win is not None:        # serving._window_advance
+            self.win.advance(req.rid,
+                             slot.prefill_pos - (self.win.window - 1),
+                             slot.prefill_pos + n)
         slot.prefill_pos += n
         if slot.prefill_pos < S:
             if self.on_chunk is not None:
@@ -368,6 +385,10 @@ class _Group:
         slot = self.slots[slot_id]
         req = slot.req
         t = _EOS if eos else _gen_tok(req, len(req.tokens))
+        if self.win is not None:        # serving._window_advance
+            self.win.advance(req.rid,
+                             slot.seq_len - (self.win.window - 1),
+                             slot.seq_len + 1)
         req.tokens.append(t)
         slot.seq_len += 1
         if eos or len(req.tokens) >= req.max_new:
@@ -635,6 +656,11 @@ class _World:
                   for s in g.slots),
             self._tree_key(g.pcache) if g.pcache is not None else None,
             g.pcache._host_pages if g.pcache is not None else 0,
+            None if g.win is None else (
+                tuple(g.win.free),
+                tuple(sorted((sid, tuple(sorted(t.items())))
+                             for sid, t in g.win.tables.items())),
+                tuple(sorted(g.win.reserved.items()))),
         )
 
     def _req_key(self):
@@ -1271,6 +1297,16 @@ SCOPES: Dict[str, Scope] = {s.name: s for s in (
                   ReqSpec((1, 2, 5, 6), max_new=1)),
         capacity=1, num_blocks=5, block_size=2, chunk=2,
         prefix_cache=True, spill=True, host_budget=1),
+    Scope(
+        name="coloc_window",
+        note="two page classes under one manager: global pages kept to "
+             "a request's end, window pages given back behind a window "
+             "of 3 positions while it prefills and decodes; admission "
+             "reckons both, a preempted victim keeps both",
+        requests=(ReqSpec((1, 2, 3, 4, 5), max_new=3, priority=1),
+                  ReqSpec((6, 7), max_new=2, priority=0),
+                  ReqSpec((8, 9, 10), max_new=3, priority=2)),
+        capacity=2, num_blocks=8, block_size=2, chunk=2, window=3),
     Scope(
         name="disagg",
         note="chunked-prefill partial handoff windows, final handoff "

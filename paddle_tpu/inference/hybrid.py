@@ -1,50 +1,174 @@
-"""The serving programs of a model with recurrent layers beside its
-attention layers (``models/granite_hybrid.py``): one decode step over
-all slots, and one prefill chunk of one request.
+"""The serving programs of a model that is run BY ITS LAYER PATTERN
+(``models/pattern.py``: ``models/granite_hybrid.py``,
+``models/mellum.py``): one decode step over all slots, and one prefill
+chunk of one request, both driven by what the config says of each kind
+of layer (``cfg.kinds``), and what ``ServingEngine`` reads of such a
+model (:class:`ServedPattern`).
 
-Two kinds of per-request state live side by side. Keys and values stay
-in the paged pools ``[La, N, BS, KV, hd]`` (only the attention layers
-have any), addressed through block tables as for every other model. A
-Mamba layer's state is indexed by SLOT and never paged:
+Three kinds of per-request state live side by side:
+
+- keys and values a request keeps to its end: the paged pools
+  ``[Lg, N, BS, KV, hd]`` of the layers whose page class is "global",
+  addressed through block tables as for every other model;
+- keys and values behind a sliding window: pools of their own
+  ``[Lw, Nw, BS, KV, hd]`` for the layers whose page class is "window",
+  addressed through a RING a slot (``win_tables`` [slots, R]: logical
+  block ``n`` in column ``n % R``); the host gives a page back to the
+  pool once it lies behind the window (``ops/paged_attention
+  .WindowPages``), so a launch is handed each slot's first live
+  position and visits nothing before it;
+- a Mamba layer's state, indexed by SLOT and never paged:
 
     ssm   [Lm, slots, N, H*hp]    the recurrence's state (state_dtype)
     conv  [Lm, slots, K-1, C]     the convolution's last K-1 inputs
-    stats int [3]                 routing counts, summed on the device
 
-``state`` is that dict. Both programs take it as an argument, carry it
-through their loops beside the KV pools, write the layer (and, in a
-chunk, the slot) they are at in place, and return it; the engine
-donates it. A slot that is not decoding has ``dt = 0`` in the decode
-step, which leaves its state bit for bit; a chunk's padding likewise.
+``state`` is the dict of what the model has of the last two, plus
+``stats`` (int [3]: routing counts, summed on the device). Both
+programs take it as an argument, carry it through their loops beside
+the global pools, write the layer (and, in a chunk, the slot) they are
+at in place, and return it; the engine donates it. A slot that is not
+decoding has ``dt = 0`` in the decode step, which leaves its state bit
+for bit, and writes its keys to the scratch page; a chunk's padding
+likewise.
 
 The layer pattern is run as its segments of equal layers
-(``cfg.segments()``): each run of Mamba layers is ONE loop over the
-stacked weights, so a period of "5 Mamba, 1 attention, 4 Mamba"
-compiles two loop bodies and one attention layer, not ten layers.
+(``cfg.segments()``): each run is ONE loop over that kind's stacked
+weights, so a period of "5 Mamba, 1 attention, 4 Mamba" compiles two
+loop bodies and one attention layer, not ten layers, and "3 window, 1
+full" twice compiles four loops of two bodies.
 """
 from __future__ import annotations
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
 
-from ..models import granite_hybrid as gh
-from ..ops import mamba2
+from ..models import pattern as pt
 from ..ops.moe_experts import expert_counts
 from ..ops.paged_attention import paged_attention_decode, write_to_pool
 
-__all__ = ["init_state", "decode_step", "prefill_chunk", "reset_slot"]
+__all__ = ["ServedPattern", "served_pattern", "init_state", "decode_step",
+           "prefill_chunk", "reset_slot", "chunk_attention"]
 
 F32 = jnp.float32
 # leaves of params["moe"] that a loop hands to the expert launch whole
 _EXPERT_STACKS = ("w_in", "w_out")
+# keys a chunk's attention reads at once (a block of the live keys)
+CHUNK_KEY_BLOCK = 512
+
+_NO_SNAPSHOTS = (
+    "a recurrent layer's state lives in its slot and there are no "
+    "state snapshots yet (a copy of a slot's state kept beside its KV "
+    "pages), which {what} needs to {need}")
 
 
-def init_state(cfg, slots: int, state_dtype=F32):
-    """Zeroed state pools for ``slots`` slots."""
-    ssm, conv = cfg.state_shapes(slots)
-    return {"ssm": jnp.zeros(ssm, state_dtype),
-            "conv": jnp.zeros(conv, cfg.dtype),
-            "stats": jnp.zeros((3,), jnp.asarray(0).dtype)}
+@dataclasses.dataclass(frozen=True)
+class ServedPattern:
+    """What ``ServingEngine`` reads of a model served by its pattern:
+    its pools and their page classes, its per-slot state, the options
+    it cannot take yet. One description; the engine asks it and holds
+    no model's name."""
+    recurrent_layers: int        # layers with a state a slot
+    window_layers: int           # layers whose pages go back behind ...
+    window: int                  # ... this many positions (0: none)
+
+    @property
+    def prefix_skip_counter(self) -> str:
+        """The counter of requests the prefix cache would have looked
+        up: the cache stays off for this model, by the mechanism that
+        is missing."""
+        return ("prefix_skipped_recurrent" if self.recurrent_layers
+                else "prefix_skipped_window")
+
+    @property
+    def counters(self):
+        """Counters this model adds to ``engine.counters``."""
+        names = [self.prefix_skip_counter, "expert_assignments",
+                 "expert_assignments_held", "expert_load_max"]
+        if self.recurrent_layers:
+            names.append("state_resets")
+        if self.window:
+            names += ["window_pages_released", "kv_tokens_held_window",
+                      "kv_tokens_seen_window", "kv_pages_live_global"]
+        return tuple(names)
+
+    def ring(self, block_size: int, largest_chunk: int) -> int:
+        """Pages a slot's window ring holds: a chunk's queries reach
+        ``window - 1`` positions back from its first, and its own keys
+        are written before it attends."""
+        return -(-(self.window + largest_chunk) // block_size) + 1
+
+    def refuse(self, mesh=None, weight_quant=None, cache_dtype=None,
+               kv_offload=False):
+        """What such a model cannot be served with yet, each refused by
+        the mechanism that is missing."""
+        if kv_offload:
+            raise ValueError(
+                "ServingEngine(kv_offload=...): " + (
+                    _NO_SNAPSHOTS.format(what="the host tier",
+                                         need="restore a spilled prefix")
+                    if self.recurrent_layers else
+                    "the host tier spills the prefix cache's pages, and "
+                    "the prefix cache cannot share a prefix across two "
+                    "page lifetimes yet (a window layer's pages behind "
+                    "the window are gone when the prefix is matched)"))
+        if mesh is not None:
+            raise ValueError(
+                "ServingEngine(mesh=...): the recurrent and expert layers "
+                "have no sharded placement yet (inference/tp.py shards "
+                "attention heads and MLP columns, and has no expert "
+                "exchange)")
+        if weight_quant is not None:
+            raise ValueError(
+                "ServingEngine(weight_quant=...): the quantized leaves' "
+                "dequantize-then-matmul route does not cover the expert "
+                "stacks or the Mamba projections")
+        if cache_dtype in ("int8", jnp.int8):
+            raise ValueError(
+                'ServingEngine(cache_dtype="int8"): the int8 pools\' scales '
+                "are calibrated through the dense decoder's forward pass")
+
+    def refuse_preemption(self):
+        if self.recurrent_layers:
+            raise RuntimeError("preemption: " + _NO_SNAPSHOTS.format(
+                what="a preempted request",
+                need="resume where it was evicted"))
+
+def served_pattern(cfg):
+    """The :class:`ServedPattern` of ``cfg``, or None for a model that
+    is not run by a pattern (the dense decoder)."""
+    kinds = getattr(cfg, "kinds", None)
+    if not kinds:
+        return None
+    used = [kinds[name] for name in cfg.pattern]
+    windows = {k.window for k in used if k.pool == "window"}
+    if len(windows) > 1:
+        raise ValueError(f"window layers of several widths {windows}: "
+                         "the window page class has one")
+    return ServedPattern(
+        sum(k.mixer == "mamba" for k in used),
+        sum(k.pool == "window" for k in used),
+        windows.pop() if windows else 0)
+
+
+def init_state(cfg, slots: int, state_dtype=F32, window_blocks: int = 0,
+               block_size: int = 16, ring: int = 0):
+    """Zeroed pools for ``slots`` slots: what the model has of the
+    recurrent state and of the window layers' pages and tables."""
+    state = {"stats": jnp.zeros((3,), jnp.asarray(0).dtype)}
+    if getattr(cfg, "num_recurrent_layers", 0):
+        ssm, conv = cfg.state_shapes(slots)
+        state.update(ssm=jnp.zeros(ssm, state_dtype),
+                     conv=jnp.zeros(conv, cfg.dtype))
+    lw = getattr(cfg, "num_window_layers", 0)
+    if lw:
+        shape = (lw, window_blocks, block_size, cfg.num_key_value_heads,
+                 cfg.head_dim)
+        state.update(k_win=jnp.zeros(shape, cfg.dtype),
+                     v_win=jnp.zeros(shape, cfg.dtype),
+                     win_tables=jnp.zeros((slots, ring), jnp.int32))
+    return state
 
 
 def reset_slot(state, slot):
@@ -66,10 +190,10 @@ def _moe(params, h, cfg, l, live, stats):
     """A layer's second half inside a loop at layer ``l`` (traced), and
     the running routing counts."""
     moe = params["moe"]
-    mp = gh.at_layer({k: v for k, v in moe.items()
+    mp = pt.at_layer({k: v for k, v in moe.items()
                       if k not in _EXPERT_STACKS}, l)
     mp.update({k: moe[k] for k in _EXPERT_STACKS})
-    x, experts = gh.moe_block(mp, h, cfg, layer=l)
+    x, experts = pt.moe_block(mp, h, cfg, layer=l)
     if stats is not None:
         c = expert_counts(experts, live, cfg.num_experts,
                           cfg.num_local_experts,
@@ -79,6 +203,29 @@ def _moe(params, h, cfg, l, live, stats):
     return x, stats
 
 
+def _run_segments(cfg, x, k_pools, v_pools, state, mamba_layers,
+                  attn_layers, stats=()):
+    """The pattern as its runs of equal layers. ``mamba_layers(carry,
+    l0, m0, n)`` and ``attn_layers(carry, l0, a0, n, kind)`` are one
+    loop each; the carry of an attention run holds the pools of its
+    kind's page class. ``stats``: () or (the routing counts,), carried
+    by every loop. Returns (x, k_pools, v_pools, state, stats)."""
+    state = dict(state)
+    for name, l0, n, k0 in cfg.segments():
+        kind = cfg.kinds[name]
+        if kind.mixer == "mamba":
+            x, state["ssm"], state["conv"], *stats = mamba_layers(
+                (x, state["ssm"], state["conv"], *stats), l0, k0, n)
+        elif kind.pool == "window":
+            x, state["k_win"], state["v_win"], *stats = attn_layers(
+                (x, state["k_win"], state["v_win"], *stats), l0, k0, n,
+                kind)
+        else:
+            x, k_pools, v_pools, *stats = attn_layers(
+                (x, k_pools, v_pools, *stats), l0, k0, n, kind)
+    return x, k_pools, v_pools, state, stats
+
+
 def decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
                 seq_lens, state):
     """One token for every slot. tok, seq_lens: [S] (a slot that is not
@@ -86,13 +233,16 @@ def decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
     its recurrent state is left as it is). Returns (logits [S, V],
     k_pools, v_pools, state)."""
     active = seq_lens > 0
-    x = gh.embed(params, tok, cfg)
+    x = pt.embed(params, tok, cfg)
 
     def mamba_layers(carry, l0, m0, n):
+        from ..models import granite_hybrid as gh
+        from ..ops import mamba2
+
         def body(i, carry):
             x, ssm, conv, stats = carry
             l, m = l0 + i, m0 + i
-            lp = gh.at_layer(params["mamba"], m)
+            lp = pt.at_layer(params["mamba"], m)
             z, xbc, dt = gh.mamba_in(lp, x, cfg)
             xbc, tail = mamba2.conv_update(
                 xbc, lp["conv_w"], lp["conv_b"],
@@ -107,34 +257,96 @@ def decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
             return x, ssm, conv, stats
         return jax.lax.fori_loop(0, n, body, carry)
 
-    def attn_layers(carry, l0, a0, n):
+    def attn_layers(carry, l0, a0, n, kind):
+        # a window layer's launch gets each slot's first live position
+        # and its ring of the window pool; a global layer's neither
+        windowed = kind.pool == "window"
+        tables = state["win_tables"] if windowed else block_tables
+        first = (jnp.maximum(seq_lens + 1 - kind.window, 0)
+                 if windowed else None)
+
         def body(i, carry):
             x, kp, vp, stats = carry
             l, a = l0 + i, a0 + i
-            lp = gh.at_layer(params["attn"], a)
-            q, k, v = gh.attn_qkv(lp, x, cfg)
-            kp, vp = write_to_pool(kp, vp, block_tables, seq_lens,
+            lp = pt.at_layer(params[kind.stack], a)
+            q, k, v = pt.attn_qkv(lp, x, cfg, kind, seq_lens)
+            kp, vp = write_to_pool(kp, vp, tables, seq_lens,
                                    k.astype(kp.dtype), v.astype(vp.dtype),
-                                   layer=a)
+                                   layer=a, ring=windowed)
             o = paged_attention_decode(
-                q, kp, vp, block_tables, seq_lens + 1,
-                scale=cfg.attention_multiplier, layer=a)
-            h = x + cfg.residual_multiplier * (
-                o.reshape(x.shape[0], -1).astype(x.dtype) @ lp["o_proj"])
+                q, kp, vp, tables, seq_lens + 1,
+                scale=cfg.attention_multiplier, layer=a, first=first)
+            h = pt.residual(x, o.reshape(x.shape[0], -1).astype(x.dtype)
+                            @ lp["o_proj"], cfg)
             x, stats = _moe(params, h, cfg, l, active, stats)
             return x, kp, vp, stats
         return jax.lax.fori_loop(0, n, body, carry)
 
-    ssm, conv, stats = state["ssm"], state["conv"], state["stats"]
-    for kind, l0, n, k0 in cfg.segments():
-        if kind == "mamba":
-            x, ssm, conv, stats = mamba_layers((x, ssm, conv, stats),
-                                               l0, k0, n)
-        else:
-            x, k_pools, v_pools, stats = attn_layers(
-                (x, k_pools, v_pools, stats), l0, k0, n)
-    return (gh.lm_logits(params, x, cfg).astype(F32), k_pools, v_pools,
-            {"ssm": ssm, "conv": conv, "stats": stats})
+    x, k_pools, v_pools, state, (stats,) = _run_segments(
+        cfg, x, k_pools, v_pools, state, mamba_layers, attn_layers,
+        (state["stats"],))
+    state["stats"] = stats
+    return (pt.lm_logits(params, x, cfg).astype(F32), k_pools, v_pools,
+            state)
+
+
+def chunk_attention(q, kp, vp, layer, table, q_pos, lo, hi, scale,
+                    window=None, ring=False):
+    """Attention of a chunk's queries over the request's LIVE keys,
+    read from the pool in blocks.
+
+    q [P, H, hd] at absolute positions ``q_pos`` [P]; kp / vp the
+    stacked pools [L, N, BS, KV, hd], read at ``layer`` through the
+    request's ``table`` (``ring``: logical block ``n`` in column ``n %
+    width``); the live keys are the positions ``[lo, hi)`` (the chunk's
+    own among them: they were written before this is called). A query
+    sees ``j <= q_pos`` and, with ``window``, ``j > q_pos - window``.
+    The loop's trip count follows ``hi - lo``, not the table's width:
+    a block is ``CHUNK_KEY_BLOCK`` keys gathered page by page, one
+    online-softmax update in float32. Rows of V outside ``[lo, hi)``
+    are selected away (a page given back, or never written, may hold
+    anything). Returns [P, H * hd]."""
+    P, H, hd = q.shape
+    BS, KV = kp.shape[2], kp.shape[3]
+    G = H // KV
+    width = table.shape[0]
+    pages = max(1, min(CHUNK_KEY_BLOCK // BS, width))
+    T = pages * BS
+    i32 = jnp.int32
+    lo, hi = jnp.asarray(lo, i32), jnp.asarray(hi, i32)
+    q_pos = q_pos.astype(i32)
+    qg = q.reshape(P, KV, G, hd).astype(F32)
+    table = jnp.asarray(table, i32)
+
+    def block(blk, carry):
+        m, l, acc = carry
+        cols = blk * pages + jnp.arange(pages, dtype=i32)
+        cols = cols % width if ring else jnp.minimum(cols, width - 1)
+        page = jnp.take(table, cols)
+        kb = kp[layer, page].reshape(T, KV, hd)
+        vb = vp[layer, page].reshape(T, KV, hd)
+        kpos = blk * T + jnp.arange(T, dtype=i32)
+        held = (kpos >= lo) & (kpos < hi)
+        see = held[None, :] & (kpos[None, :] <= q_pos[:, None])
+        if window is not None:
+            see = see & (kpos[None, :] > q_pos[:, None] - window)
+        s = jnp.einsum("pngh,tnh->ngpt", qg, kb.astype(F32)) * scale
+        s = jnp.where(see[None, None], s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        # a query with no key in any block so far keeps m = -inf
+        m_safe = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+        p = jnp.exp(s - m_safe)
+        alpha = jnp.exp(m - m_safe)
+        vb = jnp.where(held[:, None, None], vb, jnp.zeros_like(vb))
+        acc = acc * alpha + jnp.einsum("ngpt,tnh->ngph", p, vb.astype(F32))
+        return m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True), acc
+
+    m0 = jnp.full((KV, G, P, 1), -jnp.inf, F32)
+    init = (m0, jnp.zeros((KV, G, P, 1), F32),
+            jnp.zeros((KV, G, P, hd), F32))
+    _, l, acc = jax.lax.fori_loop(lo // T, (hi + T - 1) // T, block, init)
+    o = acc / jnp.where(l == 0, 1.0, l)
+    return o.transpose(2, 0, 1, 3).reshape(P, H * hd).astype(q.dtype)
 
 
 def prefill_chunk(params, toks, cfg, k_pools, v_pools, table, wtable,
@@ -145,29 +357,32 @@ def prefill_chunk(params, toks, cfg, k_pools, v_pools, table, wtable,
     A Mamba layer continues from the slot's state and leaves the state
     after the last real token there; padding advances nothing (its
     ``dt`` is 0 and the convolution's tail is taken at the last real
-    token). An attention layer attends over the request's pages (a
-    dense view through ``table``) and the chunk, and writes the chunk's
-    keys and values through the write table. Returns (the logits
-    [1, V] of the last real position, k_pools, v_pools, state)."""
+    token). An attention layer writes the chunk's keys and values
+    through the write table of its page class (padding lands in the
+    scratch page) and then attends over the request's live keys in
+    blocks (:func:`chunk_attention`): a global layer over everything
+    up to the chunk's end, a window layer over at most ``window - 1``
+    positions before the chunk's first. Returns (the logits [1, V] of
+    the last real position, k_pools, v_pools, state)."""
     P = toks.shape[0]
     BS = k_pools.shape[2]
-    MB = table.shape[0]
     pos0 = jnp.asarray(pos0, jnp.int32)
     n_valid = jnp.asarray(n_valid, jnp.int32)
     slot = jnp.asarray(slot, jnp.int32)
     rows = jnp.arange(P, dtype=jnp.int32)
     valid = rows < n_valid
     pos = pos0 + rows
-    page = jnp.where(valid, jnp.take(jnp.asarray(wtable, jnp.int32),
-                                     pos // BS), 0)
     off = pos % BS
-    x = gh.embed(params, toks, cfg)
+    x = pt.embed(params, toks, cfg)
 
     def mamba_layers(carry, l0, m0, n):
+        from ..models import granite_hybrid as gh
+        from ..ops import mamba2
+
         def body(i, carry):
             x, ssm, conv = carry
             l, m = l0 + i, m0 + i
-            lp = gh.at_layer(params["mamba"], m)
+            lp = pt.at_layer(params["mamba"], m)
             z, xbc, dt = gh.mamba_in(lp, x, cfg)
             xbc, tail = mamba2.causal_conv1d(
                 xbc, lp["conv_w"], lp["conv_b"], conv[m, slot], n_valid)
@@ -184,37 +399,39 @@ def prefill_chunk(params, toks, cfg, k_pools, v_pools, table, wtable,
             return x, ssm, conv
         return jax.lax.fori_loop(0, n, body, carry)
 
-    def attn_layers(carry, l0, a0, n):
+    def attn_layers(carry, l0, a0, n, kind):
+        windowed = kind.pool == "window"
+        if windowed:
+            tab = jnp.take(state["win_tables"], slot, axis=0)
+            wtab, col = tab, (pos // BS) % tab.shape[0]
+            lo = jnp.maximum(pos0 - (kind.window - 1), 0)
+        else:
+            tab, wtab, col = table, wtable, pos // BS
+            lo = jnp.int32(0)
+        # the chunk's own rows through the WRITE table (shared pages
+        # and padding land in the scratch page)
+        page = jnp.where(valid, jnp.take(jnp.asarray(wtab, jnp.int32),
+                                         col), 0)
+
         def body(i, carry):
             x, kp, vp = carry
             l, a = l0 + i, a0 + i
-            lp = gh.at_layer(params["attn"], a)
-            q, k, v = gh.attn_qkv(lp, x, cfg)
-            # the request's pages as a dense view, the chunk laid in
-            kc = jnp.take(kp[a], table, axis=0).reshape(MB * BS, *k.shape[1:])
-            vc = jnp.take(vp[a], table, axis=0).reshape(MB * BS, *v.shape[1:])
-            kc = jax.lax.dynamic_update_slice_in_dim(
-                kc, k.astype(kc.dtype), pos0, axis=0)
-            vc = jax.lax.dynamic_update_slice_in_dim(
-                vc, v.astype(vc.dtype), pos0, axis=0)
-            o = gh.attn_dense(q, kc, vc, pos, cfg)
-            # the chunk's own rows through the WRITE table (shared
-            # pages and padding land in the scratch page), one scatter
-            # into the carried stack
+            lp = pt.at_layer(params[kind.stack], a)
+            q, k, v = pt.attn_qkv(lp, x, cfg, kind, pos)
+            # one scatter into the carried stack, then the live keys
+            # (the chunk's among them) in blocks out of it
             kp = kp.at[a, page, off].set(k.astype(kp.dtype))
             vp = vp.at[a, page, off].set(v.astype(vp.dtype))
-            h = x + cfg.residual_multiplier * (o @ lp["o_proj"])
+            o = chunk_attention(q, kp, vp, a, tab, pos, lo,
+                                pos0 + n_valid, cfg.attention_multiplier,
+                                kind.window, windowed)
+            h = pt.residual(x, o @ lp["o_proj"], cfg)
             x, _ = _moe(params, h, cfg, l, valid, None)
             return x, kp, vp
         return jax.lax.fori_loop(0, n, body, carry)
 
-    ssm, conv = state["ssm"], state["conv"]
-    for kind, l0, n, k0 in cfg.segments():
-        if kind == "mamba":
-            x, ssm, conv = mamba_layers((x, ssm, conv), l0, k0, n)
-        else:
-            x, k_pools, v_pools = attn_layers((x, k_pools, v_pools),
-                                              l0, k0, n)
+    x, k_pools, v_pools, state, _ = _run_segments(
+        cfg, x, k_pools, v_pools, state, mamba_layers, attn_layers)
     last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=0)
-    return (gh.lm_logits(params, last, cfg).astype(F32), k_pools, v_pools,
-            {**state, "ssm": ssm, "conv": conv})
+    return (pt.lm_logits(params, last, cfg).astype(F32), k_pools, v_pools,
+            state)

@@ -110,37 +110,6 @@ def _collectives_snapshot(counters: Dict, obs: Observability) -> Dict:
                 and name.endswith("_ms")}}
 
 
-_NO_SNAPSHOTS = (
-    "a recurrent layer's state lives in its slot and there are no "
-    "state snapshots yet (a copy of a slot's state kept beside its KV "
-    "pages), which {what} needs to {need}")
-
-
-def _refuse_for_recurrent(mesh, weight_quant, cache_dtype, kv_offload):
-    """What a model with recurrent layers cannot be served with yet,
-    each refused by the mechanism that is missing."""
-    if kv_offload:
-        raise ValueError("ServingEngine(kv_offload=...): " +
-                         _NO_SNAPSHOTS.format(
-                             what="the host tier",
-                             need="restore a spilled prefix"))
-    if mesh is not None:
-        raise ValueError(
-            "ServingEngine(mesh=...): the recurrent and expert layers "
-            "have no sharded placement yet (inference/tp.py shards "
-            "attention heads and MLP columns, and has no expert "
-            "exchange)")
-    if weight_quant is not None:
-        raise ValueError(
-            "ServingEngine(weight_quant=...): the quantized leaves' "
-            "dequantize-then-matmul route does not cover the expert "
-            "stacks or the Mamba projections")
-    if cache_dtype in ("int8", jnp.int8):
-        raise ValueError(
-            'ServingEngine(cache_dtype="int8"): the int8 pools\' scales '
-            "are calibrated through the dense decoder's forward pass")
-
-
 def _drain_loop(eng, max_steps: Optional[int], starve_reason: str,
                 starve_error: str) -> int:
     """The shared drain loop (ServingEngine and DisaggregatedEngine):
@@ -245,17 +214,20 @@ class ServingEngine:
                  fused_prefill=None, weight_quant=None,
                  aging_s: Optional[float] = None, telemetry=False,
                  clock=None, state_dtype=None):
-        # a model with recurrent layers (models/granite_hybrid.py)
-        # keeps a second kind of per-request state, indexed by slot
-        # (inference/hybrid.py); what cannot serve it yet is refused
-        # here, by the mechanism that is missing
-        self._recurrent = getattr(cfg, "num_recurrent_layers", 0) > 0
-        if self._recurrent:
-            _refuse_for_recurrent(mesh=mesh, weight_quant=weight_quant,
-                                  cache_dtype=cache_dtype,
-                                  kv_offload=kv_offload)
+        # a model that is run by its layer pattern (models/pattern.py)
+        # keeps more per-request state than one class of pages: a
+        # recurrent state a slot, or a second class of pages that go
+        # back behind a sliding window. What the engine needs of such a
+        # model it reads from ONE description (inference/hybrid.py
+        # ServedPattern; None for the dense decoder); what cannot serve
+        # it yet is refused here, by the mechanism that is missing
+        from .hybrid import served_pattern
+        self._pattern = pat = served_pattern(cfg)
+        if pat is not None:
+            pat.refuse(mesh=mesh, weight_quant=weight_quant,
+                       cache_dtype=cache_dtype, kv_offload=kv_offload)
             fused_prefill = False
-        elif state_dtype is not None:
+        if state_dtype is not None and not (pat and pat.recurrent_layers):
             raise ValueError("state_dtype is the recurrent state's type: "
                              f"{type(cfg).__name__} has no recurrent layer")
         # tensor parallelism (inference/tp.py): a ServingMesh shards
@@ -380,7 +352,17 @@ class ServingEngine:
         self._v_pools = jnp.zeros(shape, pool_dtype, device=where)
         self._kv_scales = None       # (k [L,KV], v [L,KV]) once calibrated
 
-        self.mgr = BlockManager(self.num_blocks, BS, self.max_blocks)
+        # a model with window layers: a second class of pages under
+        # the same manager, in a pool of its own that holds every
+        # slot's whole ring (and the scratch page)
+        self._ring = pat.ring(BS, self.buckets[-1]) \
+            if pat is not None and pat.window else 0
+        self.window_blocks = self.capacity * self._ring + 1 \
+            if self._ring else 0
+        self.mgr = BlockManager(
+            self.num_blocks, BS, self.max_blocks,
+            window=pat.window if self._ring else None,
+            window_blocks=self.window_blocks, window_ring=self._ring)
         # reserve physical page 0 as scratch: padded table entries (and
         # inactive decode slots) default there, so their writes land in
         # a page no live sequence ever reads
@@ -411,21 +393,23 @@ class ServingEngine:
                 "kv_offload requires prefix_cache=True: the host tier "
                 "spills radix-tree pages, not per-request tables")
         # a prefix match skips tokens; nobody stored the recurrent state
-        # after them, so with recurrent layers the cache stays off and
-        # every request it would have looked up is counted
-        self._prefix_skipped = bool(prefix_cache) and self._recurrent
+        # after them, and a window layer's pages behind the window are
+        # gone, so for a pattern-run model the cache stays off and every
+        # request it would have looked up is counted
+        self._prefix_skipped = bool(prefix_cache) and pat is not None
         self._state = None
         # the decode program's forward, picked once: dense, the same
         # per-shard body under shard_map, or the hybrid model's (which
         # carries the slots' recurrent state)
         self._decode_forward = (self._dense_forward if self._mesh is None
                                 else self._tp_forward)
-        if self._recurrent:
+        if pat is not None:
             from . import hybrid
             self._decode_forward = self._hybrid_forward
             prefix_cache = False
             self._state = hybrid.init_state(
-                cfg, self.capacity, jnp.dtype(state_dtype or jnp.float32))
+                cfg, self.capacity, jnp.dtype(state_dtype or jnp.float32),
+                self.window_blocks, BS, self._ring)
             self._state_reset_fn = jax.jit(hybrid.reset_slot,
                                            donate_argnums=(0,))
         if prefix_cache:
@@ -470,6 +454,11 @@ class ServingEngine:
         self._h_seq = np.zeros((C,), np.int32)
         self._h_tables = np.zeros((C, MB), np.int32)
         self._h_temps = np.zeros((C,), np.float32)
+        # a window layer's ring a slot (the rows of state["win_tables"]);
+        # a row changes every block_size tokens of its slot, so it has a
+        # dirty mark of its own and the decode inputs are not re-sent
+        self._h_wtab = np.zeros((C, self._ring), np.int32)
+        self._dirty_w = False
         self._dirty = True
         self._d_tok = self._d_seq = None
         self._d_tables = self._d_temps = None
@@ -513,14 +502,11 @@ class ServingEngine:
             # asked about, and those it matched
             "prefix_lookup_tokens": 0, "prefix_hit_tokens": 0,
         }
-        if self._recurrent:
+        if pat is not None:
             # the expert_* three are summed on the device (the state's
             # "stats", carried by the decode program) and folded in
             # here only when metrics() reads them
-            self.counters.update(
-                state_resets=0, prefix_skipped_recurrent=0,
-                expert_assignments=0, expert_assignments_held=0,
-                expert_load_max=0)
+            self.counters.update(dict.fromkeys(pat.counters, 0))
         self._t_first = None
         self._t_last = None
         self._metrics_reset_t = None   # TTFTs from before this are warmup
@@ -890,15 +876,17 @@ class ServingEngine:
                                               decode_step_bytes)
 
         cfg = self.cfg
-        if self._recurrent:
+        if self._pattern is not None:
             # the arm model is a dense decoder's (attention + one MLP a
-            # layer): it does not reckon expert or recurrent layers
+            # layer): it does not reckon expert, recurrent or window
+            # layers
             return {"active": "unfused", "layers": cfg.num_hidden_layers,
                     "reckoned": False,
                     "why": "decode_step_bytes models dense-MLP layers "
-                           "with KV; this model has expert layers and "
-                           f"{cfg.num_recurrent_layers} recurrent "
-                           "layers (see metrics()['recurrent'])"}
+                           "with KV; this model has expert layers, "
+                           f"{self._pattern.recurrent_layers} recurrent "
+                           f"and {self._pattern.window_layers} window "
+                           "layers (see metrics()['pattern'])"}
         tp = 1 if self._mesh is None else self._mesh.tp
         act = jnp.dtype(cfg.dtype).itemsize
         pool = jnp.dtype(self._k_pools.dtype).itemsize
@@ -1039,13 +1027,18 @@ class ServingEngine:
         # "collectives" key below (the Trainer.metrics contract).
         # mixed_steps is read from ``counters`` itself (the benchmark's
         # mixed_step_pct.*): the key set of metrics() is frozen
-        if self._recurrent:
+        pat = self._pattern
+        if pat is not None:
             self._fold_expert_stats()
         c = {k: (dict(v) if isinstance(v, dict) else v)
              for k, v in self.counters.items()
              if k not in self._COUNTERS_ONLY}
-        if self._recurrent:
-            c["recurrent"] = self._recurrent_metrics()
+        if pat is not None:
+            # one report; a model with recurrent layers keeps its older
+            # name for it too
+            c["pattern"] = self._pattern_metrics()
+            if pat.recurrent_layers:
+                c["recurrent"] = c["pattern"]
         if self._mesh is not None:
             c["mesh"] = self._mesh.describe()
         wall = ((self._t_last - self._t_first)
@@ -1104,8 +1097,11 @@ class ServingEngine:
     _COUNTERS_ONLY = frozenset((
         "collective_calls", "collective_bytes", "mixed_steps",
         "prefix_lookup_tokens", "prefix_hit_tokens", "state_resets",
-        "prefix_skipped_recurrent", "expert_assignments",
-        "expert_assignments_held", "expert_load_max"))
+        "prefix_skipped_recurrent", "prefix_skipped_window",
+        "expert_assignments", "expert_assignments_held",
+        "expert_load_max", "window_pages_released",
+        "kv_tokens_held_window", "kv_tokens_seen_window",
+        "kv_pages_live_global"))
 
     def _fold_expert_stats(self):
         """The routing counts the decode program summed on the device
@@ -1115,25 +1111,23 @@ class ServingEngine:
                          "expert_load_max"), stats):
             self.counters[k] = int(v)
 
-    def _recurrent_metrics(self) -> Dict:
-        """What a model with recurrent layers adds to ``metrics()``:
-        the state pools the engine holds beside the KV pools, and the
-        expert layer's routing over the decode steps since the reset
-        (``load_skew``: the largest number of tokens one expert got in
-        a step, over the mean an expert got)."""
-        c, cfg = self.counters, self.cfg
-        nbytes = sum(int(self._state[k].nbytes) for k in ("ssm", "conv"))
+    def _pattern_metrics(self) -> Dict:
+        """What a pattern-run model adds to ``metrics()``: the pools
+        the engine holds beside the global KV pools (a recurrent state
+        a slot, the window layers' pages), what the window gave back,
+        and the expert layer's routing over the decode steps since the
+        reset (``load_skew``: the largest number of tokens one expert
+        got in a step, over the mean an expert got)."""
+        c, cfg, pat = self.counters, self.cfg, self._pattern
         layers = cfg.num_hidden_layers
         mean = (c["expert_assignments"]
                 / (cfg.num_experts * layers * c["decode_steps"])
                 if c["decode_steps"] else 0.0)
-        return {
-            "state_bytes": nbytes,
-            "state_dtype": str(self._state["ssm"].dtype),
-            "recurrent_layers": cfg.num_recurrent_layers,
+        out = {
+            "recurrent_layers": pat.recurrent_layers,
             "kv_layers": cfg.num_kv_layers,
-            "state_resets": c["state_resets"],
-            "prefix_skipped_recurrent": c["prefix_skipped_recurrent"],
+            "window_layers": pat.window_layers,
+            pat.prefix_skip_counter: c[pat.prefix_skip_counter],
             "experts": {
                 "held": cfg.num_local_experts, "of": cfg.num_experts,
                 "offset": cfg.expert_offset,
@@ -1145,6 +1139,23 @@ class ServingEngine:
                 "load_max": c["expert_load_max"],
                 "load_skew": (round(c["expert_load_max"] / mean, 3)
                               if mean else None)}}
+        if pat.recurrent_layers:
+            out.update(
+                state_bytes=sum(int(self._state[k].nbytes)
+                                for k in ("ssm", "conv")),
+                state_dtype=str(self._state["ssm"].dtype),
+                state_resets=c["state_resets"])
+        if pat.window:
+            seen = c["kv_tokens_seen_window"]
+            out["window"] = {
+                "positions": pat.window, "ring_pages": self._ring,
+                "pool_pages": self.window_blocks,
+                "pool_bytes": sum(int(self._state[k].nbytes)
+                                  for k in ("k_win", "v_win")),
+                "pages_released": c["window_pages_released"],
+                "held_share": (round(c["kv_tokens_held_window"] / seen, 4)
+                               if seen else None)}
+        return out
 
     def _scheduler_metrics(self) -> Dict:
         """The SLO-admission window report: per-class queue-wait stats
@@ -1180,11 +1191,8 @@ class ServingEngine:
                   "kv_restore_bytes", "mixed_steps",
                   "prefix_lookup_tokens", "prefix_hit_tokens"):
             self.counters[k] = 0
-        if self._recurrent:
-            for k in ("state_resets", "prefix_skipped_recurrent",
-                      "expert_assignments", "expert_assignments_held",
-                      "expert_load_max"):
-                self.counters[k] = 0
+        if self._pattern is not None:
+            self.counters.update(dict.fromkeys(self._pattern.counters, 0))
             self._state = {**self._state, "stats": jnp.zeros_like(
                 self._state["stats"])}
         self._sched_cls = {}
@@ -1323,9 +1331,10 @@ class ServingEngine:
                 self.counters["prefix_lookup_tokens"] += int(
                     req.prompt.size)
                 self.counters["prefix_hit_tokens"] += int(matched)
+            pat = self._pattern
             if self._prefix_skipped:
-                self.counters["prefix_skipped_recurrent"] += 1
-            if self._state is not None:
+                self.counters[pat.prefix_skip_counter] += 1
+            if pat is not None and pat.recurrent_layers:
                 # the slot's last request left its state there
                 with span("serve/state_reset", self._obs, slot=slot_id):
                     self._state = self._state_reset_fn(
@@ -1333,6 +1342,11 @@ class ServingEngine:
                 self.counters["state_resets"] += 1
             table = self.mgr.allocate(req.req_id,
                                       self._alloc_tokens(req))
+            if self.mgr.window is not None:
+                # the window class hands out pages as the request
+                # reaches them; admission sets its worst case aside
+                self.mgr.window.reserve(req.req_id,
+                                        self._alloc_tokens(req))
             slot.req = req
             slot.phase = "prefill"
             slot.seq_len = 0
@@ -1354,7 +1368,10 @@ class ServingEngine:
         cover the un-matched remainder."""
         need = -(-self._alloc_tokens(req) // self.block_size)
         if self._pcache is None:
-            return len(self.mgr.free) >= need, None
+            win = self.mgr.window
+            return (len(self.mgr.free) >= need and (
+                win is None
+                or win.can_reserve(self._alloc_tokens(req)))), None
         acquired = self._pcache.acquire(
             req.prompt, int(req.prompt.size) - 1, need)
         return acquired is not None, acquired
@@ -1412,7 +1429,7 @@ class ServingEngine:
         Raw classes compare — aging promotes queue ORDER, not the right
         to evict running work. None when no slot is evictable (never,
         with recurrent layers: see :meth:`_preempt`)."""
-        if self._recurrent:
+        if self._pattern is not None and self._pattern.recurrent_layers:
             return None
         cand = [(s.req.priority, s.req.admit_t or 0.0, i)
                 for i, s in enumerate(self._slots)
@@ -1428,10 +1445,8 @@ class ServingEngine:
         saved on the request, so the requeued entry — re-inserted at
         its ORIGINAL line position within its class — resumes decode
         bit-identically to the un-preempted run."""
-        if self._recurrent:
-            raise RuntimeError("preemption: " + _NO_SNAPSHOTS.format(
-                what="a preempted request",
-                need="resume where it was evicted"))
+        if self._pattern is not None:
+            self._pattern.refuse_preemption()
         slot = self._slots[slot_id]
         req = slot.req
         req.resume = (slot.seq_len, int(self._h_tok[slot_id]))
@@ -1475,6 +1490,9 @@ class ServingEngine:
         self._h_tables[slot_id] = self._slot_tables[slot_id]
         self._h_temps[slot_id] = self._temp_of(req.gen)
         self._dirty = True
+        if self.mgr.window is not None:     # the pages it kept
+            self._h_wtab[slot_id] = self.mgr.window.row(req.req_id)
+            self._dirty_w = True
         self._record_admit(req, slot_id, now)
 
     def _run_prefill(self) -> bool:
@@ -1504,6 +1522,8 @@ class ServingEngine:
                 # pos0/last_idx ride at the platform default int width
                 # so the literal indices inside cached_forward's dynamic
                 # slices promote consistently whether or not x64 is on
+                if self.mgr.window is not None:
+                    self._window_advance([(slot_id, pos0, pos0 + n)])
                 args = (jnp.asarray(toks), jnp.asarray(pos0),
                         jnp.asarray(self._slot_tables[slot_id].copy()),
                         jnp.asarray(self._slot_wtables[slot_id].copy()),
@@ -1630,6 +1650,10 @@ class ServingEngine:
             # replay the program the old route compiled
             self._decode_fn = self._make_decode_fn()
             self._decode_route = route
+        if self.mgr.window is not None:
+            self._window_advance(
+                [(i, self._slots[i].seq_len, self._slots[i].seq_len + 1)
+                 for i in live], decode=True)
         if self._dirty:
             with span("serve/table_upload", obs):
                 self._d_tok = self._upload(self._h_tok.copy())
@@ -1678,6 +1702,34 @@ class ServingEngine:
                         or len(req.tokens) >= req.gen.max_new_tokens):
                     self._finish(i)
         return True
+
+    def _window_advance(self, spans, decode=False):
+        """Before a program writes positions ``[start, stop)`` of each
+        slot in ``spans``: give back the window pages that lie wholly
+        behind what ``start``'s query still sees, take those up to
+        ``stop``, and send the rows that changed. On a decode step also
+        count what the window layers hold against what they would hold
+        with nothing given back, and the global layers' live pages."""
+        win, BS, c = self.mgr.window, self.block_size, self.counters
+        with span("serve/window_release", self._obs):
+            for slot_id, start, stop in spans:
+                rid = self._slots[slot_id].req.req_id
+                gone, changed = win.advance(
+                    rid, start - (win.window - 1), stop)
+                c["window_pages_released"] += gone
+                if changed:
+                    self._h_wtab[slot_id] = win.row(rid)
+                    self._dirty_w = True
+                if decode:
+                    c["kv_tokens_seen_window"] += stop
+                    c["kv_tokens_held_window"] += (
+                        stop - win.first_block(rid) * BS)
+                    c["kv_pages_live_global"] += -(-stop // BS)
+            if self._dirty_w:
+                self._state = {**self._state,
+                               "win_tables": self._upload(
+                                   self._h_wtab.copy())}
+                self._dirty_w = False
 
     def _finish(self, slot_id: int):
         slot = self._slots[slot_id]
@@ -1748,6 +1800,9 @@ class ServingEngine:
         self._h_tables[slot_id] = 0
         self._h_temps[slot_id] = 0.0
         self._dirty = True          # vacated slot must not be written
+        if self.mgr.window is not None:
+            self._h_wtab[slot_id] = 0
+            self._dirty_w = True
 
     # -- jitted programs ----------------------------------------------
     # decode step args: (params, tok, seq_lens, tables, temps, key,
@@ -1820,7 +1875,8 @@ class ServingEngine:
         # key are replaced by this call's outputs every step (on host
         # mutation the mirrors re-upload fresh arrays), so the old
         # buffers update in place — the donation audit's own finding
-        donate = self._DECODE_DONATE + ((8,) if self._recurrent else ())
+        donate = self._DECODE_DONATE + (
+            (8,) if self._pattern is not None else ())
         return jax.jit(step, donate_argnums=donate)
 
     def _make_prefill_fn_hybrid(self, P: int):
@@ -1927,7 +1983,7 @@ class ServingEngine:
         return jax.jit(chunk, donate_argnums=self._PREFILL_DONATE)
 
     def _make_prefill_fn(self, P: int, record_variant=True):
-        if self._recurrent:
+        if self._pattern is not None:
             return self._make_prefill_fn_hybrid(P)
         if self._prefill_fused_for(P):
             return self._make_prefill_fn_fused(
@@ -2103,7 +2159,8 @@ class ServingEngine:
         prefill_base = ("serving_prefill_fused"
                         if self._fused_prefill in ("pallas",)
                         else "serving_prefill")
-        # a model with recurrent layers: the slots' state is one more
+        # a pattern-run model: its state (a recurrent state a slot, the
+        # window layers' pools and rings) is one more
         # donated argument (a pytree) behind each program's own, and
         # its leaves come back as the outputs after the program's own
         decode_extra = prefill_extra = ()
@@ -2112,7 +2169,7 @@ class ServingEngine:
         decode_carry = {o: flat(a) for o, a in self._DECODE_CARRY.items()}
         prefill_carry = {o: flat(a)
                          for o, a in self._PREFILL_CARRY.items()}
-        if self._recurrent:
+        if self._pattern is not None:
             state_sd = abstract_signature(self._state)
             n_s = len(jax.tree_util.tree_leaves(state_sd))
             decode_extra = (state_sd,)

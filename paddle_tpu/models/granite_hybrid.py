@@ -35,9 +35,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import rms_norm as fused_rms_norm
 from ..ops import mamba2
-from ..ops.moe_experts import gated_mlp, moe_experts, route
+from .pattern import (LayerKind, at_layer, attn_dense, attn_qkv,  # noqa: F401
+                      embed, lm_logits, moe_block, norm, segments)
 
 __all__ = ["GraniteHybridConfig", "init_params", "forward",
            "GRANITE_HYBRID_TINY"]
@@ -126,17 +126,17 @@ class GraniteHybridConfig:
         return (self.mamba_d_inner
                 + 2 * self.mamba_n_groups * self.mamba_d_state)
 
+    @property
+    def kinds(self) -> Dict[str, LayerKind]:
+        """What each word of ``layer_types`` is (models/pattern.py)."""
+        return {"mamba": LayerKind("mamba", "mamba", "mamba"),
+                "attention": LayerKind("attention", "attention", "attn",
+                                       pool="global")}
+
     def segments(self):
         """Runs of equal layers: [(kind, first layer, number of layers,
         first index among the layers of that kind)]."""
-        out, seen = [], {"mamba": 0, "attention": 0}
-        for l, kind in enumerate(self.pattern):
-            if out and out[-1][0] == kind:
-                out[-1][2] += 1
-            else:
-                out.append([kind, l, 1, seen[kind]])
-            seen[kind] += 1
-        return [tuple(s) for s in out]
+        return segments(self.pattern)
 
     def state_shapes(self, slots: int):
         """(ssm [Lm, slots, N, H*hp], conv tail [Lm, slots, K-1, C]):
@@ -211,17 +211,8 @@ def init_params(cfg: GraniteHybridConfig, key=None, dtype=None) -> Dict:
 
 
 # -- layer halves shared by forward and the serving programs -------------
-def norm(x, weight, eps):
-    """RMSNorm over the last axis of x [..., D] (the ops pack's)."""
-    flat = x.reshape(1, -1, x.shape[-1])
-    return fused_rms_norm(flat, weight.astype(x.dtype), eps).reshape(x.shape)
-
-
-def at_layer(tree, i):
-    """Layer ``i``'s slice of every stacked leaf."""
-    return jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-        tree)
+# what every pattern-run family shares lives in models/pattern.py; the
+# Mamba-2 halves below are this family's own
 
 
 def mamba_in(lp, x, cfg):
@@ -248,55 +239,6 @@ def mamba_out(lp, x, y, z, cfg):
 def split(xbc, cfg):
     return mamba2.split_xbc(xbc, cfg.mamba_n_heads, cfg.mamba_d_head,
                             cfg.mamba_n_groups, cfg.mamba_d_state)
-
-
-def moe_block(mp, h, cfg, layer=None):
-    """The layer's second half on h [T, D]. ``mp`` is one layer's slice
-    of ``params["moe"]``, except that with ``layer`` given its two
-    expert leaves are the whole stacks (``moe_experts`` then addresses
-    the layer itself). Returns (x', (gates, experts))."""
-    u = norm(h, mp["post_norm"], cfg.rms_norm_eps)
-    gates, experts = route(u, mp["router"], cfg.num_experts_per_tok)
-    out = moe_experts(u, gates, experts, mp["w_in"], mp["w_out"],
-                      offset=cfg.expert_offset, layer=layer)
-    out = out + gated_mlp(u, mp["shared_in"], mp["shared_out"])
-    return h + cfg.residual_multiplier * out, experts
-
-
-def attn_qkv(lp, x, cfg):
-    """Norm and the three projections on x [T, D]: q [T, H, hd], k and
-    v [T, KV, hd]. No position embedding."""
-    T = x.shape[0]
-    h = norm(x, lp["input_norm"], cfg.rms_norm_eps)
-    q = (h @ lp["q_proj"]).reshape(T, cfg.num_attention_heads, -1)
-    k = (h @ lp["k_proj"]).reshape(T, cfg.num_key_value_heads, -1)
-    v = (h @ lp["v_proj"]).reshape(T, cfg.num_key_value_heads, -1)
-    return q, k, v
-
-
-def attn_dense(q, k, v, q_pos, cfg):
-    """Causal attention of q [P, H, hd] at absolute positions ``q_pos``
-    [P] over keys and values [T, KV, hd] at positions 0..T-1."""
-    P, H, hd = q.shape
-    T, KV, _ = k.shape
-    qg = q.reshape(P, KV, H // KV, hd).astype(F32)
-    s = jnp.einsum("pngh,tnh->ngpt", qg, k.astype(F32)) \
-        * cfg.attention_multiplier
-    see = jnp.arange(T)[None, :] <= q_pos[:, None]
-    s = jnp.where(see[None, None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("ngpt,tnh->pngh", p, v.astype(F32))
-    return o.reshape(P, H * hd).astype(q.dtype)
-
-
-def lm_logits(params, x, cfg):
-    x = norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return (x @ params["embed_tokens"].T) / cfg.logits_scaling
-
-
-def embed(params, tokens, cfg):
-    x = jnp.take(params["embed_tokens"], tokens, axis=0)
-    return x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
 
 
 def forward(params: Dict, tokens, cfg: GraniteHybridConfig):

@@ -38,6 +38,7 @@ SERVE_SPANS = (
     "serve/prefill_stage",   # bucket choice, padding, chunk uploads
     "serve/prefill_dispatch",    # the jitted chunk call returning
     "serve/first_token_sync",    # blocking read of a prompt's 1st token
+    "serve/window_release",  # giving window pages back, taking new ones
     "serve/table_upload",    # the _dirty re-upload of decode inputs
     "serve/decode_dispatch",     # the jitted decode call returning
     "serve/token_sync",      # the per-step host read of the tokens

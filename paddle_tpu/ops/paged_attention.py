@@ -30,7 +30,7 @@ from .pallas.registry import KERNELS
 
 def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
                            scale: Optional[float] = None, layer=None,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None, first=None):
     """Single-step decode attention over a paged cache.
 
     q:            [B, H, hd]     query for the current position
@@ -41,6 +41,14 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
                   is the layer to attend over (the decode loop's carried
                   pools: the kernel addresses the layer itself)
     k_scale/v_scale: [KV] per-head dequant scales of int8 pools
+    first:        [B]    int32   a sliding-window layer: each slot's
+                  first live position. Only positions ``first[b] <= j <
+                  seq_lens[b]`` are attended, only their pages visited,
+                  and the table is a RING: logical block ``n`` sits in
+                  column ``n % MB`` (a slot holds at most MB pages at
+                  once; what lies behind the window went back to the
+                  pool and its column was reused). None: every position
+                  before ``seq_lens[b]``, column ``n`` (today's program)
     returns       [B, H, hd]
 
     The kernel registry chooses the launch (op ``paged_attention_decode``):
@@ -50,17 +58,19 @@ def paged_attention_decode(q, k_pool, v_pool, block_tables, seq_lens,
     Every decode program gets its attention here, so they all get the
     same choice. A kernel failure on TPU raises.
     """
-    _, fn = KERNELS.dispatch("paged_attention_decode",
-                             decode_attention_meta(k_pool.dtype))
+    _, fn = KERNELS.dispatch(
+        "paged_attention_decode",
+        decode_attention_meta(k_pool.dtype, q.shape[-1]))
     return fn(q, k_pool, v_pool, block_tables, seq_lens, scale=scale,
-              k_scale=k_scale, v_scale=v_scale, layer=layer)
+              k_scale=k_scale, v_scale=v_scale, layer=layer, first=first)
 
 
-def decode_attention_meta(pool_dtype) -> dict:
+def decode_attention_meta(pool_dtype, head_dim: int = 128) -> dict:
     """What the ``paged_attention_decode`` variants' predicates read."""
     return {"backend": jax.default_backend(),
             "interpret": bool(interpret_mode()),
-            "pool_dtype": str(jnp.dtype(pool_dtype))}
+            "pool_dtype": str(jnp.dtype(pool_dtype)),
+            "head_dim": int(head_dim)}
 
 
 def _supports_pallas(meta):
@@ -75,11 +85,16 @@ def _supports_pallas(meta):
         return False, ("int8 pools: the kernel fetches pages at the "
                        "pool's dtype and takes no scales; the "
                        "composition dequantizes in its gather")
+    if meta["head_dim"] % 128:
+        return False, (f"head_dim {meta['head_dim']}: the kernel's page "
+                       "rows [BS*KV, head_dim] and its q and accumulator "
+                       "blocks need whole 128-lane rows (the v5e compiler "
+                       "refuses the launch at head_dim 64)")
     return True, "TPU backend, compiled kernel, pools at the model dtype"
 
 
 def _pallas_variant(q, k_pool, v_pool, block_tables, seq_lens, scale=None,
-                    k_scale=None, v_scale=None, layer=None):
+                    k_scale=None, v_scale=None, layer=None, first=None):
     from .pallas.paged_attention import paged_attention_decode_pallas
     if k_scale is not None or v_scale is not None:
         raise ValueError("paged_attention_decode variant 'pallas' takes "
@@ -87,16 +102,22 @@ def _pallas_variant(q, k_pool, v_pool, block_tables, seq_lens, scale=None,
                          "the 'xla' variant dequantizes in its gather")
     return paged_attention_decode_pallas(
         q, k_pool, v_pool, block_tables, seq_lens, scale=scale,
-        layer=layer)
+        layer=layer, first=first)
 
 
 def paged_attention_decode_xla(q, k_pool, v_pool, block_tables, seq_lens,
                                scale: Optional[float] = None,
-                               k_scale=None, v_scale=None, layer=None):
+                               k_scale=None, v_scale=None, layer=None,
+                               first=None):
     """Gather+einsum reference path (always XLA, any backend).
     ``k_scale``/``v_scale`` [KV]: per-head dequant for int8 pools —
     applied right after the gather so the rest of the math is shared
-    with the bf16 path. ``layer``: stacked pools, read at that layer."""
+    with the bf16 path. ``layer``: stacked pools, read at that layer.
+    ``first``: the window's first live position a slot, the table a
+    ring (see :func:`paged_attention_decode`): a column's tokens get
+    the positions of the one logical block inside ``[first // BS,
+    first // BS + MB)`` that maps to it, and the rows of V outside the
+    window are selected away (a page given back may hold anything)."""
     if layer is not None:
         k_pool, v_pool = k_pool[layer], v_pool[layer]
     B, H, hd = q.shape
@@ -119,7 +140,18 @@ def paged_attention_decode_xla(q, k_pool, v_pool, block_tables, seq_lens,
     scores = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     T = MB * BS
-    mask = jnp.arange(T)[None, None, :] < seq_lens[:, None, None]
+    if first is None:
+        mask = jnp.arange(T)[None, None, :] < seq_lens[:, None, None]
+    else:
+        first = jnp.asarray(first, jnp.int32)
+        blk0 = first // BS                                   # [B]
+        col = jnp.arange(MB, dtype=jnp.int32)[None, :]
+        blk = blk0[:, None] + (col - blk0[:, None]) % MB     # [B, MB]
+        pos = (blk[:, :, None] * BS
+               + jnp.arange(BS, dtype=jnp.int32)).reshape(B, T)
+        live = (pos >= first[:, None]) & (pos < seq_lens[:, None])
+        v = jnp.where(live[:, :, None, None], v, jnp.zeros_like(v))
+        mask = live[:, None, :]
     # finite mask value: a padding slot with seq_len 0 would otherwise get
     # an all--inf row and softmax NaN; zero its output instead
     scores = jnp.where(mask, scores, jnp.float32(-1e30))
@@ -139,11 +171,12 @@ KERNELS.register("paged_attention_decode", "xla",
 # (generation.kernel_route); the backend is the process's and the pool
 # dtype is in the jit signature
 KERNELS.declare_cache_key("paged_attention_decode",
-                          ("backend", "interpret", "pool_dtype"))
+                          ("backend", "interpret", "pool_dtype",
+                           "head_dim"))
 
 
 def write_to_pool(k_pool, v_pool, block_tables, seq_lens, k_new, v_new,
-                  layer=None):
+                  layer=None, ring=False):
     """Append one token's K/V per sequence into the paged pools.
 
     k_new/v_new: [B, KV, hd] for the token at position seq_lens[b] (0-based
@@ -152,13 +185,21 @@ def write_to_pool(k_pool, v_pool, block_tables, seq_lens, k_new, v_new,
     rows land at ``[layer, page, slot]``: one scatter into the whole
     buffer, which a loop that carries the pools performs in place and
     which touches no other layer's pages.
+    ``ring``: the table is a sliding-window layer's ring (logical block
+    ``n`` in column ``n % MB``), and a slot that is not decoding
+    (``seq_lens`` 0) writes the scratch page whatever its row holds: a
+    request that is still prefilling keeps its true row there.
     """
     BS = k_pool.shape[-3]
     pos = seq_lens                       # position to write
     blk_idx = pos // BS                  # logical block
     offset = pos % BS
+    if ring:
+        blk_idx = blk_idx % block_tables.shape[1]
     phys = jnp.take_along_axis(block_tables, blk_idx[:, None],
                                axis=1)[:, 0]          # [B]
+    if ring:
+        phys = jnp.where(seq_lens > 0, phys, 0)
     at = (phys, offset) if layer is None else (layer, phys, offset)
     k_pool = k_pool.at[at].set(k_new)
     v_pool = v_pool.at[at].set(v_new)
@@ -283,7 +324,8 @@ class BlockManager:
     allocator gives up."""
 
     def __init__(self, num_blocks: int, block_size: int,
-                 max_blocks_per_seq: int):
+                 max_blocks_per_seq: int, window: Optional[int] = None,
+                 window_blocks: int = 0, window_ring: int = 0):
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
@@ -291,6 +333,12 @@ class BlockManager:
         self.tables = {}            # seq_id -> list of physical block ids
         self.refcount = np.zeros(num_blocks, np.int32)
         self.reclaim = None         # callback(n_pages) -> pages freed
+        # the second page class, of a model with sliding-window layers:
+        # pages of a pool of its own that a request holds only while
+        # they lie inside its window (None: the model has no such layer)
+        self.window = (WindowPages(window_blocks, block_size, window,
+                                   window_ring)
+                       if window else None)
 
     def alloc_page(self) -> int:
         """Pop one free page at refcount 1 (sole owner: the caller)."""
@@ -364,8 +412,11 @@ class BlockManager:
         return self.allocate(seq_id, cur_len + 1)
 
     def release(self, seq_id: int):
+        """Give back everything ``seq_id`` holds, of both page classes."""
         for b in self.tables.pop(seq_id, []):
             self.decref(b)
+        if self.window is not None:
+            self.window.release(seq_id)
 
     def table_array(self, seq_ids) -> np.ndarray:
         out = np.zeros((len(seq_ids), self.max_blocks_per_seq), np.int32)
@@ -392,7 +443,9 @@ class BlockManager:
           (refcount > 0) — no page is ever lost;
         - every table entry is a valid page id with refcount >= the
           number of table references to it (a table can never hold
-          more references than the refcount records).
+          more references than the refcount records);
+        - the window page class (:meth:`WindowPages.check`), where the
+          model has one.
         """
         problems = []
         seen_free = set()
@@ -429,7 +482,150 @@ class BlockManager:
                 problems.append(
                     f"page {p} refcount {rc} < {int(table_refs[p])} "
                     "table references (tables over-share the page)")
+        if self.window is not None:
+            problems += self.window.check()
         if problems and raise_on_violation:
             raise RuntimeError(
                 "BlockManager.check failed:\n  " + "\n  ".join(problems))
+        return problems
+
+
+class WindowPages:
+    """The page class of sliding-window layers (host side, beside
+    :class:`BlockManager`'s own, which keeps a request's pages to its
+    end): a pool of ``num_blocks`` pages of which a request holds only
+    those that cover positions a later query can still see.
+
+    A free list, not a ring of fixed pages a slot: a page that falls
+    behind a request's window goes back at once and the next request to
+    ask gets it, so the pool is sized by what the traffic holds (a
+    short request never holds a window's worth), and ``reserve``
+    admits by each request's own worst case. What a slot's ROW of the
+    device table holds is a ring all the same: logical block ``n`` sits
+    in column ``n % ring``, so the row is as wide as the most pages a
+    request holds at once, not as its longest length. Page 0 is the
+    scratch page (padding and idle slots write there) and is never
+    handed out. No page is shared: a reference count would say 0 or 1,
+    so ownership is the tables themselves."""
+
+    def __init__(self, num_blocks: int, block_size: int, window: int,
+                 ring: int):
+        self.num_blocks = int(num_blocks)
+        self.block_size = int(block_size)
+        self.window = int(window)
+        self.ring = int(ring)
+        self.free = list(range(self.num_blocks - 1, 0, -1))
+        # seq_id -> {logical block: page}; a request's blocks are one
+        # run, kept beside the table as [first, end) so that a decode
+        # step that changes nothing costs two comparisons
+        self.tables = {}
+        self.held = {}
+        self.reserved = {}      # seq_id -> most pages it may hold at once
+        self.released = 0       # pages given back before their request ended
+
+    def need(self, num_tokens: int) -> int:
+        """The most pages a request of ``num_tokens`` holds at once."""
+        return min(-(-int(num_tokens) // self.block_size), self.ring)
+
+    def can_reserve(self, num_tokens: int) -> bool:
+        return (sum(self.reserved.values()) + self.need(num_tokens)
+                <= self.num_blocks - 1)
+
+    def reserve(self, seq_id: int, num_tokens: int):
+        """Admission: set aside the request's worst case, so that no
+        ``advance`` of a running request can find the pool dry."""
+        if not self.can_reserve(num_tokens):
+            raise RuntimeError("window page pool exhausted")
+        self.reserved[seq_id] = self.need(num_tokens)
+        self.tables.setdefault(seq_id, {})
+        self.held.setdefault(seq_id, (0, 0))
+
+    def advance(self, seq_id: int, lo: int, hi: int):
+        """Hold exactly the pages that cover positions ``[lo, hi)``
+        (``lo`` may be negative: the window reaches back past the
+        start): pages wholly before ``lo`` go back to the free list,
+        pages up to ``hi`` are taken from it. Returns (pages released,
+        whether the row changed)."""
+        BS = self.block_size
+        first, end = max(0, lo) // BS, (hi - 1) // BS + 1
+        was = self.held[seq_id]
+        if (first, end) == was:
+            return 0, False
+        if end - first > self.ring:
+            raise RuntimeError(
+                f"positions [{lo}, {hi}) need {end - first} window "
+                f"pages at once; a slot's ring has {self.ring}")
+        table = self.tables[seq_id]
+        gone = range(was[0], min(first, was[1]))
+        for n in gone:
+            self.free.append(table.pop(n))
+        self.released += len(gone)
+        for n in range(max(first, was[1]), end):
+            if not self.free:
+                raise RuntimeError("window page pool exhausted: a "
+                                   "request holds more than it reserved")
+            table[n] = self.free.pop()
+        self.held[seq_id] = (first, end)
+        return len(gone), True
+
+    def first_block(self, seq_id: int) -> int:
+        """The first logical block the request still holds."""
+        return self.held[seq_id][0]
+
+    def row(self, seq_id: int) -> np.ndarray:
+        """The request's row of the device table: [ring] int32, logical
+        block ``n`` in column ``n % ring``, the scratch page elsewhere."""
+        out = np.zeros((self.ring,), np.int32)
+        for n, p in self.tables.get(seq_id, {}).items():
+            out[n % self.ring] = p
+        return out
+
+    def release(self, seq_id: int):
+        for p in self.tables.pop(seq_id, {}).values():
+            self.free.append(p)
+        self.reserved.pop(seq_id, None)
+        self.held.pop(seq_id, None)
+
+    def check(self):
+        """Violations of this class's invariants (strings; empty =
+        clean): every page but the scratch page is free or held by
+        exactly one request, never both, never lost; a request holds no
+        more than it reserved, in distinct columns of its ring; the
+        reservations fit the pool."""
+        problems = []
+        seen = {}
+        for p in self.free:
+            if not (0 < p < self.num_blocks):
+                problems.append(f"window free list holds invalid page {p}")
+            elif p in seen:
+                problems.append(f"window page {p} appears twice in free "
+                                "list")
+            seen[p] = "free"
+        for sid, table in self.tables.items():
+            if len(table) > self.reserved.get(sid, 0):
+                problems.append(
+                    f"table {sid} holds {len(table)} window pages, "
+                    f"reserved {self.reserved.get(sid, 0)}")
+            if sorted(table) != list(range(*self.held.get(sid, (0, 0)))):
+                problems.append(f"table {sid}: window blocks "
+                                f"{sorted(table)} are not the run "
+                                f"{self.held.get(sid)} it is said to hold")
+            if len(table) > self.ring:
+                problems.append(f"table {sid}: more window blocks than a "
+                                "ring has columns")
+            for p in table.values():
+                if not (0 < p < self.num_blocks):
+                    problems.append(
+                        f"table {sid} holds invalid window page {p}")
+                elif p in seen:
+                    problems.append(
+                        f"window page {p} held by table {sid} and "
+                        f"{seen[p]}")
+                seen[p] = f"table {sid}"
+        for p in range(1, self.num_blocks):
+            if p not in seen:
+                problems.append(f"window page {p} leaked: neither free "
+                                "nor held")
+        if sum(self.reserved.values()) > self.num_blocks - 1:
+            problems.append("window reservations exceed the pool")
         return problems

@@ -36,6 +36,10 @@ fetches each sequence's own pages into VMEM directly from the pool:
   masked by position. Dead rows there hold what an earlier block or
   slot left in VMEM: their weights are zero AND their rows of V are
   selected away (``0 x NaN`` is NaN), so they contribute exactly zero.
+- a sliding-window layer's launch (``first``) gets each slot's first
+  live position as a fourth prefetch operand: the loop starts at the
+  page that holds it, the table is read as a ring, and every block (a
+  window is a handful) is one update masked at both ends.
 - online softmax across blocks: the reduction's order follows ``P``,
   so ``pages_per_step`` moves the last float32 places of the result
   (the reference is ``paged_attention_decode_xla``, to a tolerance:
@@ -78,7 +82,7 @@ PAGE_BLOCK_CANDIDATES = (16, 8, 4)
 
 
 def _block_update(q, k, v, n_live, scale, kv, groups, m_scr, l_scr,
-                  acc_scr):
+                  acc_scr, n_dead=None):
     """One online-softmax update over a block's ``T`` flattened rows.
 
     ``q`` [H, hd]; ``k``/``v`` [T, hd], row ``r`` holding token
@@ -108,7 +112,11 @@ def _block_update(q, k, v, n_live, scale, kv, groups, m_scr, l_scr,
         live = jax.lax.rem(col, i32(kv)) == jax.lax.div(row, i32(groups))
     if n_live is not None:
         live = live & (col < n_live)
-        dead = jax.lax.broadcasted_iota(i32, v.shape, 0) >= n_live
+        vrow = jax.lax.broadcasted_iota(i32, v.shape, 0)
+        dead = vrow >= n_live
+        if n_dead is not None:
+            live = live & (col >= n_dead)
+            dead = dead | (vrow < n_dead)
         v = jnp.where(dead, jnp.zeros_like(v), v)
     s = jnp.where(live, s, f32(-jnp.inf))
     m_prev = m_scr[:]                                     # (H, 1)
@@ -133,19 +141,30 @@ def _block_update(q, k, v, n_live, scale, kv, groups, m_scr, l_scr,
     m_scr[:] = m_new
 
 
-def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
-                   k_buf, v_buf, sem, m_scr, l_scr, acc_scr, *, scale, bs,
-                   kv, groups, pp, mb):
+def _decode_kernel(bt_ref, len_ref, layer_ref, *refs, scale, bs, kv,
+                   groups, pp, mb, windowed=False):
     # explicitly-typed literals: the body can be retraced at LOWERING
     # time outside the no_x64 window (jit callers), where bare python
     # literals become f64/i64 and break the specialized call signatures
     i32, f32 = jnp.int32, jnp.float32
+    if windowed:        # a fourth prefetch operand: first live positions
+        first_ref, *refs = refs
+    (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, m_scr, l_scr,
+     acc_scr) = refs
     b = pl.program_id(0)
     layer = layer_ref[0]
     page_rows = bs * kv                  # a page, flattened: [BS*KV, hd]
     # never past the table, whatever length a caller hands in: a page
     # number read beyond it would send a copy anywhere in HBM
     seq_len = jnp.minimum(len_ref[b], i32(mb * bs))
+    if windowed:
+        # the slot's pages from its first live one on; the table is a
+        # ring, so a length past mb * bs is in order and what must not
+        # pass the ring is the count of pages held at once
+        first = jnp.clip(first_ref[b], i32(0), len_ref[b])
+        page0 = first // i32(bs)
+        seq_len = jnp.minimum(len_ref[b] - page0 * i32(bs), i32(mb * bs))
+        head = first - page0 * i32(bs)   # dead tokens of the first page
     n_pages = (seq_len + i32(bs - 1)) // i32(bs)
 
     m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
@@ -155,7 +174,10 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
     def page_copies(blk, j):
         # the table is read for live pages only (callers guard with
         # pl.when), so garbage past a slot's length is never fetched
-        page = bt_ref[b, blk * i32(pp) + i32(j)]
+        col = blk * i32(pp) + i32(j)
+        if windowed:
+            col = jax.lax.rem(page0 + col, i32(mb))
+        page = bt_ref[b, col]
         half = blk % i32(2)
         return [pltpu.make_async_copy(
             hbm.at[layer, page],
@@ -177,10 +199,10 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
         for c in page_copies(blk, j):
             c.wait()
 
-    def reduce_block(blk, n_live=None):
+    def reduce_block(blk, n_live=None, n_dead=None):
         half = blk % i32(2)
         _block_update(q_ref[0], k_buf[half], v_buf[half], n_live, scale,
-                      kv, groups, m_scr, l_scr, acc_scr)
+                      kv, groups, m_scr, l_scr, acc_scr, n_dead=n_dead)
 
     for_live_pages(i32(0), start_page)
 
@@ -192,16 +214,31 @@ def _decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
         reduce_block(blk)
         return carry
 
-    # a block whose every token is live is reduced with no position
-    # mask; what is left of the slot (fewer than pp * bs tokens, some
-    # of them inside a page) is one update masked by position
-    n_full = seq_len // i32(pp * bs)
-    jax.lax.fori_loop(i32(0), n_full, full_block, i32(0))
+    def window_block(blk, carry):
+        # a window is a handful of blocks, its first and its last cut
+        # by position: every block is one update masked at both ends
+        for_live_pages(blk + i32(1), start_page)
+        for_live_pages(blk, wait_page)
+        left = seq_len - blk * i32(pp * bs)
+        reduce_block(blk, jnp.minimum(left, i32(pp * bs)) * i32(kv),
+                     jnp.where(blk == i32(0), head, i32(0)) * i32(kv))
+        return carry
 
-    @pl.when(n_full * i32(pp) < n_pages)
-    def _rest():
-        for_live_pages(n_full, wait_page)
-        reduce_block(n_full, (seq_len - n_full * i32(pp * bs)) * i32(kv))
+    if windowed:
+        jax.lax.fori_loop(i32(0), (n_pages + i32(pp - 1)) // i32(pp),
+                          window_block, i32(0))
+    else:
+        # a block whose every token is live is reduced with no position
+        # mask; what is left of the slot (fewer than pp * bs tokens,
+        # some of them inside a page) is one update masked by position
+        n_full = seq_len // i32(pp * bs)
+        jax.lax.fori_loop(i32(0), n_full, full_block, i32(0))
+
+        @pl.when(n_full * i32(pp) < n_pages)
+        def _rest():
+            for_live_pages(n_full, wait_page)
+            reduce_block(n_full,
+                         (seq_len - n_full * i32(pp * bs)) * i32(kv))
 
     l = l_scr[:]
     l_safe = jnp.where(l == f32(0.0), f32(1.0), l)
@@ -249,7 +286,8 @@ def _tuned_page_step(q, k_pool, v_pool, block_tables, seq_lens, MB,
 @no_x64
 def paged_attention_decode_pallas(q, k_pool, v_pool, block_tables,
                                   seq_lens, scale=None,
-                                  pages_per_step=None, layer=None):
+                                  pages_per_step=None, layer=None,
+                                  first=None):
     """q: [B, H, hd]; pools: [N, BS, KV, hd]; block_tables: [B, MB] int32;
     seq_lens: [B] int32 → [B, H, hd]. seq_len 0 slots return 0.
 
@@ -262,7 +300,15 @@ def paged_attention_decode_pallas(q, k_pool, v_pool, block_tables,
     softmax update, per loop iteration (:data:`PAGE_BLOCK_CANDIDATES`).
     None resolves through the autotune cache (``paged_autotune_key``).
     The choice sets the order of the float32 reduction, so it moves the
-    result's last float32 places."""
+    result's last float32 places.
+
+    ``first`` [B] int32: a sliding-window layer's launch. Each slot's
+    first live position rides as a fourth scalar-prefetch operand; the
+    loop starts at the page that holds it, masks the rows before it,
+    reads the table as a ring (logical block ``n`` in column ``n %
+    MB``) and fetches nothing that lies behind it. None is the program
+    without any of that; with ``first`` all zero and lengths within the
+    table the two give the same bits."""
     B, H, hd = q.shape
     BS, KV = k_pool.shape[-3:-1]
     MB = block_tables.shape[1]
@@ -284,16 +330,26 @@ def paged_attention_decode_pallas(q, k_pool, v_pool, block_tables,
     v_pool = v_pool.reshape(v_pool.shape[:2] + (BS * KV, hd))
     buf_shape = (2, pp * BS * KV, hd)
 
-    def pool_bytes_fetched(_bt, lens, _layer):
-        # what the kernel copies out of ONE pool: the slots' live pages
-        return page * sum(min(pl.cdiv(int(lens[b]), BS), MB)
-                          for b in range(B))
+    def pool_bytes_fetched(_bt, lens, _layer, first=None):
+        # what the kernel copies out of ONE pool: the pages it visits,
+        # a slot's live pages from its first live one on
+        def pages(b):
+            start = 0 if first is None else min(int(first[b]),
+                                                int(lens[b])) // BS
+            return min(pl.cdiv(int(lens[b]), BS) - start, MB)
+        return page * sum(pages(b) for b in range(B))
 
+    windowed = first is not None
+    prefetch = (jnp.asarray(block_tables, jnp.int32),
+                jnp.asarray(seq_lens, jnp.int32),
+                jnp.asarray(layer, jnp.int32).reshape(1))
+    if windowed:
+        prefetch += (jnp.asarray(first, jnp.int32),)
     out = audited_pallas_call(
         functools.partial(_decode_kernel, scale=scale, bs=BS, kv=KV,
-                          groups=groups, pp=pp, mb=MB),
+                          groups=groups, pp=pp, mb=MB, windowed=windowed),
         name="paged_attention_decode",
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
@@ -312,7 +368,5 @@ def paged_attention_decode_pallas(q, k_pool, v_pool, block_tables,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         fetched_bytes={1: pool_bytes_fetched, 2: pool_bytes_fetched},
         interpret=_interpret(),
-    )(jnp.asarray(block_tables, jnp.int32),
-      jnp.asarray(seq_lens, jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, k_pool, v_pool)
+    )(*prefetch, q, k_pool, v_pool)
     return out

@@ -85,9 +85,11 @@ def _call_flash():
 
 def _call_paged():
     from paddle_tpu.ops.paged_attention import paged_attention_decode
-    pool = jnp.ones((4, 8, 2, 16), jnp.float32)
+    # head_dim 128: at 16 the registry itself keeps the launch off a
+    # TPU (whole 128-lane rows), and nothing would be there to fail
+    pool = jnp.ones((4, 8, 2, 128), jnp.float32)
     return paged_attention_decode(
-        jnp.ones((2, 2, 16), jnp.float32), pool, pool,
+        jnp.ones((2, 2, 128), jnp.float32), pool, pool,
         jnp.zeros((2, 2), jnp.int32), jnp.ones((2,), jnp.int32))
 
 
@@ -220,8 +222,12 @@ def test_chip_smoke_rehearsal_ends_ok_and_names_the_cpu():
         "ok": True,
         "device": {"platform": "cpu", "kind": "cpu", "count": count}}
     phases = {json.loads(ln)["phase"]: json.loads(ln) for ln in lines[:-1]}
-    assert set(phases) == {"start", "sync", "serving", "training"}
-    s, t = phases["serving"], phases["training"]
+    assert set(phases) == {"start", "sync", "serving", "pattern",
+                           "training"}
+    s, t, p = phases["serving"], phases["training"], phases["pattern"]
+    # the window + global + expert model: a prompt past the window
+    assert p["first_token_equal"] and p["window_pages_released"] > 0
+    assert max(p["prompt_lens"]) > p["window"]["positions"]
     assert s["finished"] == s["requests"] == 6
     assert s["first_token_equal"] and s["retrace_warnings"] == 0
     assert max(s["prompt_lens"]) > max(s["prefill_buckets"])

@@ -301,13 +301,13 @@ def _lowered_decode_program(topo, config):
     model = importlib.import_module(module)
     cfg = getattr(model, cls)(**{k: conf[k]
                                  for k in conf["program"]["config_keys"]})
-    recurrent = getattr(cfg, "num_recurrent_layers", 0) > 0
+    pattern = hybrid.served_pattern(cfg)
     eng, tp = conf["engine"], conf["engine"].get("mesh", 1)
     mesh = Mesh(np.array(topo.devices[:tp]), ("tp",))
     params = jax.eval_shape(lambda: model.init_params(cfg))
     specs = jax.tree_util.tree_map(lambda _: P(), params)
     pool_spec = P()
-    if recurrent:
+    if pattern is not None:
         step = lambda p, tok, seq, tab, kp, vp, st: hybrid.decode_step(  # noqa: E731
             p, tok, cfg, kp, vp, tab, seq, st)
     elif tp == 1:
@@ -329,10 +329,10 @@ def _lowered_decode_program(topo, config):
                 eng["num_blocks"], BS, cfg.num_key_value_heads,
                 cfg.head_dim), cfg.dtype, pool_spec)
     state = ()
-    if recurrent:       # the slots' state: one more donated argument
-        state = (jax.tree_util.tree_map(
-            lambda v: sds(v.shape, v.dtype), jax.eval_shape(
-                lambda: hybrid.init_state(cfg, C, jnp.float32))),)
+    if pattern is not None:
+        # the model's own state (a recurrent state a slot, the window
+        # layers' pools and rings): one more donated argument
+        state = (_pattern_state(cfg, pattern, eng, sds),)
 
     def program(params, tok, seq_lens, tables, temps, key, k_pools,
                 v_pools, *state):
@@ -342,12 +342,25 @@ def _lowered_decode_program(topo, config):
                 jnp.where(seq_lens > 0, seq_lens + 1, 0), key, k_pools,
                 v_pools, *state)
 
-    donate = ServingEngine._DECODE_DONATE + ((8,) if recurrent else ())
+    donate = ServingEngine._DECODE_DONATE + (
+        (8,) if pattern is not None else ())
     lowered = jax.jit(program, donate_argnums=donate).lower(
         params, sds((C,), jnp.int32), sds((C,), jnp.int32),
         sds((C, -(-eng["max_seq_len"] // BS)), jnp.int32),
         sds((C,), jnp.float32), sds((2,), jnp.uint32), pool, pool, *state)
     return lowered, int(np.prod(pool.shape)) * 2 // tp
+
+
+def _pattern_state(cfg, pattern, eng, sds):
+    """A pattern-run model's state at an engine's geometry, as structs."""
+    from paddle_tpu.inference import hybrid
+    C, BS = eng["capacity"], eng["block_size"]
+    ring = pattern.ring(BS, max(eng["prefill_buckets"])) \
+        if pattern.window else 0
+    return jax.tree_util.tree_map(
+        lambda v: sds(v.shape, v.dtype), jax.eval_shape(
+            lambda: hybrid.init_state(cfg, C, jnp.float32, C * ring + 1,
+                                      BS, ring)))
 
 
 _DENSE_LAUNCHES = {"paged_attention_decode", "decode_mlp_block"}
@@ -360,7 +373,10 @@ _DENSE_LAUNCHES = {"paged_attention_decode", "decode_mlp_block"}
         # one attention layer's pool (0.27 GB) beside nine expert layers'
         # buffers (0.06 GB of temporaries): under half a pool
         ("granite-4.0-h-small-l10-e36",
-         {"paged_attention_decode", "ssm_update"}, 2))])
+         {"paged_attention_decode", "ssm_update"}, 2),
+        # two page classes: the global pool (0.54 GB) is the measure,
+        # the window pools ride in the state and are written in place
+        ("mellum2-12b-a2.5b-l8", {"paged_attention_decode"}, 2))])
 def test_decode_program_holds_no_second_copy_of_a_pool(
         topo, monkeypatch, config, launches, share):
     """The layer loop's pools are carried and written in place and its

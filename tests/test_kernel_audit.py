@@ -592,7 +592,7 @@ def test_paged_attention_refusals_each_name_their_reason():
 
     def refusal(**kw):
         meta = {"backend": "tpu", "interpret": False,
-                "pool_dtype": "bfloat16", **kw}
+                "pool_dtype": "bfloat16", "head_dim": 128, **kw}
         rows = {r["name"]: r for r in
                 KERNELS.explain("paged_attention_decode", meta)}
         assert rows["xla"]["selected"] != rows["pallas"]["selected"]
@@ -600,8 +600,12 @@ def test_paged_attention_refusals_each_name_their_reason():
             else rows["pallas"]["reason"]
 
     assert set(pa.decode_attention_meta(jnp.bfloat16)) == {
-        "backend", "interpret", "pool_dtype"}
+        "backend", "interpret", "pool_dtype", "head_dim"}
     assert refusal() is None
+    # the v5e compiler refuses the launch at head_dim 64: supports()
+    # says so, and a whole number of 128-lane rows passes
+    assert "head_dim 64" in refusal(head_dim=64)
+    assert refusal(head_dim=256) is None
     assert "interpret" in refusal(interpret=True)
     assert "'cpu'" in refusal(backend="cpu")
     assert "int8 pools" in refusal(pool_dtype="int8")

@@ -111,15 +111,19 @@ def test_span_names_are_frozen():
     assert SERVE_SPANS == (
         "serve/step", "serve/admit", "serve/state_reset",
         "serve/prefill_stage", "serve/prefill_dispatch", "serve/first_token_sync",
-        "serve/table_upload", "serve/decode_dispatch", "serve/token_sync",
+        "serve/window_release", "serve/table_upload",
+        "serve/decode_dispatch", "serve/token_sync",
         "serve/emit", "serve/observe")
     assert TRAIN_SPANS == ("train/stage", "train/dispatch", "train/sync")
 
 
 # serve/state_reset is entered only by an engine whose model has
-# recurrent layers: tests/test_granite_hybrid_serving.py holds it to
-# the same two sinks
-DENSE_SPANS = tuple(n for n in SERVE_SPANS if n != "serve/state_reset")
+# recurrent layers, serve/window_release only by one whose model has
+# window layers: tests/test_granite_hybrid_serving.py and
+# tests/test_mellum_serving.py hold them to the same two sinks
+DENSE_SPANS = tuple(n for n in SERVE_SPANS
+                    if n not in ("serve/state_reset",
+                                 "serve/window_release"))
 
 
 @pytest.mark.parametrize("name", DENSE_SPANS)
