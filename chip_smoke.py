@@ -406,7 +406,9 @@ def phase_training(jax, jnp, np, args, clock):
         params=int(n_params), batch=list(toks.shape), steps=len(losses),
         losses=[round(x, 4) for x in losses],
         learning_rate=LEARNING_RATE,
-        fused_optimizer=bool(tr._fused), step_kernels_compiled=found,
+        fused_optimizer=bool(tr._fused),
+        optimizer_variant=tr.metrics()["optimizer_variant"],
+        step_kernels_compiled=found,
         # host share of a step: stage + dispatch against the wait for
         # the device (a blocking transfer in the step plumbing shows here)
         step_phase_ms_mean={k: latency[k]["mean"] for k in
@@ -421,8 +423,10 @@ def phase_training(jax, jnp, np, args, clock):
                          "one-chip mesh did not take the fused AdamW")
         want = {"flash_attention_fwd", "flash_attention_bwd_dq",
                 "flash_attention_bwd_dkv", "linear_ce_fwd",
-                "linear_ce_bwd_dx", "linear_ce_bwd_dh"} | (
-            {"fused_adamw"} if tr._fused else set())
+                "linear_ce_bwd_dx", "linear_ce_bwd_dh", "fused_adamw"}
+        picked = tr.optimizer_variant
+        check(picked["variant"] == "pallas_fused" and picked["block"],
+              f"the step's optimizer is not the Pallas launch: {picked}")
         check(want <= set(found),
               f"the compiled train step lacks {sorted(want - set(found))}")
 

@@ -313,6 +313,12 @@ def kernel_cases() -> List[KernelCase]:
           _adamw_case(1024, "float32", "float32", None)),
         C("fused_adamw", "flagship_train", ("fused_adamw",),
           _adamw_case(4 << 20, "float32", "bfloat16", "bfloat16")),
+        # the most VMEM a (ROWS, LANES) block takes (fp32 moments and a
+        # shadow) and a flat count that leaves a ragged last block, as
+        # the trainer's BLOCK-padded state does
+        C("fused_adamw", "f32_moments_ragged", ("fused_adamw",),
+          _adamw_case((4 << 20) + (32 << 10), "float32", "float32",
+                      "bfloat16")),
         C("paged_attention", "tiny", ("paged_attention_decode",),
           _paged_case(2, 4, 2, 16, 8, 8, 4, "float32")),
         C("paged_attention", "flagship_serving",

@@ -234,6 +234,8 @@ class Trainer:
         self._fused_opt = fused_optimizer
         self._fused = False
         self._flat_meta = None
+        # what the step's optimizer compiled in; None until it traces
+        self._optimizer_variant = None
         self.moment_dtype = moment_dtype
         # throughput counters exist in both modes (cheap dict ticks —
         # the frozen metrics schema needs them); the harness itself is
@@ -286,6 +288,28 @@ class Trainer:
                         for v in leaves)
                 and len(non_f32) <= 1)
 
+    @staticmethod
+    def _flat_layout(params):
+        """``_flat_meta`` of a parameter tree (arrays or shapes): how
+        the fused path lays its flat master / moment state out."""
+        from ..ops.pallas.fused_adamw import BLOCK
+        leaves = jax.tree_util.tree_leaves(params)
+        sizes = [int(np.prod(v.shape)) for v in leaves]
+        # pad the flat state to a kernel-block multiple: whole tiles of
+        # the launch's 2-D view at every dtype, so the view is a bitcast
+        # and the launch pads nothing. Padding tail sees zero grads, so
+        # its moments stay zero.
+        pad = (-sum(sizes)) % BLOCK
+        # one low-precision shadow dtype; fp32 leaves slice back
+        # from the master itself (exact) so an all-fp32 tree needs
+        # no shadow output at all
+        non_f32 = [v.dtype for v in leaves
+                   if v.dtype != jnp.dtype(jnp.float32)]
+        return (jax.tree_util.tree_structure(params),
+                [v.shape for v in leaves], sizes,
+                non_f32[0] if non_f32 else None, pad,
+                [v.dtype for v in leaves])
+
     def _decide_fused(self, params) -> bool:
         if self._fused_opt is not None:
             return bool(self._fused_opt)
@@ -324,27 +348,8 @@ class Trainer:
         mdt = self.moment_dtype or jnp.float32
         if self._fused:
             leaves = jax.tree_util.tree_leaves(params)
-            n = sum(int(np.prod(v.shape)) for v in leaves)
-            # pad the flat state to a kernel-block multiple: an awkward
-            # total would force fused_adamw onto its internal padding
-            # path every step. Padding tail sees zero grads, so its
-            # moments stay zero.
-            from ..ops.pallas.fused_adamw import BLOCK
-            pad = (-n) % BLOCK
-            # one low-precision shadow dtype; fp32 leaves slice back
-            # from the master itself (exact) so an all-fp32 tree needs
-            # no shadow output at all
-            non_f32 = [v.dtype for v in leaves
-                       if v.dtype != jnp.dtype(jnp.float32)]
-            pdtype = non_f32[0] if non_f32 else None
-            self._flat_meta = (
-                jax.tree_util.tree_structure(params),
-                [v.shape for v in leaves],
-                [int(np.prod(v.shape)) for v in leaves],
-                pdtype,
-                pad,
-                [v.dtype for v in leaves],
-            )
+            self._flat_meta = self._flat_layout(params)
+            pad = self._flat_meta[4]
             master = jnp.concatenate(
                 [jnp.ravel(v).astype(jnp.float32) for v in leaves]
                 + ([jnp.zeros((pad,), jnp.float32)] if pad else []))
@@ -404,6 +409,8 @@ class Trainer:
                 new_state, gnorm = _adamw_update(
                     grads, state_tree, lr, b1=hp["b1"], b2=hp["b2"],
                     eps=1e-8, wd=hp["wd"], grad_clip=hp["grad_clip"])
+                self._optimizer_variant = {"variant": "per_leaf",
+                                           "block": None}
             metrics = {"loss": loss, "grad_norm": gnorm}
             if nan_check:
                 # FLAGS_check_nan_inf inside the compiled hybrid-parallel
@@ -430,9 +437,13 @@ class Trainer:
         feeds the multi-tensor kernel, and the updated shadow is sliced
         back into the param tree shapes. The kernel is registry-
         dispatched (``adamw_update``): the Pallas multi-tensor kernel
-        on TPU, its bit-matching jnp composition under interpret mode —
-        the dispatch inputs are covered by ``_fused_train_key``."""
-        from ..ops.pallas.fused_adamw import adamw_update
+        on TPU, the jnp composition of the same arithmetic under
+        interpret mode — the dispatch inputs are covered by
+        ``_fused_train_key``; what it picked while the step traced is
+        ``optimizer_variant``."""
+        from ..ops.pallas.fused_adamw import (LANES, adamw_update,
+                                              variant_record)
+        from ..ops.pallas.registry import KERNELS
         hp = self.hp
         treedef, shapes, sizes, pdtype, pad, dtypes = self._flat_meta
         _, master, mu, nu, step = state_tree
@@ -453,22 +464,36 @@ class Trainer:
         scale = jnp.minimum(1.0, hp["grad_clip"]
                             / jnp.maximum(gnorm, 1e-12)) \
             if hp["grad_clip"] else jnp.float32(1.0)
-        outs = adamw_update(
-            master, g_flat, mu, nu, lr, step_n.astype(jnp.float32),
-            beta1=hp["b1"], beta2=hp["b2"], epsilon=1e-8,
-            weight_decay=hp["wd"], grad_scale=scale, shadow_dtype=pdtype)
+        with KERNELS.record() as picked:
+            outs = adamw_update(
+                master, g_flat, mu, nu, lr, step_n.astype(jnp.float32),
+                beta1=hp["b1"], beta2=hp["b2"], epsilon=1e-8,
+                weight_decay=hp["wd"], grad_scale=scale,
+                shadow_dtype=pdtype)
+        self._optimizer_variant = variant_record(picked, master.shape[0])
         if pdtype is not None:
             master_n, mu_n, nu_n, shadow = outs
         else:
             master_n, mu_n, nu_n = outs
             shadow = master_n
+        # fp32 leaves come back exact from the master; the rest from
+        # the single low-precision shadow written in the same pass
+        flat = {True: master_n, False: shadow}
+        # ... as rows of the launch's own (rows, 128) view (a bitcast of
+        # the flat vector) where a leaf is whole rows: XLA turns a slice
+        # of the FLAT fp32 master into a re-layout of the whole master
+        # (``f32[n / 4096, 4096] reshape``: 8 ms a step at the training
+        # cell), then slices that
+        rows = {k: v.reshape(-1, LANES) for k, v in flat.items()}
         leaves, off = [], 0
         for shp, sz, dt in zip(shapes, sizes, dtypes):
-            # fp32 leaves come back exact from the master; the rest from
-            # the single low-precision shadow written in the same pass
-            src = master_n if dt == jnp.dtype(jnp.float32) else shadow
-            leaves.append(jax.lax.slice(src, (off,),
-                                        (off + sz,)).reshape(shp))
+            exact = dt == jnp.dtype(jnp.float32)
+            if off % LANES == 0 and sz % LANES == 0:
+                leaf = jax.lax.slice(rows[exact], (off // LANES, 0),
+                                     ((off + sz) // LANES, LANES))
+            else:
+                leaf = jax.lax.slice(flat[exact], (off,), (off + sz,))
+            leaves.append(leaf.reshape(shp))
             off += sz
         params_n = jax.tree_util.tree_unflatten(treedef, leaves)
         return (params_n, master_n, mu_n, nu_n, step_n), gnorm
@@ -790,6 +815,19 @@ class Trainer:
                 "with Trainer(..., observability=True)")
         return self._obs
 
+    @property
+    def optimizer_variant(self) -> Dict:
+        """Which optimizer the compiled step holds: ``{"variant": ...,
+        "block": ...}`` — ``"pallas_fused"`` with the ``[R, W]`` blocks
+        the launch streams the flat state in, ``"unfused"`` (XLA's own
+        fusion over the flat state) or ``"per_leaf"`` (no flat state),
+        both without blocks. It IS the registry's record of the
+        dispatch made while the step traced (``KERNELS.record``, as
+        ``ServingEngine.decode_variant``), so it cannot drift from the
+        compiled program. Before the first step the names are None."""
+        return dict(self._optimizer_variant
+                    or {"variant": None, "block": None})
+
     def metrics(self) -> Dict:
         """Training telemetry snapshot. Base keys (both modes): step /
         sample / token counters and throughput over the current window.
@@ -813,6 +851,7 @@ class Trainer:
                                 if wall > 0 else 0.0)
         c["tokens_per_sec"] = (round(c["tokens"] / wall, 3)
                                if wall > 0 else 0.0)
+        c["optimizer_variant"] = self.optimizer_variant
         if "audit_findings" in self.counters:
             # conditional key (the prefix_cache idiom): present only
             # after a static program audit ran against this trainer

@@ -185,6 +185,57 @@ def test_kernel_compiles_for_v5e(topo, name, build, want, selected):
     assert want <= _kernels(_compile(topo, build))
 
 
+# the training cell's flat optimizer state (mistral-7b-v0.3-train-l2:
+# 704,663,552 parameters, padded as Trainer._flat_layout pads it)
+N_TRAIN = -(-704_663_552 // fa.BLOCK) * fa.BLOCK
+
+
+def _state_sized_relayouts(text, n):
+    """Instructions of a compiled program that COPY an array the size
+    of the flat optimizer state into another layout, whatever shape
+    they give it (XLA turned a slice of the flat master into
+    ``f32[n / 4096, 4096] reshape``: 8 ms a step at the training cell);
+    a view that costs nothing compiles to ``bitcast``."""
+    found = []
+    for m in re.finditer(r"= (?:f32|bf16)\[([0-9,]+)\]\S* "
+                         r"(?:copy|reshape|transpose)\(.*", text):
+        if np.prod([int(d) for d in m.group(1).split(",")]) == n:
+            found.append(m.group(0)[:160])
+    return found
+
+
+@pytest.mark.parametrize("grad,moments,shadow", [
+    ("float32", BF16, BF16),            # the training cell's mix
+    (BF16, BF16, BF16),
+    ("float32", "float32", BF16),       # the most VMEM a block takes
+    ("float32", "float32", None),
+], ids=["cell", "bf16_grad", "f32_moments", "f32_moments_no_shadow"])
+def test_fused_adamw_streams_the_cells_flat_state_in_place(
+        topo, grad, moments, shadow):
+    """Every dtype mix the registry admits, at the training cell's
+    padded count, compiles with ``(ROWS, LANES)`` blocks, and the
+    ``(rows, 128)`` view of the flat vectors is a bitcast both ways: the
+    program holds no temporary and no state-sized copy."""
+    def fn(p, g, m, v, lr, step, scale):
+        return fa.fused_adamw(p, g, m, v, lr, step, grad_scale=scale,
+                              shadow_dtype=shadow)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=SingleDeviceSharding(topo.devices[0]))
+    assert _selects("fused_adamw",
+                    fa.adamw_meta(N_TRAIN, "float32", moments, bool(shadow)))
+    # master and moments donated, as the Trainer's step donates its state
+    # (without that XLA copies them to keep the caller's arrays whole)
+    compiled = jax.jit(fn, donate_argnums=(0, 2, 3)).lower(
+        on_chip((N_TRAIN,), "float32"), on_chip((N_TRAIN,), grad),
+        on_chip((N_TRAIN,), moments), on_chip((N_TRAIN,), moments),
+        *[on_chip((), "float32")] * 3).compile()
+    assert "fused_adamw" in _kernels(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert not _state_sized_relayouts(compiled.as_text(), N_TRAIN)
+
+
 def test_every_refusal_on_the_chip_names_its_reason():
     """What dispatch refuses at the smoke widths, and what the chip's
     compiler refused outright, falls back with a reason a person can
@@ -261,6 +312,65 @@ def test_train_step_on_one_device_runs_the_training_kernels(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     found = _kernels(_lowered_train_step(topo, MeshConfig()).compile())
     assert set(kc._FLASH_KERNELS) | set(kc._CE_KERNELS) <= found
+
+
+def test_training_cell_step_holds_the_2d_launch_and_no_state_sized_copy(
+        topo, monkeypatch):
+    """The benchmark's training configuration (Mistral-7B widths, depth
+    2, fp32 master, bf16 moments) as the one-chip Trainer compiles it:
+    the step holds the ``fused_adamw`` launch, ``optimizer_variant`` is
+    the record of that trace (variant and block geometry), and no
+    instruction re-lays the flat master, moments, gradient or shadow
+    out (the launch's 2-D view of them is a bitcast)."""
+    import json
+    from paddle_tpu.distributed.trainer import (MeshConfig, Trainer,
+                                                make_mesh)
+    from paddle_tpu.models import llama
+    from paddle_tpu.ops.pallas.autotune import GLOBAL_FLAGS
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "mistral-7b-v0.3-train-l2.json")) as f:
+        conf = json.load(f)
+    cfg = llama.LlamaConfig(dtype=jnp.bfloat16, **{
+        k: conf[k] for k in conf["program"]["config_keys"]})
+    opt = conf["trainer"]
+    mesh = make_mesh(MeshConfig(), devices=list(topo.devices[:1]))
+    tr = Trainer(lambda p, t, l: llama.loss_fn(p, t, l, cfg), mesh,
+                 llama.param_shardings(mesh, cfg), lr=opt["lr"],
+                 b1=opt["b1"], b2=opt["b2"],
+                 weight_decay=opt["weight_decay"],
+                 grad_clip=opt["grad_clip"],
+                 moment_dtype=getattr(jnp, opt["moment_dtype"]))
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0)))
+    # what init_state decides on the chip, from shapes alone
+    tr._fused, tr._flat_meta = True, tr._flat_layout(params)
+    n = sum(tr._flat_meta[2]) + tr._flat_meta[4]
+    assert n == N_TRAIN
+
+    def on_chip(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    state = (jax.tree_util.tree_map(
+        lambda v: on_chip(v.shape, v.dtype), params),
+        on_chip((n,), jnp.float32), on_chip((n,), jnp.bfloat16),
+        on_chip((n,), jnp.bfloat16), on_chip((), jnp.int32))
+    toks = on_chip((2, 2048), jnp.int32, tr.data_spec)
+    autotune = GLOBAL_FLAGS.get("kernel_autotune")
+    GLOBAL_FLAGS.set("kernel_autotune", False)     # as the cell sets it
+    try:
+        tr._build()
+        compiled = tr._step_fn.lower(
+            state, np.float32(opt["lr"]), toks, toks).compile()
+    finally:
+        GLOBAL_FLAGS.set("kernel_autotune", autotune)
+    assert "fused_adamw" in _kernels(compiled)
+    assert tr.metrics()["optimizer_variant"] == {
+        "variant": "pallas_fused", "block": [fa.ROWS, fa.LANES]}
+    assert not _state_sized_relayouts(compiled.as_text(), n)
 
 
 def test_gspmd_sharded_train_step_compiles_without_mosaic_kernels(

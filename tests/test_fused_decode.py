@@ -544,3 +544,110 @@ class TestFusedOptimizerPath:
             assert raised
         finally:
             GLOBAL_FLAGS.set("check_nan_inf", False)
+
+    @staticmethod
+    def _mixed_tree_trainer(fused, **kw):
+        """The llama layout at toy size (bf16 weights + fp32 norms) and
+        the training cell's optimizer state (bf16 moments)."""
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec
+        from paddle_tpu.distributed.trainer import (MeshConfig, Trainer,
+                                                    make_mesh)
+
+        def loss_fn(p, x):
+            h = jnp.tanh(x @ p["w"].astype(jnp.float32))
+            return jnp.mean(jnp.square(h * p["scale"]))
+
+        rng = np.random.RandomState(0)
+        params = {"w": jnp.asarray(rng.randn(8, 16), jnp.bfloat16),
+                  "scale": jnp.ones((16,), jnp.float32)}
+        specs = {"w": PartitionSpec(), "scale": PartitionSpec()}
+        x = jnp.asarray(rng.randn(32, 8), jnp.float32)
+        tr = Trainer(loss_fn, make_mesh(MeshConfig()), specs, lr=1e-2,
+                     grad_clip=1.0, fused_optimizer=fused, donate=False,
+                     moment_dtype=jnp.bfloat16, **kw)
+        return tr, params, x
+
+    def test_2d_launch_in_the_step_over_flat_state(self):
+        """The step's optimizer pinned to the Pallas variant (interpreted
+        here): the state stays the flat vectors it was (what a checkpoint
+        and the benchmark's per-leaf slices read), the launch views them
+        ``(rows, 128)``, ``metrics()["optimizer_variant"]`` names what the
+        step traced, and two steps track the per-leaf trainer."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas import fused_adamw as fa
+        from paddle_tpu.ops.pallas.registry import KERNELS
+
+        outs = {}
+        for fused in (False, True):
+            tr, params, x = self._mixed_tree_trainer(fused)
+            st = tr.init_state(dict(params))
+            assert tr.metrics()["optimizer_variant"] == {
+                "variant": None, "block": None}
+            if fused:
+                for flat in (st.master, st.mu, st.nu):
+                    assert flat.shape == (fa.BLOCK,)
+                assert st.master.dtype == jnp.float32
+                assert st.mu.dtype == st.nu.dtype == jnp.bfloat16
+            with KERNELS.force("fused_adamw", "pallas_fused"):
+                for _ in range(2):
+                    st, m = tr.step(st, x)
+            outs[fused] = (np.asarray(m["loss"]),
+                           {k: np.asarray(v, np.float32)
+                            for k, v in st.params.items()})
+            assert tr.metrics()["optimizer_variant"] == (
+                {"variant": "pallas_fused",
+                 "block": [fa.BLOCK // fa.LANES, fa.LANES]} if fused
+                else {"variant": "per_leaf", "block": None})
+        # a later trace under another pin is another record
+        tr.step(st, x)
+        assert tr.optimizer_variant == {"variant": "unfused", "block": None}
+        np.testing.assert_allclose(outs[True][0], outs[False][0],
+                                   rtol=1e-3, atol=1e-4)
+        for k in outs[True][1]:
+            np.testing.assert_allclose(outs[True][1][k], outs[False][1][k],
+                                       rtol=2e-2, atol=2e-3)
+
+    def test_checkpoint_round_trip_of_the_flat_state(self, tmp_path):
+        """A fused trainer's state through ``dist.save_state_dict`` /
+        ``load_state_dict``: on disk the flat state has the shape it
+        always had (a multiple of BLOCK, unchanged by the 2-D launch),
+        and a step from the restored state is the step from the saved
+        one, bit for bit."""
+        import jax
+        import jax.numpy as jnp
+        import paddle_tpu.distributed as dist
+        from paddle_tpu.distributed.trainer import TrainState
+        from paddle_tpu.ops.pallas import fused_adamw as fa
+        from paddle_tpu.ops.pallas.registry import KERNELS
+
+        tr, params, x = self._mixed_tree_trainer(True)
+        st = tr.init_state(dict(params))
+        with KERNELS.force("fused_adamw", "pallas_fused"):
+            st, _ = tr.step(st, x)
+            names = ("params", "master", "mu", "nu", "step")
+            # npz holds no bfloat16: those leaves travel as their bits
+            bits = lambda v: (jax.lax.bitcast_convert_type(v, jnp.uint16)  # noqa: E731
+                              if v.dtype == jnp.bfloat16 else v)
+            saved = jax.tree_util.tree_map(bits, dict(zip(names, st.tree())))
+            dist.save_state_dict(saved, str(tmp_path))
+            # a target that is no Tensor is handed back under its flat key
+            flat = dict.fromkeys([f"params.{k}" for k in params]
+                                 + list(names[1:]))
+            dist.load_state_dict(flat, str(tmp_path))
+            for k in ("master", "mu", "nu"):
+                assert flat[k].shape == (fa.BLOCK,)
+            unbits = lambda v, like: (  # noqa: E731
+                jax.lax.bitcast_convert_type(jnp.asarray(v), jnp.bfloat16)
+                if like.dtype == jnp.bfloat16 else jnp.asarray(v, like.dtype))
+            restored = TrainState(
+                {k: unbits(flat[f"params.{k}"], st.params[k])
+                 for k in params},
+                *(unbits(flat[k], getattr(st, k)) for k in names[1:]))
+            a, ma = tr.step(st, x)
+            b, mb = tr.step(restored, x)
+        assert float(ma["loss"]) == float(mb["loss"])
+        for u, v in zip(jax.tree_util.tree_leaves(a.tree()),
+                        jax.tree_util.tree_leaves(b.tree())):
+            np.testing.assert_array_equal(np.asarray(u, np.float32),
+                                          np.asarray(v, np.float32))

@@ -286,6 +286,22 @@ def test_rule_vmem_counts_prefetch_streamed_pages_double_buffered():
     assert found[0].detail["need_bytes"] == (28 << 20) + 64  # + out windows
 
 
+def test_rule_vmem_refuses_the_adamw_block_the_chip_refuses(monkeypatch):
+    """``fused_adamw``'s block height is chosen against the scoped
+    VMEM: at 4096 rows the chip's compiler refuses every dtype mix
+    ("vmem while allocating on stack"), and the window model refuses
+    the catalog's cases with it; at the module's ROWS both pass."""
+    from paddle_tpu.analysis.kernel_catalog import audit_case, kernel_cases
+    cases = [c for c in kernel_cases() if c.name.startswith("fused_adamw@")
+             and not c.name.endswith("@tiny")]
+    assert len(cases) == 2
+    for case in cases:
+        assert audit_case(case).findings == []
+    monkeypatch.setattr(fa, "ROWS", 4096)
+    for case in cases:
+        assert _codes(audit_case(case).findings) == ["VMEM_OVERCOMMIT"]
+
+
 def test_rule_scratch_mismatch():
     from paddle_tpu.analysis.kernel_rules import check_launch
 

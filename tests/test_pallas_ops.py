@@ -138,25 +138,73 @@ class TestFusedAdamW:
         np.testing.assert_allclose(np.asarray(p2), p_ref, rtol=1e-5,
                                    atol=1e-6)
 
-    def test_prime_length_pads_not_degrades(self):
-        # awkward (prime) n must pad to a block multiple, not fall back
-        # to block=1 with an n-wide sequential grid; outputs keep n
-        from paddle_tpu.ops.pallas.fused_adamw import fused_adamw
-        rng = np.random.RandomState(1)
-        n = 1009  # prime
-        p = jnp.asarray(rng.randn(n), jnp.float32)
-        g = jnp.asarray(rng.randn(n), jnp.float32)
-        m = jnp.zeros(n, jnp.float32)
-        v = jnp.zeros(n, jnp.float32)
-        p2, m2, v2 = fused_adamw(p, g, m, v, lr=0.1, step=1.0,
-                                 weight_decay=0.01)
-        assert p2.shape == (n,) and m2.shape == (n,) and v2.shape == (n,)
-        m_ref = 0.1 * np.asarray(g)
-        vhat = (0.001 * np.asarray(g) ** 2) / (1 - 0.999)
-        p_ref = np.asarray(p) * (1 - 0.1 * 0.01) - \
-            0.1 * (m_ref / (1 - 0.9)) / (np.sqrt(vhat) + 1e-8)
-        np.testing.assert_allclose(np.asarray(p2), p_ref, rtol=1e-5,
-                                   atol=1e-6)
+    # the flat count of each layout, in units the module names: whole
+    # blocks, a ragged last block, less than one block, and a count the
+    # 1-D entry has to pad (prime: no tile, no lane divides it)
+    LAYOUTS = {
+        "whole_blocks": lambda fa: 2 * fa.ROWS * fa.LANES,
+        "ragged_last_block": lambda fa: (fa.ROWS + 256) * fa.LANES,
+        "below_one_block": lambda fa: 48 * fa.LANES,
+        "flat_awkward_n": lambda fa: 1009,
+    }
+
+    @pytest.mark.parametrize("grad_scale", [None, 0.37])
+    @pytest.mark.parametrize("layout", sorted(LAYOUTS))
+    @pytest.mark.parametrize("shadow", [None, "bfloat16"])
+    @pytest.mark.parametrize("moments", ["float32", "bfloat16"])
+    def test_2d_launch_matches_reference(self, moments, shadow, layout,
+                                         grad_scale):
+        """The (rows, LANES) launch, interpreted, against
+        ``adamw_update_ref`` on the flat vectors: same dtypes and shapes
+        out, and every number within one unit of the last place of the
+        larger operand of the sum that made it. Not bit for bit here:
+        XLA's CPU backend contracts a multiply and an add into one
+        rounding where they share a fusion, and the interpreted launch
+        and the composition fuse differently (measured: 1-3% of the
+        master's numbers differ, by that one unit). On the chip the
+        launch equals the 1-D kernel it replaces bit for bit (PERF.md
+        section 6, PR 33)."""
+        from paddle_tpu.ops.pallas import fused_adamw as fa
+        n = self.LAYOUTS[layout](fa)
+        rng = np.random.RandomState(n % 1000)
+        mdt = jnp.dtype(moments)
+        p = jnp.asarray(rng.randn(n) * 0.02, jnp.float32)
+        # gradients over six decades, as a real tree's leaves differ
+        g = jnp.asarray(rng.randn(n) * np.exp2(rng.randint(-20, 0, n)),
+                        jnp.float32)
+        m = jnp.asarray(rng.randn(n) * 1e-4, mdt)
+        v = jnp.asarray(rng.randn(n) ** 2 * 1e-7, mdt)
+        kw = dict(beta1=0.9, beta2=0.95, weight_decay=0.1,
+                  grad_scale=grad_scale, shadow_dtype=shadow)
+        want = fa.adamw_update_ref(p, g, m, v, 3e-4, 2.0, **kw)
+        if layout == "flat_awkward_n":
+            got = fa.fused_adamw(p, g, m, v, 3e-4, 2.0, **kw)
+        else:
+            got = [o.reshape(-1) for o in fa.fused_adamw_2d(
+                *(x.reshape(-1, fa.LANES) for x in (p, g, m, v)),
+                3e-4, 2.0, **kw)]
+        assert len(got) == len(want) == (4 if shadow else 3)
+        f32 = lambda x: np.asarray(x, np.float32)  # noqa: E731
+        # the larger operand of the sum behind each output (the second
+        # moment's terms are both positive: the output itself)
+        p_big = np.maximum(np.abs(f32(p)), np.abs(f32(want[0]) - f32(p)))
+        m_big = np.maximum(0.9 * np.abs(f32(m)),
+                           0.1 * np.abs(f32(g)) * (grad_scale or 1.0))
+        bigs = [p_big, m_big, f32(want[2]), p_big]
+        for a, b, big, units in zip(got, want, bigs, (16, 2, 2, 16)):
+            assert a.dtype == b.dtype and a.shape == b.shape == (n,)
+            allowed = units * 2.0 ** -23 * big
+            if b.dtype == jnp.bfloat16:   # and one flip of its rounding
+                allowed = allowed + 2.0 ** -7 * np.abs(f32(b))
+            worst = np.max(np.abs(f32(a) - f32(b)) - allowed)
+            assert worst <= 0, (str(b.dtype), float(worst))
+
+    def test_2d_entry_refuses_what_is_not_whole_tiles(self):
+        from paddle_tpu.ops.pallas import fused_adamw as fa
+        for shape in ((16, 256), (24, fa.LANES)):
+            z = jnp.zeros(shape, jnp.float32)
+            with pytest.raises(ValueError, match="multiple of 16"):
+                fa.fused_adamw_2d(z, z, z, z, 1e-3, 1.0)
 
 
 class TestNormRowPadding:

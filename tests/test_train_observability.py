@@ -51,7 +51,7 @@ def _batch(seed=0, b=2, s=8):
 # -- trainer metrics schema contract ------------------------------------
 
 BASE_KEYS = {"steps", "samples", "tokens", "wall_time_s",
-             "samples_per_sec", "tokens_per_sec"}
+             "samples_per_sec", "tokens_per_sec", "optimizer_variant"}
 OBS_KEYS = {"latency", "gauges", "compile", "compiles",
             "retrace_warnings", "mfu", "hbm", "host_gap_findings",
             "stall_dumps", "timeline_events", "timeline_dropped"}
@@ -69,6 +69,8 @@ def test_trainer_metrics_schema_frozen_disabled():
     m = tr.metrics()
     assert set(m.keys()) == BASE_KEYS
     assert m["steps"] == 2
+    # a multi-leaf CPU trainer takes no flat state: the record says so
+    assert m["optimizer_variant"] == {"variant": "per_leaf", "block": None}
     assert m["samples"] == 4 and m["tokens"] == 32
     assert m["tokens_per_sec"] > 0
 
