@@ -32,7 +32,8 @@ from ..core.backend import on_tpu
 from ..observability import (CompileWatcher, HostGapDetector,
                              Observability, TRAIN_HISTOGRAMS,
                              TelemetryConfig, TelemetryPlane,
-                             live_hbm_bytes, span)
+                             live_hbm_bytes, programs as _programs, span,
+                             tracing)
 
 __all__ = ["MeshConfig", "make_mesh", "TrainState", "Trainer"]
 
@@ -98,20 +99,22 @@ def _adamw_update(grads, state: Tuple, lr, b1=0.9, b2=0.95, eps=1e-8,
                   wd=0.1, grad_clip=1.0):
     params, master, mu, nu, step = state
     step = step + 1
-    gnorm_sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                   for g in jax.tree_util.tree_leaves(grads))
-    gnorm = jnp.sqrt(gnorm_sq)
-    scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-12)) \
-        if grad_clip else 1.0
+    with jax.named_scope("optimizer/clip"):
+        gnorm_sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                       for g in jax.tree_util.tree_leaves(grads))
+        gnorm = jnp.sqrt(gnorm_sq)
+        scale = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-12)) \
+            if grad_clip else 1.0
     # bias corrections pinned to float32: `b1 ** step` with an int32
     # step promotes through float64 under the global x64 flag (the
     # Python float drops its weak type against the integer array),
     # which widened the whole master tree after step 1 and recompiled
     # step 2 in every earlier bench window. pow(f32, f32) is the same
     # computation the weak-typed path ran in f32 mode — bit-identical.
-    stepf = step.astype(jnp.float32)
-    bc1 = 1.0 - jnp.float32(b1) ** stepf
-    bc2 = 1.0 - jnp.float32(b2) ** stepf
+    with jax.named_scope("optimizer/update"):
+        stepf = step.astype(jnp.float32)
+        bc1 = 1.0 - jnp.float32(b1) ** stepf
+        bc2 = 1.0 - jnp.float32(b2) ** stepf
 
     def upd(g, m, mu_i, nu_i):
         g32 = g.astype(jnp.float32) * scale
@@ -130,16 +133,18 @@ def _adamw_update(grads, state: Tuple, lr, b1=0.9, b2=0.95, eps=1e-8,
     flat_nu = jax.tree_util.tree_leaves(nu)
     treedef = jax.tree_util.tree_structure(grads)
     new_m, new_mu, new_nu = [], [], []
-    for g, m, mi, ni in zip(flat_g, flat_m, flat_mu, flat_nu):
-        a, b, c = upd(g, m, mi, ni)
-        new_m.append(a)
-        new_mu.append(b)
-        new_nu.append(c)
+    with jax.named_scope("optimizer/update"):
+        for g, m, mi, ni in zip(flat_g, flat_m, flat_mu, flat_nu):
+            a, b, c = upd(g, m, mi, ni)
+            new_m.append(a)
+            new_mu.append(b)
+            new_nu.append(c)
     master_n = jax.tree_util.tree_unflatten(treedef, new_m)
     mu_n = jax.tree_util.tree_unflatten(treedef, new_mu)
     nu_n = jax.tree_util.tree_unflatten(treedef, new_nu)
-    params_n = jax.tree_util.tree_map(
-        lambda m, p: m.astype(p.dtype), master_n, params)
+    with jax.named_scope("optimizer/params_out"):
+        params_n = jax.tree_util.tree_map(
+            lambda m, p: m.astype(p.dtype), master_n, params)
     return (params_n, master_n, mu_n, nu_n, step), gnorm
 
 
@@ -230,6 +235,10 @@ class Trainer:
         self.hp = dict(b1=b1, b2=b2, wd=weight_decay, grad_clip=grad_clip)
         self.accumulate_steps = accumulate_steps
         self._step_fn = None
+        # the registry of compiled programs (observability/programs.py)
+        # captures the step at its first dispatch UNDER A PROFILER SESSION
+        self._program_keys = []
+        self._noted = False
         self._donate = donate
         self._fused_opt = fused_optimizer
         self._fused = False
@@ -400,7 +409,8 @@ class Trainer:
                     micro, (jnp.zeros((), jnp.float32), zero_g), batch)
                 n = self.accumulate_steps
                 loss = tot_loss / n
-                grads = jax.tree_util.tree_map(lambda g: g / n, grads)
+                with jax.named_scope("optimizer/grads"):
+                    grads = jax.tree_util.tree_map(lambda g: g / n, grads)
             else:
                 loss, grads = jax.value_and_grad(loss_of)(params, *batch)
             if self._fused:
@@ -426,6 +436,7 @@ class Trainer:
         self._step_nan = nan_check
         self._step_fused = _fused_train_key()
         self._step_fn = jax.jit(step_fn, donate_argnums=donate)
+        self._noted = False
         if self._compiled_cache is not None:
             # the program changed (nan-check flag flip): cached AOT
             # executables compile against the OLD step_fn
@@ -457,14 +468,20 @@ class Trainer:
         leaf_dts = {g.dtype for g in g_leaves}
         gdt = (pdtype if pdtype is not None
                and leaf_dts == {jnp.dtype(pdtype)} else jnp.float32)
-        g_flat = jnp.concatenate(
-            [jnp.ravel(g).astype(gdt) for g in g_leaves]
-            + ([jnp.zeros((pad,), gdt)] if pad else []))
-        gnorm = jnp.sqrt(jnp.sum(jnp.square(g_flat.astype(jnp.float32))))
-        scale = jnp.minimum(1.0, hp["grad_clip"]
-                            / jnp.maximum(gnorm, 1e-12)) \
-            if hp["grad_clip"] else jnp.float32(1.0)
-        with KERNELS.record() as picked:
+        # the named scopes are observability.PROGRAM_SCOPES: a reader of
+        # a device trace finds each operation's by them (metadata only)
+        with jax.named_scope("optimizer/grads"):
+            g_flat = jnp.concatenate(
+                [jnp.ravel(g).astype(gdt) for g in g_leaves]
+                + ([jnp.zeros((pad,), gdt)] if pad else []))
+        with jax.named_scope("optimizer/clip"):
+            gnorm = jnp.sqrt(jnp.sum(jnp.square(
+                g_flat.astype(jnp.float32))))
+            scale = jnp.minimum(1.0, hp["grad_clip"]
+                                / jnp.maximum(gnorm, 1e-12)) \
+                if hp["grad_clip"] else jnp.float32(1.0)
+        with KERNELS.record() as picked, \
+                jax.named_scope("optimizer/update"):
             outs = adamw_update(
                 master, g_flat, mu, nu, lr, step_n.astype(jnp.float32),
                 beta1=hp["b1"], beta2=hp["b2"], epsilon=1e-8,
@@ -484,17 +501,18 @@ class Trainer:
         # of the FLAT fp32 master into a re-layout of the whole master
         # (``f32[n / 4096, 4096] reshape``: 8 ms a step at the training
         # cell), then slices that
-        rows = {k: v.reshape(-1, LANES) for k, v in flat.items()}
         leaves, off = [], 0
-        for shp, sz, dt in zip(shapes, sizes, dtypes):
-            exact = dt == jnp.dtype(jnp.float32)
-            if off % LANES == 0 and sz % LANES == 0:
-                leaf = jax.lax.slice(rows[exact], (off // LANES, 0),
-                                     ((off + sz) // LANES, LANES))
-            else:
-                leaf = jax.lax.slice(flat[exact], (off,), (off + sz,))
-            leaves.append(leaf.reshape(shp))
-            off += sz
+        with jax.named_scope("optimizer/params_out"):
+            rows = {k: v.reshape(-1, LANES) for k, v in flat.items()}
+            for shp, sz, dt in zip(shapes, sizes, dtypes):
+                exact = dt == jnp.dtype(jnp.float32)
+                if off % LANES == 0 and sz % LANES == 0:
+                    leaf = jax.lax.slice(rows[exact], (off // LANES, 0),
+                                         ((off + sz) // LANES, LANES))
+                else:
+                    leaf = jax.lax.slice(flat[exact], (off,), (off + sz,))
+                leaves.append(leaf.reshape(shp))
+                off += sz
         params_n = jax.tree_util.tree_unflatten(treedef, leaves)
         return (params_n, master_n, mu_n, nu_n, step_n), gnorm
 
@@ -584,8 +602,10 @@ class Trainer:
             # one h2d when lr changes, not one per step
             self._lr_cache = (self.lr, jnp.float32(self.lr))
         with span("train/dispatch"), self.mesh:
-            new_tree, metrics = self._step_fn(state.tree(),
-                                              self._lr_cache[1], *batch)
+            args = (state.tree(), self._lr_cache[1], *batch)
+            if not self._noted and tracing():
+                self._note(self._step_fn, args)
+            new_tree, metrics = self._step_fn(*args)
         self._count_step(batch, time.perf_counter())
         if "finite" in metrics and not bool(metrics.pop("finite")):
             raise FloatingPointError(
@@ -675,6 +695,8 @@ class Trainer:
             else:
                 fn, compile_ms = self._compiled_for(
                     tree, self._lr_cache[1], staged)
+                if not self._noted and tracing():
+                    self._note(fn, None)    # the executable itself
                 try:
                     new_tree, metrics = fn(tree, self._lr_cache[1],
                                            *staged)
@@ -814,6 +836,19 @@ class Trainer:
                 "observability is disabled for this trainer; construct "
                 "with Trainer(..., observability=True)")
         return self._obs
+
+    def _note(self, fn, args):
+        """Hand the step to the registry of compiled programs, with the
+        arguments the dispatch is about to donate."""
+        self._noted = True
+        self._program_keys.append(_programs.note(fn, args))
+
+    def program_scopes(self):
+        """The compiled step as a reader of a device trace needs it
+        (``observability.programs.Program``: ``{instruction name:
+        scope}``; ``ServingEngine.program_scopes`` is the same for the
+        serving programs). Parsed on the first call, never on a step."""
+        return _programs.scopes(self._program_keys)
 
     @property
     def optimizer_variant(self) -> Dict:

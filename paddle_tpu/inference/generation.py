@@ -93,33 +93,43 @@ def _cached_layer(lp, x, sin, cos, cfg, kc, vc, pos):
                  cfg.head_dim)
     b, s, _ = x.shape
     T = kc.shape[1]
-    h = fused_rms_norm(x, lp["input_norm"].astype(x.dtype),
-                       cfg.rms_norm_eps)
-    q = _mm(h, lp["q_proj"]).reshape(b, s, H, hd)
-    k = _mm(h, lp["k_proj"]).reshape(b, s, KV, hd)
-    v = _mm(h, lp["v_proj"]).reshape(b, s, KV, hd)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
-    kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype), (0, pos, 0, 0))
-    vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype), (0, pos, 0, 0))
+    with jax.named_scope("layer/qkv"):
+        h = fused_rms_norm(x, lp["input_norm"].astype(x.dtype),
+                           cfg.rms_norm_eps)
+        q = _mm(h, lp["q_proj"]).reshape(b, s, H, hd)
+        k = _mm(h, lp["k_proj"]).reshape(b, s, KV, hd)
+        v = _mm(h, lp["v_proj"]).reshape(b, s, KV, hd)
+        q = apply_rope(q, sin, cos)
+        k = apply_rope(k, sin, cos)
+    with jax.named_scope("layer/kv_write"):
+        kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
+                                          (0, pos, 0, 0))
+        vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
+                                          (0, pos, 0, 0))
 
-    rep = H // KV
-    kk = _repeat_kv(kc, rep)    # [B, T, H, hd]
-    vv = _repeat_kv(vc, rep)
-    scale = 1.0 / math.sqrt(hd)
-    scores = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32),
-                        kk.astype(jnp.float32)) * scale
-    # causal over absolute positions: query i at pos+i sees keys <= pos+i
-    t_idx = jnp.arange(T)[None, None, None, :]
-    q_idx = pos + jnp.arange(s)[None, None, :, None]
-    scores = jnp.where(t_idx <= q_idx, scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    attn = jnp.einsum("bhst,bthd->bshd", probs, vv.astype(jnp.float32))
-    attn = attn.astype(x.dtype).reshape(b, s, H * hd)
-    x = x + _mm(attn, lp["o_proj"])
-    h = fused_rms_norm(x, lp["post_norm"].astype(x.dtype), cfg.rms_norm_eps)
-    ff = fused_swiglu(_mm(h, lp["gate_proj"]), _mm(h, lp["up_proj"]))
-    x = x + _mm(ff, lp["down_proj"])
+    with jax.named_scope("layer/attention"):
+        rep = H // KV
+        kk = _repeat_kv(kc, rep)    # [B, T, H, hd]
+        vv = _repeat_kv(vc, rep)
+        scale = 1.0 / math.sqrt(hd)
+        scores = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32),
+                            kk.astype(jnp.float32)) * scale
+        # causal over absolute positions: query i at pos+i sees keys
+        # <= pos+i
+        t_idx = jnp.arange(T)[None, None, None, :]
+        q_idx = pos + jnp.arange(s)[None, None, :, None]
+        scores = jnp.where(t_idx <= q_idx, scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        attn = jnp.einsum("bhst,bthd->bshd", probs,
+                          vv.astype(jnp.float32))
+    with jax.named_scope("layer/attn_out"):
+        attn = attn.astype(x.dtype).reshape(b, s, H * hd)
+        x = x + _mm(attn, lp["o_proj"])
+    with jax.named_scope("layer/mlp"):
+        h = fused_rms_norm(x, lp["post_norm"].astype(x.dtype),
+                           cfg.rms_norm_eps)
+        ff = fused_swiglu(_mm(h, lp["gate_proj"]), _mm(h, lp["up_proj"]))
+        x = x + _mm(ff, lp["down_proj"])
     return x, kc, vc
 
 
@@ -127,13 +137,15 @@ def cached_forward(params: Dict, tokens, cfg: _llama.LlamaConfig,
                    k_cache, v_cache, pos):
     """Forward over S tokens starting at absolute position ``pos``.
     Returns (logits [B, S, V], k_cache, v_cache)."""
-    x = jnp.take(params["embed_tokens"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed_tokens"], tokens, axis=0)
     T = k_cache.shape[2]
-    sin_full, cos_full = build_rope_cache(T, cfg.head_dim,
-                                          base=cfg.rope_theta)
     s = tokens.shape[1]
-    sin = jax.lax.dynamic_slice_in_dim(sin_full, pos, s, axis=0)
-    cos = jax.lax.dynamic_slice_in_dim(cos_full, pos, s, axis=0)
+    with jax.named_scope("layer/qkv"):        # the rotary table
+        sin_full, cos_full = build_rope_cache(T, cfg.head_dim,
+                                              base=cfg.rope_theta)
+        sin = jax.lax.dynamic_slice_in_dim(sin_full, pos, s, axis=0)
+        cos = jax.lax.dynamic_slice_in_dim(cos_full, pos, s, axis=0)
 
     def scan_fn(carry, xs):
         lp, kc, vc = xs
@@ -141,14 +153,16 @@ def cached_forward(params: Dict, tokens, cfg: _llama.LlamaConfig,
         return x, (kc, vc)
 
     from ..ops import rms_norm as fused_rms_norm
-    x, (k_cache, v_cache) = jax.lax.scan(
-        scan_fn, x, (params["layers"], k_cache, v_cache))
-    x = fused_rms_norm(x, params["final_norm"].astype(x.dtype),
-                       cfg.rms_norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed_tokens"].T
-    return x @ head, k_cache, v_cache
+    with jax.named_scope("layers"):
+        x, (k_cache, v_cache) = jax.lax.scan(
+            scan_fn, x, (params["layers"], k_cache, v_cache))
+    with jax.named_scope("head"):
+        x = fused_rms_norm(x, params["final_norm"].astype(x.dtype),
+                           cfg.rms_norm_eps)
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embed_tokens"].T
+        return x @ head, k_cache, v_cache
 
 
 def sample_token(logits, key, gen: GenerationConfig):
@@ -330,13 +344,15 @@ def _fused_prefill_forward(params, toks, cfg, k_pools, v_pools, table,
                         kv_scales is not None,
                         weight_dtype=_wq_mode(params))
     attn_fn, mlp_fn, _ = resolve_prefill_blocks(meta, mode)
-    x = jnp.take(params["embed_tokens"], toks, axis=0)       # [P, D]
-    sin_full, cos_full = build_rope_cache(MB * BS, cfg.head_dim,
-                                          base=cfg.rope_theta)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed_tokens"], toks, axis=0)   # [P, D]
     pos0 = jnp.asarray(pos0, jnp.int32)
     n_valid = jnp.asarray(n_valid, jnp.int32)
-    sin = jax.lax.dynamic_slice_in_dim(sin_full, pos0, P, axis=0)
-    cos = jax.lax.dynamic_slice_in_dim(cos_full, pos0, P, axis=0)
+    with jax.named_scope("layer/qkv"):        # the rotary table
+        sin_full, cos_full = build_rope_cache(MB * BS, cfg.head_dim,
+                                              base=cfg.rope_theta)
+        sin = jax.lax.dynamic_slice_in_dim(sin_full, pos0, P, axis=0)
+        cos = jax.lax.dynamic_slice_in_dim(cos_full, pos0, P, axis=0)
     wtable = jnp.asarray(wtable, jnp.int32)
 
     def layer(x, xs):
@@ -346,29 +362,36 @@ def _fused_prefill_forward(params, toks, cfg, k_pools, v_pools, table,
         else:
             lp, kp, vp, ksc, vsc = xs
             scales = (ksc, vsc)
-        x, k_new, v_new = attn_fn(
-            x, lp["input_norm"].astype(x.dtype), lp["q_proj"],
-            lp["k_proj"], lp["v_proj"], lp["o_proj"], sin, cos, kp, vp,
-            table, pos0, n_valid, scales, cfg.rms_norm_eps)
-        if scales is None:
-            kp, vp = write_chunk_to_pool(kp, vp, wtable, pos0, n_valid,
-                                         k_new, v_new)
-        else:
-            kp, vp = write_chunk_to_pool_quant(
-                kp, vp, wtable, pos0, n_valid, k_new, v_new, ksc, vsc)
-        x = mlp_fn(x, lp["post_norm"].astype(x.dtype), lp["gate_proj"],
-                   lp["up_proj"], lp["down_proj"], cfg.rms_norm_eps)
+        # one launch holds norm, q/k/v, rotary, attention and o_proj
+        with jax.named_scope("layer/attention"):
+            x, k_new, v_new = attn_fn(
+                x, lp["input_norm"].astype(x.dtype), lp["q_proj"],
+                lp["k_proj"], lp["v_proj"], lp["o_proj"], sin, cos, kp,
+                vp, table, pos0, n_valid, scales, cfg.rms_norm_eps)
+        with jax.named_scope("layer/kv_write"):
+            if scales is None:
+                kp, vp = write_chunk_to_pool(kp, vp, wtable, pos0,
+                                             n_valid, k_new, v_new)
+            else:
+                kp, vp = write_chunk_to_pool_quant(
+                    kp, vp, wtable, pos0, n_valid, k_new, v_new, ksc, vsc)
+        with jax.named_scope("layer/mlp"):
+            x = mlp_fn(x, lp["post_norm"].astype(x.dtype),
+                       lp["gate_proj"], lp["up_proj"], lp["down_proj"],
+                       cfg.rms_norm_eps)
         return x, (kp, vp)
 
     scan_xs = (params["layers"], k_pools, v_pools) if kv_scales is None \
         else (params["layers"], k_pools, v_pools) + tuple(kv_scales)
-    x, (k_pools, v_pools) = jax.lax.scan(layer, x, scan_xs)
-    x = fused_rms_norm(x[None], params["final_norm"].astype(x.dtype),
-                       cfg.rms_norm_eps)[0]
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed_tokens"].T
-    return x @ head, k_pools, v_pools
+    with jax.named_scope("layers"):
+        x, (k_pools, v_pools) = jax.lax.scan(layer, x, scan_xs)
+    with jax.named_scope("head"):
+        x = fused_rms_norm(x[None], params["final_norm"].astype(x.dtype),
+                           cfg.rms_norm_eps)[0]
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embed_tokens"].T
+        return x @ head, k_pools, v_pools
 
 
 def _mesh_route(sm):
@@ -466,9 +489,10 @@ def _layer_loop(params, x, k_pools, v_pools, kv_scales, layer,
         return layer(x, l, {**lp, **whole}, kp, vp, scales), None
 
     n = k_pools.shape[0]
-    (x, k_pools, v_pools), _ = jax.lax.scan(
-        body, (x, k_pools, v_pools),
-        (jnp.arange(n, dtype=jnp.int32), sliced, kv_scales))
+    with jax.named_scope("layers"):      # the loop's own bookkeeping
+        (x, k_pools, v_pools), _ = jax.lax.scan(
+            body, (x, k_pools, v_pools),
+            (jnp.arange(n, dtype=jnp.int32), sliced, kv_scales))
     return x, k_pools, v_pools
 
 
@@ -518,8 +542,9 @@ def _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
     mlp_by_index = "decode_mlp_block" in fdb.launch_operands(
         {"decode_mlp_block": mlp_name})
     eps = cfg.rms_norm_eps
-    sin, cos = build_rope_cache(cfg.max_position_embeddings,
-                                cfg.head_dim, base=cfg.rope_theta)
+    with jax.named_scope("layer/qkv"):
+        sin, cos = build_rope_cache(cfg.max_position_embeddings,
+                                    cfg.head_dim, base=cfg.rope_theta)
     # "psum": a stage returns its bare projection partial, ONE
     # all-reduce rebuilds the replicated residual stream (the partial
     # sums associate differently than the single-device reduction:
@@ -535,40 +560,53 @@ def _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
     def add(x, out):
         return out if residual else x + jax.lax.psum(out, axis)
 
+    # the named scopes are observability.PROGRAM_SCOPES: a reader of a
+    # device trace finds each operation's by them (metadata only)
     def layer(x, l, lp, kp, vp, scales):
         # the composition reads the new token from the pool: write it
         # first (once, in place), then attend over the carried pools at
         # this layer
-        q, k_new, v_new = fdb.attn_qkv_ref(
-            x, lp["input_norm"].astype(x.dtype), lp["q_proj"],
-            lp["k_proj"], lp["v_proj"], sin, cos, seq_lens, eps)
-        if scales is None:
-            kp, vp = write_to_pool(kp, vp, block_tables, seq_lens,
-                                   k_new.astype(kp.dtype),
-                                   v_new.astype(vp.dtype), layer=l)
-        else:
-            kp, vp = write_to_pool_quant(kp, vp, block_tables, seq_lens,
-                                         k_new, v_new, *scales, layer=l)
-        x = add(x, fdb.attn_out_ref(
+        with jax.named_scope("layer/qkv"):
+            q, k_new, v_new = fdb.attn_qkv_ref(
+                x, lp["input_norm"].astype(x.dtype), lp["q_proj"],
+                lp["k_proj"], lp["v_proj"], sin, cos, seq_lens, eps)
+        with jax.named_scope("layer/kv_write"):
+            if scales is None:
+                kp, vp = write_to_pool(kp, vp, block_tables, seq_lens,
+                                       k_new.astype(kp.dtype),
+                                       v_new.astype(vp.dtype), layer=l)
+            else:
+                kp, vp = write_to_pool_quant(
+                    kp, vp, block_tables, seq_lens, k_new, v_new, *scales,
+                    layer=l)
+        # attn_out_ref names its two halves itself: layer/attention,
+        # layer/attn_out
+        out = fdb.attn_out_ref(
             x, q, lp["o_proj"], kp, vp, block_tables, seq_lens, scales,
-            residual, layer=l, gather=gather))
+            residual, layer=l, gather=gather)
+        with jax.named_scope("layer/attn_out"):
+            x = add(x, out)
         kw = {}
         if mlp_by_index:            # lp holds the MLP leaves whole
             kw["layer"] = l
         elif gather is not None:    # the composition (above)
             kw["gather"] = gather
-        out = mlp_fn(x, lp["post_norm"].astype(x.dtype), lp["gate_proj"],
-                     lp["up_proj"], lp["down_proj"], eps,
-                     residual=residual, **kw)
-        return add(x, out), kp, vp
+        with jax.named_scope("layer/mlp"):
+            out = mlp_fn(x, lp["post_norm"].astype(x.dtype),
+                         lp["gate_proj"], lp["up_proj"], lp["down_proj"],
+                         eps, residual=residual, **kw)
+            return add(x, out), kp, vp
 
-    x = jnp.take(params["embed_tokens"], tok, axis=0)        # [B, D]
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed_tokens"], tok, axis=0)    # [B, D]
     x, k_pools, v_pools = _layer_loop(
         params, x, k_pools, v_pools, kv_scales, layer,
         stacked=mlp_w if mlp_by_index else ())
-    x = fused_rms_norm(x[:, None], params["final_norm"].astype(x.dtype),
-                       cfg.rms_norm_eps)[:, 0]
-    return x @ _lm_head(params), k_pools, v_pools
+    with jax.named_scope("head"):
+        x = fused_rms_norm(x[:, None],
+                           params["final_norm"].astype(x.dtype),
+                           cfg.rms_norm_eps)[:, 0]
+        return x @ _lm_head(params), k_pools, v_pools
 
 
 _FUSED_PREFILL_CACHE: Dict = {}

@@ -195,11 +195,12 @@ def _moe(params, h, cfg, l, live, stats):
     mp.update({k: moe[k] for k in _EXPERT_STACKS})
     x, experts = pt.moe_block(mp, h, cfg, layer=l)
     if stats is not None:
-        c = expert_counts(experts, live, cfg.num_experts,
-                          cfg.num_local_experts,
-                          cfg.expert_offset).astype(stats.dtype)
-        stats = jnp.stack([stats[0] + c[0], stats[1] + c[1],
-                           jnp.maximum(stats[2], c[2])])
+        with jax.named_scope("layer/router"):
+            c = expert_counts(experts, live, cfg.num_experts,
+                              cfg.num_local_experts,
+                              cfg.expert_offset).astype(stats.dtype)
+            stats = jnp.stack([stats[0] + c[0], stats[1] + c[1],
+                               jnp.maximum(stats[2], c[2])])
     return x, stats
 
 
@@ -213,16 +214,18 @@ def _run_segments(cfg, x, k_pools, v_pools, state, mamba_layers,
     state = dict(state)
     for name, l0, n, k0 in cfg.segments():
         kind = cfg.kinds[name]
-        if kind.mixer == "mamba":
-            x, state["ssm"], state["conv"], *stats = mamba_layers(
-                (x, state["ssm"], state["conv"], *stats), l0, k0, n)
-        elif kind.pool == "window":
-            x, state["k_win"], state["v_win"], *stats = attn_layers(
-                (x, state["k_win"], state["v_win"], *stats), l0, k0, n,
-                kind)
-        else:
-            x, k_pools, v_pools, *stats = attn_layers(
-                (x, k_pools, v_pools, *stats), l0, k0, n, kind)
+        # each loop's own bookkeeping reads "layers" in a trace
+        with jax.named_scope("layers"):
+            if kind.mixer == "mamba":
+                x, state["ssm"], state["conv"], *stats = mamba_layers(
+                    (x, state["ssm"], state["conv"], *stats), l0, k0, n)
+            elif kind.pool == "window":
+                x, state["k_win"], state["v_win"], *stats = attn_layers(
+                    (x, state["k_win"], state["v_win"], *stats), l0, k0,
+                    n, kind)
+            else:
+                x, k_pools, v_pools, *stats = attn_layers(
+                    (x, k_pools, v_pools, *stats), l0, k0, n, kind)
     return x, k_pools, v_pools, state, stats
 
 
@@ -232,8 +235,11 @@ def decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
     decoding has seq_len 0: its KV write lands in the scratch page and
     its recurrent state is left as it is). Returns (logits [S, V],
     k_pools, v_pools, state)."""
+    # the named scopes are observability.PROGRAM_SCOPES: a reader of a
+    # device trace finds each operation's by them (metadata only)
     active = seq_lens > 0
-    x = pt.embed(params, tok, cfg)
+    with jax.named_scope("embed"):
+        x = pt.embed(params, tok, cfg)
 
     def mamba_layers(carry, l0, m0, n):
         from ..models import granite_hybrid as gh
@@ -242,17 +248,21 @@ def decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
         def body(i, carry):
             x, ssm, conv, stats = carry
             l, m = l0 + i, m0 + i
-            lp = pt.at_layer(params["mamba"], m)
-            z, xbc, dt = gh.mamba_in(lp, x, cfg)
-            xbc, tail = mamba2.conv_update(
-                xbc, lp["conv_w"], lp["conv_b"],
-                jax.lax.dynamic_index_in_dim(conv, m, 0, False), active)
-            conv = jax.lax.dynamic_update_index_in_dim(conv, tail, m, 0)
-            xs, b, c = gh.split(xbc, cfg)
-            y, ssm = mamba2.ssm_update(
-                xs, jnp.where(active[:, None], dt, 0.0),
-                -jnp.exp(lp["A_log"].astype(F32)), b, c, lp["D"], ssm, m)
-            h = gh.mamba_out(lp, x, y, z, cfg)
+            with jax.named_scope("layer/mixer_in"):
+                lp = pt.at_layer(params["mamba"], m)
+                z, xbc, dt = gh.mamba_in(lp, x, cfg)
+                xbc, tail = mamba2.conv_update(
+                    xbc, lp["conv_w"], lp["conv_b"],
+                    jax.lax.dynamic_index_in_dim(conv, m, 0, False),
+                    active)
+                conv = jax.lax.dynamic_update_index_in_dim(conv, tail, m,
+                                                           0)
+                xs, b, c = gh.split(xbc, cfg)
+                dt = jnp.where(active[:, None], dt, 0.0)
+                a = -jnp.exp(lp["A_log"].astype(F32))
+            y, ssm = mamba2.ssm_update(xs, dt, a, b, c, lp["D"], ssm, m)
+            with jax.named_scope("layer/mixer_out"):
+                h = gh.mamba_out(lp, x, y, z, cfg)
             x, stats = _moe(params, h, cfg, l, active, stats)
             return x, ssm, conv, stats
         return jax.lax.fori_loop(0, n, body, carry)
@@ -268,16 +278,21 @@ def decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
         def body(i, carry):
             x, kp, vp, stats = carry
             l, a = l0 + i, a0 + i
-            lp = pt.at_layer(params[kind.stack], a)
-            q, k, v = pt.attn_qkv(lp, x, cfg, kind, seq_lens)
-            kp, vp = write_to_pool(kp, vp, tables, seq_lens,
-                                   k.astype(kp.dtype), v.astype(vp.dtype),
-                                   layer=a, ring=windowed)
-            o = paged_attention_decode(
-                q, kp, vp, tables, seq_lens + 1,
-                scale=cfg.attention_multiplier, layer=a, first=first)
-            h = pt.residual(x, o.reshape(x.shape[0], -1).astype(x.dtype)
-                            @ lp["o_proj"], cfg)
+            with jax.named_scope("layer/qkv"):
+                lp = pt.at_layer(params[kind.stack], a)
+                q, k, v = pt.attn_qkv(lp, x, cfg, kind, seq_lens)
+            with jax.named_scope("layer/kv_write"):
+                kp, vp = write_to_pool(
+                    kp, vp, tables, seq_lens, k.astype(kp.dtype),
+                    v.astype(vp.dtype), layer=a, ring=windowed)
+            with jax.named_scope("layer/attention"):
+                o = paged_attention_decode(
+                    q, kp, vp, tables, seq_lens + 1,
+                    scale=cfg.attention_multiplier, layer=a, first=first)
+            with jax.named_scope("layer/attn_out"):
+                h = pt.residual(
+                    x, o.reshape(x.shape[0], -1).astype(x.dtype)
+                    @ lp["o_proj"], cfg)
             x, stats = _moe(params, h, cfg, l, active, stats)
             return x, kp, vp, stats
         return jax.lax.fori_loop(0, n, body, carry)
@@ -286,8 +301,9 @@ def decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
         cfg, x, k_pools, v_pools, state, mamba_layers, attn_layers,
         (state["stats"],))
     state["stats"] = stats
-    return (pt.lm_logits(params, x, cfg).astype(F32), k_pools, v_pools,
-            state)
+    with jax.named_scope("head"):
+        logits = pt.lm_logits(params, x, cfg).astype(F32)
+    return logits, k_pools, v_pools, state
 
 
 def chunk_attention(q, kp, vp, layer, table, q_pos, lo, hi, scale,
@@ -369,11 +385,12 @@ def prefill_chunk(params, toks, cfg, k_pools, v_pools, table, wtable,
     pos0 = jnp.asarray(pos0, jnp.int32)
     n_valid = jnp.asarray(n_valid, jnp.int32)
     slot = jnp.asarray(slot, jnp.int32)
-    rows = jnp.arange(P, dtype=jnp.int32)
-    valid = rows < n_valid
-    pos = pos0 + rows
-    off = pos % BS
-    x = pt.embed(params, toks, cfg)
+    with jax.named_scope("embed"):
+        rows = jnp.arange(P, dtype=jnp.int32)
+        valid = rows < n_valid
+        pos = pos0 + rows
+        off = pos % BS
+        x = pt.embed(params, toks, cfg)
 
     def mamba_layers(carry, l0, m0, n):
         from ..models import granite_hybrid as gh
@@ -382,56 +399,69 @@ def prefill_chunk(params, toks, cfg, k_pools, v_pools, table, wtable,
         def body(i, carry):
             x, ssm, conv = carry
             l, m = l0 + i, m0 + i
-            lp = pt.at_layer(params["mamba"], m)
-            z, xbc, dt = gh.mamba_in(lp, x, cfg)
-            xbc, tail = mamba2.causal_conv1d(
-                xbc, lp["conv_w"], lp["conv_b"], conv[m, slot], n_valid)
-            conv = conv.at[m, slot].set(tail)
-            xs, b, c = gh.split(xbc, cfg)
-            y, new = mamba2.ssd_scan(
-                xs, jnp.where(valid[:, None], dt, 0.0),
-                -jnp.exp(lp["A_log"].astype(F32)), b, c, lp["D"],
-                mamba2.slot_state(ssm, m, slot),
-                block=cfg.mamba_chunk_size)
-            ssm = mamba2.set_slot_state(ssm, m, slot, new)
-            h = gh.mamba_out(lp, x, y, z, cfg)
+            with jax.named_scope("layer/mixer_in"):
+                lp = pt.at_layer(params["mamba"], m)
+                z, xbc, dt = gh.mamba_in(lp, x, cfg)
+                xbc, tail = mamba2.causal_conv1d(
+                    xbc, lp["conv_w"], lp["conv_b"], conv[m, slot],
+                    n_valid)
+                conv = conv.at[m, slot].set(tail)
+                xs, b, c = gh.split(xbc, cfg)
+                dt = jnp.where(valid[:, None], dt, 0.0)
+                a = -jnp.exp(lp["A_log"].astype(F32))
+            # the slot's state out of the pool and back belongs to the
+            # scan (benchmarks' ssd_scan cost counts both launches)
+            with jax.named_scope("ssd_scan"):
+                before = mamba2.slot_state(ssm, m, slot)
+            y, new = mamba2.ssd_scan(xs, dt, a, b, c, lp["D"], before,
+                                     block=cfg.mamba_chunk_size)
+            with jax.named_scope("ssd_scan"):
+                ssm = mamba2.set_slot_state(ssm, m, slot, new)
+            with jax.named_scope("layer/mixer_out"):
+                h = gh.mamba_out(lp, x, y, z, cfg)
             x, _ = _moe(params, h, cfg, l, valid, None)
             return x, ssm, conv
         return jax.lax.fori_loop(0, n, body, carry)
 
     def attn_layers(carry, l0, a0, n, kind):
         windowed = kind.pool == "window"
-        if windowed:
-            tab = jnp.take(state["win_tables"], slot, axis=0)
-            wtab, col = tab, (pos // BS) % tab.shape[0]
-            lo = jnp.maximum(pos0 - (kind.window - 1), 0)
-        else:
-            tab, wtab, col = table, wtable, pos // BS
-            lo = jnp.int32(0)
-        # the chunk's own rows through the WRITE table (shared pages
-        # and padding land in the scratch page)
-        page = jnp.where(valid, jnp.take(jnp.asarray(wtab, jnp.int32),
-                                         col), 0)
+        with jax.named_scope("layer/kv_write"):
+            if windowed:
+                tab = jnp.take(state["win_tables"], slot, axis=0)
+                wtab, col = tab, (pos // BS) % tab.shape[0]
+                lo = jnp.maximum(pos0 - (kind.window - 1), 0)
+            else:
+                tab, wtab, col = table, wtable, pos // BS
+                lo = jnp.int32(0)
+            # the chunk's own rows through the WRITE table (shared
+            # pages and padding land in the scratch page)
+            page = jnp.where(valid, jnp.take(
+                jnp.asarray(wtab, jnp.int32), col), 0)
 
         def body(i, carry):
             x, kp, vp = carry
             l, a = l0 + i, a0 + i
-            lp = pt.at_layer(params[kind.stack], a)
-            q, k, v = pt.attn_qkv(lp, x, cfg, kind, pos)
+            with jax.named_scope("layer/qkv"):
+                lp = pt.at_layer(params[kind.stack], a)
+                q, k, v = pt.attn_qkv(lp, x, cfg, kind, pos)
             # one scatter into the carried stack, then the live keys
             # (the chunk's among them) in blocks out of it
-            kp = kp.at[a, page, off].set(k.astype(kp.dtype))
-            vp = vp.at[a, page, off].set(v.astype(vp.dtype))
-            o = chunk_attention(q, kp, vp, a, tab, pos, lo,
-                                pos0 + n_valid, cfg.attention_multiplier,
-                                kind.window, windowed)
-            h = pt.residual(x, o @ lp["o_proj"], cfg)
+            with jax.named_scope("layer/kv_write"):
+                kp = kp.at[a, page, off].set(k.astype(kp.dtype))
+                vp = vp.at[a, page, off].set(v.astype(vp.dtype))
+            with jax.named_scope("layer/attention"):
+                o = chunk_attention(
+                    q, kp, vp, a, tab, pos, lo, pos0 + n_valid,
+                    cfg.attention_multiplier, kind.window, windowed)
+            with jax.named_scope("layer/attn_out"):
+                h = pt.residual(x, o @ lp["o_proj"], cfg)
             x, _ = _moe(params, h, cfg, l, valid, None)
             return x, kp, vp
         return jax.lax.fori_loop(0, n, body, carry)
 
     x, k_pools, v_pools, state, _ = _run_segments(
         cfg, x, k_pools, v_pools, state, mamba_layers, attn_layers)
-    last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=0)
-    return (pt.lm_logits(params, last, cfg).astype(F32), k_pools, v_pools,
-            state)
+    with jax.named_scope("head"):
+        last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=0)
+        logits = pt.lm_logits(params, last, cfg).astype(F32)
+    return logits, k_pools, v_pools, state

@@ -70,7 +70,8 @@ import jax.numpy as jnp
 
 from ..core.jax_compat import shard_map_norep
 from ..observability import (Observability, TelemetryConfig,
-                             TelemetryPlane, span)
+                             TelemetryPlane, programs as _programs, span,
+                             tracing)
 from ..ops.paged_attention import (BlockManager, dequant_cache,
                                    quant_cache)
 from .admission import AdmissionQueue
@@ -470,6 +471,12 @@ class ServingEngine:
             self._d_key = self._mesh.replicate(self._d_key)
 
         self._decode_fn = None
+        # the registry of compiled programs (observability/programs.py)
+        # captures a program at its first dispatch UNDER A PROFILER
+        # SESSION: its keys there, and which programs it has
+        self._program_keys = []
+        self._decode_noted = False
+        self._prefill_noted = set()     # keys of _prefill_fns
         self._decode_route = None
         self._prefill_fns: Dict[int, object] = {}
         self._calib_fn = None
@@ -1544,16 +1551,17 @@ class ServingEngine:
                       hist="prefill_chunk_ms", ring=False,
                       req_id=req.req_id, pos0=pos0, n=n,
                       bucket=P) as disp:
-                if self._state is None:
-                    tok, self._d_key, self._k_pools, self._v_pools = fn(
-                        self.params, *args,
-                        self._d_key, self._k_pools, self._v_pools)
-                else:
-                    (tok, self._d_key, self._k_pools, self._v_pools,
-                     self._state) = fn(
-                        self.params, *args, self._d_key, self._k_pools,
-                        self._v_pools, jnp.asarray(slot_id, jnp.int32),
-                        self._state)
+                args = (self.params, *args, self._d_key, self._k_pools,
+                        self._v_pools)
+                if self._state is not None:
+                    args += (jnp.asarray(slot_id, jnp.int32), self._state)
+                if pk not in self._prefill_noted and tracing():
+                    self._prefill_noted.add(pk)
+                    self._program_keys.append(_programs.note(fn, args))
+                out = fn(*args)
+                tok, self._d_key, self._k_pools, self._v_pools = out[:4]
+                if self._state is not None:
+                    self._state = out[4]
             self._end_collectives(tasks)
             self.counters["prefill_chunks"] += 1
             self.counters["prefill_tokens"] += n
@@ -1650,6 +1658,7 @@ class ServingEngine:
             # replay the program the old route compiled
             self._decode_fn = self._make_decode_fn()
             self._decode_route = route
+            self._decode_noted = False
         if self.mgr.window is not None:
             self._window_advance(
                 [(i, self._slots[i].seq_len, self._slots[i].seq_len + 1)
@@ -1663,10 +1672,15 @@ class ServingEngine:
             self._dirty = False
         tasks = self._record_collectives(self._coll_decode)
         with span("serve/decode_dispatch", obs, ring=False) as disp:
-            out = self._decode_fn(
-                self.params, self._d_tok, self._d_seq, self._d_tables,
-                self._d_temps, self._d_key, self._k_pools, self._v_pools,
-                *(() if self._state is None else (self._state,)))
+            args = (self.params, self._d_tok, self._d_seq, self._d_tables,
+                    self._d_temps, self._d_key, self._k_pools,
+                    self._v_pools,
+                    *(() if self._state is None else (self._state,)))
+            if not self._decode_noted and tracing():
+                self._decode_noted = True
+                self._program_keys.append(
+                    _programs.note(self._decode_fn, args))
+            out = self._decode_fn(*args)
             (self._d_tok, self._d_seq, self._d_key, self._k_pools,
              self._v_pools) = out[:5]
             if self._state is not None:
@@ -1839,6 +1853,15 @@ class ServingEngine:
         return decode_step(params, tok, self.cfg, k_pools, v_pools,
                            tables, seq_lens, state)
 
+    def program_scopes(self):
+        """This engine's compiled programs as a reader of a device
+        trace needs them (``observability.programs.Program``: the
+        module's name as the trace prints it, ``{instruction name:
+        scope}``): ``prog.scope("%fusion.121 = ...")`` beside an open
+        profile says which ``PROGRAM_SCOPES`` name issued an operation.
+        Parsed on the first call, never on a step."""
+        return _programs.scopes(self._program_keys)
+
     def _make_decode_fn(self, record_variant=True):
         """THE decode program: one jitted ``step`` around the forward
         picked in ``__init__``. A model with recurrent layers carries
@@ -1864,11 +1887,12 @@ class ServingEngine:
                     "attn": picked.get("paged_attention_decode"),
                     "mlp": picked.get("decode_mlp_block", "unfused"),
                     "operands": launch_operands(picked)}
-            key, sub = jax.random.split(key)
-            nxt = _sample_slots(logits, sub, temps)
-            # inactive (padded) slots hold seq 0 and stay there; their
-            # write above landed in scratch page 0, never read
-            seq_lens = jnp.where(seq_lens > 0, seq_lens + 1, 0)
+            with jax.named_scope("sample"):
+                key, sub = jax.random.split(key)
+                nxt = _sample_slots(logits, sub, temps)
+                # inactive (padded) slots hold seq 0 and stay there;
+                # their write above landed in scratch page 0, never read
+                seq_lens = jnp.where(seq_lens > 0, seq_lens + 1, 0)
             return (nxt, seq_lens, key, k_pools, v_pools, *state)
 
         # donate the whole carried state, not just the pools: tok/seq/
@@ -1895,8 +1919,9 @@ class ServingEngine:
             lg, k_pools, v_pools, state = prefill_chunk(
                 params, toks[0], cfg, k_pools, v_pools, table, wtable,
                 pos0, n_valid, slot, state)
-            key, sub = jax.random.split(key)
-            tok = _sample_slots(lg, sub, temp[None])[0]
+            with jax.named_scope("sample"):
+                key, sub = jax.random.split(key)
+                tok = _sample_slots(lg, sub, temp[None])[0]
             return tok, key, k_pools, v_pools, state
 
         return jax.jit(chunk, donate_argnums=self._PREFILL_DONATE + (11,))
@@ -1974,10 +1999,11 @@ class ServingEngine:
             logits, k_pools, v_pools = _fused_prefill_forward(
                 params, toks[0], cfg, k_pools, v_pools, table, wtable,
                 pos0, n_valid, kv_scales=scales, mode=mode)
-            lg = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1,
-                                              axis=0)
-            key, sub = jax.random.split(key)
-            tok = _sample_slots(lg, sub, temp[None])[0]
+            with jax.named_scope("sample"):
+                lg = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1,
+                                                  axis=0)
+                key, sub = jax.random.split(key)
+                tok = _sample_slots(lg, sub, temp[None])[0]
             return tok, key, k_pools, v_pools
 
         return jax.jit(chunk, donate_argnums=self._PREFILL_DONATE)
@@ -2010,32 +2036,35 @@ class ServingEngine:
             # this request's pages as a dense [L, 1, T, KV, hd] cache:
             # the chunk runs the SAME cached_forward math as generate()'s
             # prefill, so single-request outputs match token-for-token
-            kc = jnp.take(k_pools, table, axis=1) \
-                .reshape(L, 1, MB * BS, KV, hd)
-            vc = jnp.take(v_pools, table, axis=1) \
-                .reshape(L, 1, MB * BS, KV, hd)
-            if scales is not None:
-                kc = dequant_cache(kc, scales[0]).astype(cfg.dtype)
-                vc = dequant_cache(vc, scales[1]).astype(cfg.dtype)
+            with jax.named_scope("kv_gather"):
+                kc = jnp.take(k_pools, table, axis=1) \
+                    .reshape(L, 1, MB * BS, KV, hd)
+                vc = jnp.take(v_pools, table, axis=1) \
+                    .reshape(L, 1, MB * BS, KV, hd)
+                if scales is not None:
+                    kc = dequant_cache(kc, scales[0]).astype(cfg.dtype)
+                    vc = dequant_cache(vc, scales[1]).astype(cfg.dtype)
             logits, kc, vc = cached_forward(params, toks, cfg, kc, vc,
                                             pos0)
-            if scales is not None:
-                kc = quant_cache(kc, scales[0])
-                vc = quant_cache(vc, scales[1])
             # the scatter goes through the WRITE table: entries backed
             # by shared prefix-cache pages are redirected to scratch
             # page 0 there, so the chunk cannot corrupt a shared page
             # (without a prefix cache wtable == table)
-            k_pools = k_pools.at[:, wtable].set(
-                kc.reshape(L, MB, BS, KV, hd).astype(k_pools.dtype))
-            v_pools = v_pools.at[:, wtable].set(
-                vc.reshape(L, MB, BS, KV, hd).astype(v_pools.dtype))
+            with jax.named_scope("kv_scatter"):
+                if scales is not None:
+                    kc = quant_cache(kc, scales[0])
+                    vc = quant_cache(vc, scales[1])
+                k_pools = k_pools.at[:, wtable].set(
+                    kc.reshape(L, MB, BS, KV, hd).astype(k_pools.dtype))
+                v_pools = v_pools.at[:, wtable].set(
+                    vc.reshape(L, MB, BS, KV, hd).astype(v_pools.dtype))
             # sample the request's FIRST token from the last valid
             # position (only meaningful on the final chunk)
-            lg = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1,
-                                              axis=1)[:, 0]
-            key, sub = jax.random.split(key)
-            tok = _sample_slots(lg, sub, temp[None])[0]
+            with jax.named_scope("sample"):
+                lg = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1,
+                                                  axis=1)[:, 0]
+                key, sub = jax.random.split(key)
+                tok = _sample_slots(lg, sub, temp[None])[0]
             return tok, key, k_pools, v_pools
 
         # key is carried state exactly like the pools: the caller
@@ -2066,23 +2095,25 @@ class ServingEngine:
         def fwd(params, toks, pos0, table, wtable, k_pools, v_pools,
                 *sc):
             KV_l = k_pools.shape[3]       # local KV heads of this shard
-            kc = jnp.take(k_pools, table, axis=1) \
-                .reshape(L, 1, MB * BS, KV_l, hd)
-            vc = jnp.take(v_pools, table, axis=1) \
-                .reshape(L, 1, MB * BS, KV_l, hd)
-            if sc:
-                kc = dequant_cache(kc, sc[0]).astype(cfg.dtype)
-                vc = dequant_cache(vc, sc[1]).astype(cfg.dtype)
+            with jax.named_scope("kv_gather"):
+                kc = jnp.take(k_pools, table, axis=1) \
+                    .reshape(L, 1, MB * BS, KV_l, hd)
+                vc = jnp.take(v_pools, table, axis=1) \
+                    .reshape(L, 1, MB * BS, KV_l, hd)
+                if sc:
+                    kc = dequant_cache(kc, sc[0]).astype(cfg.dtype)
+                    vc = dequant_cache(vc, sc[1]).astype(cfg.dtype)
             logits, kc, vc = _tp_cached_forward(
                 params, toks, cfg, kc, vc, pos0, axis=sm.axis,
                 collective=sm.collective)
-            if sc:
-                kc = quant_cache(kc, sc[0])
-                vc = quant_cache(vc, sc[1])
-            k_pools = k_pools.at[:, wtable].set(
-                kc.reshape(L, MB, BS, KV_l, hd).astype(k_pools.dtype))
-            v_pools = v_pools.at[:, wtable].set(
-                vc.reshape(L, MB, BS, KV_l, hd).astype(v_pools.dtype))
+            with jax.named_scope("kv_scatter"):
+                if sc:
+                    kc = quant_cache(kc, sc[0])
+                    vc = quant_cache(vc, sc[1])
+                k_pools = k_pools.at[:, wtable].set(
+                    kc.reshape(L, MB, BS, KV_l, hd).astype(k_pools.dtype))
+                v_pools = v_pools.at[:, wtable].set(
+                    vc.reshape(L, MB, BS, KV_l, hd).astype(v_pools.dtype))
             return logits, k_pools, v_pools
 
         sharded = shard_map_norep(fwd, sm.mesh, in_specs,
@@ -2095,10 +2126,11 @@ class ServingEngine:
             logits, k_pools, v_pools = sharded(
                 params, toks, pos0, table, wtable, k_pools, v_pools,
                 *extra)
-            lg = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1,
-                                              axis=1)[:, 0]
-            key, sub = jax.random.split(key)
-            tok = _sample_slots(lg, sub, temp[None])[0]
+            with jax.named_scope("sample"):
+                lg = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1,
+                                                  axis=1)[:, 0]
+                key, sub = jax.random.split(key)
+                tok = _sample_slots(lg, sub, temp[None])[0]
             return tok, key, k_pools, v_pools
 
         return jax.jit(chunk, donate_argnums=self._PREFILL_DONATE)

@@ -343,43 +343,49 @@ def _tp_cached_layer(lp, x, sin, cos, cfg, kc, vc, pos, axis,
     T = kc.shape[1]
     H_loc = _wshape(lp["q_proj"])[1] // hd
     KV_loc = _wshape(lp["k_proj"])[1] // hd
-    h = fused_rms_norm(x, lp["input_norm"].astype(x.dtype),
-                       cfg.rms_norm_eps)
-    q = _mm(h, lp["q_proj"]).reshape(b, s, H_loc, hd)
-    k = _mm(h, lp["k_proj"]).reshape(b, s, KV_loc, hd)
-    v = _mm(h, lp["v_proj"]).reshape(b, s, KV_loc, hd)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
-    kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
-                                      (0, pos, 0, 0))
-    vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
-                                      (0, pos, 0, 0))
-    rep = H_loc // KV_loc                 # groups survive sharding
-    kk = _repeat_kv(kc, rep)              # [B, T, H_loc, hd]
-    vv = _repeat_kv(vc, rep)
-    scale = 1.0 / math.sqrt(hd)
-    scores = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32),
-                        kk.astype(jnp.float32)) * scale
-    t_idx = jnp.arange(T)[None, None, None, :]
-    q_idx = pos + jnp.arange(s)[None, None, :, None]
-    scores = jnp.where(t_idx <= q_idx, scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    attn = jnp.einsum("bhst,bthd->bshd", probs, vv.astype(jnp.float32))
-    if collective == "gather":
-        attn = jax.lax.all_gather(attn, axis, axis=2, tiled=True)
-        attn = attn.astype(x.dtype).reshape(b, s, H * hd)
-        x = x + _mm(attn, lp["o_proj"])
-    else:
-        attn = attn.astype(x.dtype).reshape(b, s, H_loc * hd)
-        x = x + jax.lax.psum(_mm(attn, lp["o_proj"]), axis)
-    h = fused_rms_norm(x, lp["post_norm"].astype(x.dtype),
-                       cfg.rms_norm_eps)
-    ff = fused_swiglu(_mm(h, lp["gate_proj"]), _mm(h, lp["up_proj"]))
-    if collective == "gather":
-        ff = jax.lax.all_gather(ff, axis, axis=2, tiled=True)
-        x = x + _mm(ff, lp["down_proj"])
-    else:
-        x = x + jax.lax.psum(_mm(ff, lp["down_proj"]), axis)
+    with jax.named_scope("layer/qkv"):
+        h = fused_rms_norm(x, lp["input_norm"].astype(x.dtype),
+                           cfg.rms_norm_eps)
+        q = _mm(h, lp["q_proj"]).reshape(b, s, H_loc, hd)
+        k = _mm(h, lp["k_proj"]).reshape(b, s, KV_loc, hd)
+        v = _mm(h, lp["v_proj"]).reshape(b, s, KV_loc, hd)
+        q = apply_rope(q, sin, cos)
+        k = apply_rope(k, sin, cos)
+    with jax.named_scope("layer/kv_write"):
+        kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
+                                          (0, pos, 0, 0))
+        vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
+                                          (0, pos, 0, 0))
+    with jax.named_scope("layer/attention"):
+        rep = H_loc // KV_loc                 # groups survive sharding
+        kk = _repeat_kv(kc, rep)              # [B, T, H_loc, hd]
+        vv = _repeat_kv(vc, rep)
+        scale = 1.0 / math.sqrt(hd)
+        scores = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32),
+                            kk.astype(jnp.float32)) * scale
+        t_idx = jnp.arange(T)[None, None, None, :]
+        q_idx = pos + jnp.arange(s)[None, None, :, None]
+        scores = jnp.where(t_idx <= q_idx, scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        attn = jnp.einsum("bhst,bthd->bshd", probs,
+                          vv.astype(jnp.float32))
+    with jax.named_scope("layer/attn_out"):
+        if collective == "gather":
+            attn = jax.lax.all_gather(attn, axis, axis=2, tiled=True)
+            attn = attn.astype(x.dtype).reshape(b, s, H * hd)
+            x = x + _mm(attn, lp["o_proj"])
+        else:
+            attn = attn.astype(x.dtype).reshape(b, s, H_loc * hd)
+            x = x + jax.lax.psum(_mm(attn, lp["o_proj"]), axis)
+    with jax.named_scope("layer/mlp"):
+        h = fused_rms_norm(x, lp["post_norm"].astype(x.dtype),
+                           cfg.rms_norm_eps)
+        ff = fused_swiglu(_mm(h, lp["gate_proj"]), _mm(h, lp["up_proj"]))
+        if collective == "gather":
+            ff = jax.lax.all_gather(ff, axis, axis=2, tiled=True)
+            x = x + _mm(ff, lp["down_proj"])
+        else:
+            x = x + jax.lax.psum(_mm(ff, lp["down_proj"]), axis)
     return x, kc, vc
 
 
@@ -393,13 +399,15 @@ def _tp_cached_forward(params, tokens, cfg, k_cache, v_cache, pos,
     from ..ops import rms_norm as fused_rms_norm
     from ..ops.rope import build_rope_cache
 
-    x = jnp.take(params["embed_tokens"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed_tokens"], tokens, axis=0)
     T = k_cache.shape[2]
-    sin_full, cos_full = build_rope_cache(T, cfg.head_dim,
-                                          base=cfg.rope_theta)
     s = tokens.shape[1]
-    sin = jax.lax.dynamic_slice_in_dim(sin_full, pos, s, axis=0)
-    cos = jax.lax.dynamic_slice_in_dim(cos_full, pos, s, axis=0)
+    with jax.named_scope("layer/qkv"):        # the rotary table
+        sin_full, cos_full = build_rope_cache(T, cfg.head_dim,
+                                              base=cfg.rope_theta)
+        sin = jax.lax.dynamic_slice_in_dim(sin_full, pos, s, axis=0)
+        cos = jax.lax.dynamic_slice_in_dim(cos_full, pos, s, axis=0)
 
     def scan_fn(carry, xs):
         lp, kc, vc = xs
@@ -407,8 +415,10 @@ def _tp_cached_forward(params, tokens, cfg, k_cache, v_cache, pos,
                                      pos, axis, collective)
         return x, (kc, vc)
 
-    x, (k_cache, v_cache) = jax.lax.scan(
-        scan_fn, x, (params["layers"], k_cache, v_cache))
-    x = fused_rms_norm(x, params["final_norm"].astype(x.dtype),
-                       cfg.rms_norm_eps)
-    return x @ _lm_head(params), k_cache, v_cache
+    with jax.named_scope("layers"):
+        x, (k_cache, v_cache) = jax.lax.scan(
+            scan_fn, x, (params["layers"], k_cache, v_cache))
+    with jax.named_scope("head"):
+        x = fused_rms_norm(x, params["final_norm"].astype(x.dtype),
+                           cfg.rms_norm_eps)
+        return x @ _lm_head(params), k_cache, v_cache

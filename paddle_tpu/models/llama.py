@@ -268,11 +268,15 @@ def loss_fn(params: Dict, tokens, labels, cfg: LlamaConfig) -> jax.Array:
     kernel on TPU (neither logits nor their gradient touch HBM), the
     lax.scan composition elsewhere (``cfg.fused_train`` pins a
     variant)."""
-    hidden = forward_hidden(params, tokens, cfg)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed_tokens"].T
-    return fused_linear_ce(hidden, head, labels, mode=cfg.fused_train)
+    # observability.PROGRAM_SCOPES; the backward keeps them inside
+    # ``transpose(jvp(...))``
+    with jax.named_scope("forward"):
+        hidden = forward_hidden(params, tokens, cfg)
+    with jax.named_scope("loss"):
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embed_tokens"].T
+        return fused_linear_ce(hidden, head, labels, mode=cfg.fused_train)
 
 
 def build_forward(cfg: LlamaConfig, key=None):

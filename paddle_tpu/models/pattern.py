@@ -130,13 +130,15 @@ def moe_block(mp, h, cfg, layer=None):
     except that with ``layer`` given its two expert leaves are the
     whole stacks (``moe_experts`` then addresses the layer itself).
     Returns (x', experts [T, k])."""
-    u = norm(h, mp["post_norm"], cfg.rms_norm_eps)
-    gates, experts = route(u, mp["router"], cfg.num_experts_per_tok)
+    with jax.named_scope("layer/router"):
+        u = norm(h, mp["post_norm"], cfg.rms_norm_eps)
+        gates, experts = route(u, mp["router"], cfg.num_experts_per_tok)
     out = moe_experts(u, gates, experts, mp["w_in"], mp["w_out"],
                       offset=cfg.expert_offset, layer=layer)
-    if "shared_in" in mp:
-        out = out + gated_mlp(u, mp["shared_in"], mp["shared_out"])
-    return h + _times(out, cfg.residual_multiplier), experts
+    with jax.named_scope("layer/mlp"):
+        if "shared_in" in mp:
+            out = out + gated_mlp(u, mp["shared_in"], mp["shared_out"])
+        return h + _times(out, cfg.residual_multiplier), experts
 
 
 def _times(x, m):

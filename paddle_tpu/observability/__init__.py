@@ -26,9 +26,11 @@ from typing import Dict, Optional, Sequence
 from .compile import (CompileWatcher, HostGapDetector, device_peak_flops,
                       device_peak_hbm_bw, live_hbm_bytes)
 from .metrics import Gauge, Histogram, MetricsRegistry
+from . import programs
+from .programs import PROGRAM_SCOPES
 from .roofline import (capture_kernel_costs, decode_roofline,
                        decode_step_bytes, kernel_cost, roofline_point)
-from .spans import SERVE_SPANS, TRAIN_SPANS, span
+from .spans import SERVE_SPANS, TRAIN_SPANS, span, tracing
 from .stall import dump_path_for, dump_stall
 from .telemetry import (TelemetryConfig, TelemetryPlane, flatten_metrics,
                         lint_exposition, render_exposition)
@@ -43,7 +45,8 @@ __all__ = ["Observability", "MetricsRegistry", "Histogram", "Gauge",
            "decode_roofline", "LATENCY_HISTOGRAMS", "TRAIN_HISTOGRAMS",
            "TelemetryConfig", "TelemetryPlane", "flatten_metrics",
            "render_exposition", "lint_exposition",
-           "span", "SERVE_SPANS", "TRAIN_SPANS"]
+           "span", "tracing", "SERVE_SPANS", "TRAIN_SPANS", "programs",
+           "PROGRAM_SCOPES"]
 
 # the latency histograms every engine window reports (schema-stable:
 # tests freeze this set — extend deliberately, never ad hoc)

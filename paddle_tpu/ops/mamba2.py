@@ -23,10 +23,14 @@ it is stored in. The contractions are small beside the projections.
 The state is laid out ``[N, H*hp]`` (the state size first, a head's
 rows last: ``ops/pallas/mamba2.py`` says why) wherever it is stored or
 handed over. The computations sit in ``jax.named_scope``s (``ssd_scan``,
-``ssm_update``), which a compiled program's text shows; a device
-trace's event names do not carry them (PR 27, on the v5e), so what a
-trace reader has to find by name is a Pallas launch: ``ssm_update``,
-``ssm_state_read``, ``ssm_state_write``.
+``ssm_update``: names of ``observability.PROGRAM_SCOPES``), which a
+compiled program's text shows. A device trace's event names do not
+carry them (PR 27, on the v5e); a trace's reader gets an operation's
+scope from the registry of compiled programs
+(``observability/programs.py``: the engine notes each program at its
+first dispatch under a profiler session, ``engine.program_scopes()``
+gives {instruction name: scope}), not from the event's name. By name it finds a Pallas launch:
+``ssm_update``, ``ssm_state_read``, ``ssm_state_write``.
 """
 from __future__ import annotations
 

@@ -390,14 +390,16 @@ def attn_out_ref(x, q, wo, k_pool, v_pool, block_tables, seq_lens,
     from ...quantization.quanters import maybe_dequantize
 
     k_scale, v_scale = kv_scales or (None, None)
-    attn = paged_attention_decode(q, k_pool, v_pool, block_tables,
-                                  seq_lens + 1, layer=layer,
-                                  k_scale=k_scale, v_scale=v_scale)
-    if gather is not None:
-        attn = gather(attn)
-    o = attn.reshape(x.shape[0], -1).astype(x.dtype) \
-        @ maybe_dequantize(wo, x.dtype)
-    return x + o if residual else o
+    with jax.named_scope("layer/attention"):
+        attn = paged_attention_decode(q, k_pool, v_pool, block_tables,
+                                      seq_lens + 1, layer=layer,
+                                      k_scale=k_scale, v_scale=v_scale)
+    with jax.named_scope("layer/attn_out"):
+        if gather is not None:
+            attn = gather(attn)
+        o = attn.reshape(x.shape[0], -1).astype(x.dtype) \
+            @ maybe_dequantize(wo, x.dtype)
+        return x + o if residual else o
 
 
 def mlp_block_ref(x, nw, wg, wu, wd, eps=1e-6, residual=True,
