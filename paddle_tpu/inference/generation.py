@@ -88,6 +88,7 @@ def _cached_layer(lp, x, sin, cos, cfg, kc, vc, pos):
     """Decoder block over S new tokens at absolute position ``pos``,
     reading/writing the cache. kc/vc: [B, T, KV, hd]."""
     from ..ops import rms_norm as fused_rms_norm, swiglu as fused_swiglu
+    from ..ops.pallas.fused_decode_block import qkv_project
 
     H, KV, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                  cfg.head_dim)
@@ -96,9 +97,7 @@ def _cached_layer(lp, x, sin, cos, cfg, kc, vc, pos):
     with jax.named_scope("layer/qkv"):
         h = fused_rms_norm(x, lp["input_norm"].astype(x.dtype),
                            cfg.rms_norm_eps)
-        q = _mm(h, lp["q_proj"]).reshape(b, s, H, hd)
-        k = _mm(h, lp["k_proj"]).reshape(b, s, KV, hd)
-        v = _mm(h, lp["v_proj"]).reshape(b, s, KV, hd)
+        q, k, v = qkv_project(h, lp, (H, KV, hd))
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
     with jax.named_scope("layer/kv_write"):
@@ -334,9 +333,11 @@ def _fused_prefill_forward(params, toks, cfg, k_pools, v_pools, table,
     from ..ops import rms_norm as fused_rms_norm
     from ..ops.paged_attention import (write_chunk_to_pool,
                                        write_chunk_to_pool_quant)
+    from ..ops.pallas.fused_decode_block import split_qkv
     from ..ops.pallas.fused_prefill_block import (prefill_meta,
                                                   resolve_prefill_blocks)
 
+    dims = (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim)
     P = toks.shape[0]
     BS = k_pools.shape[2]
     MB = table.shape[0]
@@ -365,9 +366,9 @@ def _fused_prefill_forward(params, toks, cfg, k_pools, v_pools, table,
         # one launch holds norm, q/k/v, rotary, attention and o_proj
         with jax.named_scope("layer/attention"):
             x, k_new, v_new = attn_fn(
-                x, lp["input_norm"].astype(x.dtype), lp["q_proj"],
-                lp["k_proj"], lp["v_proj"], lp["o_proj"], sin, cos, kp,
-                vp, table, pos0, n_valid, scales, cfg.rms_norm_eps)
+                x, lp["input_norm"].astype(x.dtype), *split_qkv(lp, dims),
+                lp["o_proj"], sin, cos, kp, vp, table, pos0, n_valid,
+                scales, cfg.rms_norm_eps)
         with jax.named_scope("layer/kv_write"):
             if scales is None:
                 kp, vp = write_chunk_to_pool(kp, vp, wtable, pos0,
@@ -472,12 +473,17 @@ def _layer_loop(params, x, k_pools, v_pools, kv_scales, layer,
     (``write_to_pool(..., layer=l)``) and the program's donated input
     pool is its output pool. As a scan's stacked input and output they
     were copied whole every step and held twice in HBM. ``lp`` holds
-    layer ``l``'s slice of each leaf of ``params["layers"]`` (free where
-    an XLA matmul consumes it: the slice fuses into the dot) except the
+    layer ``l``'s slice of each leaf of ``params["layers"]`` except the
     leaves named in ``stacked``, which it holds WHOLE, for launches
     that address the layer themselves (a Pallas launch needs a whole
-    buffer, so a slice would be copied out for it). ``scales``: layer
-    ``l``'s int8-pool scales, or None.
+    buffer, so a slice would be copied out for it). A slice that an
+    XLA matmul consumes is free where the compiler fuses it into the
+    dot: it does for ``o_proj`` and for the engine's fused ``qkv_proj``
+    (``fused_decode_block.qkv_project``), which are read in place; the
+    three leaves ``q_proj`` / ``k_proj`` / ``v_proj`` of any other tree
+    it copies out a layer and re-lays out before it multiplies
+    (tests/test_chip_compile.py holds both). ``scales``: layer ``l``'s
+    int8-pool scales, or None.
     """
     layers = params["layers"]
     whole = {k: layers[k] for k in stacked}
@@ -542,6 +548,7 @@ def _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
     mlp_by_index = "decode_mlp_block" in fdb.launch_operands(
         {"decode_mlp_block": mlp_name})
     eps = cfg.rms_norm_eps
+    dims = (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim)
     with jax.named_scope("layer/qkv"):
         sin, cos = build_rope_cache(cfg.max_position_embeddings,
                                     cfg.head_dim, base=cfg.rope_theta)
@@ -568,8 +575,8 @@ def _decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
         # this layer
         with jax.named_scope("layer/qkv"):
             q, k_new, v_new = fdb.attn_qkv_ref(
-                x, lp["input_norm"].astype(x.dtype), lp["q_proj"],
-                lp["k_proj"], lp["v_proj"], sin, cos, seq_lens, eps)
+                x, lp["input_norm"].astype(x.dtype), lp, dims, sin, cos,
+                seq_lens, eps)
         with jax.named_scope("layer/kv_write"):
             if scales is None:
                 kp, vp = write_to_pool(kp, vp, block_tables, seq_lens,

@@ -258,6 +258,14 @@ class ServingEngine:
         # param tree, so every traced program keys on it for free and
         # kernel dispatch sees it via the weight_dtype meta key.
         params, self._wq = ensure_quantized(params, weight_quant)
+        if "qkv_proj" in params.get("layers", {}):
+            # one way in: the engine makes the leaf itself, per shard
+            # over a mesh, where a column split of a [q | k | v] made
+            # elsewhere hands shard 0 query heads only
+            raise ValueError(
+                "ServingEngine takes q_proj / k_proj / v_proj and fuses"
+                " them itself (per shard over a mesh): this tree "
+                "already holds the fused leaf 'qkv_proj'")
         self._mesh = normalize_mesh(mesh)
         if self._wq and self._mesh is not None and self._mesh.tp > 1:
             raise ValueError(
@@ -274,7 +282,10 @@ class ServingEngine:
                 raise ValueError(f"ServingEngine(mesh=...): {reason}")
             params = self._mesh.shard(
                 params, self._mesh.param_specs(cfg, params))
-        self.params = params
+        # rebound, not only stored: over a mesh the three sharded
+        # stacks are this constructor's own, and go before the pools
+        # are made
+        self.params = params = self._fuse_qkv(params, cfg)
         self.cfg = cfg
         # prefill-chunk kernel routing: False = always the verbatim
         # gather/cached_forward/scatter chunk;
@@ -829,17 +840,56 @@ class ServingEngine:
             if self._pcache is not None:
                 self._pcache.check()
 
+    def _fuse_qkv(self, params, cfg):
+        """The dense decoder's tree as this engine's programs read it:
+        the three projection stacks as the ONE leaf ``qkv_proj``
+        (``fused_decode_block.fuse_qkv``), which the layer loop's
+        product reads in place, where it copies a layer of each of the
+        three out and re-lays it out first (``qkv_project``). Made once,
+        here, before the pools exist; over a mesh per shard, so a shard
+        holds [q | k | v] of its own heads. Nothing is donated: on one
+        device the three stacks are the CALLER's arrays, and stay alive
+        beside the leaf for as long as the caller keeps them (through
+        this constructor at the least: its peak is one leaf higher),
+        as are a mesh's when the tree arrives already placed; copies
+        that ``ServingMesh.shard`` had to make are this constructor's
+        own, and are freed before the pools are made. A quantized tree (leaves
+        with scales and packed rows) keeps its leaves, and a pattern-run
+        model's tree has no such stacks (inference/hybrid.py runs its
+        own loop)."""
+        from ..ops.pallas.fused_decode_block import QKV_LEAVES, fuse_qkv
+        layers = params.get("layers", {})
+        if self._wq or not all(k in layers for k in QKV_LEAVES):
+            return params
+        wrap = jax.jit
+        if self._mesh is not None:
+            mesh = self._mesh.mesh
+            col = self._mesh.param_specs(cfg)["layers"]["q_proj"]
+            wrap = lambda f: jax.jit(                        # noqa: E731
+                shard_map_norep(f, mesh, (col,) * 3, col))
+        fused = fuse_qkv(layers, wrap)
+        if self._mesh is not None:
+            # the leaf is written before the shards' three stacks lose
+            # their last reference (``__init__`` rebinds ``params``):
+            # the allocator then never holds them beside the pools
+            jax.block_until_ready(fused["qkv_proj"])
+        return {**params, "layers": fused}
+
     @property
     def decode_variant(self) -> Dict:
         """Which launches this engine's decode program holds:
-        ``{"attn": ..., "mlp": ..., "operands": {...}}`` — the variant
+        ``{"attn": ..., "mlp": ..., "operands": {...}, "qkv": ...}`` —
+        the variant
         of ``paged_attention_decode`` ("pallas" | "xla") and of
         ``decode_mlp_block`` ("pallas_fused" | "unfused": also where the
         program has no such stage, the "gather" placement and a model
         with expert layers) that the kernel registry picked, and for
         each Pallas launch of the layer loop how it takes its layer of
         the KV pools / stacked weights (``fused_decode_block
-        .launch_operands``). It IS the registry's record of the
+        .launch_operands``); ``"qkv"`` is the form of the q/k/v
+        projections in the tree the program traced over
+        ("fused_stack": one product over ``qkv_proj``, read in place;
+        "per_leaf": three). It IS the registry's record of the
         dispatches made while the decode program traced, so later env
         changes — the VMEM budget, a ``KERNELS.force`` pin around a
         ``metrics()`` call — cannot make the report drift from the
@@ -847,7 +897,7 @@ class ServingEngine:
         program, and the names are None."""
         if self._decode_variant is not None:
             return dict(self._decode_variant)
-        return {"attn": None, "mlp": None, "operands": {}}
+        return {"attn": None, "mlp": None, "operands": {}, "qkv": None}
 
     @property
     def weight_quant_variant(self) -> Dict:
@@ -1886,7 +1936,10 @@ class ServingEngine:
                 self._decode_variant = {
                     "attn": picked.get("paged_attention_decode"),
                     "mlp": picked.get("decode_mlp_block", "unfused"),
-                    "operands": launch_operands(picked)}
+                    "operands": launch_operands(picked),
+                    "qkv": ("fused_stack"
+                            if "qkv_proj" in params.get("layers", {})
+                            else "per_leaf")}
             with jax.named_scope("sample"):
                 key, sub = jax.random.split(key)
                 nxt = _sample_slots(logits, sub, temps)

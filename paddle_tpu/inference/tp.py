@@ -290,11 +290,20 @@ def _wshape(w):
 def _local_dims(params, cfg):
     """Local head/intermediate counts, read off the sharded arrays
     (shard_map hands the body local shapes, so the arrays themselves
-    are the single source of truth for what this shard owns)."""
+    are the single source of truth for what this shard owns). The
+    engine's fused ``qkv_proj`` leaf holds a shard's q, k and v heads
+    side by side: ``cfg``'s ratio of them splits its width."""
+    from ..ops.pallas.fused_decode_block import local_heads
     hd = cfg.head_dim
-    H_loc = _wshape(params["layers"]["q_proj"])[2] // hd
-    KV_loc = _wshape(params["layers"]["k_proj"])[2] // hd
-    F_loc = _wshape(params["layers"]["gate_proj"])[2]
+    layers = params["layers"]
+    if "qkv_proj" in layers:
+        H_loc, KV_loc = local_heads(
+            layers["qkv_proj"].shape[2],
+            (cfg.num_attention_heads, cfg.num_key_value_heads, hd))
+    else:
+        H_loc = _wshape(layers["q_proj"])[2] // hd
+        KV_loc = _wshape(layers["k_proj"])[2] // hd
+    F_loc = _wshape(layers["gate_proj"])[2]
     return H_loc, KV_loc, F_loc
 
 
@@ -336,19 +345,17 @@ def _tp_cached_layer(lp, x, sin, cos, cfg, kc, vc, pos, axis,
     how the residual stream is rebuilt (module docstring)."""
     from ..inference.generation import _mm, _repeat_kv
     from ..ops import rms_norm as fused_rms_norm, swiglu as fused_swiglu
+    from ..ops.pallas.fused_decode_block import qkv_project
     from ..ops.rope import apply_rope
 
     H, hd = cfg.num_attention_heads, cfg.head_dim
     b, s, _ = x.shape
     T = kc.shape[1]
-    H_loc = _wshape(lp["q_proj"])[1] // hd
-    KV_loc = _wshape(lp["k_proj"])[1] // hd
     with jax.named_scope("layer/qkv"):
         h = fused_rms_norm(x, lp["input_norm"].astype(x.dtype),
                            cfg.rms_norm_eps)
-        q = _mm(h, lp["q_proj"]).reshape(b, s, H_loc, hd)
-        k = _mm(h, lp["k_proj"]).reshape(b, s, KV_loc, hd)
-        v = _mm(h, lp["v_proj"]).reshape(b, s, KV_loc, hd)
+        q, k, v = qkv_project(h, lp, (H, cfg.num_key_value_heads, hd))
+        H_loc, KV_loc = q.shape[2], k.shape[2]    # this shard's heads
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
     with jax.named_scope("layer/kv_write"):

@@ -157,9 +157,11 @@ def tp_param_specs(cfg: LlamaConfig, axis: str = "tp",
     operands and shapes, which is what makes that mode's greedy output
     bit-identical (inference/tp.py documents the contract).
 
-    ``params``: pass the actual tree when it may carry QUANTIZED
+    ``params``: pass the actual tree when it may carry the serving
+    engine's fused ``qkv_proj`` leaf (column-sharded like the three it
+    replaces) or QUANTIZED
     weight leaves (``{"qw8"|"qw4": q, "scale": s}`` —
-    quantization/ptq.py): the spec tree must mirror their dict
+    quantization/ptq.py): the spec tree must mirror the tree's
     structure. The integer tile keeps the base weight's spec
     (column sharding survives packing — int4 packs the hidden axis,
     never the output columns of q/k/v/gate/up) and the
@@ -184,6 +186,15 @@ def tp_param_specs(cfg: LlamaConfig, axis: str = "tp",
         specs["lm_head"] = P(None, None)
     if params is not None:
         layers = params.get("layers", {})
+        if "qkv_proj" in layers:
+            # the serving engine's fused leaf (fused_decode_block
+            # .fuse_qkv, made per shard): a shard's columns are
+            # [q_loc | k_loc | v_loc] of its own heads. The spec is how
+            # the engine's programs take the leaf it placed, never a
+            # way to place a global [q | k | v] (the engine refuses one)
+            for k in ("q_proj", "k_proj", "v_proj"):
+                del specs["layers"][k]
+            specs["layers"]["qkv_proj"] = col
         for k, w in layers.items():
             if isinstance(w, dict):
                 base = specs["layers"][k]

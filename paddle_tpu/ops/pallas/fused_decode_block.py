@@ -41,7 +41,8 @@ from .registry import KERNELS
 __all__ = [
     "fused_mlp_block_pallas", "mlp_block_ref", "attn_qkv_ref",
     "attn_out_ref", "decode_meta_dims", "launch_operands",
-    "mlp_autotune_key", "weight_dtype_of",
+    "mlp_autotune_key", "weight_dtype_of", "QKV_LEAVES", "fuse_qkv",
+    "qkv_project", "split_qkv", "local_heads",
 ]
 
 
@@ -357,25 +358,92 @@ def fused_mlp_block_pallas(x, nw, wg, wu, wd, eps=1e-6, block_f=None,
 # loop performs its one in-place pool write between them) and the MLP
 # stage's reference variant
 # ---------------------------------------------------------------------------
-def attn_qkv_ref(x, nw, wq, wk, wv, sin, cos, seq_lens, eps=1e-6):
-    """First half of the unfused attention stage: RMSNorm, the q/k/v
-    projections and RoPE at each slot's position. Returns (q [B, H, hd],
-    k_new, v_new [B, KV, hd]); head counts are read off the weights, so
-    a tensor-parallel shard gets its local heads."""
-    from .. import rms_norm as fused_rms_norm
-    from ..rope import apply_rope
+QKV_LEAVES = ("q_proj", "k_proj", "v_proj")
+
+
+def _concat_qkv(q, k, v):
+    return jnp.concatenate([q, k, v], axis=-1)
+
+
+def fuse_qkv(layers, wrap=lambda concat: concat):
+    """The serving engine's step from the tree it is handed to the tree
+    its programs read (``ServingEngine._fuse_qkv``, the one caller
+    outside the tests): ``layers`` (the stacked per-layer parameters)
+    with the three projection stacks ``[L, D, n * hd]`` as the ONE leaf
+    ``qkv_proj`` ``[L, D, (H + 2 KV) * hd]``, ``[q | k | v]`` along the
+    last axis: the form :func:`qkv_project` reads in place. ``wrap``
+    takes the concatenation to what runs it: the engine's jit, over a
+    mesh under ``shard_map`` so that a shard holds the columns of ITS
+    heads (a column split of a global [q | k | v] would not: the engine
+    refuses a tree fused elsewhere); the tests' ``jax.eval_shape``."""
+    rest = {k: v for k, v in layers.items() if k not in QKV_LEAVES}
+    rest["qkv_proj"] = wrap(_concat_qkv)(*(layers[k] for k in QKV_LEAVES))
+    return rest
+
+
+def local_heads(width, dims):
+    """(H_loc, KV_loc) of a fused ``qkv_proj`` leaf ``width`` columns
+    wide: ``dims`` is the MODEL's (H, KV, hd), and head-axis sharding
+    keeps the ratio of query to key heads on every shard."""
+    H, KV, hd = dims
+    kv = width // hd * KV // (H + 2 * KV)
+    return width // hd - 2 * kv, kv
+
+
+def _qkv_ranges(t, dims):
+    """The q, k and v column ranges of ``t [..., (H_loc + 2 KV_loc) *
+    hd]``: the fused leaf itself, or a product over it."""
+    h_loc, kv_loc = local_heads(t.shape[-1], dims)
+    return jnp.split(t, [h_loc * dims[2], (h_loc + kv_loc) * dims[2]],
+                     axis=-1)
+
+
+def split_qkv(lp, dims):
+    """One layer's (wq, wk, wv) whatever the tree holds: the fused
+    leaf's column ranges, or the three leaves (plain or quantized) as
+    they are: for a launch that takes the three separately."""
+    if "qkv_proj" in lp:
+        return tuple(_qkv_ranges(lp["qkv_proj"], dims))
+    return tuple(lp[k] for k in QKV_LEAVES)
+
+
+def qkv_project(h, lp, dims):
+    """The q/k/v projections of rows ``h [..., D]`` by one layer's
+    parameters ``lp``: (q ``[..., H_loc, hd]``, k, v ``[..., KV_loc,
+    hd]``). The tree's STRUCTURE decides the form, as it decides the
+    weight class: where ``lp`` holds the fused ``qkv_proj``
+    (:func:`fuse_qkv`; the serving engine's tree) it is ONE product
+    whose result is split, which XLA computes reading layer ``l`` of the
+    stack in place; where it holds the three leaves (every other
+    caller, and a quantized tree: DEQUANTIZE-THEN-MATMUL) it is the
+    three products. Same operands, same accumulation, column for
+    column. ``dims``: the model's (H, KV, hd); a tensor-parallel shard
+    gets its local heads, read off the weights."""
     from ...quantization.quanters import maybe_dequantize
 
-    # quantized weight leaves take the DEQUANTIZE-THEN-MATMUL route
-    B = x.shape[0]
-    hd = sin.shape[-1] * 2               # the rope table is [T, hd // 2]
+    if "qkv_proj" in lp:
+        q, k, v = _qkv_ranges(h @ lp["qkv_proj"], dims)
+    else:
+        q, k, v = (h @ maybe_dequantize(lp[name], h.dtype)
+                   for name in QKV_LEAVES)
+    return tuple(t.reshape(*t.shape[:-1], -1, dims[2]) for t in (q, k, v))
+
+
+def attn_qkv_ref(x, nw, lp, dims, sin, cos, seq_lens, eps=1e-6):
+    """First half of the unfused attention stage: RMSNorm, the q/k/v
+    projections (:func:`qkv_project` over the layer's parameters
+    ``lp``) and RoPE at each slot's position. Returns (q [B, H, hd],
+    k_new, v_new [B, KV, hd]); a tensor-parallel shard gets its local
+    heads."""
+    from .. import rms_norm as fused_rms_norm
+    from ..rope import apply_rope
+
     pos_ids = seq_lens[:, None]
     h = fused_rms_norm(x[:, None], nw, eps)[:, 0]
-    q, k, v = ((h @ maybe_dequantize(w, x.dtype)).reshape(B, 1, -1, hd)
-               for w in (wq, wk, wv))
-    q = apply_rope(q, sin, cos, position_ids=pos_ids)
-    k = apply_rope(k, sin, cos, position_ids=pos_ids)
-    return q[:, 0], k[:, 0], v[:, 0]
+    q, k, v = qkv_project(h, lp, dims)
+    q = apply_rope(q[:, None], sin, cos, position_ids=pos_ids)
+    k = apply_rope(k[:, None], sin, cos, position_ids=pos_ids)
+    return q[:, 0], k[:, 0], v
 
 
 def attn_out_ref(x, q, wo, k_pool, v_pool, block_tables, seq_lens,

@@ -148,9 +148,14 @@ def _dense_attention_stage(args, scales):
     return out
 
 
-def _attention_stage(args, scales):
+def _attention_stage(args, scales, fused=False):
     x, nw, wq, wk, wv, wo, sin, cos, kp, vp, bt, lens = args
-    q, k_new, v_new = fdb.attn_qkv_ref(x, nw, wq, wk, wv, sin, cos, lens)
+    lp = dict(zip(fdb.QKV_LEAVES, (wq, wk, wv)))
+    hd = kp.shape[-1]
+    dims = (fdb._wq_parts(wq)[0].shape[-1] // hd, kp.shape[-2], hd)
+    if fused:       # the serving engine's form of the same weights
+        lp = fdb.fuse_qkv(lp)
+    q, k_new, v_new = fdb.attn_qkv_ref(x, nw, lp, dims, sin, cos, lens)
     if scales is None:
         kp, vp = PA.write_to_pool(kp, vp, bt, lens, k_new, v_new)
     else:
@@ -177,6 +182,10 @@ ATTENTION_STAGE_CASES = {
     "gqa_32_8": (dict(STAGE, KV=8, groups=4), {}),
     "gqa_8_2": (dict(STAGE, KV=2, groups=4), {}),
     "w8": (STAGE, {"bits": 8}), "w4": (STAGE, {"bits": 4}),
+    # q/k/v as the engine's one leaf: one product, split
+    "fused_seed1": (_random_dims(1), {"fused": True}),
+    "fused_gqa_32_8": (dict(STAGE, KV=8, groups=4), {"fused": True}),
+    "fused_gqa_8_2": (dict(STAGE, KV=2, groups=4), {"fused": True}),
 }
 
 
@@ -195,7 +204,7 @@ def test_attention_stage_matches_dense_attention(case):
                                 for w in args[2:6]) + args[6:]
     if scales is None:
         with _pinned("pallas", None):
-            got = _attention_stage(args, scales)
+            got = _attention_stage(args, scales, opt.get("fused", False))
     else:
         meta = dict(PA.decode_attention_meta(jnp.int8), interpret=False,
                     backend="tpu")
@@ -447,7 +456,8 @@ def test_engine_stream_fused_vs_unfused_bit_parity(params, cdt):
     assert set(c["prefill_traces"]) <= {8, 16}
     assert all(n <= 1 for n in c["prefill_traces"].values()), c
     assert eng_f.metrics()["decode_variant"] == eng_u.decode_variant == {
-        "attn": "xla", "mlp": "unfused", "operands": {}}
+        "attn": "xla", "mlp": "unfused", "operands": {},
+        "qkv": "fused_stack"}
 
 
 def test_engine_forced_pallas_smoke(params):
@@ -456,7 +466,7 @@ def test_engine_forced_pallas_smoke(params):
     trace picked — nothing before it has traced."""
     eng = _engine(params, capacity=2, prefill_buckets=(8,))
     assert eng.decode_variant == {"attn": None, "mlp": None,
-                                  "operands": {}}
+                                  "operands": {}, "qkv": None}
     rng = np.random.RandomState(8)
     with _pinned(*PALLAS):
         rs = [eng.submit(rng.randint(0, 97, (6,)).astype(np.int32),
@@ -472,7 +482,8 @@ def test_engine_forced_pallas_smoke(params):
     assert eng.decode_variant == {
         "attn": "pallas", "mlp": "pallas_fused",
         "operands": {"paged_attention_decode": "index",
-                     "decode_mlp_block": "index"}}
+                     "decode_mlp_block": "index"},
+        "qkv": "fused_stack"}
 
 
 @pytest.mark.parametrize("op,variant", [

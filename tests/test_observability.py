@@ -125,7 +125,7 @@ def test_metrics_schema_frozen_disabled(params):
     # trace, and PR 26's "operands": how each Pallas launch of the layer
     # loop gets its layer; off the TPU the compositions launch nothing
     assert m["decode_variant"] == {"attn": "xla", "mlp": "unfused",
-                                   "operands": {}}
+                                   "operands": {}, "qkv": "fused_stack"}
     assert m["weight_quant_variant"] == {"mode": "off"}
 
 
@@ -134,7 +134,8 @@ def test_metrics_schema_frozen_enabled(params):
     _run_stream(eng)
     m = eng.metrics()
     assert set(m.keys()) == BASE_KEYS | OBS_KEYS
-    assert set(m["decode_variant"].keys()) == {"attn", "mlp", "operands"}
+    assert set(m["decode_variant"].keys()) == {"attn", "mlp", "operands",
+                                               "qkv"}
     assert set(m["latency"].keys()) == LATENCY_KEYS
     for name, snap in m["latency"].items():
         assert set(snap.keys()) == HIST_KEYS, name
