@@ -663,11 +663,17 @@ def _lint_metas() -> Dict[str, dict]:
     from ..ops.pallas.fused_train import ce_meta, swiglu_meta
     from ..ops.pallas.norms import rms_bwd_meta
 
+    from ..ops.moe_experts import experts_meta
     from ..ops.paged_attention import decode_attention_meta
+    import jax
+    stack = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)  # noqa: E731
     prefill = prefill_meta_dims(64, 1024, 16, 16, 64, 4096, 16, 24,
                                 jnp.bfloat16, jnp.bfloat16, False)
     return {
         "paged_attention_decode": decode_attention_meta(jnp.bfloat16),
+        # two-matrix relu² experts at widths XLA tiles 128 x 128
+        "moe_experts": experts_meta(stack(7, 64, 2688, 1920),
+                                    stack(7, 64, 1856, 2688), "relu2"),
         "decode_mlp_block": decode_meta_dims(8, 1024, 4096, jnp.bfloat16),
         "prefill_attn_block": prefill,
         "prefill_mlp_block": prefill,

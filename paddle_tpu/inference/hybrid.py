@@ -1,9 +1,16 @@
 """The serving programs of a model that is run BY ITS LAYER PATTERN
 (``models/pattern.py``: ``models/granite_hybrid.py``,
-``models/mellum.py``): one decode step over all slots, and one prefill
-chunk of one request, both driven by what the config says of each kind
-of layer (``cfg.kinds``), and what ``ServingEngine`` reads of such a
-model (:class:`ServedPattern`).
+``models/mellum.py``, ``models/nemotron_h.py``): one decode step over
+all slots, and one prefill chunk of one request, both driven by what
+the config says of each kind of layer (``cfg.kinds``), and what
+``ServingEngine`` reads of such a model (:class:`ServedPattern`).
+
+A layer is its HALVES (``pattern.LayerKind``): a mixer (Mamba-2 or
+attention) or none, then an expert half or none. Granite's and Mellum
+2's layers are both; a Nemotron-H layer is one of the three alone.
+Each program writes each half once (its ``mamba``, ``attention`` and
+``_moe``) and ``_run_pattern`` puts a layer together from its kind:
+no program asks which family it runs.
 
 Three kinds of per-request state live side by side:
 
@@ -23,7 +30,7 @@ Three kinds of per-request state live side by side:
     conv  [Lm, slots, K-1, C]     the convolution's last K-1 inputs
 
 ``state`` is the dict of what the model has of the last two, plus
-``stats`` (int [3]: routing counts, summed on the device). Both
+``stats`` (int [5]: routing counts, summed on the device). Both
 programs take it as an argument, carry it through their loops beside
 the global pools, write the layer (and, in a chunk, the slot) they are
 at in place, and return it; the engine donates it. A slot that is not
@@ -31,11 +38,14 @@ decoding has ``dt = 0`` in the decode step, which leaves its state bit
 for bit, and writes its keys to the scratch page; a chunk's padding
 likewise.
 
-The layer pattern is run as its segments of equal layers
-(``cfg.segments()``): each run is ONE loop over that kind's stacked
-weights, so a period of "5 Mamba, 1 attention, 4 Mamba" compiles two
-loop bodies and one attention layer, not ten layers, and "3 window, 1
-full" twice compiles four loops of two bodies.
+The layer pattern is run as ``pattern.runs(cfg)``: repeats of a unit of
+layers, each run ONE loop over its kinds' stacked weights. Runs of
+equal layers come first, so a period of "5 Mamba, 1 attention, 4
+Mamba" compiles two loop bodies and one attention layer, not ten
+layers, and "3 window, 1 full" twice compiles four loops of two
+bodies; a pattern that alternates ("MEMEM*EMEMEM*EME") has no two
+equal neighbours and runs as units of two layers: (ME) x 2 and (EM) x
+3 are a loop each.
 """
 from __future__ import annotations
 
@@ -45,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import pattern as pt
+from ..ops import mamba2
 from ..ops.moe_experts import expert_counts
 from ..ops.paged_attention import paged_attention_decode, write_to_pool
 
@@ -72,6 +83,7 @@ class ServedPattern:
     recurrent_layers: int        # layers with a state a slot
     window_layers: int           # layers whose pages go back behind ...
     window: int                  # ... this many positions (0: none)
+    expert_layers: int = 0       # layers with an expert half
 
     @property
     def prefix_skip_counter(self) -> str:
@@ -85,7 +97,8 @@ class ServedPattern:
     def counters(self):
         """Counters this model adds to ``engine.counters``."""
         names = [self.prefix_skip_counter, "expert_assignments",
-                 "expert_assignments_held", "expert_load_max"]
+                 "expert_assignments_held", "expert_load_max",
+                 "experts_touched_held", "expert_layer_steps"]
         if self.recurrent_layers:
             names.append("state_resets")
         if self.window:
@@ -102,7 +115,13 @@ class ServedPattern:
     def refuse(self, mesh=None, weight_quant=None, cache_dtype=None,
                kv_offload=False):
         """What such a model cannot be served with yet, each refused by
-        the mechanism that is missing."""
+        the mechanism that is missing: a host tier and preemption
+        (state snapshots; prefix sharing across two page lifetimes), a
+        mesh (an expert exchange, a sharded recurrent state), quantized
+        weights (the expert stacks and Mamba projections have no
+        dequantizing route), int8 pools (calibrated through the dense
+        decoder). Every pattern-run family is refused the same ones: a
+        layer's halves change none of these mechanisms."""
         if kv_offload:
             raise ValueError(
                 "ServingEngine(kv_offload=...): " + (
@@ -149,14 +168,15 @@ def served_pattern(cfg):
     return ServedPattern(
         sum(k.mixer == "mamba" for k in used),
         sum(k.pool == "window" for k in used),
-        windows.pop() if windows else 0)
+        windows.pop() if windows else 0,
+        sum(k.experts for k in used))
 
 
 def init_state(cfg, slots: int, state_dtype=F32, window_blocks: int = 0,
                block_size: int = 16, ring: int = 0):
     """Zeroed pools for ``slots`` slots: what the model has of the
     recurrent state and of the window layers' pages and tables."""
-    state = {"stats": jnp.zeros((3,), jnp.asarray(0).dtype)}
+    state = {"stats": jnp.zeros((5,), jnp.asarray(0).dtype)}
     if getattr(cfg, "num_recurrent_layers", 0):
         ssm, conv = cfg.state_shapes(slots)
         state.update(ssm=jnp.zeros(ssm, state_dtype),
@@ -187,8 +207,9 @@ def reset_slot(state, slot):
 
 
 def _moe(params, h, cfg, l, live, stats):
-    """A layer's second half inside a loop at layer ``l`` (traced), and
-    the running routing counts."""
+    """A layer's expert half inside a loop, at layer ``l`` (traced) of
+    ``params["moe"]``, and the running routing counts (the last is the
+    number of expert layers run: what the others are summed over)."""
     moe = params["moe"]
     mp = pt.at_layer({k: v for k, v in moe.items()
                       if k not in _EXPERT_STACKS}, l)
@@ -200,33 +221,67 @@ def _moe(params, h, cfg, l, live, stats):
                               cfg.num_local_experts,
                               cfg.expert_offset).astype(stats.dtype)
             stats = jnp.stack([stats[0] + c[0], stats[1] + c[1],
-                               jnp.maximum(stats[2], c[2])])
+                               jnp.maximum(stats[2], c[2]),
+                               stats[3] + c[3], stats[4] + 1])
     return x, stats
 
 
-def _run_segments(cfg, x, k_pools, v_pools, state, mamba_layers,
-                  attn_layers, stats=()):
-    """The pattern as its runs of equal layers. ``mamba_layers(carry,
-    l0, m0, n)`` and ``attn_layers(carry, l0, a0, n, kind)`` are one
-    loop each; the carry of an attention run holds the pools of its
-    kind's page class. ``stats``: () or (the routing counts,), carried
-    by every loop. Returns (x, k_pools, v_pools, state, stats)."""
+# the pools a program carries through its loops, in the carry's order
+_POOLS = ("ssm", "conv", "k", "v", "k_win", "v_win")
+
+
+def _pools_of(kind):
+    """The carried pools a kind's mixer reads and writes."""
+    if kind.mixer == "mamba":
+        return ("ssm", "conv")
+    if kind.mixer == "attention":
+        return ("k_win", "v_win") if kind.pool == "window" else ("k", "v")
+    return ()
+
+
+def _run_pattern(cfg, x, k_pools, v_pools, state, mixers, expert_half,
+                 stats=()):
+    """The pattern as ``pattern.runs(cfg)``, a loop each, a layer put
+    together from its kind's halves. ``mixers[kind.mixer](kind)``
+    returns that mixer's half ``(x, *pools, m) -> (h, *pools)`` at
+    layer ``m`` of the kind's stack (what it computes once a run, a
+    table or a page list, it computes when it is made);
+    ``expert_half(h, e, stats) -> (x, stats)`` is the expert half at
+    layer ``e`` of ``params["moe"]``. A loop carries the pools its
+    unit's mixers write and ``stats`` (() or (the routing counts,)).
+    Returns (x, k_pools, v_pools, state, stats)."""
     state = dict(state)
-    for name, l0, n, k0 in cfg.segments():
-        kind = cfg.kinds[name]
+    pools = {"k": k_pools, "v": v_pools,
+             **{n: state[n] for n in _POOLS if n in state}}
+    for run in pt.runs(cfg):
+        used = {n for mem in run.members for n in _pools_of(mem.kind)}
+        names = [n for n in _POOLS if n in used]
         # each loop's own bookkeeping reads "layers" in a trace
         with jax.named_scope("layers"):
-            if kind.mixer == "mamba":
-                x, state["ssm"], state["conv"], *stats = mamba_layers(
-                    (x, state["ssm"], state["conv"], *stats), l0, k0, n)
-            elif kind.pool == "window":
-                x, state["k_win"], state["v_win"], *stats = attn_layers(
-                    (x, state["k_win"], state["v_win"], *stats), l0, k0,
-                    n, kind)
-            else:
-                x, k_pools, v_pools, *stats = attn_layers(
-                    (x, k_pools, v_pools, *stats), l0, k0, n, kind)
-    return x, k_pools, v_pools, state, stats
+            halves = [mixers[mem.kind.mixer](mem.kind)
+                      if mem.kind.mixer else None for mem in run.members]
+
+            def body(i, carry, run=run, names=names, halves=halves):
+                x, *rest = carry
+                held = dict(zip(names, rest))
+                stats = tuple(rest[len(names):])
+                for mem, mixer in zip(run.members, halves):
+                    m, e = mem.at(i)
+                    if mixer is not None:
+                        mine = _pools_of(mem.kind)
+                        x, *new = mixer(x, *(held[n] for n in mine), m)
+                        held.update(zip(mine, new))
+                    if mem.kind.experts:
+                        x, stats = expert_half(x, e, stats)
+                return (x, *(held[n] for n in names), *stats)
+
+            x, *rest = jax.lax.fori_loop(
+                0, run.repeats, body,
+                (x, *(pools[n] for n in names), *stats))
+        pools.update(zip(names, rest))
+        stats = tuple(rest[len(names):])
+    state.update({n: pools[n] for n in pools if n in state})
+    return x, pools["k"], pools["v"], state, stats
 
 
 def decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
@@ -241,33 +296,27 @@ def decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
     with jax.named_scope("embed"):
         x = pt.embed(params, tok, cfg)
 
-    def mamba_layers(carry, l0, m0, n):
-        from ..models import granite_hybrid as gh
-        from ..ops import mamba2
-
-        def body(i, carry):
-            x, ssm, conv, stats = carry
-            l, m = l0 + i, m0 + i
+    def mamba(kind):
+        def half(x, ssm, conv, m):
             with jax.named_scope("layer/mixer_in"):
-                lp = pt.at_layer(params["mamba"], m)
-                z, xbc, dt = gh.mamba_in(lp, x, cfg)
+                lp = pt.at_layer(params[kind.stack], m)
+                z, xbc, dt = pt.mamba_in(lp, x, cfg)
                 xbc, tail = mamba2.conv_update(
                     xbc, lp["conv_w"], lp["conv_b"],
                     jax.lax.dynamic_index_in_dim(conv, m, 0, False),
                     active)
                 conv = jax.lax.dynamic_update_index_in_dim(conv, tail, m,
                                                            0)
-                xs, b, c = gh.split(xbc, cfg)
+                xs, b, c = pt.split_xbc(xbc, cfg)
                 dt = jnp.where(active[:, None], dt, 0.0)
                 a = -jnp.exp(lp["A_log"].astype(F32))
             y, ssm = mamba2.ssm_update(xs, dt, a, b, c, lp["D"], ssm, m)
             with jax.named_scope("layer/mixer_out"):
-                h = gh.mamba_out(lp, x, y, z, cfg)
-            x, stats = _moe(params, h, cfg, l, active, stats)
-            return x, ssm, conv, stats
-        return jax.lax.fori_loop(0, n, body, carry)
+                h = pt.mamba_out(lp, x, y, z, cfg)
+            return h, ssm, conv
+        return half
 
-    def attn_layers(carry, l0, a0, n, kind):
+    def attention(kind):
         # a window layer's launch gets each slot's first live position
         # and its ring of the window pool; a global layer's neither
         windowed = kind.pool == "window"
@@ -275,9 +324,7 @@ def decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
         first = (jnp.maximum(seq_lens + 1 - kind.window, 0)
                  if windowed else None)
 
-        def body(i, carry):
-            x, kp, vp, stats = carry
-            l, a = l0 + i, a0 + i
+        def half(x, kp, vp, a):
             with jax.named_scope("layer/qkv"):
                 lp = pt.at_layer(params[kind.stack], a)
                 q, k, v = pt.attn_qkv(lp, x, cfg, kind, seq_lens)
@@ -293,12 +340,16 @@ def decode_step(params, tok, cfg, k_pools, v_pools, block_tables,
                 h = pt.residual(
                     x, o.reshape(x.shape[0], -1).astype(x.dtype)
                     @ lp["o_proj"], cfg)
-            x, stats = _moe(params, h, cfg, l, active, stats)
-            return x, kp, vp, stats
-        return jax.lax.fori_loop(0, n, body, carry)
+            return h, kp, vp
+        return half
 
-    x, k_pools, v_pools, state, (stats,) = _run_segments(
-        cfg, x, k_pools, v_pools, state, mamba_layers, attn_layers,
+    def expert_half(h, e, stats):
+        x, stat = _moe(params, h, cfg, e, active, stats[0])
+        return x, (stat,)
+
+    x, k_pools, v_pools, state, (stats,) = _run_pattern(
+        cfg, x, k_pools, v_pools, state,
+        {"mamba": mamba, "attention": attention}, expert_half,
         (state["stats"],))
     state["stats"] = stats
     with jax.named_scope("head"):
@@ -392,21 +443,16 @@ def prefill_chunk(params, toks, cfg, k_pools, v_pools, table, wtable,
         off = pos % BS
         x = pt.embed(params, toks, cfg)
 
-    def mamba_layers(carry, l0, m0, n):
-        from ..models import granite_hybrid as gh
-        from ..ops import mamba2
-
-        def body(i, carry):
-            x, ssm, conv = carry
-            l, m = l0 + i, m0 + i
+    def mamba(kind):
+        def half(x, ssm, conv, m):
             with jax.named_scope("layer/mixer_in"):
-                lp = pt.at_layer(params["mamba"], m)
-                z, xbc, dt = gh.mamba_in(lp, x, cfg)
+                lp = pt.at_layer(params[kind.stack], m)
+                z, xbc, dt = pt.mamba_in(lp, x, cfg)
                 xbc, tail = mamba2.causal_conv1d(
                     xbc, lp["conv_w"], lp["conv_b"], conv[m, slot],
                     n_valid)
                 conv = conv.at[m, slot].set(tail)
-                xs, b, c = gh.split(xbc, cfg)
+                xs, b, c = pt.split_xbc(xbc, cfg)
                 dt = jnp.where(valid[:, None], dt, 0.0)
                 a = -jnp.exp(lp["A_log"].astype(F32))
             # the slot's state out of the pool and back belongs to the
@@ -418,12 +464,11 @@ def prefill_chunk(params, toks, cfg, k_pools, v_pools, table, wtable,
             with jax.named_scope("ssd_scan"):
                 ssm = mamba2.set_slot_state(ssm, m, slot, new)
             with jax.named_scope("layer/mixer_out"):
-                h = gh.mamba_out(lp, x, y, z, cfg)
-            x, _ = _moe(params, h, cfg, l, valid, None)
-            return x, ssm, conv
-        return jax.lax.fori_loop(0, n, body, carry)
+                h = pt.mamba_out(lp, x, y, z, cfg)
+            return h, ssm, conv
+        return half
 
-    def attn_layers(carry, l0, a0, n, kind):
+    def attention(kind):
         windowed = kind.pool == "window"
         with jax.named_scope("layer/kv_write"):
             if windowed:
@@ -438,9 +483,7 @@ def prefill_chunk(params, toks, cfg, k_pools, v_pools, table, wtable,
             page = jnp.where(valid, jnp.take(
                 jnp.asarray(wtab, jnp.int32), col), 0)
 
-        def body(i, carry):
-            x, kp, vp = carry
-            l, a = l0 + i, a0 + i
+        def half(x, kp, vp, a):
             with jax.named_scope("layer/qkv"):
                 lp = pt.at_layer(params[kind.stack], a)
                 q, k, v = pt.attn_qkv(lp, x, cfg, kind, pos)
@@ -455,12 +498,15 @@ def prefill_chunk(params, toks, cfg, k_pools, v_pools, table, wtable,
                     cfg.attention_multiplier, kind.window, windowed)
             with jax.named_scope("layer/attn_out"):
                 h = pt.residual(x, o @ lp["o_proj"], cfg)
-            x, _ = _moe(params, h, cfg, l, valid, None)
-            return x, kp, vp
-        return jax.lax.fori_loop(0, n, body, carry)
+            return h, kp, vp
+        return half
 
-    x, k_pools, v_pools, state, _ = _run_segments(
-        cfg, x, k_pools, v_pools, state, mamba_layers, attn_layers)
+    def expert_half(h, e, stats):
+        return _moe(params, h, cfg, e, valid, None)[0], stats
+
+    x, k_pools, v_pools, state, _ = _run_pattern(
+        cfg, x, k_pools, v_pools, state,
+        {"mamba": mamba, "attention": attention}, expert_half)
     with jax.named_scope("head"):
         last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=0)
         logits = pt.lm_logits(params, last, cfg).astype(F32)

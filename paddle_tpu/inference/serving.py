@@ -1156,7 +1156,8 @@ class ServingEngine:
         "prefix_lookup_tokens", "prefix_hit_tokens", "state_resets",
         "prefix_skipped_recurrent", "prefix_skipped_window",
         "expert_assignments", "expert_assignments_held",
-        "expert_load_max", "window_pages_released",
+        "expert_load_max", "experts_touched_held", "expert_layer_steps",
+        "window_pages_released",
         "kv_tokens_held_window", "kv_tokens_seen_window",
         "kv_pages_live_global"))
 
@@ -1165,7 +1166,8 @@ class ServingEngine:
         since the last reset, into ``counters`` (one small read)."""
         stats = np.asarray(self._state["stats"])
         for k, v in zip(("expert_assignments", "expert_assignments_held",
-                         "expert_load_max"), stats):
+                         "expert_load_max", "experts_touched_held",
+                         "expert_layer_steps"), stats):
             self.counters[k] = int(v)
 
     def _pattern_metrics(self) -> Dict:
@@ -1176,7 +1178,7 @@ class ServingEngine:
         reset (``load_skew``: the largest number of tokens one expert
         got in a step, over the mean an expert got)."""
         c, cfg, pat = self.counters, self.cfg, self._pattern
-        layers = cfg.num_hidden_layers
+        layers = pat.expert_layers
         mean = (c["expert_assignments"]
                 / (cfg.num_experts * layers * c["decode_steps"])
                 if c["decode_steps"] else 0.0)
@@ -1195,7 +1197,11 @@ class ServingEngine:
                                if c["expert_assignments"] else None),
                 "load_max": c["expert_load_max"],
                 "load_skew": (round(c["expert_load_max"] / mean, 3)
-                              if mean else None)}}
+                              if mean else None),
+                # held experts that got a token, a layer a decode step
+                "touched_held": (round(c["experts_touched_held"]
+                                       / c["expert_layer_steps"], 3)
+                                 if c["expert_layer_steps"] else None)}}
         if pat.recurrent_layers:
             out.update(
                 state_bytes=sum(int(self._state[k].nbytes)
@@ -1939,7 +1945,10 @@ class ServingEngine:
                     "operands": launch_operands(picked),
                     "qkv": ("fused_stack"
                             if "qkv_proj" in params.get("layers", {})
-                            else "per_leaf")}
+                            else "per_leaf"),
+                    # a model with an expert half: its grouped product
+                    **({"experts": picked["moe_experts"]}
+                       if "moe_experts" in picked else {})}
             with jax.named_scope("sample"):
                 key, sub = jax.random.split(key)
                 nxt = _sample_slots(logits, sub, temps)

@@ -37,7 +37,8 @@ import jax.numpy as jnp
 
 from ..ops import mamba2
 from .pattern import (LayerKind, at_layer, attn_dense, attn_qkv,  # noqa: F401
-                      embed, lm_logits, moe_block, norm, segments)
+                      embed, lm_logits, mamba_in, mamba_out, moe_block,
+                      norm, segments, split_xbc)
 
 __all__ = ["GraniteHybridConfig", "init_params", "forward",
            "GRANITE_HYBRID_TINY"]
@@ -210,35 +211,8 @@ def init_params(cfg: GraniteHybridConfig, key=None, dtype=None) -> Dict:
     }
 
 
-# -- layer halves shared by forward and the serving programs -------------
-# what every pattern-run family shares lives in models/pattern.py; the
-# Mamba-2 halves below are this family's own
-
-
-def mamba_in(lp, x, cfg):
-    """Norm and in_proj of a Mamba layer on x [T, D]: (z [T, d_in],
-    xBC [T, C] before the convolution, dt [T, H] float32 after
-    softplus)."""
-    h = norm(x, lp["input_norm"], cfg.rms_norm_eps)
-    zxd = h @ lp["in_proj"]
-    d_in, C = cfg.mamba_d_inner, cfg.mamba_conv_dim
-    z, xbc, dt = zxd[:, :d_in], zxd[:, d_in:d_in + C], zxd[:, d_in + C:]
-    dt = jax.nn.softplus(dt.astype(F32) + lp["dt_bias"].astype(F32)[None])
-    return z, xbc, dt
-
-
-def mamba_out(lp, x, y, z, cfg):
-    """Gate, the norm over all of d_in, out_proj and the residual."""
-    g = y.reshape(y.shape[0], -1).astype(F32) * jax.nn.silu(z.astype(F32))
-    var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-    g = g * jax.lax.rsqrt(var + cfg.rms_norm_eps) * lp["norm"].astype(F32)
-    return x + cfg.residual_multiplier * (g.astype(x.dtype)
-                                          @ lp["out_proj"])
-
-
-def split(xbc, cfg):
-    return mamba2.split_xbc(xbc, cfg.mamba_n_heads, cfg.mamba_d_head,
-                            cfg.mamba_n_groups, cfg.mamba_d_state)
+# -- the layer halves forward and the serving programs share live in
+# models/pattern.py (the Mamba-2 mixer's two ends among them)
 
 
 def forward(params: Dict, tokens, cfg: GraniteHybridConfig):
@@ -261,7 +235,7 @@ def forward(params: Dict, tokens, cfg: GraniteHybridConfig):
                              x.dtype)
             xbc, _ = mamba2.causal_conv1d(xbc, lp["conv_w"], lp["conv_b"],
                                           tail, S)
-            xs, b, c = split(xbc, cfg)
+            xs, b, c = split_xbc(xbc, cfg)
             s0 = jnp.zeros((cfg.mamba_d_state, cfg.mamba_d_inner), F32)
             y, _ = mamba2.ssd_scan(
                 xs, jnp.where(valid[:, None], dt, 0.0),

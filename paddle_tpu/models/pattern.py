@@ -2,19 +2,24 @@
 of layer, in one place, and the layer halves such models share.
 
 A config class of such a family (``granite_hybrid.GraniteHybridConfig``,
-``mellum.MellumConfig``) maps every word of its ``layer_types`` to a
-:class:`LayerKind`: which mixer the layer runs, which page class its
-keys and values live in (and so how long a request keeps them), how far
-back a query sees, and which rotary table it uses. The parameter tree
-(one stack of mixer weights a kind), the cache manager
-(``ops/paged_attention.BlockManager``: a pool a page class), the two
-serving programs (``inference/hybrid.py``) and the full-sequence
-``forward`` all read that one description.
+``mellum.MellumConfig``, ``nemotron_h.NemotronHConfig``) maps every word
+of its layer pattern to a :class:`LayerKind`: the layer's HALVES (a
+mixer or none, an expert half or none: a granite or Mellum 2 layer is
+both, a Nemotron-H layer is one), which page class its keys and values
+live in (and so how long a request keeps them), how far back a query
+sees, and which rotary table it uses. The parameter tree (one stack of
+mixer weights a kind, ``moe`` over the layers that have an expert
+half), the cache manager (``ops/paged_attention.BlockManager``: a pool
+a page class), the two serving programs (``inference/hybrid.py``) and
+the full-sequence ``forward`` all read that one description;
+:func:`runs` gives the loops the programs make of a pattern.
 
 The halves below are written once for all kinds: norm, q/k/v with the
 kind's rotary table, dense attention with the kind's window (the
-full-sequence program's), the expert layer with or without a shared
-MLP beside it, embedding and head.
+full-sequence program's), the Mamba-2 mixer's two ends, the expert half
+as the config describes it (``cfg.expert_half``:
+``ops/moe_experts.ExpertHalf``) with or without a shared MLP beside it,
+embedding and head.
 """
 from __future__ import annotations
 
@@ -24,22 +29,27 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops import mamba2
 from ..ops import rms_norm as fused_rms_norm
-from ..ops.moe_experts import gated_mlp, moe_experts, route
+from ..ops.moe_experts import ExpertHalf, mlp, moe_experts, route
 from ..ops.rope import rope_frequencies, rotate_half
 
-__all__ = ["LayerKind", "segments", "norm", "at_layer", "attn_qkv",
-           "attn_dense", "moe_block", "residual", "embed", "lm_logits"]
+__all__ = ["LayerKind", "Run", "Member", "segments", "runs", "norm",
+           "at_layer", "attn_qkv", "attn_dense", "mamba_in", "mamba_out",
+           "split_xbc", "moe_block", "residual", "embed", "lm_logits"]
 
 F32 = jnp.float32
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
-    """One word of ``layer_types``.
+    """One word of the layer pattern: a layer's halves.
 
-    ``mixer``: "mamba" | "attention". ``stack``: the key of the
-    parameter tree that holds this kind's stacked mixer weights.
+    ``mixer``: "mamba" | "attention" | None (the layer has no mixer:
+    it is an expert half alone). ``stack``: the key of the parameter
+    tree that holds this kind's stacked mixer weights. ``experts``: the
+    layer has an expert half (its weights are the next layer of
+    ``params["moe"]``, which is stacked over such layers only).
     ``pool``: the page class of its keys and values: "global" (a
     request keeps its pages to its end), "window" (it gives back what
     lies behind the window while it runs), None (no keys and values: a
@@ -48,11 +58,19 @@ class LayerKind:
     kind's section of ``rope_parameters`` (None: no position
     embedding)."""
     name: str
-    mixer: str
-    stack: str
+    mixer: Optional[str]
+    stack: Optional[str]
     pool: Optional[str] = None
     window: Optional[int] = None
     rope: Optional[Tuple[Tuple[str, object], ...]] = None
+    experts: bool = True
+
+    def __post_init__(self):
+        if self.mixer not in ("mamba", "attention", None):
+            raise ValueError(f"layer kind {self.name!r}: mixer "
+                             f"{self.mixer!r}")
+        if self.mixer is None and not self.experts:
+            raise ValueError(f"layer kind {self.name!r} has no half")
 
     def rope_table(self, head_dim):
         """(inv_freq [head_dim // 2], attention factor) or None."""
@@ -72,6 +90,76 @@ def segments(pattern):
             out.append([kind, l, 1, seen.get(kind, 0)])
         seen[kind] = seen.get(kind, 0) + 1
     return [tuple(s) for s in out]
+
+
+@dataclasses.dataclass(frozen=True)
+class Member:
+    """One layer of a run's unit: its kind, and where its weights lie
+    in repeat ``i`` of the run: layer ``k0 + i * dk`` of the kind's
+    mixer stack, layer ``e0 + i * de`` of ``params["moe"]``."""
+    kind: LayerKind
+    k0: int
+    dk: int
+    e0: int
+    de: int
+
+    def at(self, i):
+        """(mixer layer, expert layer) in repeat ``i`` (traced)."""
+        e = self.e0 + (i if self.de == 1 else i * self.de)
+        return self.k0 + (i if self.dk == 1 else i * self.dk), e
+
+
+@dataclasses.dataclass(frozen=True)
+class Run:
+    """``repeats`` times a unit of layers, from layer ``first`` on: ONE
+    loop of the serving programs, whose body is the unit."""
+    first: int
+    repeats: int
+    members: Tuple[Member, ...]
+
+
+# layers of a loop body at most: two halves make a whole layer
+_UNIT = 2
+
+
+def runs(cfg):
+    """The pattern as the loops the serving programs run: repeats of a
+    UNIT of one or two layers, so that a loop's body compiles once
+    whatever its repeats. Runs of equal layers are found first (granite
+    and Mellum 2 come out as their :func:`segments`); a pattern whose
+    layers are ONE half each alternates, and "MEMEM*EMEMEM" is (ME) x
+    2, M, *, (EM) x 3: as sixteen loops of one layer the Nemotron-H
+    cell's three programs compile in ~150 s on the chip, as nine loops
+    of ten bodies in ~60 (PERF.md, PR 43). Greedy from the left: the
+    unit length that covers most layers by repeating at least twice,
+    else one layer."""
+    pattern, kinds = tuple(cfg.pattern), cfg.kinds
+    widest = _UNIT
+    out, seen, n_exp, l = [], {}, 0, 0
+    while l < len(pattern):
+        best = (1, 1)
+        for u in range(1, widest + 1):
+            unit, r = pattern[l:l + u], 1
+            while pattern[l + r * u:l + (r + 1) * u] == unit:
+                r += 1
+            if (r >= 2 or u == 1) and u * r > best[0] * best[1]:
+                best = (u, r)
+        u, r = best
+        unit = pattern[l:l + u]
+        in_unit = {n: unit.count(n) for n in unit}
+        exp_in_unit = sum(kinds[n].experts for n in unit)
+        members, k_at, e_at = [], dict(seen), n_exp
+        for n in unit:
+            members.append(Member(kinds[n], k_at.get(n, 0), in_unit[n],
+                                  e_at, exp_in_unit))
+            k_at[n] = k_at.get(n, 0) + 1
+            e_at += kinds[n].experts
+        out.append(Run(l, r, tuple(members)))
+        for n, c in in_unit.items():
+            seen[n] = seen.get(n, 0) + r * c
+        n_exp += r * exp_in_unit
+        l += u * r
+    return out
 
 
 # -- layer halves ------------------------------------------------------------
@@ -123,21 +211,61 @@ def attn_dense(q, k, v, q_pos, cfg, window=None):
     return o.reshape(P, H * hd).astype(q.dtype)
 
 
+def mamba_in(lp, x, cfg):
+    """Norm and in_proj of a Mamba-2 layer on x [T, D]: (z [T, d_in],
+    xBC [T, C] before the convolution, dt [T, H] float32 after
+    softplus). ``in_proj``'s columns are [z | xBC | dt] and, where
+    those are no whole number of 128 lanes, zeros up to one (a family's
+    tree says so): the chip lays a product whose columns are not whole
+    lanes out column-major, and with it everything the convolution
+    reads and writes, the slots' tails among them."""
+    h = norm(x, lp["input_norm"], cfg.rms_norm_eps)
+    zxd = h @ lp["in_proj"]
+    d_in, C = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    z, xbc = zxd[:, :d_in], zxd[:, d_in:d_in + C]
+    dt = zxd[:, d_in + C:d_in + C + cfg.mamba_n_heads]
+    dt = jax.nn.softplus(dt.astype(F32) + lp["dt_bias"].astype(F32)[None])
+    return z, xbc, dt
+
+
+def mamba_out(lp, x, y, z, cfg):
+    """Gate, the norm over each B/C group's channels of d_in (all of
+    d_in with one group), out_proj and the residual."""
+    g = y.reshape(y.shape[0], -1).astype(F32) * jax.nn.silu(z.astype(F32))
+    groups = cfg.mamba_n_groups
+    if groups > 1:
+        g = g.reshape(g.shape[0], groups, -1)
+    var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+    g = (g * jax.lax.rsqrt(var + cfg.rms_norm_eps)).reshape(y.shape[0], -1) \
+        * lp["norm"].astype(F32)
+    return residual(x, g.astype(x.dtype) @ lp["out_proj"], cfg)
+
+
+def split_xbc(xbc, cfg):
+    return mamba2.split_xbc(xbc, cfg.mamba_n_heads, cfg.mamba_d_head,
+                            cfg.mamba_n_groups, cfg.mamba_d_state)
+
+
 def moe_block(mp, h, cfg, layer=None):
-    """The layer's second half on h [T, D]: the experts held here and,
-    where the family has one (``shared_in`` among the leaves), the
-    shared MLP. ``mp`` is one layer's slice of ``params["moe"]``,
-    except that with ``layer`` given its two expert leaves are the
-    whole stacks (``moe_experts`` then addresses the layer itself).
-    Returns (x', experts [T, k])."""
+    """A layer's expert half on h [T, D]: norm, the route, the experts
+    held here and, where the family has one (``shared_in`` among the
+    leaves), the shared MLP, as ``cfg.expert_half`` describes them;
+    ``router_bias`` among the leaves is the bias of the choice. ``mp``
+    is one layer's slice of ``params["moe"]``, except that with
+    ``layer`` given its two expert leaves are the whole stacks
+    (``moe_experts`` then addresses the layer itself). Returns (x',
+    experts [T, k])."""
+    half = getattr(cfg, "expert_half", None) or ExpertHalf()
     with jax.named_scope("layer/router"):
         u = norm(h, mp["post_norm"], cfg.rms_norm_eps)
-        gates, experts = route(u, mp["router"], cfg.num_experts_per_tok)
+        gates, experts = route(u, mp["router"], cfg.num_experts_per_tok,
+                               half.scoring, mp.get("router_bias"),
+                               half.scale)
     out = moe_experts(u, gates, experts, mp["w_in"], mp["w_out"],
-                      offset=cfg.expert_offset, layer=layer)
+                      offset=cfg.expert_offset, layer=layer, act=half.act)
     with jax.named_scope("layer/mlp"):
         if "shared_in" in mp:
-            out = out + gated_mlp(u, mp["shared_in"], mp["shared_out"])
+            out = out + mlp(u, mp["shared_in"], mp["shared_out"], half.act)
         return h + _times(out, cfg.residual_multiplier), experts
 
 

@@ -177,19 +177,19 @@ def ssm_update(x, dt, a, b, c, d, pool, layer):
     state size on the sublanes, a head's rows on the lanes: see
     ``ops/pallas/mamba2.py``). Returns (y [S, H, hp] float32, pool).
     On the chip one Pallas launch a layer (``ssm_update``), which
-    reads and writes each state once; elsewhere, and with more than one
-    B/C group, the composition below."""
+    reads and writes each state once, whatever the number of B/C
+    groups whose lanes are whole tiles; elsewhere the composition
+    below."""
     from .pallas._util import pallas_route
+    from .pallas.mamba2 import group_blocks, ssm_update_pallas
     S, H, hp = x.shape
     G = b.shape[1]
     with jax.named_scope("ssm_update"):
         dtf = dt.astype(F32)
         decay = jnp.repeat(jnp.exp(dtf * a.astype(F32)[None]), hp, axis=1)
         xdt = (x.astype(F32) * dtf[..., None]).reshape(S, H * hp)
-        if G == 1 and pallas_route():
-            from .pallas.mamba2 import ssm_update_pallas
-            y, pool = ssm_update_pallas(decay, xdt, b[:, 0], c[:, 0], pool,
-                                        layer)
+        if pallas_route() and (G == 1 or group_blocks(H * hp, G)):
+            y, pool = ssm_update_pallas(decay, xdt, b, c, pool, layer)
         else:
             rows = (H // G) * hp                 # rows that share a group
             bn = jnp.repeat(b.astype(F32), rows, axis=1).transpose(0, 2, 1)

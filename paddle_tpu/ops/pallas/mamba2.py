@@ -14,6 +14,14 @@ everything which varies by (head, row) is a lane vector ``[1, R]`` and
 ``B`` and ``C`` are sublane vectors ``[N, 1]``: every broadcast and the
 reduction over ``N`` are the cheap kind. ``layer`` (scalar prefetch)
 picks the layer of the pool, so that no slice of the pool is made.
+
+With ``G`` B/C groups (head ``h`` reads group ``h // (H / G)``) a
+group owns ``R / G`` consecutive lanes. A lane block keeps the size it
+has at ``G = 1`` (a block of 512 lanes moves 0.5 MB a grid step, of
+2048 lanes 2 MB: the step's fixed cost is the same) and so holds a
+whole number of groups, or lies inside one: the kernel walks the
+block's groups in a static loop, each on its own aligned lane slice
+with that group's ``B`` and ``C`` columns.
 """
 from __future__ import annotations
 
@@ -38,6 +46,37 @@ def _kernel(layer_ref, decay_ref, xdt_ref, b_ref, c_ref, s_ref, y_ref,
                          keepdims=True)
 
 
+def _kernel_groups(layer_ref, decay_ref, xdt_ref, b_ref, c_ref, s_ref,
+                   y_ref, o_ref):
+    """The same update on a block that holds ``b_ref.shape[0]`` groups
+    side by side: b_ref / c_ref [groups, N, 1], the others [.., rb]."""
+    del layer_ref
+    groups = b_ref.shape[0]
+    gl = s_ref.shape[-1] // groups
+    for g in range(groups):
+        at = (slice(None), pl.ds(g * gl, gl))
+        new = (s_ref[at].astype(F32) * decay_ref[at]
+               + b_ref[g] * xdt_ref[at])                # [N, gl]
+        stored = new.astype(o_ref.dtype)
+        o_ref[at] = stored
+        y_ref[at] = jnp.sum(stored.astype(F32) * c_ref[g], axis=0,
+                            keepdims=True)
+
+
+def group_blocks(rows: int, groups: int):
+    """(lanes of a block, groups a block holds, blocks a group spans)
+    for ``groups`` B/C groups over ``rows`` lanes, or None where a
+    group's lanes are no whole number of 128 (no aligned lane slice:
+    the caller computes the composition)."""
+    gl = rows // groups
+    if rows % groups or gl % 128:
+        return None
+    rb = lane_block(rows)
+    if rb % gl and gl % rb:
+        rb = lane_block(gl)
+    return rb, max(1, rb // gl), max(1, gl // rb)
+
+
 def lane_block(rows: int, cap: int = 2048) -> int:
     """The lanes of one block: the largest multiple of 128 that divides
     ``rows`` and is at most ``cap`` (``rows`` itself when it is small or
@@ -53,17 +92,30 @@ def lane_block(rows: int, cap: int = 2048) -> int:
 @no_x64
 def ssm_update_pallas(decay, xdt, b, c, pool, layer):
     """decay, xdt: [S, R] float32 (``exp(dt A)`` and ``dt x`` spread
-    over a head's rows); b, c: [S, N] float32; pool: [Lm, S, N, R] in
-    its storage type. Returns (y [S, R] float32, the pool with layer
-    ``layer``'s states replaced)."""
+    over a head's rows); b, c: [S, N] float32, or [S, G, N] for ``G``
+    B/C groups (``group_blocks(R, G)`` must not be None); pool: [Lm, S,
+    N, R] in its storage type. Returns (y [S, R] float32, the pool with
+    layer ``layer``'s states replaced)."""
     Lm, S, N, R = pool.shape
-    rb = lane_block(R)
+    if b.ndim == 3 and b.shape[1] == 1:
+        b, c = b[:, 0], c[:, 0]
+    if b.ndim == 2:
+        kernel, rb = _kernel, lane_block(R)
+        col = pl.BlockSpec((None, N, 1), lambda s, j, l: (s, 0, 0))
+        b, c = (t.astype(F32)[:, :, None] for t in (b, c))
+    else:
+        kernel = _kernel_groups
+        rb, held, span = group_blocks(R, b.shape[1])
+        # block j's groups: the j-th ``held`` of them, or (a group
+        # spanning ``span`` blocks) group j // span
+        col = pl.BlockSpec((None, held, N, 1),
+                           lambda s, j, l: (s, j // span, 0, 0))
+        b, c = (t.astype(F32)[..., None] for t in (b, c))
     row = pl.BlockSpec((None, 1, rb), lambda s, j, l: (s, 0, j))
-    col = pl.BlockSpec((None, N, 1), lambda s, j, l: (s, 0, 0))
     state = pl.BlockSpec((None, None, N, rb),
                          lambda s, j, l: (l[0], s, 0, j))
     y, pool = audited_pallas_call(
-        _kernel, name="ssm_update", num_scalar_prefetch=1,
+        kernel, name="ssm_update", num_scalar_prefetch=1,
         grid=(S, R // rb),
         in_specs=[row, row, col, col, state],
         out_specs=[row, state],
@@ -73,8 +125,8 @@ def ssm_update_pallas(decay, xdt, b, c, pool, layer):
         input_output_aliases={5: 1},
         interpret=interpret_mode(),
     )(jnp.asarray(layer, jnp.int32).reshape(1),
-      decay.astype(F32)[:, None, :], xdt.astype(F32)[:, None, :],
-      b.astype(F32)[:, :, None], c.astype(F32)[:, :, None], pool)
+      decay.astype(F32)[:, None, :], xdt.astype(F32)[:, None, :], b, c,
+      pool)
     return y[:, 0, :], pool
 
 

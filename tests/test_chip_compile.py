@@ -97,6 +97,42 @@ def _selects(op, meta, variant="pallas_fused"):
     return KERNELS.dispatch(op, meta)[0] == variant
 
 
+def _ssm_update_case(S, R, N, G, Lm):
+    """``ssm_update_pallas`` on a float32 pool [Lm, S, N, R] with G B/C
+    groups, layer a traced operand."""
+    def build():
+        from paddle_tpu.ops.pallas.mamba2 import ssm_update_pallas
+        f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+        bc = (S, N) if G == 1 else (S, G, N)
+        return ssm_update_pallas, (
+            f32(S, R), f32(S, R), f32(*bc), f32(*bc), f32(Lm, S, N, R),
+            jax.ShapeDtypeStruct((), jnp.int32))
+    return build
+
+
+def _experts_meta():
+    from paddle_tpu.ops.moe_experts import experts_meta
+    bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)  # noqa: E731
+    return {**experts_meta(bf(7, 64, 2688, 1920), bf(7, 64, 1856, 2688),
+                           "relu2"), "backend": "tpu", "interpret": False}
+
+
+def _moe_grouped_case(T, L=7, held=64, D=2688, Fs=1920, F=1856, k=6):
+    """One expert layer of the Nemotron-H cell over T tokens: the
+    layout and both launches, the layer a traced operand."""
+    def build():
+        from paddle_tpu.ops import moe_experts as me
+        bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)  # noqa: E731
+
+        def fn(u, router, w_in, w_out, layer):
+            gates, experts = me.route(u, router, k, "sigmoid", None, 2.5)
+            return me._grouped(u, gates, experts, w_in, w_out, 0, layer,
+                               "relu2")
+        return fn, (bf(T, D), bf(D, 2 * held), bf(L, held, D, Fs),
+                    bf(L, held, F, D), jax.ShapeDtypeStruct((), jnp.int32))
+    return build
+
+
 # (id, builder, launch names the program must hold, "does dispatch
 # select this kernel on the chip?" or None where no registry op routes it)
 CASES = [
@@ -117,6 +153,23 @@ CASES = [
      kc._paged_case(64, 32, 8, 128, 16, 8192, 128, BF16, L=1,
                     scale=1.0 / 128),
      {"paged_attention_decode"}, None),
+    # 2 KV heads of 16 query heads each (page rows BS * KV = 32)
+    ("paged_attention_nemotron_layer",
+     kc._paged_case(128, 32, 2, 128, 16, 32768, 256, BF16, L=2),
+     {"paged_attention_decode"}, None),
+    # -- the one-token state update: granite's one B/C group (blocks of
+    #    2048 lanes inside the group) and Nemotron-H's eight (a block
+    #    holds four groups of 512 lanes) --------------------------------
+    ("ssm_update_one_group", _ssm_update_case(64, 8192, 128, 1, 9),
+     {"ssm_update"}, None),
+    ("ssm_update_eight_groups", _ssm_update_case(128, 4096, 128, 8, 7),
+     {"ssm_update"}, None),
+    # -- the grouped product over the touched experts, both products of a
+    #    Nemotron-H expert layer (the second reads 1856 of the hidden
+    #    rows' 1920 stored columns), at a decode step's and a chunk's rows
+    ("moe_grouped_decode", _moe_grouped_case(128), {"moe_grouped"},
+     lambda: _selects("moe_experts", _experts_meta(), "pallas_grouped")),
+    ("moe_grouped_chunk", _moe_grouped_case(512), {"moe_grouped"}, None),
     ("rms_norm_decode_rows", kc._rms_case(CAP, D, BF16),
      {"rms_norm_fwd", "rms_norm_bwd"}, None),
     ("decode_mlp_block", kc._mlp_block_case(CAP, D, F, BF16),
@@ -184,6 +237,36 @@ def test_kernel_compiles_for_v5e(topo, name, build, want, selected):
     if selected is not None:
         assert selected(), f"dispatch does not select {name} on the chip"
     assert want <= _kernels(_compile(topo, build))
+
+
+@pytest.mark.parametrize("K,N,held", [
+    (2688, 1920, 64),     # the Nemotron-H cell's first product
+    (1856, 2688, 64),     # ... and its second (F x K)
+    (4096, 1536, 36),     # granite's gated pair of columns
+    (2304, 1792, 64),     # Mellum 2's
+], ids=["nemotron_in", "nemotron_out", "granite", "mellum2"])
+def test_xla_tile_is_what_the_compiler_writes(topo, K, N, held):
+    """``ops/pallas/moe_experts.xla_tile`` restates a rule of XLA's (a
+    ``ragged_dot`` dimension is tiled by the largest power of two up to
+    512 that divides it) and ``supports()`` hands the expert layer to
+    the Pallas launch where that rule gives 128 x 128. The rule is not
+    JAX's to keep: this reads the tiling out of the compiled text at
+    the benchmark's widths, so an upgrade that changes it fails here
+    and not silently in a cell (ROADMAP S1: the predicate goes when
+    ``moe_grouped`` replaces ``ragged_dot``)."""
+    from paddle_tpu.ops.pallas.moe_experts import xla_tile
+
+    def build():
+        bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)  # noqa: E731
+        return (lambda x, w, sizes: jax.lax.ragged_dot(x, w, sizes),
+                (bf(768, K), bf(held, K, N),
+                 jax.ShapeDtypeStruct((held,), jnp.int32)))
+    found = re.findall(r'ragged_dot_tiling="?(\d+),(\d+),(\d+)',
+                       _compile(topo, build).as_text())
+    assert found, "the compiled text names no ragged_dot_tiling"
+    # (rows, contracted, columns) of the launch
+    assert {(int(k), int(n)) for _, k, n in found} \
+        == {(xla_tile(K), xla_tile(N))}
 
 
 # the training cell's flat optimizer state (mistral-7b-v0.3-train-l2:
@@ -513,7 +596,13 @@ _DENSE_LAUNCHES = {"paged_attention_decode", "decode_mlp_block"}
          {"paged_attention_decode", "ssm_update"}, 2),
         # two page classes: the global pool (0.54 GB) is the measure,
         # the window pools ride in the state and are written in place
-        ("mellum2-12b-a2.5b-l8", {"paged_attention_decode"}, 2))])
+        ("mellum2-12b-a2.5b-l8", {"paged_attention_decode"}, 2),
+        # both pools of two attention layers (0.54 GB each) and seven
+        # layers of state ride in the carry; the expert stacks are read
+        # in place (their first matrix stored in whole lanes: handed
+        # 1856 columns the launch gets a 4.3 GB copy of the stack)
+        ("nemotron-3-nano-30b-a3b-l16-e64",
+         {"paged_attention_decode", "ssm_update", "moe_grouped"}, 2))])
 def test_decode_program_holds_no_second_copy_of_a_pool(
         topo, monkeypatch, config, launches, share):
     """The layer loop's pools are carried and written in place and its
@@ -528,6 +617,57 @@ def test_decode_program_holds_no_second_copy_of_a_pool(
     assert launches <= _kernels(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes \
         < pool_bytes // share
+
+
+def test_nemotron_chunk_keeps_the_conv_tails_in_their_layout(
+        topo, monkeypatch):
+    """The 512-token chunk of the Nemotron-H cell: its temporaries are
+    a fraction of a KV pool. With ``in_proj`` 10304 columns wide (no
+    whole number of lanes) the compiler laid the product, the
+    convolution and with them the slots' tails out column-major: the
+    tails' pool [7, 128, 3, 6144] with the THREE taps on the lanes,
+    1.3 GB of padding carried through every loop (temporaries 1.51 GB);
+    stored 10368 wide they stay as they are (0.15 GB)."""
+    import json
+    from paddle_tpu.inference import hybrid
+    from paddle_tpu.models import nemotron_h as nh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "nemotron-3-nano-30b-a3b-l16-e64.json")) as f:
+        conf = json.load(f)
+    cfg = nh.NemotronHConfig(**{k: conf[k]
+                                for k in conf["program"]["config_keys"]})
+    eng = conf["engine"]
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one),
+            tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+    C, BS, MB = (eng["capacity"], eng["block_size"],
+                 eng["max_seq_len"] // eng["block_size"])
+    params = sds(jax.eval_shape(lambda: nh.init_params(cfg)))
+    pool = sds(jax.ShapeDtypeStruct(
+        (cfg.num_kv_layers, eng["num_blocks"], BS, cfg.num_key_value_heads,
+         cfg.head_dim), cfg.dtype))
+    state = sds(jax.eval_shape(
+        lambda: hybrid.init_state(cfg, C, jnp.float32, 0, BS, 0)))
+
+    def chunk(params, toks, table, pos0, n, slot, kp, vp, st):
+        return hybrid.prefill_chunk(params, toks, cfg, kp, vp, table,
+                                    table, pos0, n, slot, st)
+
+    compiled = jax.jit(chunk, donate_argnums=(6, 7, 8)).lower(
+        params, i32(512), i32(MB), i32(), i32(), i32(), pool, pool,
+        state).compile()
+    assert {"ssm_state_read", "ssm_state_write"} <= _kernels(compiled)
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
 
 
 # ---------------------------------------------------------------------------

@@ -423,11 +423,11 @@ def test_no_token_is_dropped_when_all_choose_one_expert():
     assert (np.abs(want).max(axis=1) > 1e-4).all()     # every token
     np.testing.assert_allclose(np.asarray(got), want, atol=3e-5)
     counts = expert_counts(experts, jnp.ones((40,), bool), 8, 4, 4)
-    assert [int(v) for v in counts] == [120, 80, 40]   # 5 and 6 are held
+    assert [int(v) for v in counts] == [120, 80, 40, 2]   # 5, 6 held
 
 
 def test_expert_counts_skip_idle_rows():
     experts = jnp.asarray([[0, 1, 2], [0, 4, 5], [0, 6, 7]], jnp.int32)
     live = jnp.asarray([True, False, True])
     assert [int(v) for v in expert_counts(experts, live, 8, 4, 0)] \
-        == [6, 4, 2]
+        == [6, 4, 2, 3]     # experts 0, 1, 2 of the held four got one

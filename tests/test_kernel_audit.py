@@ -26,6 +26,7 @@ from paddle_tpu.ops.pallas import (fused_adamw as fa,           # noqa: E402
                                    fused_decode_block as fdb,
                                    fused_prefill_block as fpb,
                                    fused_train as ft, norms)
+from paddle_tpu.ops import moe_experts as _moe  # noqa: E402,F401 — its op
 from paddle_tpu.ops.pallas._util import (KernelLaunchSpec,      # noqa: E402
                                          KernelOperand,
                                          capture_kernel_launches)
@@ -480,6 +481,22 @@ def _diff_paged_attention_decode():
     return run, ("paged_attention_decode",)
 
 
+def _diff_moe_experts():
+    # 5 held of 8 experts from the third on, groups of 0 to 13 rows
+    # (blocks of 16: whole, partial, none), a layer of a stack, the
+    # first matrix stored wider than the expert
+    from paddle_tpu.ops import moe_experts as me
+    T, D, E, held, F, k = 24, 32, 8, 5, 24, 3
+    u = _f32(T, D)
+    gates, experts = me.route(u, _f32(D, E), k, "sigmoid", None, 2.5)
+    w_in = jnp.pad(_f32(2, held, D, F) * 0.2, ((0, 0),) * 3 + ((0, 8),))
+    w_out = _f32(2, held, F, D) * 0.2
+
+    def run(fn):
+        return fn(u, gates, experts, w_in, w_out, 2, 1, "relu2")
+    return run, ("moe_experts",)
+
+
 def _diff_decode_mlp_block():
     B, D, F = 2, 32, 96                               # no divisor tile
     args = (_f32(B, D), jnp.abs(_f32(D)) + 0.5, _f32(D, F),
@@ -531,6 +548,7 @@ _DIFF_CASES = {
     "fused_swiglu": _diff_fused_swiglu,
     "fused_adamw": _diff_fused_adamw,
     "paged_attention_decode": _diff_paged_attention_decode,
+    "moe_experts": _diff_moe_experts,
     "decode_mlp_block": _diff_decode_mlp_block,
     "prefill_attn_block": _diff_prefill_attn_block,
     "prefill_mlp_block": _diff_prefill_mlp_block,

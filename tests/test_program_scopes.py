@@ -21,6 +21,7 @@ from paddle_tpu.distributed.trainer import MeshConfig, Trainer, make_mesh
 from paddle_tpu.inference import GenerationConfig, ServingEngine
 from paddle_tpu.inference.tp import ServingMesh
 from paddle_tpu.models import granite_hybrid as gh, llama, mellum
+from paddle_tpu.models import nemotron_h as nh
 from paddle_tpu.models.llama import loss_fn, param_shardings
 from paddle_tpu.observability import PROGRAM_SCOPES, programs, tracing
 
@@ -57,9 +58,10 @@ def build(kind):
         return ServingEngine(llama.init_params(DENSE, jax.random.key(0)),
                              DENSE, mesh=ServingMesh.make(tp=2),
                              **GEOMETRY)
-    mod = {"granite": gh, "mellum": mellum}[kind]
+    mod = {"granite": gh, "mellum": mellum, "nemotron": nh}[kind]
     cfg = {"granite": gh.GRANITE_HYBRID_TINY,
-           "mellum": mellum.MELLUM_TINY}[kind]
+           "mellum": mellum.MELLUM_TINY,
+           "nemotron": nh.NEMOTRON_H_TINY}[kind]
     return ServingEngine(mod.init_params(cfg, jax.random.key(3)), cfg,
                          **GEOMETRY)
 
@@ -101,7 +103,7 @@ def served(tmp_path_factory):
     twice under one, and is gone when its programs are read."""
     out = {}
     counter = CompileCounter()
-    for kind in ("dense", "granite", "mellum", "tp2"):
+    for kind in ("dense", "granite", "mellum", "tp2", "nemotron"):
         eng = build(kind)
         jax.block_until_ready(eng.params)
         counter.reset()
@@ -276,7 +278,7 @@ def test_trainer_without_a_session_touches_no_registry(forbidden, kw):
 
 
 # -- the engines, under a session --------------------------------------
-@pytest.mark.parametrize("kind", ["dense", "granite", "mellum", "tp2"])
+@pytest.mark.parametrize("kind", ["dense", "granite", "mellum", "tp2", "nemotron"])
 def test_engine_notes_each_program_once_under_a_session(served, kind):
     progs, facts = served[kind]
     # built, warmed and served with no session: nothing noted
@@ -294,7 +296,7 @@ def test_engine_notes_each_program_once_under_a_session(served, kind):
                      "jit_chunk": len(facts["prefill_traces"])}
 
 
-@pytest.mark.parametrize("kind", ["dense", "granite", "mellum", "tp2"])
+@pytest.mark.parametrize("kind", ["dense", "granite", "mellum", "tp2", "nemotron"])
 def test_registry_keeps_no_engine_alive(served, kind):
     progs, facts = served[kind]
     assert facts["engine_dead"] and progs
@@ -336,7 +338,8 @@ def test_a_rebuilt_decode_program_is_noted_again(tmp_path):
     assert [p.name for p in eng.program_scopes()].count("jit_step") == 2
 
 
-@pytest.mark.parametrize("kind", ["dense", "granite", "mellum", "tp2"])
+@pytest.mark.parametrize("kind", ["dense", "granite", "mellum", "tp2",
+                                  "nemotron"])
 def test_instructions_resolve_to_program_scopes(served, kind):
     for prog in served[kind][0]:
         unnamed = [h for h in work(prog)
@@ -360,6 +363,12 @@ def test_instructions_resolve_to_program_scopes(served, kind):
                 "sample"}),
     ("tp2", {"embed", "layer/qkv", "layer/attention", "layer/attn_out",
              "layer/mlp", "head", "sample"}),
+    # every layer ONE half: the expert-only layer under layer/router,
+    # moe_experts and layer/mlp, the state update under ssm_update
+    ("nemotron", {"embed", "layer/mixer_in", "ssm_update",
+                  "layer/mixer_out", "layer/router", "moe_experts",
+                  "layer/mlp", "layer/qkv", "layer/kv_write",
+                  "layer/attention", "layer/attn_out", "head", "sample"}),
 ])
 def test_decode_program_holds_its_scopes(served, kind, wanted):
     step = next(p for p in served[kind][0] if p.name == "jit_step")
@@ -374,6 +383,9 @@ def test_decode_program_holds_its_scopes(served, kind, wanted):
                  "moe_experts", "layer/attention", "head"}),
     ("mellum", {"layer/kv_write", "layer/attention", "moe_experts"}),
     ("tp2", {"kv_gather", "kv_scatter", "layer/attn_out", "layer/mlp"}),
+    ("nemotron", {"layer/mixer_in", "ssd_scan", "layer/mixer_out",
+                  "layer/router", "moe_experts", "layer/mlp",
+                  "layer/attention", "head"}),
 ])
 def test_chunk_programs_hold_their_scopes(served, kind, wanted):
     for chunk in (p for p in served[kind][0] if p.name == "jit_chunk"):
