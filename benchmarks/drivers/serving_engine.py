@@ -21,6 +21,14 @@ from benchmarks import harness
 from benchmarks.harness import clock, say
 
 
+# The tails of ``tpot_ms`` a window reports; BENCHMARK.json judges a cell
+# on ONE: the highest that leaves 10 of the window's requests beyond it
+# and whose spread over the cell's ten runs is at most half its bound
+# (PERF.md 2 holds each cell's runs); the others stay in the ``summary``
+# line.
+TPOT_TAILS = (75, 90, 95)
+
+
 class Record:
     __slots__ = ("spec", "req", "due_t", "submit_t", "first_t", "last_t",
                  "seen", "in_window")
@@ -182,18 +190,21 @@ def window_metrics(done, counts, seconds, closed_loop):
     late = [(r.submit_t - r.due_t) * 1e3 for r in win]
     wait = [(r.req.admit_t - r.due_t) * 1e3 for r in win
             if r.req.admit_t is not None]
+    tails = ({f"p{q}": harness.percentile(tpot, q) for q in TPOT_TAILS}
+             if tpot else {})
     out = {"out_tok_s": counts["window_tokens"] / seconds}
     if not closed_loop and ttft:
         out["ttft_mean_ms"] = sum(ttft) / len(ttft)
-        out["tpot_p95_ms"] = harness.percentile(tpot, 95)
+        out.update({f"tpot_{p}_ms": v for p, v in tails.items()})
     say(summary={"ttft_ms": harness.summary(ttft),
-                 "tpot_ms": harness.summary(tpot),
+                 "tpot_ms": {**harness.summary(tpot), **tails},
                  "generator_late_ms": harness.summary(late),
                  "queue_wait_ms": harness.summary(wait),
                  "window_tokens": counts["window_tokens"],
                  "engine_steps": counts["steps"]})
     return win, out, {"queue_wait_ms": wait, "ttft_ms": ttft,
-                      "tpot_ms": tpot}
+                      "tpot_ms": tpot,
+                      "tpot_tokens": [r.seen for r in win if r.seen > 1]}
 
 
 def pick_sample(win, seed, n):
@@ -248,22 +259,25 @@ def run(ctx):
     cfg, mix = ctx["config"], ctx["mix"]
     spans = harness.Spans(annotate=ctx["trace"])
     gen_cfg = harness.resolve(cfg["program"]["generation_config"])
+    phases = ctx["phases"]
     engine, make_weights = build(ctx, jax)
     vocab = ctx["model"]["vocab_size"]
     gen = harness.plugin("generators", mix["generator"]).Generator(
         mix, ctx["seed"], ctx["seconds"], vocab)
     say(offered=gen.offered(), cell=ctx["cell"]["name"], seed=ctx["seed"])
+    phases.mark("build_s")
     warm_up(engine, gen_cfg, vocab,
             np.random.default_rng([ctx["seed"], 0x3A93]))
+    phases.mark("warm_up_s")
     say(decode_variant=engine.metrics()["decode_variant"],
         prefill_variant=engine.metrics()["prefill_variant"])
     profiler = trace_mod.Profiler(ctx["trace_dir"]) if ctx["trace"] else None
 
     done, counts = serve(ctx, engine, gen, gen_cfg, spans, profiler)
-    setup_s = counts["t_open"] - ctx["t_start"]
+    phases["warm_traffic_s"] = counts["t_open"] - counts["t0"]
     win, e2e, samples = window_metrics(done, counts, ctx["seconds"],
                                        gen.closed)
-    e2e["setup_s"] = setup_s
+    e2e["setup_s"] = counts["t_open"] - ctx["t_imported"]
     facts = counts["engine_metrics"]
     failed = len(counts["unfinished"])
     attempted = len(win) + failed
@@ -285,7 +299,7 @@ def run(ctx):
                "cost_model": harness.plugin("cost_models",
                                             cfg["cost_model"]),
                "trace": profiler.load() if profiler else None,
-               "traced": counts["traced"]}
+               "traced": counts["traced"], "import_s": ctx["import_s"]}
     return {"correct": correct, "attempted": attempted, "failed": failed,
             "end_to_end": e2e, "sources": sources,
             "memory_peak_bytes": memory_peak}

@@ -220,6 +220,7 @@ def run(ctx):
     gen = harness.plugin("generators", mix["generator"]).Generator(
         mix, ctx["seed"], ctx["seconds"], model["vocab_size"])
     say(offered=gen.offered(), cell=ctx["cell"]["name"], seed=ctx["seed"])
+    phases = ctx["phases"]
     trainer, make_weights = build(ctx, jax)
     n_ref = int(ctx["tolerance"]["steps"])
     ref, low = ctx["reference"]["ref"], ctx["reference"]["low"]
@@ -229,11 +230,13 @@ def run(ctx):
                    "losses": ref["losses"]})
 
     state = trainer.init_state(make_weights())
+    phases.mark("build_s")
     feed = trainer.prefetch(gen.batches(), depth=int(mix["prefetch_depth"]))
     try:
         state, got = first_steps(ctx, trainer, state, feed, make_weights,
                                  n_ref)
         say(first_steps=got["losses"], fused_optimizer=bool(trainer._fused))
+        t_stepped = phases.mark("first_steps_s")
         profiler = (trace_mod.Profiler(ctx["trace_dir"]) if ctx["trace"]
                     else None)
         tokens_per_step = gen.offered()["tokens_per_step"]
@@ -247,6 +250,7 @@ def run(ctx):
         for t in threading.enumerate():
             if t.name == "device-prefetch":
                 t.join(timeout=30)
+    phases["warm_steps_s"] = facts["t_open"] - t_stepped
     elapsed = facts["t_close"] - facts["t_open"]
     rate = facts["steps"] * tokens_per_step / elapsed
     say(summary={"steps": facts["steps"], "window_s": elapsed,
@@ -262,9 +266,9 @@ def run(ctx):
                "shape": {"batch": gen.batch, "seq": gen.seq},
                "cost_model": harness.plugin("cost_models",
                                             cfg["cost_model"]),
-               "trace": profiler.load() if profiler else None}
+               "trace": profiler.load() if profiler else None,
+               "import_s": ctx["import_s"]}
     return {"correct": correct, "attempted": facts["steps"], "failed": 0,
             "end_to_end": {"train_tok_s": rate,
-                           "setup_s": facts["t_open"] - ctx["t_start"]
-                           - ctx["reference_s"]},
+                           "setup_s": facts["t_open"] - ctx["t_imported"]},
             "sources": sources, "memory_peak_bytes": memory_peak}

@@ -133,6 +133,25 @@ def peak_table(kind):
 clock = time.perf_counter
 
 
+class Phases(dict):
+    """Lengths of a run's consecutive phases (host clock): ``mark`` ends
+    the phase that began at the last mark; ``skip`` leaves out what ran
+    since (the training reference's process)."""
+
+    def __init__(self, t0):
+        super().__init__()
+        self._t = t0
+
+    def mark(self, name):
+        now = clock()
+        self[name] = now - self._t
+        self._t = now
+        return now
+
+    def skip(self):
+        self._t = clock()
+
+
 class Spans:
     """Host spans of the harness's own calls into the program, kept in
     memory; with ``annotate`` they are also written into the profiler's
@@ -194,6 +213,12 @@ def summary(values):
 
 
 # -- limits of the correctness check -----------------------------------
+#: every row a Check reported in this process: run.py closes its result
+#: line and its standard error with them (what the driver's record keeps
+#: of a run that is not correct)
+COMPARED = []
+
+
 class Check:
     """Each number compared, beside its limit; ``ok`` once all are in."""
 
@@ -219,6 +244,7 @@ class Check:
     def report(self):
         for r in self.rows:
             say(check=r)
+        COMPARED.extend(self.rows)
 
 
 # -- per-layer metrics -------------------------------------------------

@@ -13,7 +13,9 @@ the lower-precision paths the configuration's file lists under
 
 The last line of standard output is the result object; every earlier
 line is one JSON object too (what was offered, summaries, each number
-the correctness check compared beside its limit).
+the correctness check compared beside its limit). The result's last key,
+``compared``, holds those numbers and limits again, and they are the
+last lines of standard error.
 """
 import argparse
 import json
@@ -85,19 +87,30 @@ def context(args, bench, t_start):
 def main(argv=None, t_start=None):
     args = parse(argv)
     t_start = T_START if t_start is None else t_start
+    harness.COMPARED.clear()
     bench = harness.load_benchmark()
     ctx = context(args, bench, t_start)
     cell = ctx["cell"]
 
     driver = harness.plugin("drivers", ctx["config"]["driver"])
+    # the process's start, phase by phase: the interpreter and the
+    # harness's own files, JAX, the chip's client, the program. Their
+    # sum is ``import_s``; ``setup_s`` runs from its end (t_imported)
+    phases = ctx["phases"] = harness.Phases(t_start)
+    phases.mark("harness_s")
     if hasattr(driver, "before_jax"):     # e.g. a reference of its own
         driver.before_jax(ctx, sys.argv[1:] if argv is None else argv)
+        phases.skip()
 
     import jax
+    phases.mark("import_jax_s")
     device = harness.require_chips(jax, cell["chips"], args.rehearse)
+    phases.mark("devices_s")
     ctx["peak"] = (harness.peak_table(device["kind"])
                    if device["platform"] == "tpu" else None)
     import paddle_tpu  # noqa: F401 — the program: x64 mode, compile cache
+    ctx["t_imported"] = phases.mark("import_program_s")
+    ctx["import_s"] = sum(phases.values())
     ctx["compiles"] = harness.CompileCounter()
     harness.say(start={"workload": cell["name"], "seed": args.seed,
                        "seconds": ctx["seconds"], "trace": args.trace,
@@ -109,9 +122,12 @@ def main(argv=None, t_start=None):
         result = driver.run(ctx)
     finally:
         shutil.rmtree(ctx["trace_dir"], ignore_errors=True)
+    harness.say(setup_phases=phases)
     if args.dump:
         with open(args.dump, "w") as fh:
-            json.dump(result["sources"].get("samples"), fh)
+            json.dump({"samples": result["sources"].get("samples"),
+                       "phases": phases,
+                       "end_to_end": result["end_to_end"]}, fh)
 
     e2e = harness.metrics_of(bench, "end_to_end", cell["name"])
     reported = [m["name"] for m in e2e if m["name"] in result["end_to_end"]]
@@ -136,6 +152,16 @@ def main(argv=None, t_start=None):
         line["metrics"] = {n: {"value": float(result["end_to_end"][n]),
                                "unit": units[n]} for n in reported}
     line["device"] = device
+    # each number compared beside its limit: the line's last key and the
+    # last lines of standard error
+    line["compared"] = {
+        r["compared"]: {"value": r["value"] if r["value"] == r["value"]
+                        else None, "limit": r["limit"], "ok": r["ok"]}
+        for r in harness.COMPARED}
+    for name, r in line["compared"].items():
+        print(f"compared: {name}: {r['value']} (limit: {r['limit']})"
+              f"{'' if r['ok'] else ' NOT MET'}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return line
 

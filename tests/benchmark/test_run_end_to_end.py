@@ -26,7 +26,12 @@ def rehearse(capsys, *argv):
 
 def hold_to_contract(line, cell, trace):
     assert set(line) - {"breakdown"} == {"correct", "attempted", "failed",
-                                         "metrics", "device"}
+                                         "metrics", "device", "compared"}
+    # each number compared beside its limit comes last in the line
+    assert list(line)[-1] == "compared" and line["compared"]
+    for row in line["compared"].values():
+        assert set(row) == {"value", "limit", "ok"}
+    assert line["correct"] == all(r["ok"] for r in line["compared"].values())
     assert isinstance(line["correct"], bool)
     assert line["attempted"] > 0 and line["failed"] == 0
     dev = line["device"]
